@@ -1,0 +1,517 @@
+#!/usr/bin/env python3
+"""The quickest proof that paddle_tpu still starts on the chip.
+
+One process drives the two main paths through the entry points a user
+calls, at published widths, on one TPU:
+
+- ``serve``: Llama-3-8B widths (hidden 4096, FFN 14336, 32q/8kv, vocab
+  128256), depth cut to 8 layers, seeded bf16 weights;
+  ``LlamaServingEngine`` with its default (rope-fused) program answers
+  8 requests through ``add_request``/``step``, float pages and int8
+  pages, and is held against ``model.generate`` and a teacher-forced
+  reference on the same chip;
+- ``train``: the "0.5b" recipe of ``examples/llama_pretrain.py``, bf16
+  autocast, a ``jit.to_static`` AdamW step with the flash-attention and
+  fused-CE kernels in the program, 4 steps from a ``TokenFeed``.
+
+``--chips 4`` runs instead (and only) the same train recipe on a
+dp2 x mp2 mesh and its one-device comparison.
+
+Every phase prints one JSON line; a phase that raises ends the run
+non-zero, nothing is caught. Without a TPU the script exits non-zero at
+once. The LAST line of a run that passed is
+``{"ok": true, "device": {...}}``. ``--rehearse`` shrinks every size so
+the control flow can be walked on the CPU; a rehearsal never prints
+that line and never exits 0.
+"""
+
+import argparse
+import gc
+import json
+import os
+import sys
+import tempfile
+import time
+
+import numpy as np
+
+REHEARSAL_EXIT = 4
+
+# first-token / every-token bar of the serve phase: bf16 on the chip
+# breaks exact ties differently in the paged kernel (f32 online softmax)
+# and in generate's XLA attention, and random weights give near-flat
+# logits (top-2 gap ~0.26 at std 1.28 over 128k words), so the engine is
+# held to the reference's logits, not to its argmax: each token the
+# engine emits must score within this many logit units of the
+# reference's best at that position. A wrong token scores ~5 below.
+SERVE_LOGIT_TOL = 0.25
+# |kernel loss - kernel-free loss| at step 1, relative (bf16 autocast)
+TRAIN_LOSS_RTOL = 2e-2
+# sharded vs one-device losses over 4 steps, relative (bf16 autocast)
+MESH_LOSS_RTOL = 2e-2
+
+
+def emit(**obj):
+    print(json.dumps(obj), flush=True)
+
+
+def device_info():
+    import jax
+    d = jax.devices()[0]
+    return {"platform": d.platform, "kind": d.device_kind,
+            "count": len(jax.devices())}
+
+
+def device_bytes():
+    """``{"in_use", "peak", "limit"}`` of the first device's allocator
+    (the peak is the process's so far, not the phase's)."""
+    import jax
+    stats = jax.devices()[0].memory_stats() or {}
+    return {"in_use": stats.get("bytes_in_use"),
+            "peak": stats.get("peak_bytes_in_use"),
+            "limit": stats.get("bytes_limit")}
+
+
+def cache_stats():
+    from paddle_tpu.observability import compile_watch as cw
+    return cw.persistent_cache_stats()
+
+
+def kernel_counts(static_fns, resharded=False):
+    """``{program: count of tpu_custom_call}`` over the AOT executables
+    of the given ``{name: StaticFunction}``. A program that lost its
+    executable is an error, except where ``resharded``: a GSPMD step
+    returns its state in the shardings the partitioner chose, the next
+    call no longer matches the executable's fixed input shardings, and
+    ``jit.to_static`` hands that signature to plain jit (counted None)."""
+    out = {}
+    for name, sf in static_fns.items():
+        for i, compiled in enumerate(sf._aot.values()):
+            if compiled is None and not resharded:
+                raise RuntimeError(f"{name}: no AOT executable")
+            out[f"{name}#{i}"] = None if compiled is None else \
+                compiled.as_text().count("tpu_custom_call")
+    return out
+
+
+def require_kernels(counts, on_chip):
+    """Every program that is meant to hold a kernel holds one (on the
+    CPU the kernels run interpreted: no custom call to count)."""
+    if on_chip:
+        missing = [k for k, n in counts.items() if n == 0]
+        if missing or not counts:
+            raise RuntimeError(f"no tpu_custom_call in {missing or counts}")
+
+
+# ---------------------------------------------------------------------------
+# serve
+# ---------------------------------------------------------------------------
+def serve_programs(engine):
+    fns = {"serving.mixed_step": engine._mixed_static}
+    fns.update({f"serving.mixed_scan[{n}]": sf
+                for n, sf in engine._scan_static.items()})
+    return fns
+
+
+def drive(engine, prompts, new_tokens):
+    """Submit every request, then step until all are done."""
+    from paddle_tpu.inference.serving import Request
+    reqs = [Request(p, max_new_tokens=new_tokens) for p in prompts]
+    for r in reqs:
+        engine.add_request(r)
+    steps = 0
+    while not all(r.done for r in reqs):
+        engine.step()
+        steps += 1
+        if steps > 100 * new_tokens * len(prompts):
+            raise RuntimeError("serving engine made no progress")
+    bad = [r.status for r in reqs if r.status != "completed"]
+    if bad:
+        raise RuntimeError(f"requests ended {bad}")
+    return [list(r.output_ids) for r in reqs]
+
+
+def phase_serve(seed, rehearse, on_chip):
+    import jax
+    import jax.numpy as jnp
+    import paddle_tpu as paddle
+    from paddle_tpu.inference.serving import LlamaServingEngine
+    from paddle_tpu.models import (LlamaForCausalLM, llama3_8b_config,
+                                   tiny_llama_config)
+
+    if rehearse:
+        cfg = tiny_llama_config()
+        lengths, new_tokens, page_size, dtype = (8, 41), 4, 8, "float32"
+        n_req = 3
+    else:
+        cfg = llama3_8b_config()
+        cfg.num_hidden_layers = 8
+        lengths, new_tokens, page_size, dtype = (64, 1025), 32, 16, \
+            "bfloat16"
+        n_req = 8
+    rng = np.random.RandomState(seed)
+    prompts = [rng.randint(0, cfg.vocab_size,
+                           (int(rng.randint(*lengths)),)).tolist()
+               for _ in range(n_req)]
+    pages_per_seq = -(-(max(map(len, prompts)) + new_tokens)
+                      // page_size) + 1
+    geometry = dict(max_batch=16, page_size=page_size,
+                    num_pages=n_req * pages_per_seq + 9,
+                    max_pages_per_seq=pages_per_seq)
+
+    t0 = time.perf_counter()
+    paddle.seed(seed)
+    paddle.set_default_dtype(dtype)
+    model = LlamaForCausalLM(cfg)
+    paddle.set_default_dtype("float32")
+    model.eval()
+    jax.block_until_ready([p._data for p in model.parameters()])
+    build_s = time.perf_counter() - t0
+
+    # -- float pages: the engine's default program --------------------
+    engine = LlamaServingEngine(model, **geometry)
+    if on_chip and not engine.fused_rope:
+        raise RuntimeError("engine did not pick its rope-fused program")
+    t0 = time.perf_counter()
+    outs = drive(engine, prompts, new_tokens)
+    first_s = time.perf_counter() - t0      # compiles included
+    t0 = time.perf_counter()
+    again = drive(engine, prompts, new_tokens)
+    warm_s = time.perf_counter() - t0
+    if again != outs:
+        raise RuntimeError("the same engine answered differently twice")
+    counts = kernel_counts(serve_programs(engine))
+    require_kernels(counts, on_chip)
+    engine.close()
+
+    # -- reference 1: model.generate, token for token ------------------
+    max_len = -(-(max(map(len, prompts)) + new_tokens) // 64) * 64
+    t0 = time.perf_counter()
+    exact = []
+    for p, o in zip(prompts, outs):
+        ids = paddle.to_tensor(np.asarray([p], np.int64))
+        ref = np.asarray(model.generate(ids, max_new_tokens=new_tokens,
+                                        max_length=max_len)._data)
+        ref = ref[0, len(p):].tolist()
+        same = [a == b for a, b in zip(o, ref)]
+        exact.append({"first_token": same[0],
+                      "agree_until": same.index(False)
+                      if False in same else len(same)})
+    generate_s = time.perf_counter() - t0
+
+    # -- reference 2: teacher-forced logits ----------------------------
+    # one forward over prompt + engine output (padded to max_len: a
+    # causal model's earlier positions do not see the pad) gives the
+    # reference logits at every position the engine sampled from
+    def ref_logits(ids, pos):
+        hidden = model.model(ids)
+        rows = paddle.gather(hidden.reshape([max_len, -1]), pos, axis=0)
+        return model._logits(rows).astype("float32")
+
+    ref_fn = paddle.jit.to_static(ref_logits, state=[model], donate=False,
+                                  warmup="once")
+    ref_fn._warmed_any = True       # no lazy state to materialize
+    margins = []
+    for p, o in zip(prompts, outs):
+        ids = np.zeros((1, max_len), np.int64)
+        ids[0, :len(p) + len(o)] = p + o
+        pos = np.arange(len(p) - 1, len(p) - 1 + len(o), dtype=np.int32)
+        lg = ref_fn(paddle.to_tensor(ids), paddle.to_tensor(pos))._data
+        took = lg[jnp.arange(len(o)), jnp.asarray(o)]
+        margins.append(np.asarray(lg.max(axis=-1) - took))
+        if not np.isfinite(np.asarray(lg)).all():
+            raise RuntimeError("reference logits are not finite")
+    worst = float(max(m.max() for m in margins))
+    worst_first = float(max(m[0] for m in margins))
+
+    emit(phase="serve", device=device_info(), model="llama3-8b widths",
+         shapes=dict(hidden=cfg.hidden_size, ffn=cfg.intermediate_size,
+                     heads=cfg.num_attention_heads,
+                     kv_heads=cfg.num_key_value_heads,
+                     vocab=cfg.vocab_size, layers=cfg.num_hidden_layers,
+                     dtype=dtype, prompt_lens=[len(p) for p in prompts],
+                     new_tokens=new_tokens, **geometry),
+         params=model.num_params(), build_seconds=build_s,
+         first_drive_seconds=first_s, warm_drive_seconds=warm_s,
+         generate_seconds=generate_s, kernels=counts, cache=cache_stats(),
+         device_bytes=device_bytes(),
+         compared={"with": "model.generate (token for token) and "
+                           "teacher-forced reference logits",
+                   "vs_generate": exact,
+                   "requests_token_exact": sum(
+                       e["agree_until"] == new_tokens for e in exact),
+                   "first_tokens_equal": sum(
+                       e["first_token"] for e in exact),
+                   "worst_logit_margin": worst,
+                   "worst_first_token_margin": worst_first,
+                   "logit_tolerance": SERVE_LOGIT_TOL})
+    if not worst <= SERVE_LOGIT_TOL:
+        raise RuntimeError(
+            f"an engine token scores {worst} below the reference's best "
+            f"(tolerance {SERVE_LOGIT_TOL})")
+
+    # -- int8 pages: held against int8, never against float pages ------
+    t0 = time.perf_counter()
+    q8 = []
+    for _ in range(2):
+        e8 = LlamaServingEngine(model, kv_dtype="int8", **geometry)
+        q8.append(drive(e8, prompts, new_tokens))
+        counts8 = kernel_counts(serve_programs(e8))
+        e8.close()
+    require_kernels(counts8, on_chip)
+    if q8[0] != q8[1]:
+        raise RuntimeError("two fresh int8-KV engines disagree")
+    flat = [(a, b) for o8, o in zip(q8[0], outs) for a, b in zip(o8, o)]
+    emit(phase="serve_int8_kv", seconds=time.perf_counter() - t0,
+         kernels=counts8, cache=cache_stats(),
+         device_bytes=device_bytes(),
+         compared={"with": "a second fresh int8-KV engine",
+                   "token_exact": True,
+                   "share_equal_to_float_pages":
+                       sum(a == b for a, b in flat) / len(flat)})
+
+
+# ---------------------------------------------------------------------------
+# train
+# ---------------------------------------------------------------------------
+def train_config(rehearse):
+    from paddle_tpu.models import LlamaConfig, tiny_llama_config
+    if rehearse:
+        # seq 128 is the shortest the flash kernel takes
+        return tiny_llama_config(max_position_embeddings=256), 128, 2
+    return LlamaConfig(
+        vocab_size=32000, hidden_size=2048, intermediate_size=5632,
+        num_hidden_layers=8, num_attention_heads=16,
+        num_key_value_heads=8, max_position_embeddings=4096), 2048, 2
+
+
+def token_batches(cfg, seq, batch, steps, seed, workdir):
+    """``steps`` batches of ``[batch, seq + 1]`` ids through the repo's
+    ``TokenFeed`` over a corpus made from ``seed``; also the name of the
+    feed that served them."""
+    from paddle_tpu import native
+    from paddle_tpu.io import TokenFeed
+    path = os.path.join(workdir, "corpus.bin")
+    np.random.RandomState(seed).randint(
+        0, cfg.vocab_size, (4 * steps * batch, seq + 1)) \
+        .astype(np.int32).tofile(path)
+    feed = TokenFeed(path, sample_elems=seq + 1, batch_size=batch,
+                     dtype=np.int32, seed=seed)
+    name = type(feed).__module__ + "." + type(feed).__name__
+    if not native.available():
+        name += f" (numpy feed: {native.load_error()})"
+    out = [np.array(next(feed), np.int64) for _ in range(steps)]
+    feed.close()
+    return out, name
+
+
+def make_step(model, opt, donate_inputs):
+    import paddle_tpu as paddle
+
+    def step_fn(ids, labels):
+        with paddle.amp.auto_cast(dtype="bfloat16"):
+            loss, _ = model(ids, labels)
+        loss.backward()
+        opt.step()
+        opt.clear_grad()
+        return loss
+
+    return paddle.jit.to_static(step_fn, state=[model, opt], warmup="once",
+                                donate_inputs=donate_inputs,
+                                name="chip_smoke.train_step")
+
+
+def warm_optimizer(compiled, cfg, place):
+    """The eager warmup of ``jit.to_static`` on a tiny shape (as
+    examples/llama_pretrain.py does): materializes the AdamW state
+    without paying a full-size eager pass."""
+    ids = np.random.RandomState(0).randint(
+        0, cfg.vocab_size, (2, 129)).astype(np.int64)
+    compiled(place(ids[:, :-1]), place(ids[:, 1:]))
+
+
+def phase_train(seed, rehearse, on_chip):
+    import paddle_tpu as paddle
+    from paddle_tpu import flags
+    from paddle_tpu.models import LlamaForCausalLM
+
+    cfg, seq, batch = train_config(rehearse)
+    steps = 4
+    with tempfile.TemporaryDirectory() as workdir:
+        batches, feed_name = token_batches(cfg, seq, batch, steps, seed,
+                                           workdir)
+    paddle.seed(seed)
+    model = LlamaForCausalLM(cfg)
+    opt = paddle.optimizer.AdamW(learning_rate=3e-4, weight_decay=0.1,
+                                 parameters=model.parameters())
+
+    # the kernel-free reference for step 1: the same forward loss on the
+    # same weights with the flash kernel off and the logits materialized
+    def ref_loss(ids, labels):
+        with paddle.no_grad(), paddle.amp.auto_cast(dtype="bfloat16"):
+            loss, _ = model(ids, labels)
+        return loss
+
+    ref_fn = paddle.jit.to_static(ref_loss, state=[model], donate=False,
+                                  warmup="once",
+                                  name="chip_smoke.train_reference")
+    ref_fn._warmed_any = True
+    x0 = paddle.to_tensor(batches[0][:, :-1])
+    y0 = paddle.to_tensor(batches[0][:, 1:])
+    flags.set_flags({"use_pallas_kernels": False})
+    os.environ["PADDLE_TPU_FUSED_CE"] = "0"
+    try:
+        reference = float(ref_fn(x0, y0))
+    finally:
+        flags.set_flags({"use_pallas_kernels": True})
+        del os.environ["PADDLE_TPU_FUSED_CE"]
+    ref_kernels = kernel_counts({"train_reference": ref_fn})
+    if any(ref_kernels.values()):
+        raise RuntimeError(f"the reference holds a kernel: {ref_kernels}")
+
+    compiled = make_step(model, opt, donate_inputs=True)
+    warm_optimizer(compiled, cfg, paddle.to_tensor)
+    losses, seconds = [], []
+    for b in batches:
+        t0 = time.perf_counter()
+        losses.append(float(compiled(paddle.to_tensor(b[:, :-1]),
+                                     paddle.to_tensor(b[:, 1:]))))
+        seconds.append(time.perf_counter() - t0)
+    counts = kernel_counts({"train_step": compiled})
+    # the warmup compiled nothing (eager), so every program is full size
+    require_kernels(counts, on_chip)
+    emit(phase="train", model="0.5b recipe (examples/llama_pretrain.py)",
+         shapes=dict(hidden=cfg.hidden_size, ffn=cfg.intermediate_size,
+                     heads=cfg.num_attention_heads,
+                     kv_heads=cfg.num_key_value_heads,
+                     vocab=cfg.vocab_size, layers=cfg.num_hidden_layers,
+                     seq=seq, batch=batch, amp="bfloat16"),
+         params=model.num_params(), token_feed=feed_name, losses=losses,
+         first_step_seconds=seconds[0], later_step_seconds=seconds[1:],
+         kernels=counts, cache=cache_stats(),
+         device_bytes=device_bytes(),
+         compared={"with": "forward loss of step 1, flash kernel off, "
+                           "logits materialized (no kernel in program)",
+                   "reference_loss": reference, "step1_loss": losses[0],
+                   "rtol": TRAIN_LOSS_RTOL})
+    if not np.isfinite(losses).all():
+        raise RuntimeError(f"loss is not finite: {losses}")
+    if abs(losses[0] - reference) > TRAIN_LOSS_RTOL * abs(reference):
+        raise RuntimeError(
+            f"step-1 loss {losses[0]} vs kernel-free {reference}")
+
+
+# ---------------------------------------------------------------------------
+# four chips: the sharded train step and its one-device comparison
+# ---------------------------------------------------------------------------
+def mesh_losses(cfg, batches, seed, mesh):
+    import jax
+    import paddle_tpu as paddle
+    from paddle_tpu.distributed import Replicate, Shard, shard_tensor
+    from paddle_tpu.models import LlamaForCausalLM, shard_llama
+
+    paddle.seed(seed)
+    model = LlamaForCausalLM(cfg)
+    place = paddle.to_tensor
+    if mesh is not None:
+        shard_llama(model, mesh, tp_axis="mp")
+
+        def place(a):
+            return shard_tensor(paddle.to_tensor(a), mesh,
+                                [Shard(0), Replicate()],
+                                stop_gradient=True)
+
+    opt = paddle.optimizer.AdamW(learning_rate=3e-4, weight_decay=0.1,
+                                 parameters=model.parameters())
+    compiled = make_step(model, opt, donate_inputs=False)
+    warm_optimizer(compiled, cfg, place)
+    t0 = time.perf_counter()
+    losses = [float(compiled(place(b[:, :-1]), place(b[:, 1:])))
+              for b in batches]
+    seconds = time.perf_counter() - t0
+    devices = sorted({s.device.id for p in model.parameters()
+                      for s in p._data.addressable_shards})
+    sharded = sum(len({s.device.id for s in p._data.addressable_shards
+                       if s.data.shape != p._data.shape}) > 1
+                  for p in model.parameters())
+    counts = kernel_counts({"train_step": compiled},
+                           resharded=mesh is not None)
+    del compiled, opt, model
+    jax.clear_caches()
+    return losses, seconds, devices, sharded, counts
+
+
+def phase_mesh(seed, rehearse, on_chip):
+    import jax
+    from paddle_tpu.distributed import ProcessMesh
+
+    if len(jax.devices()) != 4:
+        raise RuntimeError(f"--chips 4 found {len(jax.devices())} devices")
+    cfg, seq, batch = train_config(rehearse)
+    with tempfile.TemporaryDirectory() as workdir:
+        batches, feed_name = token_batches(cfg, seq, batch, 4, seed,
+                                           workdir)
+    mesh = ProcessMesh(np.arange(4).reshape(2, 2), dim_names=["dp", "mp"])
+    sharded, s_sec, s_dev, n_split, s_k = mesh_losses(cfg, batches, seed,
+                                                      mesh)
+    single, o_sec, o_dev, _, o_k = mesh_losses(cfg, batches, seed, None)
+    emit(phase="mesh", mesh={"dp": 2, "mp": 2},
+         model="0.5b recipe (examples/llama_pretrain.py)",
+         shapes=dict(seq=seq, batch=batch, amp="bfloat16"),
+         token_feed=feed_name, sharded_losses=sharded,
+         one_device_losses=single, sharded_seconds=s_sec,
+         one_device_seconds=o_sec, parameter_devices=s_dev,
+         one_device_parameter_devices=o_dev,
+         parameters_split_across_devices=n_split,
+         kernels={"sharded": s_k, "one_device": o_k}, cache=cache_stats(),
+         device_bytes=device_bytes(),
+         compared={"with": "the same 4 steps on one device",
+                   "rtol": MESH_LOSS_RTOL})
+    if len(s_dev) != 4 or n_split == 0:
+        raise RuntimeError(
+            f"parameters lie on devices {s_dev}, {n_split} of them split")
+    if not np.isfinite(sharded).all():
+        raise RuntimeError(f"loss is not finite: {sharded}")
+    np.testing.assert_allclose(sharded, single, rtol=MESH_LOSS_RTOL)
+    require_kernels(o_k, on_chip)
+
+
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--chips", type=int, default=1, choices=(1, 4),
+                    help="4: only the dp2 x mp2 train step and its "
+                         "one-device comparison")
+    ap.add_argument("--rehearse", action="store_true",
+                    help="tiny sizes on whatever backend there is; "
+                         "never prints the success line, exits "
+                         f"{REHEARSAL_EXIT}")
+    args = ap.parse_args()
+
+    import jax
+    on_chip = jax.devices()[0].platform == "tpu"
+    if not on_chip and not args.rehearse:
+        sys.exit(f"chip_smoke: no TPU (jax found "
+                 f"{jax.devices()[0].platform}); nothing was run")
+    if len(jax.devices()) != args.chips and not args.rehearse:
+        sys.exit(f"chip_smoke: --chips {args.chips} but jax found "
+                 f"{len(jax.devices())} devices; nothing was run")
+
+    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+    t0 = time.perf_counter()
+    if args.chips == 4:
+        phase_mesh(args.seed, args.rehearse, on_chip)
+    else:
+        phase_serve(args.seed, args.rehearse, on_chip)
+        gc.collect()        # the 8B-width weights leave before training
+        phase_train(args.seed, args.rehearse, on_chip)
+    emit(phase="all", seconds=time.perf_counter() - t0,
+         cache=cache_stats())
+    if args.rehearse:
+        emit(ok=False, rehearsal=True, device=device_info())
+        sys.exit(REHEARSAL_EXIT)
+    emit(ok=True, device=device_info())
+
+
+if __name__ == "__main__":
+    main()

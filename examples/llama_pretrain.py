@@ -12,8 +12,7 @@ loss rides the chunked fused cross-entropy lm-head by default
 stacked expert weights over the second mesh axis (expert parallelism).
 
 CPU sanity (8 virtual chips):
-  env -u PYTHONPATH JAX_PLATFORMS=cpu \
-  XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+  JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
   python examples/llama_pretrain.py --config tiny --mesh 2x4 --steps 8
 
 Expert-parallel MoE pretraining (same virtual mesh):

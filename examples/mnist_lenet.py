@@ -6,7 +6,7 @@ streaming `paddle.metric.Accuracy` and callback-reported progress —
 the reference's `hapi/model.py:1750` usage shape.
 
 Run:  python examples/mnist_lenet.py [--epochs 5] [--eager]
-CPU:  env -u PYTHONPATH JAX_PLATFORMS=cpu python examples/mnist_lenet.py
+CPU:  JAX_PLATFORMS=cpu python examples/mnist_lenet.py
 """
 
 import argparse

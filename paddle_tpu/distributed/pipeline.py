@@ -32,13 +32,8 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec
-# the experimental path still accepts check_rep (the jax.shard_map
-# replacement renamed it check_vma); silence its deprecation locally
-import warnings as _warnings
-with _warnings.catch_warnings():
-    _warnings.simplefilter("ignore", DeprecationWarning)
-    from jax.experimental.shard_map import shard_map
 
 from .process_mesh import ProcessMesh
 
@@ -211,7 +206,7 @@ def _build_run(stage_fn, jmesh, axis, M, remat, treedef, V=1,
 
     inner = shard_map(per_device, mesh=jmesh,
                       in_specs=(p_spec, PartitionSpec()),
-                      out_specs=PartitionSpec(), check_rep=False)
+                      out_specs=PartitionSpec(), check_vma=False)
 
     def run(flat_params, x):
         params = jax.tree_util.tree_unflatten(treedef, list(flat_params))
@@ -356,7 +351,7 @@ def _build_1f1b(stage_fn, loss_fn, jmesh, axis, M, treedef):
     inner = shard_map(per_device, mesh=jmesh,
                       in_specs=(p_spec, PartitionSpec(), PartitionSpec()),
                       out_specs=(PartitionSpec(), p_spec),
-                      check_rep=False)
+                      check_vma=False)
 
     def run(flat_params, x, y):
         params = jax.tree_util.tree_unflatten(treedef, list(flat_params))

@@ -37,7 +37,7 @@ from jax.sharding import PartitionSpec
 
 from ..framework.tensor import run_op
 from .process_mesh import ProcessMesh
-from .pipeline import shard_map
+from jax import shard_map
 
 __all__ = ["ring_attention", "ulysses_attention",
            "zigzag_reorder", "zigzag_restore"]
@@ -110,7 +110,7 @@ def _build_ring(jmesh, axis, causal, scale):
     seq_spec = PartitionSpec(None, axis, None, None)
     inner = shard_map(per_device, mesh=jmesh,
                       in_specs=(seq_spec, seq_spec, seq_spec),
-                      out_specs=seq_spec, check_rep=False)
+                      out_specs=seq_spec, check_vma=False)
     return jax.jit(inner)
 
 
@@ -171,7 +171,7 @@ def _build_ulysses(jmesh, axis, causal, scale, use_flash):
     seq_spec = PartitionSpec(None, axis, None, None)
     inner = shard_map(per_device, mesh=jmesh,
                       in_specs=(seq_spec, seq_spec, seq_spec),
-                      out_specs=seq_spec, check_rep=False)
+                      out_specs=seq_spec, check_vma=False)
     return jax.jit(inner)
 
 
@@ -318,5 +318,5 @@ def _build_ring_zigzag(jmesh, axis, scale):
     seq_spec = PartitionSpec(None, axis, None, None)
     inner = shard_map(per_device, mesh=jmesh,
                       in_specs=(seq_spec, seq_spec, seq_spec),
-                      out_specs=seq_spec, check_rep=False)
+                      out_specs=seq_spec, check_vma=False)
     return jax.jit(inner)

@@ -2,7 +2,7 @@
 
 Reference: `paddle/common/enforce.h` (PADDLE_ENFORCE_* macros raising
 typed EnforceNotMet errors with operator context) and
-`paddle/phi/core/errors.h` (the error-code taxonomy). Python analog:
+`paddle/phi/core/errors.h` (the error-code classes). Python analog:
 typed exception classes + ``enforce``/``check_type``/``check_dtype``
 helpers, and operator context attached to any exception crossing the
 eager dispatch seam (``run_op`` adds a PEP-678 note naming the op), so

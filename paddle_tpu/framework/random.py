@@ -24,15 +24,25 @@ __all__ = ["seed", "get_rng_state", "set_rng_state", "next_key", "rng_guard",
 
 
 class Generator:
-    """Stateful PRNG source backed by a JAX key."""
+    """Stateful PRNG source backed by a JAX key. The key is made on
+    first use, not at construction: building one initializes the JAX
+    backend, and ``import paddle_tpu`` (which constructs
+    ``default_generator``) must leave the chip to whichever process
+    goes on to use it."""
 
-    def __init__(self, seed_val: int = 0):
-        self._key = jax.random.key(seed_val)
+    def __init__(self, seed_val: int = 0, key=None):
         self._seed = seed_val
+        self._state = key
+
+    @property
+    def _key(self):
+        if self._state is None:
+            self._state = jax.random.key(self._seed)
+        return self._state
 
     def manual_seed(self, seed_val: int):
-        self._key = jax.random.key(seed_val)
         self._seed = seed_val
+        self._state = None
         return self
 
     def initial_seed(self):
@@ -42,10 +52,10 @@ class Generator:
         return self._key
 
     def set_state(self, state):
-        self._key = state
+        self._state = state
 
     def next(self):
-        self._key, sub = jax.random.split(self._key)
+        self._state, sub = jax.random.split(self._key)
         return sub
 
 
@@ -85,8 +95,7 @@ def rng_guard(key):
     Used by ``paddle_tpu.jit`` so random ops inside a traced step consume a
     traced key instead of baking host randomness into the compiled program.
     """
-    gen = Generator(0)
-    gen._key = key
+    gen = Generator(key=key)
     _guard_stack.append(gen)
     try:
         yield gen
@@ -108,8 +117,7 @@ def rng_state(name: str = "global"):
     staying reproducible.
     """
     base = _current()
-    gen = Generator(0)
-    gen._key = fold_in_name(base.next(), name)
+    gen = Generator(key=fold_in_name(base.next(), name))
     _guard_stack.append(gen)
     try:
         yield gen

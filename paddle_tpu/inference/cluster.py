@@ -1385,7 +1385,7 @@ class ServingCluster:
         spawn_grace: seconds a subprocess may spend starting (imports +
             compiles) before a missing membership stamp means "wedged".
         subprocess_env: extra environment for worker processes (e.g.
-            ``PADDLE_TPU_COMPILE_CACHE_DIR`` so replicas share a warm
+            ``JAX_COMPILATION_CACHE_DIR`` so replicas share a warm
             cache).
         log_dir: per-worker stdout/stderr log files (default: discard).
     """

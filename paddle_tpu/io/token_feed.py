@@ -15,6 +15,7 @@ from __future__ import annotations
 import queue
 import threading
 import time
+import warnings
 
 import numpy as np
 
@@ -226,7 +227,13 @@ class DevicePrefetcher:
 def TokenFeed(path, sample_elems, batch_size, dtype=np.int32, shuffle=True,
               seed=0, prefetch_depth=4, epochs=-1):
     """Factory: the native prefetching feed when buildable, else the
-    numpy fallback. Both yield ``[batch_size, sample_elems]`` arrays."""
-    cls = _native.TokenFeed if _native.available() else PyTokenFeed
+    numpy fallback — which says so, with the reason the native library
+    gave. Both yield ``[batch_size, sample_elems]`` arrays."""
+    if _native.available():
+        cls = _native.TokenFeed
+    else:
+        warnings.warn("TokenFeed: native feed unavailable "
+                      f"({_native.load_error()}); using the numpy feed")
+        cls = PyTokenFeed
     return cls(path, sample_elems, batch_size, dtype=dtype, shuffle=shuffle,
                seed=seed, prefetch_depth=prefetch_depth, epochs=epochs)

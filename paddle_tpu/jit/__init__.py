@@ -226,12 +226,18 @@ class StaticFunction:
 
     # -- the traced pure step ----------------------------------------------
     def _build(self, in_treedef):
+        from ..observability import compile_watch as _cw
+
+        # train steps get the same persistent compile cache the serving
+        # engine does (idempotent; one function places it for both)
+        _cw.enable_persistent_cache()
         state_tensors = self._state_tensors
         optimizers = self._optimizers
         fn = self._fn
         grad_idx = [i for i, t in enumerate(state_tensors)
                     if t.grad is not None]
         out_box = {}
+        donate_state = self._donate
 
         def pure_step(state, grads, in_arrays, lrs, key):
             saved = [(t._data, t.grad, t._node) for t in state_tensors]
@@ -257,6 +263,13 @@ class StaticFunction:
                     if state_tensors[i].grad is not None
                     else jnp.zeros_like(new_state[i])
                     for i in grad_idx]
+                if not donate_state:
+                    # read-only state (serving weights) stays out of the
+                    # outputs: an AOT executable returns a non-donated
+                    # pass-through as a fresh COPY — every weight, every
+                    # dispatch. None marks "unchanged" for __call__.
+                    new_state = [None if new is old else new
+                                 for new, old in zip(new_state, state)]
                 flat_out, out_treedef = jax.tree_util.tree_flatten(
                     out, is_leaf=lambda x: isinstance(x, Tensor))
                 flat_out = [o._data if isinstance(o, Tensor) else o
@@ -269,7 +282,7 @@ class StaticFunction:
                 for o, ov in zip(optimizers, overrides):
                     o._lr_override = ov
 
-        donate = (0, 1) if self._donate else ()
+        donate = (0, 1) if donate_state else ()
         if self._donate_inputs:
             donate = donate + (2,)
         return jax.jit(pure_step, donate_argnums=donate), grad_idx, out_box
@@ -326,8 +339,9 @@ class StaticFunction:
             new_state, new_grads, flat_out, _ = self._dispatch(
                 sig, jitted, step_args)
         for t, a in zip(self._state_tensors, new_state):
-            t._data = a
-            t._node = None
+            if a is not None:       # None: the step left it unchanged
+                t._data = a
+                t._node = None
         for i, g in zip(grad_idx, new_grads):
             self._state_tensors[i].grad = Tensor(g, stop_gradient=True)
         outs = [Tensor(a, stop_gradient=True) if isinstance(a, jax.Array)
@@ -371,17 +385,12 @@ class StaticFunction:
         compiled = self._aot.get(sig)
         if compiled is None:
             if sig in self._aot:
-                # AOT unsupported for this program: bail before touching
-                # the watch lock or building the descriptor — this runs
-                # per dispatch on the hot path
+                # state avals drifted earlier (see below): bail before
+                # touching the watch lock or building the descriptor —
+                # this runs per dispatch on the hot path
                 return jitted(*step_args)
-            w = _cw.watch(self._watch_name)
-            desc = self._sig_desc(sig)
-            compiled = w.aot_compile(jitted, step_args, desc=desc)
-            self._aot[sig] = compiled
-            if compiled is None:    # fall back, still count the compile
-                return w.timed_first_dispatch(jitted, step_args,
-                                              desc=desc)
+            compiled = self._aot[sig] = _cw.watch(self._watch_name) \
+                .aot_compile(jitted, step_args, desc=self._sig_desc(sig))
         try:
             from ..observability import perf as _perf
 

@@ -352,6 +352,45 @@ class LlamaMoEMLP(nn.Layer):
         return out.reshape(shape)
 
 
+def _mesh_attention(q, k, v, mesh, batch_axes, tp_axis):
+    """Causal attention of a GSPMD-sharded model, one device's share at
+    a time: batch splits over ``batch_axes`` and heads over ``tp_axis``
+    (attention mixes neither), each where the axis divides it, and the
+    per-device body picks the flash kernel or the XLA composition by
+    the same rule as ``scaled_dot_product_attention``. Without the
+    ``shard_map`` the chip's compiler refuses the program: a Mosaic
+    kernel cannot be partitioned automatically."""
+    from .. import flags
+    from ..framework.tensor import run_op
+    from ..nn.functional.attention import _naive_attention
+    from ..ops import flash_attention as fa
+
+    b, _, h, d = q.shape
+    hk = k.shape[2]
+    sizes = dict(zip(mesh.dim_names, mesh.shape))
+    batch = tuple(a for a in batch_axes if b % sizes[a] == 0)
+    n_batch = math.prod(sizes[a] for a in batch)
+    if b % n_batch:
+        batch = ()
+    heads = tp_axis if tp_axis and h % sizes[tp_axis] == 0 \
+        and hk % sizes[tp_axis] == 0 else None
+    spec = jax.sharding.PartitionSpec(batch or None, None, heads, None)
+    use_pallas = flags.flag("use_pallas_kernels")
+
+    def local(q_, k_, v_):
+        if use_pallas and fa.supported(q_, k_, v_, None, True):
+            return fa._make_flash(1.0 / math.sqrt(d), True,
+                                  q_.shape[2] // k_.shape[2])(q_, k_, v_)
+        return _naive_attention(q_, k_, v_, None, 0.0, True, None)
+
+    def fn(q_, k_, v_):
+        return jax.shard_map(local, mesh=mesh.to_jax_mesh(),
+                             in_specs=(spec,) * 3, out_specs=spec,
+                             check_vma=False)(q_, k_, v_)
+
+    return run_op("mesh_attention", fn, (q, k, v))
+
+
 class LlamaAttention(nn.Layer):
     """GQA attention with rotary embeddings; [B, S, H, D] layout throughout
     so the Pallas flash kernel path needs no relayout."""
@@ -372,6 +411,10 @@ class LlamaAttention(nn.Layer):
                                 bias_attr=False)
         self.o_proj = nn.Linear(h * d, config.hidden_size, weight_attr=wa,
                                 bias_attr=False)
+        #: set by shard_llama: (ProcessMesh, batch axes, tp axis) — the
+        #: training attention then runs per device under shard_map (a
+        #: Mosaic kernel cannot be partitioned by GSPMD)
+        self.mesh_spec = None
 
     def forward(self, x, position_ids=None, cache=None, cache_len=None,
                 attn_mask=None):
@@ -409,7 +452,10 @@ class LlamaAttention(nn.Layer):
                                                  attn_mask=attn_mask)
             out = self.o_proj(out.reshape([b, s, h * d]))
             return out, (k_buf, v_buf)
-        out = F.scaled_dot_product_attention(q, k, v, is_causal=True)
+        if self.mesh_spec is not None:
+            out = _mesh_attention(q, k, v, *self.mesh_spec)
+        else:
+            out = F.scaled_dot_product_attention(q, k, v, is_causal=True)
         return self.o_proj(out.reshape([b, s, h * d]))
 
 
@@ -741,6 +787,8 @@ def shard_llama(model: LlamaForCausalLM, mesh, tp_axis="mp",
         a.k_proj.weight = place(a.k_proj.weight, 1, 0)
         a.v_proj.weight = place(a.v_proj.weight, 1, 0)
         a.o_proj.weight = place(a.o_proj.weight, 0, 1)
+        a.mesh_spec = (mesh, tuple(n for n in mesh.dim_names
+                                   if n not in (tp_axis, ep_axis)), tp_axis)
         if isinstance(mlp, LlamaMoEMLP):
             # stacked [E, in, out] expert weights: tp splits the FFN
             # width exactly like the dense column/row layout; the

@@ -24,11 +24,16 @@ import numpy as np
 
 from . import build as _build
 
-__all__ = ["available", "TCPStore", "TokenFeed"]
+__all__ = ["available", "load_error", "TCPStore", "TokenFeed"]
 
 
 def available():
     return _build.load() is not None
+
+
+def load_error():
+    """Why the native library is unavailable (None when it loaded)."""
+    return _build.load_error()
 
 
 def _lib():

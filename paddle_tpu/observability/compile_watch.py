@@ -198,27 +198,35 @@ _cache_dir = None
 _cache_lock = threading.Lock()
 
 
+#: the checkout's own cache, used when ``JAX_COMPILATION_CACHE_DIR``
+#: does not place it: a fixed path (the path is part of the cache key,
+#: so a directory that moves never hits), git-ignored
+_CHECKOUT_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
 def default_cache_dir():
-    """Default persistent-cache location: ``PADDLE_TPU_COMPILE_CACHE_DIR``
-    or ``~/.cache/paddle_tpu/xla_cache``."""
-    return os.environ.get("PADDLE_TPU_COMPILE_CACHE_DIR") \
-        or os.path.join(os.path.expanduser("~"), ".cache", "paddle_tpu",
-                        "xla_cache")
+    """Persistent-cache location: ``JAX_COMPILATION_CACHE_DIR`` (jax's
+    own variable) or the fixed ``<checkout>/.jax_cache``."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or _CHECKOUT_CACHE
 
 
-def enable_persistent_cache(path=None):
+def enable_persistent_cache():
     """Wire JAX's persistent compilation cache (ROADMAP item 5: kill the
     ~19 s cold start). Every backend compile is keyed by its HLO and
-    stored under ``path``; a fresh process re-compiling the same serving
-    programs (mixed-step shapes, decode scans) gets executables back in
-    seconds. Called once per process by the serving engine — set
-    ``PADDLE_TPU_COMPILE_CACHE=0`` to opt out, or
-    ``PADDLE_TPU_COMPILE_CACHE_DIR`` to relocate (replicas sharing a
+    stored in :func:`default_cache_dir`; a fresh process re-compiling
+    the same programs (serving mixed-step shapes, decode scans, a
+    ``jit.to_static`` train step) gets executables back in seconds.
+    Called by the serving engine and by the ``jit.to_static``/hapi train
+    step alike — set ``PADDLE_TPU_COMPILE_CACHE=0`` to opt out, or
+    ``JAX_COMPILATION_CACHE_DIR`` to place it (jax reads that variable
+    itself, so no directory is set in code then; replicas sharing a
     host should share the directory). ``min_compile_time_secs`` is
     forced to 0 so even small programs cache — elastic restart is about
     the SUM of compiles, not the largest one.
 
-    Returns the cache directory, or None when disabled/unavailable.
+    Returns the cache directory, or None when disabled or unwritable.
     Idempotent; hit/miss land in ``compile_cache_hit_total`` /
     ``compile_cache_miss_total`` and :func:`persistent_cache_stats`."""
     global _cache_dir
@@ -228,32 +236,23 @@ def enable_persistent_cache(path=None):
     with _cache_lock:
         if _cache_dir is not None:
             return _cache_dir
-        cache = path or default_cache_dir()
+        import jax
+        from jax.experimental.compilation_cache import \
+            compilation_cache as _jcc
+
+        cache = default_cache_dir()
         try:
-            import jax
-
             os.makedirs(cache, exist_ok=True)
+        except OSError:
+            return None     # unwritable location: run uncached
+        if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
             jax.config.update("jax_compilation_cache_dir", cache)
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              0.0)
-            try:
-                jax.config.update(
-                    "jax_persistent_cache_min_entry_size_bytes", -1)
-            except Exception:
-                pass        # older jax: size gate stays at its default
-            try:
-                # the backend usually initializes during framework
-                # import, BEFORE this config lands — jax then latches
-                # "no cache" at its first compile and silently ignores
-                # the directory forever; reset re-arms the lazy init so
-                # the next compile picks the configured dir up
-                from jax._src import compilation_cache as _jcc
-
-                _jcc.reset_cache()
-            except Exception:
-                pass
-        except Exception:
-            return None     # unwritable dir / jax without the config
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        # a program compiled BEFORE this config landed makes jax latch
+        # "no cache" and ignore the directory from then on; reset
+        # re-arms the lazy init so the next compile picks it up
+        _jcc.reset_cache()
         _cache_dir = cache
     _ensure_listener()
     return _cache_dir
@@ -337,15 +336,10 @@ def shape_registry():
 def _in_outer_trace():
     """True when this thread is inside an active jax trace (grad/vjp/an
     enclosing jit) — only the plain jit path composes there. O(1): the
-    per-dispatch guard must not walk the model state. Falls back to
-    assuming a trace when the introspection API is missing (the safe
-    direction: plain jit always works)."""
+    per-dispatch guard must not walk the model state."""
     import jax
 
-    try:
-        return not jax.core.trace_state_clean()
-    except Exception:
-        return True
+    return not jax.core.trace_ctx.is_top_level()
 
 
 def _arg_key(args, kwargs=None):
@@ -418,7 +412,7 @@ class _NullWatch:
     __slots__ = ()
 
     def aot_compile(self, jitted, args, kwargs=None, desc=None):
-        return None
+        return jitted.lower(*args, **(kwargs or {})).compile()
 
     def timed_first_dispatch(self, jitted, args, kwargs=None, desc=None):
         return jitted(*args, **(kwargs or {}))
@@ -542,9 +536,10 @@ class CompileWatch:
     def aot_compile(self, jitted, args, kwargs=None, desc=None):
         """Lower + compile ``jitted`` for these concrete args, recording
         count, duration, and cost/memory analysis. Returns the compiled
-        executable (dispatch it for all later same-signature calls), or
-        None when AOT lowering is unsupported for this program — the
-        caller then falls back to :meth:`timed_first_dispatch`."""
+        executable (dispatch it for all later same-signature calls).
+        Whatever ``jax.jit`` can run it can lower, so nothing is caught
+        here: a refusal — of the tracer, of Mosaic, of the device's
+        memory — propagates with its message."""
         kwargs = kwargs or {}
         _ensure_listener()
         self.observe_signature(desc)
@@ -552,8 +547,6 @@ class CompileWatch:
         t0 = time.perf_counter()
         try:
             compiled = jitted.lower(*args, **kwargs).compile()
-        except Exception:
-            return None
         finally:
             _tls.current = None
         dur = time.perf_counter() - t0
@@ -561,9 +554,10 @@ class CompileWatch:
         return compiled
 
     def timed_first_dispatch(self, jitted, args, kwargs=None, desc=None):
-        """Fallback when AOT lowering fails: dispatch through the jit
-        wrapper and record its first-call wall time as the compile
-        duration (over-counts by one execution — honest upper bound)."""
+        """For a jit with static arguments (whose ``Compiled`` takes a
+        different call shape): dispatch through the jit wrapper and
+        record its first-call wall time as the compile duration
+        (over-counts by one execution — honest upper bound)."""
         _ensure_listener()
         self.observe_signature(desc)
         _tls.current = self.name
@@ -727,14 +721,10 @@ def watched_jit(fun, name=None, **jit_kwargs):
             return jitted(*args, **kwargs)
         compiled = cache.get(key)
         if compiled is None:
-            if key in cache:    # AOT failed earlier for this signature
+            if key in cache:    # avals drifted earlier: plain jit owns it
                 return jitted(*args, **kwargs)
-            w = watch(watch_name)
-            compiled = w.aot_compile(jitted, args, kwargs,
-                                     desc=_key_desc(key))
-            cache[key] = compiled
-            if compiled is None:
-                return jitted(*args, **kwargs)
+            compiled = cache[key] = watch(watch_name).aot_compile(
+                jitted, args, kwargs, desc=_key_desc(key))
         try:
             from . import perf as _perf
 
@@ -814,7 +804,7 @@ def sample_device_memory(registry=None, device=None, min_interval=0.0):
               "live jax arrays in this process").set(len(live))
     peak = stats.get("peak_bytes_in_use")
     if peak is None:
-        # no allocator peak (CPU / tunneled backends): the sampler's own
+        # no allocator peak (the CPU backend): the sampler's own
         # high-water — derived from the stats already fetched, not a
         # second memory_stats() walk
         _mem_peak = max(_mem_peak, in_use)

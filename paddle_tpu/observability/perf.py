@@ -13,7 +13,7 @@ go, and is this callable near its roofline?":
   extended through ``jax.block_until_ready`` — a *true* device-time
   sample, since an unfenced dispatch returns at enqueue;
 - each fenced sample publishes the roofline gauges against the
-  per-platform peak table (:data:`PEAKS`, env-overridable):
+  per-device-kind peak table (:data:`PEAKS`):
   ``paddle_tpu_perf_device_ms{callable}``,
   ``paddle_tpu_perf_attained_flops_frac{callable}`` (measured FLOP/s as
   a fraction of peak — MFU per callable) and
@@ -57,10 +57,14 @@ __all__ = [
     "capture_local", "capture_bundle",
 ]
 
-#: (peak FLOP/s, peak HBM bytes/s) per chip by device kind — the bf16
-#: MXU peak (matching ``bench.py``'s MFU denominator) and the published
-#: HBM bandwidth. CPU gets a nominal entry so the roofline fractions
-#: stay meaningful (tiny) rather than absent in smoke runs.
+#: (peak FLOP/s, peak HBM bytes/s) per chip, keyed by
+#: ``jax.devices()[0].device_kind`` — the bf16 MXU peak and the HBM
+#: bandwidth Google Cloud's TPU documentation publishes for each
+#: generation ("TPU v5e": 197 TFLOP/s, 819 GB/s; likewise the v2, v3,
+#: v4, v5p and v6e system-architecture pages). A kind that is not here
+#: gets device time but no roofline fraction. The CPU row is nominal
+#: (the CPU tests exercise the fraction gauges through it; ROADMAP S0
+#: settles it).
 PEAKS = {
     "TPU v2": (46e12, 700e9),
     "TPU v3": (123e12, 900e9),
@@ -73,10 +77,6 @@ PEAKS = {
     "TPU v6e": (918e12, 1640e9),
     "cpu": (1e12, 50e9),
 }
-
-#: fallbacks for an unknown TPU kind / non-TPU accelerator
-_DEFAULT_TPU_PEAKS = (197e12, 819e9)
-_DEFAULT_PEAKS = (1e12, 50e9)
 
 #: EWMA smoothing: fast tracks the last few fenced samples, slow is the
 #: baseline the sentinel compares against
@@ -132,36 +132,16 @@ _peaks_cache = None
 
 def device_peaks():
     """``(peak_flops_per_s, peak_hbm_bytes_per_s, device_kind)`` for the
-    default device, from :data:`PEAKS`. ``PADDLE_TPU_PEAK_FLOPS``
-    (FLOP/s) and ``PADDLE_TPU_PEAK_HBM_GBS`` (GB/s) override per entry —
-    how an operator corrects the table for a new chip without a code
-    change. Cached after the first (device-touching) call."""
+    default device, from :data:`PEAKS`; both peaks are None for a kind
+    the table does not hold. Cached after the first (device-touching)
+    call."""
     global _peaks_cache
     with _peaks_lock:
         if _peaks_cache is None:
-            kind = "unknown"
-            flops, bw = _DEFAULT_PEAKS
-            try:
-                import jax
+            import jax
 
-                d = jax.devices()[0]
-                kind = getattr(d, "device_kind", None) or d.platform
-                if kind in PEAKS:
-                    flops, bw = PEAKS[kind]
-                elif d.platform == "tpu":
-                    flops, bw = _DEFAULT_TPU_PEAKS
-            except Exception:
-                pass
-            env_flops = os.environ.get("PADDLE_TPU_PEAK_FLOPS")
-            env_bw = os.environ.get("PADDLE_TPU_PEAK_HBM_GBS")
-            try:
-                if env_flops:
-                    flops = float(env_flops)
-                if env_bw:
-                    bw = float(env_bw) * 1e9
-            except ValueError:
-                pass
-            _peaks_cache = (flops, bw, str(kind))
+            kind = str(jax.devices()[0].device_kind)
+            _peaks_cache = PEAKS.get(kind, (None, None)) + (kind,)
         return _peaks_cache
 
 
@@ -289,11 +269,11 @@ class _CallableState:
                   "regression": regression}
         metrics["device_ms"].labels(self.name).set(device_ms)
         metrics["fenced"].labels(self.name).inc()
-        if flops and flops > 0 and ewma_s > 0 and peak_flops > 0:
+        if flops and flops > 0 and ewma_s > 0 and peak_flops:
             frac = min(1.0, flops / (ewma_s * peak_flops))
             sample["attained_flops_frac"] = frac
             metrics["flops_frac"].labels(self.name).set(frac)
-        if nbytes and nbytes > 0 and ewma_s > 0 and peak_bw > 0:
+        if nbytes and nbytes > 0 and ewma_s > 0 and peak_bw:
             frac = min(1.0, nbytes / (ewma_s * peak_bw))
             sample["attained_hbm_bw_frac"] = frac
             metrics["hbm_frac"].labels(self.name).set(frac)

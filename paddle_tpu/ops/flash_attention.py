@@ -19,23 +19,20 @@ Backward uses the standard recomputation split:
   dK_j = sum_i (P_ij ∘ (dP_ij - D_i))^T Q_i * scale
   dQ_i = sum_j (P_ij ∘ (dP_ij - D_i)) K_j * scale
 with P recomputed from the saved log-sum-exp rows. The dK/dV kernel runs
-per kv-head and statically unrolls over its ``group`` query heads.
+per (kv-head, k-block) and accumulates over its ``group`` query heads'
+q-blocks along the last grid axis, one 128-row tile at a time.
 """
 
 from __future__ import annotations
 
 import functools
 import math
+import warnings
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-try:  # pltpu import works on CPU too (interpret mode)
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 from ..framework.tensor import run_op
 
@@ -44,6 +41,7 @@ __all__ = ["flash_attention", "supported"]
 BLOCK_Q = 128
 BLOCK_K = 128
 NEG_INF = -1e30
+_KV_VMEM_BUDGET = 12 * 1024 * 1024
 
 
 def _interpret():
@@ -52,8 +50,6 @@ def _interpret():
 
 def supported(q, k, v, attn_mask, causal):
     """Pallas path preconditions; anything else falls back to XLA."""
-    if not _HAS_PLTPU:
-        return False
     if attn_mask is not None:
         return False
     qs = q.shape if not hasattr(q, "_data") else q._data.shape
@@ -71,15 +67,6 @@ def supported(q, k, v, attn_mask, causal):
         return False
     if ks[3] != d:
         return False
-    # VMEM budget: the dK/dV kernel blocks (group, sq, d) Q and dO into
-    # VMEM; the fwd kernel streams the full (sk, d) K and V. Stay well
-    # under the ~16 MB/core VMEM or the pallas_call fails to map.
-    itemsize = jnp.dtype(q.dtype).itemsize if hasattr(q, "dtype") else 4
-    group = h // hk
-    if 2 * group * sq * d * itemsize > 12 * 1024 * 1024:
-        return False
-    if 2 * sk * d * itemsize > 12 * 1024 * 1024:
-        return False
     if causal and sq > sk:
         # bottom-right alignment gives offset < 0: leading q-blocks would
         # see zero keys (l == 0 -> 0/0 NaN rows); let the XLA path mask them
@@ -89,6 +76,20 @@ def supported(q, k, v, attn_mask, causal):
     if sq % BLOCK_Q or sk % BLOCK_K:
         return False
     if d % 8 or d > 256:
+        return False
+    # VMEM, the one refusal that is about size and not form: the fwd and
+    # dQ kernels keep the whole (sk, d) K and V of one kv head resident,
+    # each double-buffered by the pipeline (lanes pad to 128); every
+    # other window of the three kernels is one 128-row tile. The v5e's
+    # compiler took 24 MB of such streams and refused 32 MB
+    # (tests/test_chip_compile.py walks the boundary's safe side).
+    itemsize = jnp.dtype(q.dtype).itemsize if hasattr(q, "dtype") else 4
+    if 2 * 2 * sk * (-(-d // 128) * 128) * itemsize > _KV_VMEM_BUDGET:
+        warnings.warn(
+            f"flash attention turns away q{tuple(qs)} k/v{tuple(ks)} "
+            f"{jnp.dtype(q.dtype).name}: one kv head's K and V do not "
+            f"fit the kernels' VMEM; the XLA path taken instead holds "
+            f"the {sq} x {sk} scores of every head", stacklevel=2)
         return False
     return True
 
@@ -219,54 +220,64 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, *, scale, causal, block_q, offset,
-                    group):
-    k = k_ref[0, 0].astype(jnp.float32)          # [Bk, D]
-    v = v_ref[0, 0].astype(jnp.float32)
-    sq = q_ref.shape[2]
-    num_qb = sq // block_q
+                    dk_ref, dv_ref, dk_acc, dv_acc, *, scale, causal,
+                    block_q, offset, num_qb):
+    """One (batch, kv-head, k-block) output tile, accumulated over the
+    LAST grid axis: step ``j`` brings in q-block ``j % num_qb`` of the
+    group's query head ``j // num_qb``, so the q/do/lse/delta windows
+    are one (block_q, D) tile each and VMEM does not grow with the
+    sequence length."""
     ki = pl.program_id(2)
-    bk = k.shape[0]
+    j = pl.program_id(3)
+    i = j % num_qb
+    bk = k_ref.shape[2]
 
-    def make_body(gi):
-        def body(i, carry):
-            dk, dv = carry
-            q = q_ref[0, gi, pl.ds(i * block_q, block_q), :] \
-                .astype(jnp.float32)
-            do = do_ref[0, gi, pl.ds(i * block_q, block_q), :] \
-                .astype(jnp.float32)
-            lse = lse_ref[0, gi, pl.ds(i * block_q, block_q), :]
-            delta = delta_ref[0, gi, pl.ds(i * block_q, block_q), :]
-            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32) * scale
-            if causal:
-                q_pos = i * block_q + jax.lax.broadcasted_iota(
-                    jnp.int32, s.shape, 0) + offset
-                k_pos = ki * bk + jax.lax.broadcasted_iota(
-                    jnp.int32, s.shape, 1)
-                s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-            p = jnp.exp(s - lse)                  # [Bq, Bk]
-            dv = dv + jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
-                                          preferred_element_type=jnp.float32)
-            dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-            ds = p * (dp - delta) * scale
-            dk = dk + jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                                          preferred_element_type=jnp.float32)
-            return dk, dv
-        return body
+    @pl.when(j == 0)
+    def _init():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    if causal:
-        # q blocks whose last position precedes this k block never attend
-        start = jax.lax.max(0, (ki * bk - offset) // block_q)
-    else:
-        start = 0
-    carry = (jnp.zeros(k.shape, jnp.float32), jnp.zeros(v.shape, jnp.float32))
-    for gi in range(group):  # static unroll over the shared query heads
-        carry = jax.lax.fori_loop(start, num_qb, make_body(gi), carry)
-    dk, dv = carry
-    dk_ref[0, 0] = dk.astype(dk_ref.dtype)
-    dv_ref[0, 0] = dv.astype(dv_ref.dtype)
+    # causal: q blocks whose last position precedes this k block never
+    # attend (`_dkv_q_index` parks their fetch on the first live block)
+    live = i >= _first_live_qb(ki, bk, block_q, offset) if causal \
+        else True
+
+    @pl.when(live)
+    def _accumulate():
+        k = k_ref[0, 0].astype(jnp.float32)          # [Bk, D]
+        v = v_ref[0, 0].astype(jnp.float32)
+        q = q_ref[0, 0].astype(jnp.float32)          # [Bq, D]
+        do = do_ref[0, 0].astype(jnp.float32)
+        lse = lse_ref[0, 0]                          # [Bq, 1]
+        delta = delta_ref[0, 0]
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        if causal:
+            q_pos = i * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 0) + offset
+            k_pos = ki * bk + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1)
+            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
+        p = jnp.exp(s - lse)                          # [Bq, Bk]
+        dv_acc[...] += jax.lax.dot_general(
+            p, do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        ds = p * (dp - delta) * scale
+        dk_acc[...] += jax.lax.dot_general(
+            ds, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    @pl.when(j == pl.num_programs(3) - 1)
+    def _finish():
+        dk_ref[0, 0] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _first_live_qb(ki, block_k, block_q, offset):
+    """First q block a causal k block ``ki`` can see."""
+    return jax.lax.max(0, (ki * block_k - offset) // block_q)
 
 
 def _bwd(scale, causal, group, res, g):
@@ -295,27 +306,45 @@ def _bwd(scale, causal, group, res, g):
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), qh.dtype),
         interpret=_interpret(),
     )(qh, kh, vh, do, lse, delta)
-    # per-kv-head: the group of query heads is a contiguous head block
+    # per-kv-head: the group's query heads are a contiguous head block,
+    # walked (head, q-block) by the last grid axis
+    num_qb = sq // BLOCK_Q
+    offset = sk - sq
+
+    def q_index(bi, hi, ki, j):
+        i = j % num_qb
+        if causal:
+            # a dead q block re-points at the first live one, which the
+            # pipeline already holds: no fetch for a step that is skipped
+            i = jax.lax.max(i, _first_live_qb(ki, BLOCK_K, BLOCK_Q,
+                                              offset))
+        return bi, hi * group + j // num_qb, i, 0
+
+    def k_index(bi, hi, ki, j):
+        return bi, hi, ki, 0
+
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                          block_q=BLOCK_Q, offset=sk - sq, group=group),
-        grid=(b, hk, sk // BLOCK_K),
+                          block_q=BLOCK_Q, offset=offset, num_qb=num_qb),
+        grid=(b, hk, sk // BLOCK_K, group * num_qb),
         in_specs=[
-            pl.BlockSpec((1, group, sq, d), lambda bi, hi, ki: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, 1, BLOCK_K, d), lambda bi, hi, ki: (bi, hi, ki, 0)),
-            pl.BlockSpec((1, 1, BLOCK_K, d), lambda bi, hi, ki: (bi, hi, ki, 0)),
-            pl.BlockSpec((1, group, sq, d), lambda bi, hi, ki: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, group, sq, 1), lambda bi, hi, ki: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, group, sq, 1), lambda bi, hi, ki: (bi, hi, 0, 0)),
+            pl.BlockSpec((1, 1, BLOCK_Q, d), q_index),
+            pl.BlockSpec((1, 1, BLOCK_K, d), k_index),
+            pl.BlockSpec((1, 1, BLOCK_K, d), k_index),
+            pl.BlockSpec((1, 1, BLOCK_Q, d), q_index),
+            pl.BlockSpec((1, 1, BLOCK_Q, 1), q_index),
+            pl.BlockSpec((1, 1, BLOCK_Q, 1), q_index),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, BLOCK_K, d), lambda bi, hi, ki: (bi, hi, ki, 0)),
-            pl.BlockSpec((1, 1, BLOCK_K, d), lambda bi, hi, ki: (bi, hi, ki, 0)),
+            pl.BlockSpec((1, 1, BLOCK_K, d), k_index),
+            pl.BlockSpec((1, 1, BLOCK_K, d), k_index),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, hk, sk, d), kh.dtype),
             jax.ShapeDtypeStruct((b, hk, sk, d), vh.dtype),
         ],
+        scratch_shapes=[pltpu.VMEM((BLOCK_K, d), jnp.float32),
+                        pltpu.VMEM((BLOCK_K, d), jnp.float32)],
         interpret=_interpret(),
     )(qh, kh, vh, do, lse, delta)
     return dq, dk, dv

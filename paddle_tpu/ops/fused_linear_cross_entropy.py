@@ -65,8 +65,9 @@ from ..framework.tensor import run_op
 __all__ = ["fused_linear_cross_entropy", "fused_linear_cross_entropy_xla",
            "supported"]
 
-#: VMEM budget for one grid step's blocks (hidden tile + w tile + logits
-#: tile, all f32), kept well under the ~16 MB/core ceiling
+#: VMEM budget for one grid step's input windows (hidden tile + w tile,
+#: each double-buffered by the pipeline), kept under the 16 MB of scoped
+#: VMEM the compiler grants a kernel
 _VMEM_BUDGET = 12 * 1024 * 1024
 
 
@@ -88,13 +89,17 @@ def default_chunk():
         return 8192
 
 
-def _blocks(n, d, v):
+def _window_bytes(bn, d, bv, itemsize):
+    return 2 * (bn * d + d * bv) * itemsize
+
+
+def _blocks(n, d, v, itemsize):
     """(block_n, block_v) for the kernel grid: row tiles sublane-aligned
     and capped at 128 (the sequence tile), vocab tiles shrunk while one
-    grid step's f32 blocks exceed the VMEM budget."""
+    grid step's input windows exceed the VMEM budget."""
     bn = min(128, -(-n // 8) * 8)
     bv = min(512, -(-v // 128) * 128)
-    while bv > 128 and (bn * d + d * bv + bn * bv) * 4 > _VMEM_BUDGET:
+    while bv > 128 and _window_bytes(bn, d, bv, itemsize) > _VMEM_BUDGET:
         bv //= 2
     return bn, bv
 
@@ -116,10 +121,9 @@ def supported(hidden2d, w):
         return False
     if d % 128 or v < 128:
         return False
-    bn, bv = _blocks(n, d, v)
-    if (bn * d + d * bv + bn * bv) * 4 > _VMEM_BUDGET:
-        return False
-    return True
+    itemsize = jnp.dtype(getattr(hidden2d, "_data", hidden2d).dtype).itemsize
+    bn, bv = _blocks(n, d, v, itemsize)
+    return _window_bytes(bn, d, bv, itemsize) <= _VMEM_BUDGET
 
 
 # ---------------------------------------------------------------------------
@@ -234,7 +238,7 @@ def _kernel_parts(h2d, w, labels, block_v=None):
     run the interpreter off-TPU)."""
     n, d = h2d.shape
     v = w.shape[1]
-    bn, bv = _blocks(n, d, v)
+    bn, bv = _blocks(n, d, v, jnp.dtype(h2d.dtype).itemsize)
     if block_v is not None:
         bv = int(block_v)
     call = _make_ce_call(n, d, v, bn, bv, _interpret())

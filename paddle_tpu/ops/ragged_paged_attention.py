@@ -580,10 +580,16 @@ def _fused_kernel(tables_ref, kv_lens_ref, q_starts_ref, q_lens_ref,
         spos = page_start + jax.lax.broadcasted_iota(
             jnp.int32, (page_size, 1), 0)
         fresh = (spos >= ws) & (spos < ctx)
-        k_pg = jnp.where(fresh, nk_ref[0, pl.ds(f0, page_size), :],
-                         k_ref[0, 0])
-        v_pg = jnp.where(fresh, nv_ref[0, pl.ds(f0, page_size), :],
-                         v_ref[0, 0])
+        # the packed rows ride in an f32 container (see
+        # `_pack_new_rows`); narrowing back to the pool dtype is exact
+        k_pg = jnp.where(
+            fresh,
+            nk_ref[0, pl.ds(f0, page_size), :].astype(k_ref.dtype),
+            k_ref[0, 0])
+        v_pg = jnp.where(
+            fresh,
+            nv_ref[0, pl.ds(f0, page_size), :].astype(v_ref.dtype),
+            v_ref[0, 0])
 
         q = q_ref[0, 0].astype(jnp.float32) * scale      # [QB*G, D]
         _softmax_accumulate(q, k_pg.astype(jnp.float32),
@@ -654,10 +660,8 @@ def _fused_kernel_q8(tables_ref, kv_lens_ref, q_starts_ref, q_lens_ref,
         spos = page_start + jax.lax.broadcasted_iota(
             jnp.int32, (page_size, 1), 0)
         fresh = (spos >= ws) & (spos < ctx)
-        k_qn, k_scn = _quantize_rows(
-            nk_ref[0, pl.ds(f0, page_size), :].astype(jnp.float32))
-        v_qn, v_scn = _quantize_rows(
-            nv_ref[0, pl.ds(f0, page_size), :].astype(jnp.float32))
+        k_qn, k_scn = _quantize_rows(nk_ref[0, pl.ds(f0, page_size), :])
+        v_qn, v_scn = _quantize_rows(nv_ref[0, pl.ds(f0, page_size), :])
         # dequantized page view: fresh slots read quantize->dequantize
         # (NOT the raw float) so the fused step is bitwise what the
         # unfused engine computes after its quantizing scatter
@@ -693,22 +697,22 @@ def _rot_half(x):
     return jnp.concatenate([-x[..., h:], x[..., :h]], axis=-1)
 
 
-def _rope_k_page(nk_ref, sin_ref, cos_ref, f0, page_size):
+def _rope_k_page(nk_ref, sin_ref, cos_ref, f0, page_size, dtype):
     """Rope one replay slice of the packed pre-rope K rows: the SAME
     ``f0`` offset picks the rows and their positions' sin/cos (the
     tables are padded identically), and the rotated rows cast back
-    through the MODEL dtype — exactly `_apply_rope`'s output. Shared
-    by the fp and q8 rope kernels so the parity-critical rotation
-    chain lives in one place (like `_softmax_accumulate`)."""
+    through the MODEL ``dtype`` — exactly `_apply_rope`'s output (the
+    rows arrive widened to f32, see `_pack_new_rows`). Shared by the
+    fp and q8 rope kernels so the parity-critical rotation chain lives
+    in one place (like `_softmax_accumulate`)."""
     sin_k = sin_ref[pl.ds(f0, page_size), :]
     cos_k = cos_ref[pl.ds(f0, page_size), :]
-    k_new = nk_ref[0, pl.ds(f0, page_size), :].astype(jnp.float32)
-    return (k_new * cos_k + _rot_half(k_new) * sin_k) \
-        .astype(nk_ref.dtype)
+    k_new = nk_ref[0, pl.ds(f0, page_size), :]
+    return (k_new * cos_k + _rot_half(k_new) * sin_k).astype(dtype)
 
 
 def _rope_q_block(q_ref, sin_ref, cos_ref, q_starts_ref, w_starts_ref,
-                  w_flats_ref, r, pad, qblock, group, scale):
+                  w_flats_ref, r, pad, qblock, group, scale, dtype):
     """Load + rope + scale one row's query block from the packed
     pre-rope q: the row's tokens sit contiguously on the packed axis
     at ``w_flat + (q_start - w_start)`` — the same affine replay index
@@ -720,11 +724,11 @@ def _rope_q_block(q_ref, sin_ref, cos_ref, q_starts_ref, w_starts_ref,
     tpad = q_ref.shape[1]
     f0q = jnp.clip(w_flats_ref[r] + q_starts_ref[r] - w_starts_ref[r]
                    + pad, 0, tpad - qblock)
-    qv = q_ref[0, pl.ds(f0q, qblock), :, :].astype(jnp.float32)
+    qv = q_ref[0, pl.ds(f0q, qblock), :, :]           # f32 container
     sin_q = sin_ref[pl.ds(f0q, qblock), :][:, None, :]
     cos_q = cos_ref[pl.ds(f0q, qblock), :][:, None, :]
     q_rot = (qv * cos_q + _rot_half(qv) * sin_q) \
-        .astype(q_ref.dtype)                          # [QB, G, D]
+        .astype(dtype)                                # [QB, G, D]
     return q_rot.reshape(qblock * group, qv.shape[-1]) \
         .astype(jnp.float32) * scale                  # [QB*G, D]
 
@@ -734,7 +738,7 @@ def _fused_rope_kernel(tables_ref, kv_lens_ref, q_starts_ref,
                        w_ends_ref, q_ref, k_ref, v_ref, nk_ref, nv_ref,
                        sin_ref, cos_ref, o_ref, ko_ref, vo_ref,
                        acc_ref, m_ref, l_ref, q_s, *, page_size, group,
-                       scale, pad, qblock):
+                       scale, pad, qblock, dtype):
     """Rope-fused variant of `_fused_kernel`: q and new_k arrive
     PRE-rope in packed layouts (q ``[Hk, tpad, G, D]`` head-major,
     new_k ``[Hk, tpad, D]`` in the MODEL dtype), the sin/cos tables
@@ -757,7 +761,7 @@ def _fused_rope_kernel(tables_ref, kv_lens_ref, q_starts_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         q_s[...] = _rope_q_block(q_ref, sin_ref, cos_ref, q_starts_ref,
                                  w_starts_ref, w_flats_ref, r, pad,
-                                 qblock, group, scale)
+                                 qblock, group, scale, dtype)
 
     ctx = kv_lens_ref[r]
     ws = w_starts_ref[r]
@@ -774,10 +778,13 @@ def _fused_rope_kernel(tables_ref, kv_lens_ref, q_starts_ref,
         # rope the fresh K rows in VMEM (shared chain: `_rope_k_page`),
         # then cast on to the pool dtype, matching what the unfused
         # scatter would have stored
-        k_rot = _rope_k_page(nk_ref, sin_ref, cos_ref, f0, page_size)
+        k_rot = _rope_k_page(nk_ref, sin_ref, cos_ref, f0, page_size,
+                             dtype)
         k_pg = jnp.where(fresh, k_rot.astype(ko_ref.dtype), k_ref[0, 0])
-        v_pg = jnp.where(fresh, nv_ref[0, pl.ds(f0, page_size), :],
-                         v_ref[0, 0])
+        v_pg = jnp.where(
+            fresh,
+            nv_ref[0, pl.ds(f0, page_size), :].astype(v_ref.dtype),
+            v_ref[0, 0])
 
         _softmax_accumulate(q_s[...], k_pg.astype(jnp.float32),
                             v_pg.astype(jnp.float32), page_start,
@@ -801,7 +808,7 @@ def _fused_rope_kernel_q8(tables_ref, kv_lens_ref, q_starts_ref,
                           vs_ref, nk_ref, nv_ref, sin_ref, cos_ref,
                           o_ref, ko_ref, vo_ref, kso_ref, vso_ref,
                           acc_ref, m_ref, l_ref, q_s, *, page_size,
-                          group, scale, pad, qblock):
+                          group, scale, pad, qblock, dtype):
     """Int8-pool rope-fused variant: rope the fresh rows (as in
     `_fused_rope_kernel`, incl. the model-dtype round trip), THEN
     quantize them in-kernel with bitwise `quantize_kv_int8` math —
@@ -818,7 +825,7 @@ def _fused_rope_kernel_q8(tables_ref, kv_lens_ref, q_starts_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         q_s[...] = _rope_q_block(q_ref, sin_ref, cos_ref, q_starts_ref,
                                  w_starts_ref, w_flats_ref, r, pad,
-                                 qblock, group, scale)
+                                 qblock, group, scale, dtype)
 
     ctx = kv_lens_ref[r]
     ws = w_starts_ref[r]
@@ -834,11 +841,10 @@ def _fused_rope_kernel_q8(tables_ref, kv_lens_ref, q_starts_ref,
         fresh = (spos >= ws) & (spos < ctx)
         # shared rotation chain, then the exact f32 widening the
         # unfused engine's post-rope quantizer consumes
-        k_rot = _rope_k_page(nk_ref, sin_ref, cos_ref, f0, page_size) \
-            .astype(jnp.float32)
+        k_rot = _rope_k_page(nk_ref, sin_ref, cos_ref, f0, page_size,
+                             dtype).astype(jnp.float32)
         k_qn, k_scn = _quantize_rows(k_rot)
-        v_qn, v_scn = _quantize_rows(
-            nv_ref[0, pl.ds(f0, page_size), :].astype(jnp.float32))
+        v_qn, v_scn = _quantize_rows(nv_ref[0, pl.ds(f0, page_size), :])
         k = jnp.where(fresh, k_qn * k_scn,
                       k_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0])
         v = jnp.where(fresh, v_qn * v_scn,
@@ -1008,7 +1014,7 @@ def _make_fused_q8(scale, page_size, qb, group, tpad, dump_page,
 
 @functools.lru_cache(maxsize=32)
 def _make_fused_rope(scale, page_size, qblock, group, tpad, dump_page,
-                     interpret):
+                     dtype, interpret):
     wmap = _fused_write_map(page_size, dump_page)
 
     def call(qp, k_pages, v_pages, nk, nv, sin, cos, tables, kv_lens,
@@ -1060,10 +1066,10 @@ def _make_fused_rope(scale, page_size, qblock, group, tpad, dump_page,
         return pl.pallas_call(
             functools.partial(_fused_rope_kernel, page_size=page_size,
                               group=group, scale=scale, pad=page_size,
-                              qblock=qblock),
+                              qblock=qblock, dtype=dtype),
             grid_spec=grid_spec,
             out_shape=[
-                jax.ShapeDtypeStruct((r, hk, qbg, d), qp.dtype),
+                jax.ShapeDtypeStruct((r, hk, qbg, d), dtype),
                 jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
                 jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype),
             ],
@@ -1078,7 +1084,7 @@ def _make_fused_rope(scale, page_size, qblock, group, tpad, dump_page,
 
 @functools.lru_cache(maxsize=32)
 def _make_fused_rope_q8(scale, page_size, qblock, group, tpad,
-                        dump_page, interpret):
+                        dump_page, dtype, interpret):
     wmap = _fused_write_map(page_size, dump_page)
 
     def call(qp, k_pages, v_pages, k_scale, v_scale, nk, nv, sin, cos,
@@ -1134,10 +1140,10 @@ def _make_fused_rope_q8(scale, page_size, qblock, group, tpad,
             functools.partial(_fused_rope_kernel_q8,
                               page_size=page_size, group=group,
                               scale=scale, pad=page_size,
-                              qblock=qblock),
+                              qblock=qblock, dtype=dtype),
             grid_spec=grid_spec,
             out_shape=[
-                jax.ShapeDtypeStruct((r, hk, qbg, d), qp.dtype),
+                jax.ShapeDtypeStruct((r, hk, qbg, d), dtype),
                 jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
                 jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype),
                 jax.ShapeDtypeStruct(k_scale.shape, k_scale.dtype),
@@ -1155,8 +1161,12 @@ def _pack_new_rows(new, t, page_size, tpad, dtype):
     """[T, Hk, D] packed rows -> [Hk, tpad, D] head-major with a
     page_size left pad, so the kernels' clipped affine slice
     ``pl.ds(w_flat + page_start - w_start + pad, page_size)`` is always
-    in bounds whenever any slot of the page is fresh."""
-    nk = jnp.swapaxes(new.astype(dtype), 0, 1)
+    in bounds whenever any slot of the page is fresh. The values are
+    rounded to ``dtype`` and handed over in an f32 container: that
+    slice starts at a run-time sublane offset, which Mosaic takes
+    unaligned only from a 32-bit array, and 16-bit -> f32 -> 16-bit is
+    exact, so the kernel narrows back without changing a bit."""
+    nk = jnp.swapaxes(new.astype(dtype).astype(jnp.float32), 0, 1)
     return jnp.pad(nk, ((0, 0), (page_size, tpad - t - page_size),
                         (0, 0)))
 
@@ -1227,10 +1237,11 @@ def _pack_new_q(q, t, group, page_size, tpad):
     """Pre-rope packed q ``[T, H, D]`` -> ``[Hk, tpad, G, D]``
     head-major with the same page_size left pad as `_pack_new_rows`,
     so one affine offset addresses q rows, K/V rows and the sin/cos
-    tables alike."""
+    tables alike; widened to f32 for the same reason as the rows."""
     hk = q.shape[1] // group
     d = q.shape[-1]
-    q4 = q.reshape(t, hk, group, d).transpose(1, 0, 2, 3)
+    q4 = q.astype(jnp.float32).reshape(t, hk, group, d) \
+        .transpose(1, 0, 2, 3)
     return jnp.pad(q4, ((0, 0), (page_size, tpad - t - page_size),
                         (0, 0), (0, 0)))
 
@@ -1268,7 +1279,8 @@ def _fused_rope_impl(q, new_k, new_v, k_pages, v_pages, block_tables,
     sin = _pack_rope_table(rope_sin, t, page_size, tpad)
     cos = _pack_rope_table(rope_cos, t, page_size, tpad)
     call = _make_fused_rope(scale, page_size, qblock, group, tpad,
-                            int(dump_page), _interpret())
+                            int(dump_page), jnp.dtype(q.dtype),
+                            _interpret())
     tables = jnp.clip(block_tables.astype(jnp.int32), 0,
                       k_pages.shape[0] - 1)
     out, kp, vp = call(qp, k_pages, v_pages, nk, nv, sin, cos, tables,
@@ -1303,7 +1315,8 @@ def _fused_rope_impl_q8(q, new_k, new_v, k_pages, v_pages, k_scale,
     sin = _pack_rope_table(rope_sin, t, page_size, tpad)
     cos = _pack_rope_table(rope_cos, t, page_size, tpad)
     call = _make_fused_rope_q8(scale, page_size, qblock, group, tpad,
-                               int(dump_page), _interpret())
+                               int(dump_page), jnp.dtype(q.dtype),
+                               _interpret())
     tables = jnp.clip(block_tables.astype(jnp.int32), 0,
                       k_pages.shape[0] - 1)
     out, kp, vp, ks, vs = call(

@@ -1,0 +1,219 @@
+"""Ask the chip's compiler before the chip.
+
+Every kernel of the two main paths (Llama serving, Llama training) is
+compiled here for a TPU v5e that is described and not attached, at
+published widths. Interpret-mode tests cannot see what Mosaic refuses
+(a slice not aligned to the tiling, more VMEM than a kernel may use);
+these can, at no chip time. Nothing runs: a compile that passes is not
+a chip run.
+
+The topology is described inside a module-scoped fixture of THIS file
+(never at import: only one process may hold the TPU library, and every
+xdist worker imports every test file). The kernels read
+``jax.default_backend()``, which is ``cpu`` under test, so the tests
+steer them off interpret mode with ``monkeypatch``.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from paddle_tpu.ops import flash_attention as fa
+from paddle_tpu.ops import fused_linear_cross_entropy as flce
+from paddle_tpu.ops import grouped_gemm as gg
+from paddle_tpu.ops import ragged_paged_attention as rpa
+from paddle_tpu.quant import kernels as qk
+
+BF16 = jnp.bfloat16
+F32 = jnp.float32
+I32 = jnp.int32
+I8 = jnp.int8
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent
+    cache but cannot be read back without one: keep it off here."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+@pytest.fixture
+def chip_compile(one_chip, no_persistent_cache, monkeypatch):
+    """``compile(fn, *specs) -> compiled``: ``fn`` jitted and compiled
+    for one described v5e with every kernel module off interpret mode.
+    ``specs`` are ``(shape, dtype)`` pairs."""
+    for mod in (rpa, fa, flce, gg, qk):
+        monkeypatch.setattr(mod, "_interpret", lambda: False)
+
+    def compile_(fn, *specs):
+        args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                for s, d in specs]
+        return jax.jit(fn).lower(*args).compile()
+
+    return compile_
+
+
+def _assert_kernel(compiled):
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# ---------------------------------------------------------------------------
+# serving: the six ragged paged-attention programs at Llama-3-8B head
+# geometry and the engine's defaults for max_batch=16
+# ---------------------------------------------------------------------------
+H, HK, D = 32, 8, 128
+R, T, QB = 18, 64, 32
+MAX_CTX = 1152
+
+
+def _ragged_specs(program, page_size):
+    pages = 4 * MAX_CTX // page_size
+    pool = (pages, HK, page_size, D)
+    sidecar = (pages, HK, page_size, 1)
+    rows = [((R,), I32)]
+    tables = ((R, MAX_CTX // page_size), I32)
+    q8 = program.endswith("q8")
+    pools = [(pool, I8 if q8 else BF16)] * 2 \
+        + ([(sidecar, F32)] * 2 if q8 else [])
+    new = [((T, HK, D), BF16)] * 2
+    if program.startswith("_ragged"):
+        return [((R, QB, H, D), BF16)] + pools + [tables] + rows * 3
+    q = ((T, H, D), BF16) if "rope" in program else ((R, QB, H, D), BF16)
+    rope = [((T, D), F32)] * 2 if "rope" in program else []
+    return [q] + new + pools + [tables] + rows * 6 + rope
+
+
+@pytest.mark.parametrize("page_size", [16, 64])
+@pytest.mark.parametrize("program", [
+    "_fused_rope_impl", "_fused_impl", "_fused_rope_impl_q8",
+    "_fused_impl_q8", "_ragged_impl", "_ragged_impl_q8"])
+def test_ragged_programs_compile_bf16(chip_compile, program, page_size):
+    kw = dict(scale=D ** -0.5)
+    if program.startswith("_fused"):
+        kw["dump_page"] = 0
+    if "rope" in program:
+        kw["qblock"] = QB
+    fn = functools.partial(getattr(rpa, program), **kw)
+    _assert_kernel(chip_compile(fn, *_ragged_specs(program, page_size)))
+
+
+# ---------------------------------------------------------------------------
+# training: flash attention fwd+bwd, fused CE, and the quantized /
+# grouped matmuls
+# ---------------------------------------------------------------------------
+def _flash_grad(group):
+    flash = fa._make_flash(D ** -0.5, True, group)
+    return jax.grad(lambda q, k, v: flash(q, k, v).astype(F32).sum(),
+                    argnums=(0, 1, 2))
+
+
+def _flash_specs(b, s, h, hk, dtype):
+    return [((b, s, h, D), dtype)] + [((b, s, hk, D), dtype)] * 2
+
+
+@pytest.mark.parametrize("b,s,h,hk", [
+    (2, 2048, 16, 8),       # the "0.5b" recipe of examples/llama_pretrain
+    (1, 4096, 32, 8),       # Llama-3-8B heads at half its context
+])
+def test_flash_fwd_bwd_compiles_bf16(chip_compile, b, s, h, hk):
+    _assert_kernel(chip_compile(_flash_grad(h // hk),
+                                *_flash_specs(b, s, h, hk, BF16)))
+
+
+def test_flash_supported_agrees_with_compiler(chip_compile):
+    """Whatever ``supported()`` accepts the compiler accepts; the walk
+    must really reach Llama-3-8B's own context in bf16."""
+    walk = [(1, 8192, 16, 8, BF16), (1, 8192, 32, 8, BF16),
+            (2, 2048, 32, 8, F32), (1, 4096, 32, 8, F32),
+            (1, 8192, 32, 8, F32), (1, 16384, 32, 8, BF16)]
+    accepted = []
+    for b, s, h, hk, dtype in walk:
+        specs = _flash_specs(b, s, h, hk, dtype)
+        q, k, v = (jax.ShapeDtypeStruct(sh, dt) for sh, dt in specs)
+        if fa.supported(q, k, v, None, True):
+            accepted.append((b, s, h, hk, dtype))
+            _assert_kernel(chip_compile(_flash_grad(h // hk), *specs))
+    assert (1, 8192, 32, 8, BF16) in accepted
+    assert (1, 8192, 16, 8, BF16) in accepted
+
+
+def test_flash_under_a_mesh_compiles_for_2x2(topo, no_persistent_cache,
+                                             monkeypatch):
+    """A sharded train step cannot hold a bare Mosaic kernel (GSPMD
+    cannot partition one): ``shard_llama`` routes attention through
+    ``_mesh_attention``, which must compile for the described 2x2 with
+    its kernels in and no collective (attention mixes neither batch nor
+    heads)."""
+    import types
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from paddle_tpu.framework.tensor import Tensor
+    from paddle_tpu.models import llama
+
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    jmesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("dp", "mp"))
+    mesh = types.SimpleNamespace(dim_names=["dp", "mp"], shape=[2, 2],
+                                 to_jax_mesh=lambda: jmesh)
+
+    def loss(q, k, v):
+        out = llama._mesh_attention(Tensor(q), Tensor(k), Tensor(v), mesh,
+                                    ("dp",), "mp")
+        return out._data.astype(F32).sum()
+
+    sharding = NamedSharding(jmesh, PartitionSpec("dp", None, "mp", None))
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding)
+            for s, d in _flash_specs(2, 2048, 16, 8, BF16)]
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))) \
+        .lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3
+    assert "all-gather" not in text and "all-reduce" not in text
+
+
+@pytest.mark.parametrize("n,d,v,dtype", [
+    (4096, 2048, 32000, BF16), (4096, 4096, 128256, BF16),
+    (4096, 4096, 128256, F32)])
+def test_fused_ce_compiles(chip_compile, n, d, v, dtype):
+    _assert_kernel(chip_compile(
+        flce._kernel_parts, ((n, d), dtype), ((d, v), dtype), ((n,), I32)))
+
+
+def test_dequant_matmul_compiles_bf16(chip_compile):
+    m, k, n, block = 16, 4096, 14336, 128
+    _assert_kernel(chip_compile(
+        functools.partial(qk._kernel_impl, block=block),
+        ((m, k), BF16), ((k, n), I8), ((k // block, n), F32)))
+
+
+def test_grouped_gemm_compiles_bf16(chip_compile):
+    e, c, k, n = 8, 128, 4096, 14336
+    _assert_kernel(chip_compile(
+        gg._grouped_impl, ((e * c, k), BF16), ((e, k, n), BF16),
+        ((e,), I32)))

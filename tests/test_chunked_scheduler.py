@@ -423,6 +423,26 @@ def test_fused_mixed_hbm_gauge_recorded(model):
     engine.close()
 
 
+def test_mixed_program_does_not_return_the_weights(model):
+    """The weights are read-only state of the mixed program: an AOT
+    executable would hand a non-donated pass-through back as a fresh
+    copy (every weight, every dispatch), so they stay out of its
+    outputs — what it returns is the pools (aliased) and the tokens."""
+    from paddle_tpu.observability import metrics as om
+
+    if not om.enabled():
+        pytest.skip("PADDLE_TPU_METRICS=0")
+    engine = _engine(model)
+    before = [p._data for p in model.parameters()]
+    engine.generate([[1, 2, 3]], max_new_tokens=2)
+    weights = sum(p._data.nbytes for p in model.parameters())
+    for compiled in engine._mixed_static._aot.values():
+        ma = compiled.memory_analysis()
+        assert ma.output_size_in_bytes - ma.alias_size_in_bytes < weights / 4
+    assert all(p._data is b for p, b in zip(model.parameters(), before))
+    engine.close()
+
+
 # ----------------------------------------------------------------------
 # fused rope (PADDLE_TPU_FUSED_ROPE): rope + write + attention in one
 # Pallas program — the engine must be byte-for-byte indistinguishable

@@ -11,10 +11,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec, NamedSharding
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 import paddle_tpu as paddle
 import paddle_tpu.distributed as dist

@@ -53,7 +53,7 @@ def _fresh_state():
 def shared_cache(tmp_path_factory):
     d = tmp_path_factory.mktemp("warm")
     return {"JAX_PLATFORMS": "cpu",
-            "PADDLE_TPU_COMPILE_CACHE_DIR": str(d / "cache"),
+            "JAX_COMPILATION_CACHE_DIR": str(d / "cache"),
             "PADDLE_TPU_SHAPE_REGISTRY": str(d / "shapes.json")}
 
 

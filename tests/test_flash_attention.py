@@ -113,3 +113,29 @@ def test_sdpa_dispatches_to_pallas_and_matches():
     paddle.set_flags({"use_pallas_kernels": True})
     np.testing.assert_allclose(out_pallas.numpy(), out_naive.numpy(),
                                rtol=2e-4, atol=2e-4)
+
+
+def test_mesh_attention_matches_one_device_fwd_and_bwd():
+    """``shard_llama``'s attention path: the kernel per device under
+    ``shard_map`` (dp2 x mp2 over the virtual devices) gives the
+    one-device answer and gradients — GQA heads split over ``mp``."""
+    from paddle_tpu.distributed import ProcessMesh
+    from paddle_tpu.models.llama import _mesh_attention
+
+    mesh = ProcessMesh(np.arange(4).reshape(2, 2), dim_names=["dp", "mp"])
+    r = np.random.RandomState(3)
+    q = r.randn(2, 128, 4, 32).astype("float32") * 0.3
+    k = r.randn(2, 128, 2, 32).astype("float32") * 0.3
+    v = r.randn(2, 128, 2, 32).astype("float32") * 0.3
+
+    def run(attend):
+        ts = [paddle.to_tensor(a, stop_gradient=False) for a in (q, k, v)]
+        out = attend(*ts)
+        out.sum().backward()
+        return [out.numpy()] + [t.grad.numpy() for t in ts]
+
+    got = run(lambda a, b, c: _mesh_attention(a, b, c, mesh, ("dp",), "mp"))
+    want = run(lambda a, b, c: F.scaled_dot_product_attention(
+        a, b, c, is_causal=True))
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-6)

@@ -248,7 +248,7 @@ class TestKvPageTierUnit:
         assert t.bytes == 0
         assert t.stats()["capacity_rejections"] == 1
 
-    def test_error_taxonomy(self):
+    def test_error_hierarchy(self):
         for exc in (TierCapacityError, TierExportError,
                     TierRestoreError, TierCorruptError):
             assert issubclass(exc, TierError)

@@ -531,7 +531,7 @@ def test_chaos_soak_subprocess_rpc_faults(tmp_path):
     model = LlamaForCausalLM(tiny_llama_config(**_CFG))
     model.eval()
     env = {"JAX_PLATFORMS": "cpu",
-           "PADDLE_TPU_COMPILE_CACHE_DIR": str(tmp_path / "cache"),
+           "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache"),
            "PADDLE_TPU_SHAPE_REGISTRY": str(tmp_path / "shapes.json")}
     r0 = om.counter("rpc_retries_total").value
     # the plan is inherited by the workers (heartbeat partition fires

@@ -96,13 +96,25 @@ class TestRoofline:
         assert "attained_hbm_bw_frac" not in s
         assert _peek("paddle_tpu_perf_device_ms", "m") is not None
 
-    def test_env_peak_overrides(self, monkeypatch):
-        monkeypatch.setenv("PADDLE_TPU_PEAK_FLOPS", "2e12")
-        monkeypatch.setenv("PADDLE_TPU_PEAK_HBM_GBS", "100")
+    def test_unknown_tpu_kind_gets_no_fraction_and_no_default(
+            self, monkeypatch):
+        import jax
+
+        class _Dev:
+            platform = "tpu"
+            device_kind = "TPU v99"
+
+        monkeypatch.setattr(jax, "devices", lambda *a: [_Dev()])
         perf.reset()
-        flops, bw, _ = perf.device_peaks()
-        assert flops == 2e12
-        assert bw == 100e9
+        assert perf.device_peaks() == (None, None, "TPU v99")
+        s = perf.observe("m", 1e-3, flops=1e9, bytes_accessed=1e6)
+        assert s["device_kind"] == "TPU v99"
+        assert "attained_flops_frac" not in s
+        assert "attained_hbm_bw_frac" not in s
+        assert _peek("paddle_tpu_perf_device_ms", "m") == \
+            pytest.approx(1.0)
+        assert _peek("paddle_tpu_perf_attained_flops_frac", "m") is None
+        assert _peek("paddle_tpu_perf_attained_hbm_bw_frac", "m") is None
 
     def test_kill_switches(self, monkeypatch):
         for var in ("PADDLE_TPU_METRICS", "PADDLE_TPU_PERF"):
@@ -393,7 +405,7 @@ def test_e2e_cluster_capture_profile_two_replicas(tmp_path,
 
     warm = tmp_path_factory.mktemp("warm")
     env = {"JAX_PLATFORMS": "cpu",
-           "PADDLE_TPU_COMPILE_CACHE_DIR": str(warm / "cache"),
+           "JAX_COMPILATION_CACHE_DIR": str(warm / "cache"),
            "PADDLE_TPU_SHAPE_REGISTRY": str(warm / "shapes.json")}
     cluster = ServingCluster(
         engine_spec=_SPEC, num_replicas=2,

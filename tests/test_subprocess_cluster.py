@@ -50,7 +50,7 @@ def shared_cache(tmp_path_factory):
     only the first worker of the module pays a cold compile."""
     d = tmp_path_factory.mktemp("warm")
     return {"JAX_PLATFORMS": "cpu",
-            "PADDLE_TPU_COMPILE_CACHE_DIR": str(d / "cache"),
+            "JAX_COMPILATION_CACHE_DIR": str(d / "cache"),
             "PADDLE_TPU_SHAPE_REGISTRY": str(d / "shapes.json")}
 
 
@@ -146,7 +146,7 @@ def test_e2e_sigkill_failover_and_warm_restart(model, tmp_path):
     replacement's warm restart TTFT (persistent compile cache hits) is
     measurably below the cold TTFT recorded in the same test."""
     env = {"JAX_PLATFORMS": "cpu",
-           "PADDLE_TPU_COMPILE_CACHE_DIR": str(tmp_path / "cache"),
+           "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache"),
            "PADDLE_TPU_SHAPE_REGISTRY": str(tmp_path / "shapes.json")}
     cluster = ServingCluster(
         engine_spec=_SPEC, num_replicas=3,
