@@ -51,8 +51,7 @@ BENCH_SCHEMA_VERSION = 1
 #: the knobs that change what a bench run measures — stamped into every
 #: snapshot so a regression diff can rule out "different config"
 _PROVENANCE_KNOBS = (
-    "PADDLE_TPU_METRICS", "PADDLE_TPU_PERF",
-    "PADDLE_TPU_PERF_FENCE_INTERVAL", "PADDLE_TPU_SERVING_Q8",
+    "PADDLE_TPU_METRICS", "PADDLE_TPU_SERVING_Q8",
     "PADDLE_TPU_FUSED_KV", "PADDLE_TPU_FUSED_ROPE",
 )
 
@@ -1686,103 +1685,6 @@ def bench_trace_overhead(model, on_tpu=True):
     }
 
 
-def bench_perf_overhead(model, on_tpu=True):
-    """Perf-attribution tax at the cluster tier: tokens/sec through a
-    ServingCluster with the roofline/sentinel layer active (host timer
-    every dispatch, aggressive 50 ms fence throttle) vs
-    ``PADDLE_TPU_PERF=0``. ``perf_overhead_frac`` is the fractional
-    rate loss; the gate ``perf_overhead_ok`` requires <= 3% — the same
-    bar as ``trace_overhead_ok``. Also reports the roofline readings
-    attribution produced for the busiest serving callable during the
-    run (the numbers an on-chip sweep publishes as
-    ``paddle_tpu_perf_*`` gauges)."""
-    from paddle_tpu.inference.cluster import ServingCluster
-    from paddle_tpu.inference.serving import LlamaServingEngine
-    from paddle_tpu.observability import perf as _perf
-
-    model.eval()
-    max_batch = 8 if on_tpu else 2
-    new_tokens = 48 if on_tpu else 64
-    n_reqs = 24 if on_tpu else 12
-    rounds = 3 if on_tpu else 4
-    cluster = ServingCluster(
-        engine_factory=lambda: LlamaServingEngine(
-            model, max_batch=max_batch, page_size=64,
-            num_pages=max_batch * 8 + 8, max_pages_per_seq=8,
-            prefix_cache=False),
-        num_replicas=1, max_backlog=n_reqs * 2)
-    cluster.start()
-    rng = np.random.RandomState(0)
-    v = model.config.vocab_size
-    prompts = [rng.randint(0, v, (24,)).tolist() for _ in range(n_reqs)]
-
-    saved = {k: os.environ.get(k) for k in
-             ("PADDLE_TPU_PERF", "PADDLE_TPU_PERF_FENCE_INTERVAL")}
-
-    def mode(attribution_on):
-        if attribution_on:
-            os.environ["PADDLE_TPU_PERF"] = "1"
-            os.environ["PADDLE_TPU_PERF_FENCE_INTERVAL"] = "0.05"
-        else:
-            os.environ["PADDLE_TPU_PERF"] = "0"
-
-    def run():
-        reqs = []
-        t0 = time.perf_counter()
-        for p in prompts:
-            reqs.append(cluster.submit(p, max_new_tokens=new_tokens))
-        for r in reqs:
-            r.wait(300.0)
-        wall = time.perf_counter() - t0
-        return sum(len(r.output_ids) for r in reqs) / wall
-
-    try:
-        mode(True)
-        run()               # warm: compile + populate roofline gauges
-        on, off = [], []
-        for _ in range(rounds):  # interleave to share thermal/jit drift
-            mode(False)
-            off.append(run())
-            mode(True)
-            on.append(run())
-    finally:
-        for k, old in saved.items():
-            if old is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = old
-    cluster.stop()
-    model.train()
-    # best-of per mode (see bench_trace_overhead): noise only slows
-    tps_on, tps_off = max(on), max(off)
-    frac = round(max(0.0, 1.0 - tps_on / max(tps_off, 1e-9)), 3)
-    out = {
-        "perf_tokens_per_sec_on": round(tps_on, 1),
-        "perf_tokens_per_sec_off": round(tps_off, 1),
-        "perf_overhead_frac": frac,
-        "perf_overhead_ok": bool(frac <= 0.03),
-    }
-    serving = {n: s for n, s in _perf.recorders().items()
-               if n.startswith("serving.")}
-    if serving:
-        name, st = max(serving.items(),
-                       key=lambda kv: kv[1]["samples"])
-        peak_flops, peak_bw, _ = _perf.device_peaks()
-        out["perf_serving_callable"] = name
-        if st["device_ewma_ms"]:
-            dev_s = st["device_ewma_ms"] / 1e3
-            out["perf_serving_device_ms"] = round(
-                st["device_ewma_ms"], 3)
-            if st["flops"] and peak_flops:
-                out["perf_serving_flops_frac"] = round(
-                    min(1.0, st["flops"] / (dev_s * peak_flops)), 5)
-            if st["bytes_accessed"] and peak_bw:
-                out["perf_serving_hbm_frac"] = round(
-                    min(1.0, st["bytes_accessed"] / (dev_s * peak_bw)),
-                    5)
-    return out
-
-
 def bench_fused_ce(on_tpu=True):
     """Chunked fused cross-entropy lm-head vs the materialized logits
     path at an 8k+ vocab config: fwd+bwd step time, static peak-memory
@@ -2103,9 +2005,6 @@ def main():
     _run_section(result, "trace_overhead",
                  lambda: bench_trace_overhead(_model(), on_tpu=on_tpu),
                  label="trace-overhead")
-    _run_section(result, "perf_overhead",
-                 lambda: bench_perf_overhead(_model(), on_tpu=on_tpu),
-                 label="perf-overhead")
     _run_section(result, "fused_ce",
                  lambda: bench_fused_ce(on_tpu=on_tpu),
                  label="fused-ce")

@@ -273,6 +273,7 @@ class _null_ctx:
         return False
 
 
+@jax.named_scope("backward")        # a traced step's ops carry the phase
 def backward(tensors, grad_tensors=None, retain_graph=False):
     """``paddle.autograd.backward`` — accumulate into ``.grad`` of leaves."""
     if grad_tensors is None:

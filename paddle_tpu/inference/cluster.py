@@ -59,6 +59,7 @@ exactly once (first terminal report wins, token-exact;
 from __future__ import annotations
 
 import collections
+import contextlib
 import json
 import logging
 import os
@@ -273,6 +274,7 @@ class ClusterRequest:
                         self.priority, self.retry_budget,
                         sampling=self.sampling, stop=self.stop,
                         on_token=self._attempt_token)
+            r._t_submit = self._t_submit
             self.request = r
             self.replica_id = replica_id
             self.status = "live"
@@ -657,14 +659,24 @@ class EngineReplica:
                 _faults.fire("replica.dead", step=self._ticks,
                              path=self.replica_id)
                 self._ticks += 1
-                self._admit_from_backlog()
-                served = 0
                 e = self.engine
-                if e is not None \
-                        and any(not r.done for r in e._live.values()):
-                    served = e.decode_many(self.burst) if self.burst \
-                        else e.step()
-                self._reap_completed()
+                with self._lock:
+                    queued = bool(self._backlog)
+                live = e is not None \
+                    and any(not r.done for r in e._live.values())
+                # one span a turn that has work (an idle sleep is not a
+                # span); its self time, the turn less its dispatches,
+                # is the loop's own cost
+                with _span("replica.tick") if queued or live \
+                        else contextlib.nullcontext() as tick:
+                    admitted = len(self._admit_from_backlog())
+                    served = 0
+                    if live or admitted:
+                        served = e.decode_many(self.burst) if self.burst \
+                            else e.step()
+                    reaped = self._reap_completed()
+                    if tick is not None:
+                        tick.set(admitted=admitted, reaped=reaped)
                 with self._lock:
                     idle = not served and not self._backlog
                 if idle:
@@ -743,6 +755,7 @@ class EngineReplica:
                 del self._tracked[r]
         for r, c in finished:
             c._finish_from(r)
+        return len(finished)
 
     # -- lifecycle ------------------------------------------------------
     def begin_drain(self):

@@ -136,6 +136,7 @@ from ..observability import compile_watch as _cw
 from ..observability import flight_recorder as _fr
 from ..observability import metrics as _om
 from ..observability import tracing as _tracing
+from ..observability.trace import record as _record_span
 from ..observability.trace import span as _span
 from ..ops.ragged_paged_attention import (fused_ragged_paged_attention,
                                           fused_rope_geometry_ok,
@@ -285,6 +286,22 @@ def _serving_metrics():
         "ttft": _om.histogram(
             "serving_ttft_seconds",
             "admission -> first emitted token", buckets=_LATENCY_BUCKETS),
+        "queue_wait": _om.histogram(
+            "serving_queue_wait_seconds",
+            "submission (the cluster's submit(); the engine's own "
+            "admission call without one) -> first admission into the "
+            "continuous batch", buckets=_LATENCY_BUCKETS),
+        "dispatches": _om.counter(
+            "serving_dispatches_total",
+            "step programs dispatched, by kind: mixed (prefill chunks "
+            "and decode rows), decode (one token a row) or scan",
+            labelnames=("kind",)),
+        "dispatch_tokens": _om.counter(
+            "serving_dispatch_tokens_total",
+            "token slots of the dispatched programs, by what filled "
+            "them: prefill, decode (speculative drafts among them) or "
+            "pad (slots the program's shape had and no row used)",
+            labelnames=("kind",)),
         "tpot": _om.histogram(
             "serving_token_latency_seconds",
             "per-token decode latency (scan dispatches amortized)",
@@ -522,6 +539,12 @@ class Request:
         self.error = None             # typed terminal failure, or None
         self.trimmed = False          # budget cut by the ladder
         self._t_admit = None          # set at admission; drives TTFT
+        # the life of the request as the `serving.request` span tells it
+        # (perf_counter, each stamped once and never shifted)
+        self._t_submit = None         # the cluster's submit(), if any
+        self._t_first_admit = None
+        self._t_first_chunk = None    # first dispatch with a prefill row
+        self._t_first_token = None
         self._expires_at = None       # perf_counter stamp, or None
         self._cancel_requested = False  # honored at (re-)admission
         self._cached_tokens = 0       # prefix tokens served from cache
@@ -916,11 +939,24 @@ class LlamaServingEngine:
             req.done = True
             req.status = status
             req.error = error
-            self._spec_state.pop(req.seq_id, None)
-            if req.seq_id in self._live:
-                del self._live[req.seq_id]
-                self._release_pages(req.seq_id)
-            return True
+            sid = req.seq_id
+            self._spec_state.pop(sid, None)
+            if sid in self._live:
+                del self._live[sid]
+                self._release_pages(sid)
+        now = time.perf_counter()
+        rctx = getattr(req, "_trace", None)
+        _record_span(
+            "serving.request",
+            now if req._t_submit is None else req._t_submit, now,
+            seq_id=sid, t_submit=req._t_submit,
+            t_admit=req._t_first_admit, t_first_chunk=req._t_first_chunk,
+            t_first_token=req._t_first_token, t_done=now,
+            prompt_len=len(req.prompt_ids),
+            output_len=len(req.output_ids),
+            cached_tokens=int(req._cached_tokens), status=status,
+            **({} if rctx is None else {"trace_id": rctx.trace_id}))
+        return True
 
     def _expire(self, req, reason="deadline", now=None):
         now = time.perf_counter() if now is None else now
@@ -1556,12 +1592,12 @@ class LlamaServingEngine:
         return temps, top_ps, top_ks, seeds, slot_ids, slot_vals, cmodes
 
     def _dispatch_rows(self, rows, cow):
-        """Dispatch ONE mixed program over an already-scheduled row
-        list (caller holds the dispatch locks) and apply the results:
-        prefill progress, prefix-cache pins, speculative verification
-        (accept the longest exactly-matching draft prefix, roll back
-        rejected draft pages), emitted tokens. Returns tokens
-        emitted."""
+        """Build and enqueue ONE mixed program over an already-scheduled
+        row list (caller holds the dispatch locks): copy-on-write, the
+        host-built metadata, its transfer, the enqueue. Returns what
+        :meth:`_apply_rows` needs: ``(next tokens still on the device,
+        each row's first index in the T axis, enqueue seconds, cold,
+        needs_mixed, t_cap)``."""
         # speculative verify rows are multi-token decode rows: they
         # need the chunk-shaped program exactly like prefill chunks do
         needs_mixed = any(n > 1 or not is_dec
@@ -1597,6 +1633,10 @@ class LlamaServingEngine:
                         # request time
                         r._expires_at += warm_dur
             cold = False
+        now = time.perf_counter()
+        for r, _, _, _, _, is_dec in rows:
+            if not is_dec and r._t_first_chunk is None:
+                r._t_first_chunk = now
         # host-built metadata: reads of the allocator's tables are safe
         # here — cross-thread releases defer past the whole _entry
         tokens = np.zeros((1, t_cap), np.int64)
@@ -1689,7 +1729,14 @@ class LlamaServingEngine:
         self.k_pools, self.v_pools = list(new_k), list(new_v)
         if self.kv_quant:
             self.k_scales, self.v_scales = list(new_ks), list(new_vs)
-        out = np.asarray(nxt._data).reshape(-1)          # [t_cap]
+        return nxt, flat_start, dur, cold, needs_mixed, t_cap
+
+    def _apply_rows(self, rows, out, flat_start, dur, cold, needs_mixed):
+        """Apply one mixed dispatch's next tokens ``out`` (``[t_cap]``,
+        on the host): prefill progress, prefix-cache pins, speculative
+        verification (accept the longest exactly-matching draft prefix,
+        roll back rejected draft pages), emitted tokens. Returns tokens
+        emitted."""
         if not cold and not needs_mixed:
             # a pure-decode dispatch is one token per live row: honest
             # per-token latency. Mixed dispatches carry prefill work
@@ -2606,6 +2653,11 @@ class LlamaServingEngine:
         now = time.perf_counter()
         with self._lock:
             req._t_admit = now
+            if req._t_submit is None:
+                req._t_submit = now
+            if req._t_first_admit is None:
+                req._t_first_admit = now
+                self._m["queue_wait"].observe(now - req._t_submit)
             ttl = None
             if req.deadline is not None:
                 ttl = req.deadline
@@ -2633,15 +2685,22 @@ class LlamaServingEngine:
     def _emit(self, req, token):
         first = not req.output_ids
         if first and req._t_admit is not None:
-            ttft = time.perf_counter() - req._t_admit
+            now = time.perf_counter()
+            ttft = now - req._t_admit
             self._m["ttft"].observe(ttft)
-            # a zero-width marker node in the request's distributed
-            # trace: where the first token landed, on which pid
-            rctx = getattr(req, "_trace", None)
-            if rctx is not None:
-                with _tracing.activate(rctx), \
+            if req._t_first_token is None:
+                req._t_first_token = now
+                # a zero-width marker: where the first token landed, on
+                # which pid, after which waits (a request that has not
+                # retired yet has no `serving.request`); a node of the
+                # request's distributed trace where it has one
+                with _tracing.activate(getattr(req, "_trace", None)), \
                         _span("serving.first_token",
-                              ttft_seconds=round(ttft, 6)):
+                              ttft_seconds=round(ttft, 6),
+                              seq_id=req.seq_id, t_submit=req._t_submit,
+                              t_admit=req._t_first_admit,
+                              t_first_chunk=req._t_first_chunk,
+                              t_first_token=now):
                     pass
         # stop tokens are checked BEFORE the append: the request
         # retires ``completed`` with the stop token excluded from its
@@ -2680,33 +2739,84 @@ class LlamaServingEngine:
         """One mixed dispatch. Returns (rows dispatched, tokens
         emitted) — a dispatch that only advanced mid-prompt chunks
         reports rows > 0 with emitted == 0."""
-        with self._entry(), self._dispatch_lock, _CROSS_ENGINE_LOCK:
-            self._expire_deadlines()
-            self._pump_requeue()
-            with self._lock:
-                if not any(not r.done for r in self._live.values()):
+        # one `serving.dispatch` span a dispatch, its four phases under
+        # it sharing `step`; a turn that dispatches nothing records none
+        with _span("serving.dispatch") as disp, \
+                contextlib.ExitStack() as locks:
+            with _span("serving.schedule") as sched:
+                step = self._enter_dispatch(locks, disp, sched)
+                rows, cow = self._plan_rows()
+                if not rows:
+                    sched.cancel()
+                    disp.cancel()
                     return 0, 0
-            # before any allocator mutation: an injected raise aborts
-            # the dispatch cleanly instead of leaving lens advanced
-            # with no K/V written
-            _faults.fire("serve.decode", step=self._dispatch_count)
-            self._dispatch_count += 1
-            with self._lock:
-                # rows are snapshotted under the lock: a concurrent
-                # cancel/evict may null seq_id or swap output_ids
-                # mid-setup, but this dispatch keeps reading its own
-                # consistent view (the pages stay reserved —
-                # cross-thread releases defer past _entry); the decode
-                # extends happen while still holding the lock, so a
-                # concurrent admission can't consume the pages between
-                # _relieve_pressure's proof and the extend
-                rows, cow = self._schedule_rows()
-            if not rows:
-                return 0, 0
-            emitted = self._dispatch_rows(rows, cow)
-            self._expire_deadlines()
-            self._set_pool_gauges()
+            with _span("serving.build", step=step):
+                nxt, flat_start, dur, cold, needs_mixed, t_cap = \
+                    self._dispatch_rows(rows, cow)
+            with _span("serving.wait", step=step):
+                out = np.asarray(nxt._data).reshape(-1)      # [t_cap]
+            with _span("serving.apply", step=step) as applied:
+                emitted = self._apply_rows(rows, out, flat_start, dur,
+                                           cold, needs_mixed)
+                self._expire_deadlines()
+                self._set_pool_gauges()
+                applied.set(emitted=emitted)
+            kind = "mixed" if needs_mixed else "decode"
+            tokens = sum(row[3] for row in rows)
+            prefill = sum(row[3] for row in rows if not row[5])
+            disp.set(rows=len(rows),
+                     decode_rows=sum(1 for row in rows if row[5]),
+                     prefill_tokens=prefill, tokens=tokens, t_cap=t_cap,
+                     kind=kind)
+            self._count_dispatch(kind, prefill, tokens - prefill,
+                                t_cap - tokens)
             return len(rows), emitted
+
+    def _enter_dispatch(self, locks, disp, sched):
+        """Take the dispatch locks (held until ``locks`` closes) under
+        the open ``serving.schedule`` span and give it and its
+        ``serving.dispatch`` the dispatch's ``step``, which it returns."""
+        t_lock = time.perf_counter()
+        locks.enter_context(self._entry())
+        locks.enter_context(self._dispatch_lock)
+        locks.enter_context(_CROSS_ENGINE_LOCK)
+        step = self._dispatch_count
+        disp.set(step=step)
+        sched.set(step=step, lock_wait_s=time.perf_counter() - t_lock)
+        return step
+
+    def _plan_rows(self):
+        """The scheduling half of a mixed step (dispatch locks held):
+        expire, pump the requeue, count the dispatch, schedule its rows.
+        Returns ``(rows, cow)``; no rows means nothing to dispatch."""
+        self._expire_deadlines()
+        self._pump_requeue()
+        with self._lock:
+            if not any(not r.done for r in self._live.values()):
+                return [], []
+        # before any allocator mutation: an injected raise aborts
+        # the dispatch cleanly instead of leaving lens advanced
+        # with no K/V written
+        _faults.fire("serve.decode", step=self._dispatch_count)
+        self._dispatch_count += 1
+        with self._lock:
+            # rows are snapshotted under the lock: a concurrent
+            # cancel/evict may null seq_id or swap output_ids
+            # mid-setup, but this dispatch keeps reading its own
+            # consistent view (the pages stay reserved —
+            # cross-thread releases defer past _entry); the decode
+            # extends happen while still holding the lock, so a
+            # concurrent admission can't consume the pages between
+            # _relieve_pressure's proof and the extend
+            return self._schedule_rows()
+
+    def _count_dispatch(self, kind, prefill, decode, pad):
+        """Count one dispatched program and what filled its slots."""
+        self._m["dispatches"].labels(kind).inc()
+        for what, n in (("prefill", prefill), ("decode", decode),
+                        ("pad", pad)):
+            if n:
+                self._m["dispatch_tokens"].labels(what).inc(n)
 
     # ------------------------------------------------------------------
     # decode scan: n all-decode ticks = ONE compiled program (lax.scan)
@@ -2804,104 +2914,132 @@ class LlamaServingEngine:
         requests that retire mid-scan (EOS / max_new_tokens / expired
         deadline) have their tail tokens discarded at emit time —
         bounded waste, no correctness impact."""
-        with self._entry(), self._dispatch_lock, _CROSS_ENGINE_LOCK:
-            self._expire_deadlines()
-            self._pump_requeue()
-            with self._lock:
-                if n <= 0 or not any(not r.done
-                                     for r in self._live.values()):
+        # the same spans as a mixed step, kind "scan"
+        with _span("serving.dispatch", kind="scan") as disp, \
+                contextlib.ExitStack() as locks:
+            with _span("serving.schedule") as sched:
+                step = self._enter_dispatch(locks, disp, sched)
+                live, sids, last_tok, start_lens, cow = self._plan_scan(n)
+                if not live:
+                    sched.cancel()
+                    disp.cancel()
                     return 0
-            # as in step(): fire before any allocator mutation
-            _faults.fire("serve.decode", step=self._dispatch_count)
-            self._dispatch_count += 1
-            with self._lock:
-                live = [r for r in self._live.values() if not r.done
-                        and r._prefilled >= len(r.prompt_ids)]
-                live = self._relieve_pressure(live, n)
-                sids = [r.seq_id for r in live]
-                last_tok = [r.output_ids[-1] if r.output_ids
-                            else int(r.prompt_ids[-1]) for r in live]
-                # reserve the whole scan under the lock (see step())
-                start_lens = {sid: self.alloc._lens[sid] for sid in sids}
-                cow = []
-                for sid in sids:
-                    self.alloc.extend(sid, n)
-                    # only the scan's FIRST write position can sit in
-                    # a pre-existing (possibly shared) page; the rest
-                    # land in pages this extend just allocated
-                    cp = self.alloc.ensure_writable(sid, start_lens[sid])
-                    if cp is not None:
-                        cow.append(cp)
-            if not live:
-                return 0
-            for old, new in cow:
-                self._copy_page(old, new)
-            # as in step(): each new scan length compiles on its first
-            # call — don't let that land n inflated samples in tpot
-            key = ("scan", n)
-            cold = key not in self._warmed_keys
-            t0 = time.perf_counter()
-            b = self.max_batch
-            tables = np.full((b, self.width), self.trash_page, np.int32)
-            lens = np.ones((b,), np.int32)
-            tokens = np.zeros((b, 1), np.int64)
-            for i, sid in enumerate(sids):
-                t = self.alloc._tables[sid]
-                tables[i, :len(t)] = t
-                lens[i] = start_lens[sid] + 1       # first new token incl.
-                tokens[i, 0] = last_tok[i]
-            (temps, top_ps, top_ks, seeds, slot_ids, slot_vals,
-             cmodes) = self._sample_arrays(live, b)
-            sf = self._ensure_scan_compiled(n)
-            self._arm_watchdog(cold)
-            with self._lock:
-                self._in_dispatch = True
-            try:
-                with no_grad(), _span("serving.decode_scan",
-                                      live=len(live), ticks=n):
-                    out = sf(
-                        Tensor(jnp.asarray(tokens)),
-                        Tensor(jnp.asarray(tables)),
-                        Tensor(jnp.asarray(lens)),
-                        Tensor(jnp.asarray(temps)),
-                        Tensor(jnp.asarray(top_ps)),
-                        Tensor(jnp.asarray(top_ks)),
-                        Tensor(jnp.asarray(seeds)),
-                        Tensor(jnp.asarray(slot_ids)),
-                        Tensor(jnp.asarray(slot_vals)),
-                        Tensor(jnp.asarray(cmodes)),
-                        self.k_pools, self.v_pools,
-                        self.k_scales, self.v_scales)
-            finally:
-                with self._lock:
-                    self._in_dispatch = False
-                dur = time.perf_counter() - t0
-                self._disarm_watchdog(dur, cold=cold)
-                self._warmed_keys.add(key)
-            self._flush_deferred()
-            toks = out[0]
-            self._adopt_scan_pools(out)
-            all_tokens = np.asarray(toks._data)          # one D2H
-            # one scan tick serves every live row: per-token latency is
-            # the dispatch wall time amortized over the n ticks
-            if not cold:
-                tick = dur / n
-                self._token_times.append(tick)
-                for _ in range(n):
-                    self._m["tpot"].observe(tick)
-            served = 0
-            for i, r in enumerate(live):
-                for t in range(n):
-                    # done: retired mid-scan (EOS / budget); seq_id
-                    # mismatch: evicted + requeued mid-dispatch — the
-                    # stale tail must not land in its cleared output
-                    if r.done or r.seq_id != sids[i]:
-                        break
-                    self._emit(r, int(all_tokens[i, t]))
-                    served += 1
-            self._expire_deadlines()
-            self._set_pool_gauges()
+            with _span("serving.build", step=step):
+                out, dur, cold = self._dispatch_scan(
+                    n, live, sids, last_tok, start_lens, cow)
+            with _span("serving.wait", step=step):
+                all_tokens = np.asarray(out[0]._data)        # one D2H
+            with _span("serving.apply", step=step) as applied:
+                # one scan tick serves every live row: per-token latency
+                # is the dispatch wall time amortized over the n ticks
+                if not cold:
+                    tick = dur / n
+                    self._token_times.append(tick)
+                    for _ in range(n):
+                        self._m["tpot"].observe(tick)
+                served = 0
+                for i, r in enumerate(live):
+                    for t in range(n):
+                        # done: retired mid-scan (EOS / budget); seq_id
+                        # mismatch: evicted + requeued mid-dispatch — the
+                        # stale tail must not land in its cleared output
+                        if r.done or r.seq_id != sids[i]:
+                            break
+                        self._emit(r, int(all_tokens[i, t]))
+                        served += 1
+                self._expire_deadlines()
+                self._set_pool_gauges()
+                applied.set(emitted=served)
+            tokens, t_cap = len(live) * n, self.max_batch * n
+            disp.set(rows=len(live), decode_rows=len(live),
+                     prefill_tokens=0, tokens=tokens, t_cap=t_cap)
+            self._count_dispatch("scan", 0, tokens, t_cap - tokens)
             return served
+
+    def _plan_scan(self, n):
+        """The scheduling half of a decode scan (dispatch locks held).
+        Returns ``(live, sids, last_tok, start_lens, cow)``; no live
+        rows means nothing to dispatch."""
+        nothing = [], [], [], {}, []
+        self._expire_deadlines()
+        self._pump_requeue()
+        with self._lock:
+            if n <= 0 or not any(not r.done
+                                 for r in self._live.values()):
+                return nothing
+        # as in step(): fire before any allocator mutation
+        _faults.fire("serve.decode", step=self._dispatch_count)
+        self._dispatch_count += 1
+        with self._lock:
+            live = [r for r in self._live.values() if not r.done
+                    and r._prefilled >= len(r.prompt_ids)]
+            live = self._relieve_pressure(live, n)
+            sids = [r.seq_id for r in live]
+            last_tok = [r.output_ids[-1] if r.output_ids
+                        else int(r.prompt_ids[-1]) for r in live]
+            # reserve the whole scan under the lock (see step())
+            start_lens = {sid: self.alloc._lens[sid] for sid in sids}
+            cow = []
+            for sid in sids:
+                self.alloc.extend(sid, n)
+                # only the scan's FIRST write position can sit in
+                # a pre-existing (possibly shared) page; the rest
+                # land in pages this extend just allocated
+                cp = self.alloc.ensure_writable(sid, start_lens[sid])
+                if cp is not None:
+                    cow.append(cp)
+        return live, sids, last_tok, start_lens, cow
+
+    def _dispatch_scan(self, n, live, sids, last_tok, start_lens, cow):
+        """Build and enqueue the ``n``-tick scan over the planned rows.
+        Returns ``(the program's outputs, enqueue seconds, cold)``."""
+        for old, new in cow:
+            self._copy_page(old, new)
+        # as in step(): each new scan length compiles on its first
+        # call — don't let that land n inflated samples in tpot
+        key = ("scan", n)
+        cold = key not in self._warmed_keys
+        t0 = time.perf_counter()
+        b = self.max_batch
+        tables = np.full((b, self.width), self.trash_page, np.int32)
+        lens = np.ones((b,), np.int32)
+        tokens = np.zeros((b, 1), np.int64)
+        for i, sid in enumerate(sids):
+            t = self.alloc._tables[sid]
+            tables[i, :len(t)] = t
+            lens[i] = start_lens[sid] + 1       # first new token incl.
+            tokens[i, 0] = last_tok[i]
+        (temps, top_ps, top_ks, seeds, slot_ids, slot_vals,
+         cmodes) = self._sample_arrays(live, b)
+        sf = self._ensure_scan_compiled(n)
+        self._arm_watchdog(cold)
+        with self._lock:
+            self._in_dispatch = True
+        try:
+            with no_grad(), _span("serving.decode_scan",
+                                  live=len(live), ticks=n):
+                out = sf(
+                    Tensor(jnp.asarray(tokens)),
+                    Tensor(jnp.asarray(tables)),
+                    Tensor(jnp.asarray(lens)),
+                    Tensor(jnp.asarray(temps)),
+                    Tensor(jnp.asarray(top_ps)),
+                    Tensor(jnp.asarray(top_ks)),
+                    Tensor(jnp.asarray(seeds)),
+                    Tensor(jnp.asarray(slot_ids)),
+                    Tensor(jnp.asarray(slot_vals)),
+                    Tensor(jnp.asarray(cmodes)),
+                    self.k_pools, self.v_pools,
+                    self.k_scales, self.v_scales)
+        finally:
+            with self._lock:
+                self._in_dispatch = False
+            dur = time.perf_counter() - t0
+            self._disarm_watchdog(dur, cold=cold)
+            self._warmed_keys.add(key)
+        self._flush_deferred()
+        self._adopt_scan_pools(out)
+        return out, dur, cold
 
     def _scan_fits(self, live, n):
         """Largest scan <= n whose page reservations fit the pool and

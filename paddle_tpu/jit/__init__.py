@@ -24,7 +24,6 @@ so LR schedules and randomness never retrace.
 from __future__ import annotations
 
 import functools
-import time
 
 import jax
 import jax.numpy as jnp
@@ -392,12 +391,7 @@ class StaticFunction:
             compiled = self._aot[sig] = _cw.watch(self._watch_name) \
                 .aot_compile(jitted, step_args, desc=self._sig_desc(sig))
         try:
-            from ..observability import perf as _perf
-
-            t0 = time.perf_counter()
-            out = compiled(*step_args)
-            _perf.note_dispatch(self._watch_name, compiled, out, t0)
-            return out
+            return compiled(*step_args)
         except _cw.AOT_MISMATCH_ERRORS:
             # the cache signature tracks user inputs, not state avals: a
             # state drift the signature can't see (the model cast to a

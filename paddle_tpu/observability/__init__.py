@@ -6,9 +6,8 @@ tracer with chrome-trace export (`trace`), distributed trace-context
 propagation + cross-process trace merging (`tracing`), Prometheus/
 JSON/HTTP exporters (`export`), the XLA compile watcher +
 device-memory gauges (`compile_watch`), the crash flight recorder
-(`flight_recorder`), the SLO burn-rate engine (`slo`), and the perf
-attribution layer — roofline gauges, the EWMA perf sentinel, and
-on-demand profiler capture (`perf`).
+(`flight_recorder`), the SLO burn-rate engine (`slo`), and build
+identity, the device-peak table and on-demand profiler capture (`perf`).
 ``PADDLE_TPU_METRICS=0`` turns the whole layer into no-ops. See README
 "Observability" for the standard metric names.
 """

@@ -726,12 +726,7 @@ def watched_jit(fun, name=None, **jit_kwargs):
             compiled = cache[key] = watch(watch_name).aot_compile(
                 jitted, args, kwargs, desc=_key_desc(key))
         try:
-            from . import perf as _perf
-
-            t0 = time.perf_counter()
-            out = compiled(*args, **kwargs)
-            _perf.note_dispatch(watch_name, compiled, out, t0)
-            return out
+            return compiled(*args, **kwargs)
         except AOT_MISMATCH_ERRORS:
             # aval drift the key cannot see (weak->strong type, a
             # sharding change): plain jit retraces transparently — stop

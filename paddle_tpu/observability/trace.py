@@ -8,8 +8,16 @@ under the same ``<log_dir>/plugins/profile/<run>/`` layout the profiler
 uses, so TensorBoard's profile plugin and Perfetto load host spans next
 to device traces.
 
+Every span is also a ``jax.profiler.TraceAnnotation`` of the same name
+with the span's args as its stats: whenever a profiler session runs
+(``paddle_tpu.profiler.Profiler``, ``/debug/profile``, a benchmark's
+traced run) the span is an event on a host plane of the same
+``.xplane.pb``, on the clock of the device's "XLA Ops" line. With no
+session open the annotation is an inactive TraceMe (well under a
+microsecond) and no backend is touched.
+
 Tracing obeys the same kill switch as metrics: ``PADDLE_TPU_METRICS=0``
-makes ``span`` a no-op and records nothing.
+makes ``span`` a no-op: it records nothing and opens no annotation.
 """
 
 from __future__ import annotations
@@ -22,11 +30,14 @@ import threading
 import time
 from collections import deque
 
+from jax.profiler import TraceAnnotation as _TraceAnnotation
+
 from . import tracing as _tracing
 from .metrics import enabled
 
-__all__ = ["span", "TraceBuffer", "default_buffer", "get_events", "clear",
-           "export_chrome_trace", "unique_run_name", "epoch_unix"]
+__all__ = ["span", "record", "TraceBuffer", "default_buffer", "get_events",
+           "clear", "export_chrome_trace", "unique_run_name", "epoch_unix",
+           "to_perf_counter", "RING_CAPACITY"]
 
 #: process epoch — span timestamps are microseconds since this point.
 #: Spans are stamped off the MONOTONIC clock (an NTP step mid-run must
@@ -37,17 +48,33 @@ _EPOCH = time.perf_counter()
 _EPOCH_UNIX = time.time() - (time.perf_counter() - _EPOCH)
 
 
+def to_perf_counter(ts_us):
+    """The ``time.perf_counter()`` reading at which a ring timestamp
+    (an event's ``ts``, microseconds on this process's span clock) was
+    taken: what a reader needs to cut the ring to a window it timed
+    with ``perf_counter`` itself."""
+    return _EPOCH + ts_us / 1e6
+
+
 def epoch_unix():
     """Unix time (seconds) at which this process's span clock reads 0 —
     the recorded monotonic<->epoch clock offset."""
     return _EPOCH_UNIX
 
 
+#: default ring size, from a count: the serving loop records 7 spans a
+#: dispatch (``replica.tick``, ``serving.dispatch`` and its five children)
+#: and about 4 a request; a benchmark window of 51 s at 60 dispatches a
+#: second (six times today's fastest cell) is 21,420 spans and 2,000
+#: requests another 8,000: 2**15 keeps it whole
+RING_CAPACITY = 32768
+
+
 class TraceBuffer:
     """Bounded, thread-safe ring of chrome-trace events (oldest spans
     fall off the back once ``capacity`` is reached)."""
 
-    def __init__(self, capacity=4096):
+    def __init__(self, capacity=RING_CAPACITY):
         self._events = deque(maxlen=int(capacity))
         self._lock = threading.Lock()
 
@@ -83,6 +110,25 @@ def clear():
     _default_buffer.clear()
 
 
+def _event(name, t0, t1, args):
+    """One complete ("X") chrome-trace event from two ``perf_counter``
+    readings."""
+    event = {"name": name, "ph": "X", "ts": (t0 - _EPOCH) * 1e6,
+             "dur": (t1 - t0) * 1e6, "pid": os.getpid(),
+             "tid": threading.get_ident()}
+    if args:
+        event["args"] = args
+    return event
+
+
+def record(name, t_start, t_end, **args):
+    """Write one finished span from ``time.perf_counter()`` stamps its
+    owner kept (a request's life is known only when it retires). Ring
+    only: an annotation cannot be opened in the past."""
+    if enabled():
+        _default_buffer.add(_event(name, t_start, t_end, args))
+
+
 class span:
     """Record a named host span.
 
@@ -103,10 +149,14 @@ class span:
     its args. ``trace_ctx=`` installs a pre-allocated context verbatim
     instead — how rpc records its call span under the exact identity
     the envelope carried across the process boundary.
+
+    ``set(**args)`` adds args an open span learns late (a dispatch
+    knows its row count only after scheduling); ``cancel()`` makes an
+    open span record nothing (a loop turn that served nothing).
     """
 
     __slots__ = ("name", "args", "buffer", "_t0", "_trace_ctx_in",
-                 "_trace_ctx", "_trace_token")
+                 "_trace_ctx", "_trace_token", "_annotation")
 
     def __init__(self, name, buffer=None, trace_ctx=None, **args):
         self.name = name
@@ -116,40 +166,54 @@ class span:
         self._trace_ctx_in = trace_ctx
         self._trace_ctx = None
         self._trace_token = None
+        self._annotation = None
+
+    def set(self, **args):
+        """Add args to an open span (and to its annotation's stats)."""
+        if self._t0 is not None:
+            self.args = {**self.args, **args} if self.args else args
+            self._annotation.set_metadata(**args)
+
+    def cancel(self):
+        """Close an open span without recording it. An annotation cannot
+        be taken back: a profiler session shows it with the stat
+        ``cancelled``, which readers of the trace leave out."""
+        if self._t0 is not None:
+            self._annotation.set_metadata(cancelled=1)
+            self._close()
+
+    def _close(self):
+        """Leave the annotation and the trace context; returns the
+        context the span ran under (None outside a distributed trace)."""
+        self._t0 = None
+        self._annotation.__exit__(None, None, None)
+        self._annotation = None
+        ctx, self._trace_ctx = self._trace_ctx, None
+        _tracing._exit_span(self._trace_token)
+        self._trace_token = None
+        return ctx
 
     def __enter__(self):
         if enabled():
             self._trace_ctx, self._trace_token = \
                 _tracing._enter_span(self._trace_ctx_in)
             self._t0 = time.perf_counter()
+            self._annotation = _TraceAnnotation(self.name,
+                                                **(self.args or {}))
+            self._annotation.__enter__()
         return self
 
     def __exit__(self, *exc):
         t0 = self._t0
         if t0 is None:
             return False
-        now = time.perf_counter()
-        event = {
-            "name": self.name,
-            "ph": "X",
-            "ts": (t0 - _EPOCH) * 1e6,
-            "dur": (now - t0) * 1e6,
-            "pid": os.getpid(),
-            "tid": threading.get_ident(),
-        }
-        ctx = self._trace_ctx
+        ctx = self._close()
+        args = dict(self.args or ())
         if ctx is not None:
-            event["args"] = dict(self.args or ())
-            event["args"].update(ctx.to_wire())
-            _tracing._exit_span(self._trace_token)
-            self._trace_ctx = None
-            self._trace_token = None
-        elif self.args:
-            event["args"] = dict(self.args)
+            args.update(ctx.to_wire())
         # explicit None-check: an empty TraceBuffer is falsy (__len__)
         buf = self.buffer if self.buffer is not None else _default_buffer
-        buf.add(event)
-        self._t0 = None
+        buf.add(_event(self.name, t0, time.perf_counter(), args))
         return False
 
     def __call__(self, fn):

@@ -173,6 +173,7 @@ def _fwd(q, k, v, scale, causal, group):
             jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32),
         ],
         interpret=_interpret(),
+        name="paddle_tpu.flash_fwd",
     )(q, k, v)
     return out, lse
 
@@ -305,6 +306,7 @@ def _bwd(scale, causal, group, res, g):
                                lambda bi, hi, qi: (bi, hi, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), qh.dtype),
         interpret=_interpret(),
+        name="paddle_tpu.flash_dq",
     )(qh, kh, vh, do, lse, delta)
     # per-kv-head: the group's query heads are a contiguous head block,
     # walked (head, q-block) by the last grid axis
@@ -346,6 +348,7 @@ def _bwd(scale, causal, group, res, g):
         scratch_shapes=[pltpu.VMEM((BLOCK_K, d), jnp.float32),
                         pltpu.VMEM((BLOCK_K, d), jnp.float32)],
         interpret=_interpret(),
+        name="paddle_tpu.flash_dkdv",
     )(qh, kh, vh, do, lse, delta)
     return dq, dk, dv
 

@@ -65,6 +65,10 @@ from ..framework.tensor import run_op
 __all__ = ["fused_linear_cross_entropy", "fused_linear_cross_entropy_xla",
            "supported"]
 
+#: the kernel's name in a trace (the forward ``pallas_call``'s ``name=``
+#: and the scope of the backward's ops)
+KERNEL_NAME = "paddle_tpu.fused_ce"
+
 #: VMEM budget for one grid step's input windows (hidden tile + w tile,
 #: each double-buffered by the pipeline), kept under the 16 MB of scoped
 #: VMEM the compiler grants a kernel
@@ -227,6 +231,7 @@ def _make_ce_call(n, d, v, block_n, block_v, interpret):
             out_shape=[jax.ShapeDtypeStruct((n, 1), jnp.float32),
                        jax.ShapeDtypeStruct((n, 1), jnp.float32)],
             interpret=interpret,
+            name=KERNEL_NAME,
         )(h2d, w, lab2d)
 
     return call
@@ -274,6 +279,9 @@ def _fused_ce_vjp_fn(use_kernel, chunk, ignore_index):
         lse, pick = parts(h2d, w, lab)
         return nll_of(lse, pick, lab), (h2d, w, lab, lse)
 
+    # the backward is XLA's: its ops carry the kernel's name as a scope,
+    # so a trace finds the whole of the head's cost under one name
+    @jax.named_scope(KERNEL_NAME)
     def bwd(res, g):
         h2d, w, lab, lse = res
         n, d = h2d.shape

@@ -177,6 +177,7 @@ def _make_grouped(e, c, k, n, block_m, block_n, out_dtype, interpret):
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((e, c, n), out_dtype),
             interpret=interpret,
+            name="paddle_tpu.grouped_gemm",
         )(gs, x3, w)
 
     return call
@@ -408,6 +409,7 @@ def _make_grouped_q8(e, c, k, n, kb, block, block_m, block_n,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((e, c, n), out_dtype),
             interpret=interpret,
+            name="paddle_tpu.grouped_gemm_q8",
         )(gs, x3, w_q, scales)
 
     return call

@@ -156,6 +156,7 @@ def _make_paged(scale, page_size, group, interpret):
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((b, hk, g, d), q4.dtype),
             interpret=interpret,
+            name="paddle_tpu.paged_attn_decode",
         )(tables, lens, q4, k_pages, v_pages)
 
     return call

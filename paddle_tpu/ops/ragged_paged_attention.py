@@ -331,6 +331,7 @@ def _make_ragged_q8(scale, page_size, qb, group, interpret):
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((r, hk, qbg, d), q4.dtype),
             interpret=interpret,
+            name="paddle_tpu.ragged_attn_q8",
         )(tables, kv_lens, q_starts, q_lens, q4, k_pages, v_pages,
           k_scale, v_scale)
 
@@ -371,6 +372,7 @@ def _make_ragged(scale, page_size, qb, group, interpret):
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((r, hk, qbg, d), q4.dtype),
             interpret=interpret,
+            name="paddle_tpu.ragged_attn",
         )(tables, kv_lens, q_starts, q_lens, q4, k_pages, v_pages)
 
     return call
@@ -937,6 +939,7 @@ def _make_fused(scale, page_size, qb, group, tpad, dump_page,
             # scalar-prefetch operands, 7 is q4, 8/9 the pools
             input_output_aliases={8: 1, 9: 2},
             interpret=interpret,
+            name="paddle_tpu.ragged_attn_fused",
         )(tables, kv_lens, q_starts, q_lens, w_starts, w_flats, w_ends,
           q4, k_pages, v_pages, nk, nv)
 
@@ -1006,6 +1009,7 @@ def _make_fused_q8(scale, page_size, qb, group, tpad, dump_page,
             ],
             input_output_aliases={8: 1, 9: 2, 10: 3, 11: 4},
             interpret=interpret,
+            name="paddle_tpu.ragged_attn_fused_q8",
         )(tables, kv_lens, q_starts, q_lens, w_starts, w_flats, w_ends,
           q4, k_pages, v_pages, k_scale, v_scale, nk, nv)
 
@@ -1076,6 +1080,7 @@ def _make_fused_rope(scale, page_size, qblock, group, tpad, dump_page,
             # inputs 0-6 scalar prefetch, 7 packed q, 8/9 the pools
             input_output_aliases={8: 1, 9: 2},
             interpret=interpret,
+            name="paddle_tpu.ragged_attn_fused_rope",
         )(tables, kv_lens, q_starts, q_lens, w_starts, w_flats, w_ends,
           qp, k_pages, v_pages, nk, nv, sin, cos)
 
@@ -1151,6 +1156,7 @@ def _make_fused_rope_q8(scale, page_size, qblock, group, tpad,
             ],
             input_output_aliases={8: 1, 9: 2, 10: 3, 11: 4},
             interpret=interpret,
+            name="paddle_tpu.ragged_attn_fused_rope_q8",
         )(tables, kv_lens, q_starts, q_lens, w_starts, w_flats, w_ends,
           qp, k_pages, v_pages, k_scale, v_scale, nk, nv, sin, cos)
 

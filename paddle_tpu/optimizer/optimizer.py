@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import collections
 
+import jax
 import jax.numpy as jnp
 
 from ..framework.tensor import Tensor, Parameter, no_grad
@@ -146,6 +147,7 @@ class Optimizer:
         return g + jnp.asarray(coeff, g.dtype) * p._data.astype(g.dtype)
 
     @no_grad()
+    @jax.named_scope("optimizer")   # a traced step's ops carry the phase
     def step(self):
         params_grads = [(p, p.grad) for p in self._parameter_list
                         if p.trainable and p.grad is not None]

@@ -9,8 +9,9 @@ TPU-native mechanics: the device tracer is the XLA/JAX profiler —
 ``start_trace`` collects host + device (TPU) timelines into an XPlane
 protobuf AND a chrome ``trace.json.gz`` under
 ``<log_dir>/plugins/profile/<run>/`` (TensorBoard's profile plugin reads
-the same directory). ``RecordEvent`` lowers to
-``jax.profiler.TraceAnnotation`` so user ranges appear on the device
+the same directory). ``RecordEvent`` is
+``paddle_tpu.observability.trace.span`` with begin/end, which is a
+``jax.profiler.TraceAnnotation`` too, so user ranges appear on the device
 timeline, the analog of the reference's RecordEvent instrumentation.
 """
 
@@ -21,6 +22,8 @@ import os
 import time
 
 import jax
+
+from ..observability.trace import span as _span
 
 __all__ = ["Profiler", "RecordEvent", "ProfilerTarget",
            "export_chrome_tracing", "make_scheduler", "benchmark",
@@ -182,20 +185,22 @@ class Profiler:
 
 class RecordEvent:
     """User-annotated range on the profiler timeline (reference
-    profiler.py RecordEvent; lowers to jax.profiler.TraceAnnotation)."""
+    profiler.py RecordEvent): a begin/end wrapper over
+    :class:`~paddle_tpu.observability.trace.span`, so the range is in
+    the span ring and, as a ``TraceAnnotation``, in the device trace."""
 
     def __init__(self, name, event_type=None):
         self.name = name
-        self._ann = None
+        self._span = None
 
     def begin(self):
-        self._ann = jax.profiler.TraceAnnotation(self.name)
-        self._ann.__enter__()
+        self._span = _span(self.name)
+        self._span.__enter__()
 
     def end(self):
-        if self._ann is not None:
-            self._ann.__exit__(None, None, None)
-            self._ann = None
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+            self._span = None
 
     def __enter__(self):
         self.begin()

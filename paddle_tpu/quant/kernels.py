@@ -140,6 +140,7 @@ def _make_dq(m, k, kb, n, block, bm, bn, out_dtype, interpret):
         out_specs=pl.BlockSpec((bm, bn), lambda mi, ni: (mi, ni)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         interpret=interpret,
+        name="paddle_tpu.dequant_matmul",
     )
 
 

@@ -16,6 +16,7 @@ steer them off interpret mode with ``monkeypatch``.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -79,8 +80,23 @@ def chip_compile(one_chip, no_persistent_cache, monkeypatch):
     return compile_
 
 
-def _assert_kernel(compiled):
-    assert "tpu_custom_call" in compiled.as_text()
+def _custom_calls(text):
+    """Instruction names of the Mosaic custom calls in compiled HLO."""
+    return re.findall(r"%(\S+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
+                      text)
+
+
+def _assert_kernel(compiled, *names):
+    """The kernels are in, and the compiled HLO calls each by the name
+    its ``pallas_call`` passes: a profiler trace names a device op by
+    its instruction, so this is what a reader's pattern will see."""
+    calls = _custom_calls(compiled.as_text())
+    assert calls
+    found = {m.group(0) for c in calls
+             for m in [re.search(r"paddle_tpu\.[a-z0-9]+(?:_[a-z0-9]+)*", c)]
+             if m}
+    assert all(re.search(r"paddle_tpu\.", c) for c in calls), calls
+    assert found == {"paddle_tpu." + n for n in names}, (found, calls)
 
 
 # ---------------------------------------------------------------------------
@@ -109,10 +125,17 @@ def _ragged_specs(program, page_size):
     return [q] + new + pools + [tables] + rows * 6 + rope
 
 
+RAGGED_KERNELS = {
+    "_fused_rope_impl": "ragged_attn_fused_rope",
+    "_fused_impl": "ragged_attn_fused",
+    "_fused_rope_impl_q8": "ragged_attn_fused_rope_q8",
+    "_fused_impl_q8": "ragged_attn_fused_q8",
+    "_ragged_impl": "ragged_attn",
+    "_ragged_impl_q8": "ragged_attn_q8"}
+
+
 @pytest.mark.parametrize("page_size", [16, 64])
-@pytest.mark.parametrize("program", [
-    "_fused_rope_impl", "_fused_impl", "_fused_rope_impl_q8",
-    "_fused_impl_q8", "_ragged_impl", "_ragged_impl_q8"])
+@pytest.mark.parametrize("program", list(RAGGED_KERNELS))
 def test_ragged_programs_compile_bf16(chip_compile, program, page_size):
     kw = dict(scale=D ** -0.5)
     if program.startswith("_fused"):
@@ -120,13 +143,17 @@ def test_ragged_programs_compile_bf16(chip_compile, program, page_size):
     if "rope" in program:
         kw["qblock"] = QB
     fn = functools.partial(getattr(rpa, program), **kw)
-    _assert_kernel(chip_compile(fn, *_ragged_specs(program, page_size)))
+    _assert_kernel(chip_compile(fn, *_ragged_specs(program, page_size)),
+                   RAGGED_KERNELS[program])
 
 
 # ---------------------------------------------------------------------------
 # training: flash attention fwd+bwd, fused CE, and the quantized /
 # grouped matmuls
 # ---------------------------------------------------------------------------
+FLASH_KERNELS = ("flash_fwd", "flash_dq", "flash_dkdv")
+
+
 def _flash_grad(group):
     flash = fa._make_flash(D ** -0.5, True, group)
     return jax.grad(lambda q, k, v: flash(q, k, v).astype(F32).sum(),
@@ -143,7 +170,8 @@ def _flash_specs(b, s, h, hk, dtype):
 ])
 def test_flash_fwd_bwd_compiles_bf16(chip_compile, b, s, h, hk):
     _assert_kernel(chip_compile(_flash_grad(h // hk),
-                                *_flash_specs(b, s, h, hk, BF16)))
+                                *_flash_specs(b, s, h, hk, BF16)),
+                   *FLASH_KERNELS)
 
 
 def test_flash_supported_agrees_with_compiler(chip_compile):
@@ -158,7 +186,8 @@ def test_flash_supported_agrees_with_compiler(chip_compile):
         q, k, v = (jax.ShapeDtypeStruct(sh, dt) for sh, dt in specs)
         if fa.supported(q, k, v, None, True):
             accepted.append((b, s, h, hk, dtype))
-            _assert_kernel(chip_compile(_flash_grad(h // hk), *specs))
+            _assert_kernel(chip_compile(_flash_grad(h // hk), *specs),
+                           *FLASH_KERNELS)
     assert (1, 8192, 32, 8, BF16) in accepted
     assert (1, 8192, 16, 8, BF16) in accepted
 
@@ -194,6 +223,8 @@ def test_flash_under_a_mesh_compiles_for_2x2(topo, no_persistent_cache,
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))) \
         .lower(*args).compile().as_text()
     assert text.count("tpu_custom_call") >= 3
+    assert all(any("paddle_tpu." + k in c for c in _custom_calls(text))
+               for k in FLASH_KERNELS)
     assert "all-gather" not in text and "all-reduce" not in text
 
 
@@ -202,18 +233,20 @@ def test_flash_under_a_mesh_compiles_for_2x2(topo, no_persistent_cache,
     (4096, 4096, 128256, F32)])
 def test_fused_ce_compiles(chip_compile, n, d, v, dtype):
     _assert_kernel(chip_compile(
-        flce._kernel_parts, ((n, d), dtype), ((d, v), dtype), ((n,), I32)))
+        flce._kernel_parts, ((n, d), dtype), ((d, v), dtype), ((n,), I32)),
+        "fused_ce")
 
 
 def test_dequant_matmul_compiles_bf16(chip_compile):
     m, k, n, block = 16, 4096, 14336, 128
     _assert_kernel(chip_compile(
         functools.partial(qk._kernel_impl, block=block),
-        ((m, k), BF16), ((k, n), I8), ((k // block, n), F32)))
+        ((m, k), BF16), ((k, n), I8), ((k // block, n), F32)),
+        "dequant_matmul")
 
 
 def test_grouped_gemm_compiles_bf16(chip_compile):
     e, c, k, n = 8, 128, 4096, 14336
     _assert_kernel(chip_compile(
         gg._grouped_impl, ((e * c, k), BF16), ((e, k, n), BF16),
-        ((e,), I32)))
+        ((e,), I32)), "grouped_gemm")

@@ -1,8 +1,6 @@
-"""Perf attribution layer (ISSUE 18): per-callable roofline gauges
-from measured device time x static cost_analysis, the EWMA perf
-sentinel (counter + flight-recorder dump on sustained slowdown), the
-build-info gauge on every scrape, and cluster-wide on-demand profiler
-capture merged into one Perfetto-loadable bundle.
+"""What `observability/perf.py` keeps (ISSUE 18, cut down by ISSUE 26):
+the build-info gauge on every scrape, and cluster-wide on-demand
+profiler capture merged into one Perfetto-loadable bundle.
 
 The acceptance e2e runs a frontend + 2-subprocess-replica cluster,
 pushes traffic, and proves ``ServingCluster.capture_profile()`` (and
@@ -61,225 +59,6 @@ def _wait(cond, timeout, what):
             return
         time.sleep(0.1)
     raise AssertionError(f"timed out waiting for {what}")
-
-
-# ---------------------------------------------------------------------------
-# roofline math (observe is the fenced path's internal entry point)
-# ---------------------------------------------------------------------------
-class TestRoofline:
-    def test_observe_publishes_fractions_against_peaks(self):
-        peak_flops, peak_bw, _ = perf.device_peaks()
-        # 1 ms of device time at exactly 10% of both peaks
-        s = perf.observe("m", 1e-3, flops=0.1 * peak_flops * 1e-3,
-                         bytes_accessed=0.1 * peak_bw * 1e-3)
-        assert s["attained_flops_frac"] == pytest.approx(0.1)
-        assert s["attained_hbm_bw_frac"] == pytest.approx(0.1)
-        assert _peek("paddle_tpu_perf_device_ms", "m") == \
-            pytest.approx(1.0)
-        assert _peek("paddle_tpu_perf_attained_flops_frac", "m") == \
-            pytest.approx(0.1)
-        assert _peek("paddle_tpu_perf_attained_hbm_bw_frac", "m") == \
-            pytest.approx(0.1)
-        assert _peek("paddle_tpu_perf_fenced_samples_total",
-                     "m") == 1.0
-
-    def test_fractions_clamp_to_one(self):
-        peak_flops, _, _ = perf.device_peaks()
-        # static FLOPs claiming 5x peak (a fused program the analyzer
-        # over-counts): clamp, don't report >1
-        s = perf.observe("m", 1e-3, flops=5.0 * peak_flops * 1e-3)
-        assert s["attained_flops_frac"] == 1.0
-
-    def test_missing_cost_skips_fraction_gauges(self):
-        s = perf.observe("m", 1e-3)
-        assert "attained_flops_frac" not in s
-        assert "attained_hbm_bw_frac" not in s
-        assert _peek("paddle_tpu_perf_device_ms", "m") is not None
-
-    def test_unknown_tpu_kind_gets_no_fraction_and_no_default(
-            self, monkeypatch):
-        import jax
-
-        class _Dev:
-            platform = "tpu"
-            device_kind = "TPU v99"
-
-        monkeypatch.setattr(jax, "devices", lambda *a: [_Dev()])
-        perf.reset()
-        assert perf.device_peaks() == (None, None, "TPU v99")
-        s = perf.observe("m", 1e-3, flops=1e9, bytes_accessed=1e6)
-        assert s["device_kind"] == "TPU v99"
-        assert "attained_flops_frac" not in s
-        assert "attained_hbm_bw_frac" not in s
-        assert _peek("paddle_tpu_perf_device_ms", "m") == \
-            pytest.approx(1.0)
-        assert _peek("paddle_tpu_perf_attained_flops_frac", "m") is None
-        assert _peek("paddle_tpu_perf_attained_hbm_bw_frac", "m") is None
-
-    def test_kill_switches(self, monkeypatch):
-        for var in ("PADDLE_TPU_METRICS", "PADDLE_TPU_PERF"):
-            monkeypatch.setenv(var, "0")
-            assert not perf.enabled()
-            assert perf.observe("m", 1e-3, flops=1e9) is None
-            assert perf.note_dispatch("m", None, None, 0.0) is None
-            monkeypatch.delenv(var)
-        assert perf.enabled()
-
-
-# ---------------------------------------------------------------------------
-# EWMA sentinel
-# ---------------------------------------------------------------------------
-def _feed(name, ms, n):
-    last = None
-    for _ in range(n):
-        last = perf.observe(name, ms / 1e3, flops=1e9)
-    return last
-
-
-class TestSentinel:
-    def test_silent_on_steady_traffic(self):
-        _feed("steady", 1.0, 40)
-        st = perf.recorders()["steady"]
-        assert st["regressions"] == 0
-        assert _peek("paddle_tpu_perf_regressions_total",
-                     "steady") is None
-
-    def test_silent_on_noise_within_ratio(self):
-        rng = np.random.RandomState(0)
-        for _ in range(60):     # +-20% jitter never breaches 1.5x
-            perf.observe("noisy", rng.uniform(0.8e-3, 1.2e-3))
-        assert perf.recorders()["noisy"]["regressions"] == 0
-
-    def test_fires_on_sustained_slowdown_and_dumps(self, tmp_path,
-                                                   monkeypatch):
-        ofr.install(log_dir=str(tmp_path))
-        _feed("hot", 1.0, 12)          # baseline past warmup
-        _feed("hot", 3.0, 8)           # sustained 3x
-        st = perf.recorders()["hot"]
-        assert st["regressions"] >= 1
-        assert _peek("paddle_tpu_perf_regressions_total",
-                     "hot") >= 1.0
-        envs = glob.glob(str(tmp_path / "postmortem" / "*"
-                             / "env.json"))
-        assert envs, "sentinel fired without a flight-recorder bundle"
-        doc = json.loads(open(envs[0]).read())
-        assert doc["reason"] == "perf_regression"
-        assert doc["info"]["callable"] == "hot"
-        assert doc["info"]["slowdown_x"] > 1.5
-
-    def test_rebaselines_after_firing(self):
-        _feed("rb", 1.0, 12)
-        _feed("rb", 3.0, 8)            # fires, slow re-baselined to ~3ms
-        fired = perf.recorders()["rb"]["regressions"]
-        assert fired >= 1
-        _feed("rb", 3.0, 20)           # the new normal: no more events
-        assert perf.recorders()["rb"]["regressions"] == fired
-
-    def test_no_fire_during_warmup(self):
-        # a slowdown inside the first _SENTINEL_MIN samples is compile/
-        # cache noise, not a regression
-        _feed("young", 1.0, 3)
-        _feed("young", 5.0, 4)
-        assert perf.recorders()["young"]["regressions"] == 0
-
-    def test_dump_rate_limited_but_counter_ticks(self, tmp_path,
-                                                 monkeypatch):
-        calls = []
-        monkeypatch.setattr(ofr, "dump",
-                            lambda **kw: calls.append(kw) or "/x")
-        _feed("rl", 1.0, 12)
-        _feed("rl", 3.0, 8)            # event 1 (+ dump)
-        _feed("rl", 9.0, 8)            # event 2 inside the 60s window
-        st = perf.recorders()["rl"]
-        assert st["regressions"] == 2
-        assert len(calls) == 1         # dump throttled, counter not
-
-
-# ---------------------------------------------------------------------------
-# dispatch hooks: real serving + hapi callables on the CPU backend
-# ---------------------------------------------------------------------------
-class TestDispatchIntegration:
-    @pytest.fixture()
-    def fence_every_call(self, monkeypatch):
-        monkeypatch.setenv("PADDLE_TPU_PERF_FENCE_INTERVAL", "0")
-
-    def test_serving_mixed_programs_get_roofline(self, fence_every_call):
-        from paddle_tpu.inference.serving import LlamaServingEngine
-        from paddle_tpu.models import LlamaForCausalLM, tiny_llama_config
-
-        paddle.seed(0)
-        model = LlamaForCausalLM(tiny_llama_config(**_CFG))
-        model.eval()
-        engine = LlamaServingEngine(model, max_batch=2, page_size=8,
-                                    num_pages=48, prefix_cache=False)
-        try:
-            rng = np.random.RandomState(3)
-            prompts = [rng.randint(0, _CFG["vocab_size"], (5,)).tolist()
-                       for _ in range(2)]
-            out = engine.generate(prompts, max_new_tokens=6)
-            assert all(out)
-        finally:
-            engine.close()
-        rec = perf.recorders()
-        serving = {n: s for n, s in rec.items()
-                   if n.startswith("serving.")}
-        assert serving, f"no serving callable attributed: {list(rec)}"
-        reg = om.default_registry()
-        for name, st in serving.items():
-            if not st["samples"]:
-                continue
-            assert st["device_ewma_ms"] > 0
-            frac = _peek("paddle_tpu_perf_attained_flops_frac", name)
-            assert frac is not None, f"{name}: no flops fraction"
-            assert 0.0 < frac <= 1.0
-            hbm = _peek("paddle_tpu_perf_attained_hbm_bw_frac", name)
-            assert hbm is not None and 0.0 < hbm <= 1.0
-        assert any(st["samples"] for st in serving.values())
-
-    def test_hapi_train_step_gets_roofline(self, fence_every_call):
-        import paddle_tpu.nn as nn
-        from paddle_tpu.hapi import Model
-
-        paddle.seed(0)
-        net = nn.Sequential(nn.Linear(4, 16), nn.ReLU(),
-                            nn.Linear(16, 2))
-        m = Model(net)
-        m.prepare(optimizer=paddle.optimizer.AdamW(
-            learning_rate=0.01, parameters=net.parameters()),
-            loss=nn.CrossEntropyLoss(), jit=True)
-        x = np.random.RandomState(0).randn(8, 4).astype("float32")
-        y = (x.sum(axis=1) > 0).astype("int64")
-        for _ in range(4):
-            m.train_batch([x], [y])
-        st = perf.recorders().get("hapi.train_step")
-        assert st is not None and st["samples"] >= 1
-        frac = _peek("paddle_tpu_perf_attained_flops_frac",
-                     "hapi.train_step")
-        assert frac is not None and 0.0 < frac <= 1.0
-
-    def test_watched_jit_hook(self, fence_every_call):
-        import jax.numpy as jnp
-        from paddle_tpu.observability.compile_watch import watched_jit
-
-        f = watched_jit(lambda a, b: a @ b, name="unit.matmul")
-        x = jnp.ones((64, 64), jnp.float32)
-        for _ in range(3):
-            f(x, x)
-        st = perf.recorders().get("unit.matmul")
-        assert st is not None and st["samples"] >= 1
-        # CPU cost_analysis still yields real flops: fraction exists
-        assert st["flops"] and st["flops"] > 0
-
-    def test_metrics_off_is_true_noop(self, monkeypatch):
-        monkeypatch.setenv("PADDLE_TPU_METRICS", "0")
-        import jax.numpy as jnp
-        from paddle_tpu.observability.compile_watch import watched_jit
-
-        f = watched_jit(lambda a: a * 2, name="unit.noop")
-        f(jnp.ones((8,), jnp.float32))
-        assert perf.recorders() == {}
-        assert om.default_registry().get(
-            "paddle_tpu_perf_device_ms") is None
 
 
 # ---------------------------------------------------------------------------

@@ -1,0 +1,231 @@
+"""The serving loop's spans and counters (ISSUE 26): every dispatch is one
+``serving.dispatch`` with ``schedule``, ``build``, ``wait`` and ``apply``
+under it sharing a ``step``; every retired request writes one
+``serving.request`` with its five stamps in order; what the spans count
+is what the counters count and what the requests received."""
+
+import time
+
+import numpy as np
+import pytest
+
+import paddle_tpu as paddle
+from paddle_tpu.inference.cluster import ServingCluster
+from paddle_tpu.inference.serving import LlamaServingEngine, Request
+from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+from paddle_tpu.observability import metrics as om
+from paddle_tpu.observability import trace as otrace
+from paddle_tpu.observability import tracing as otracing
+
+PHASES = ("serving.schedule", "serving.build", "serving.wait",
+          "serving.apply")
+PROMPTS = (5, 40, 23, 9, 30, 17)        # 40, 23, 30, 17: several chunks
+NEW = 5
+
+
+@pytest.fixture(scope="module")
+def model():
+    paddle.seed(0)
+    m = LlamaForCausalLM(LlamaConfig(
+        vocab_size=256, hidden_size=64, intermediate_size=128,
+        num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2,
+        max_position_embeddings=256))
+    m.eval()
+    return m
+
+
+def _engine(model, **kw):
+    return LlamaServingEngine(model, max_batch=4, page_size=8, num_pages=64,
+                              max_pages_per_seq=16, chunk_budget=16, **kw)
+
+
+def _value(name, *labels):
+    m = om.default_registry().get(name)
+    if m is None:
+        return 0.0
+    child = m.peek(*labels) if labels else m
+    return 0.0 if child is None else child.value
+
+
+@pytest.fixture(scope="module")
+def served(model):
+    """A handful of requests through ``ServingCluster`` over one tiny
+    engine. Returns (ring events, requests, counters moved)."""
+    om.default_registry().clear()
+    cluster = ServingCluster(lambda: _engine(model), num_replicas=1,
+                             ttl=60.0).start()
+    try:
+        deadline = time.time() + 120
+        while not cluster.ready():
+            assert time.time() < deadline
+            time.sleep(0.02)
+        otrace.clear()
+        rng = np.random.RandomState(0)
+        t_before = time.perf_counter()
+        with otracing.activate(otracing.mint()):
+            traced = cluster.submit(rng.randint(0, 256, (12,)).tolist(),
+                                    max_new_tokens=NEW)
+        reqs = [cluster.submit(rng.randint(0, 256, (n,)).tolist(),
+                               max_new_tokens=NEW) for n in PROMPTS]
+        for r in reqs + [traced]:
+            assert r.wait(180), r.status
+            assert r.status == "completed"
+    finally:
+        cluster.stop()
+    counters = {
+        "dispatches": {k: _value("serving_dispatches_total", k)
+                       for k in ("mixed", "decode", "scan")},
+        "tokens": {k: _value("serving_dispatch_tokens_total", k)
+                   for k in ("prefill", "decode", "pad")},
+        "prefill_total": _value("serving_prefill_tokens_total"),
+        "generated": _value("serving_generated_tokens_total"),
+        "queue_wait": om.default_registry().get(
+            "serving_queue_wait_seconds"),
+    }
+    return otrace.get_events(), reqs + [traced], counters, t_before
+
+
+def _by(events, name):
+    return [e for e in events if e["name"] == name]
+
+
+def test_every_dispatch_has_its_four_phases_under_one_step(served):
+    events = served[0]
+    disp = _by(events, "serving.dispatch")
+    assert len(disp) >= 8
+    steps = [d["args"]["step"] for d in disp]
+    assert len(set(steps)) == len(steps) and steps == sorted(steps)
+    for d in disp:
+        kids = sorted((e for e in events if e["name"] in PHASES
+                       and e["args"]["step"] == d["args"]["step"]),
+                      key=lambda e: e["ts"])
+        assert [k["name"] for k in kids] == list(PHASES)
+        # inside the parent, in order, never overlapping
+        at = d["ts"]
+        for k in kids:
+            assert k["ts"] >= at - 1e-3
+            at = k["ts"] + k["dur"]
+        assert at <= d["ts"] + d["dur"] + 1e-3
+        assert kids[0]["args"]["lock_wait_s"] >= 0
+        assert d["args"]["kind"] in ("mixed", "decode")
+        assert d["args"]["t_cap"] == (16 if d["args"]["kind"] == "mixed"
+                                      else 4)
+        assert 0 < d["args"]["tokens"] <= d["args"]["t_cap"]
+        assert d["args"]["decode_rows"] <= d["args"]["rows"]
+    # the pre-existing span stays, inside the build
+    inner = _by(events, "serving.mixed_step")
+    builds = _by(events, "serving.build")
+    assert len(inner) == len(builds) == len(disp)
+    for m, b in zip(inner, builds):
+        assert b["ts"] <= m["ts"] \
+            and m["ts"] + m["dur"] <= b["ts"] + b["dur"] + 1e-3
+
+
+def test_replica_tick_wraps_each_dispatch_and_idle_turns_are_dark(served):
+    events = served[0]
+    ticks, disp = _by(events, "replica.tick"), _by(events,
+                                                   "serving.dispatch")
+    for d in disp:
+        assert any(t["ts"] <= d["ts"] and d["ts"] + d["dur"]
+                   <= t["ts"] + t["dur"] + 1e-3 for t in ticks)
+    # a turn is recorded only where it served something: far fewer
+    # than the idle turns of the 2 ms loop
+    assert len(ticks) <= len(disp) + len(PROMPTS) + 3
+    assert sum(t["args"]["admitted"] for t in ticks) == len(PROMPTS) + 1
+    assert sum(t["args"]["reaped"] for t in ticks) == len(PROMPTS) + 1
+
+
+def test_every_retired_request_has_one_span_with_stamps_in_order(served):
+    events, reqs, _, t_before = served
+    spans = _by(events, "serving.request")
+    assert len(spans) == len(reqs)
+    assert sorted(s["args"]["prompt_len"] for s in spans) \
+        == sorted(list(PROMPTS) + [12])
+    for s in spans:
+        a = s["args"]
+        assert t_before <= a["t_submit"] <= a["t_admit"] \
+            <= a["t_first_chunk"] <= a["t_first_token"] <= a["t_done"]
+        assert a["status"] == "completed" and a["output_len"] == NEW
+        assert a["cached_tokens"] == 0
+        assert otrace.to_perf_counter(s["ts"]) \
+            == pytest.approx(a["t_submit"], abs=1e-6)
+    # the same stamps are on the marker each request writes at its
+    # first token (a request still decoding has no serving.request yet)
+    marks = {m["args"]["seq_id"]: m["args"]
+             for m in _by(events, "serving.first_token")}
+    assert len(marks) == len(spans)
+    for s in spans:
+        a, m = s["args"], marks[s["args"]["seq_id"]]
+        assert all(m[k] == a[k] for k in (
+            "t_submit", "t_admit", "t_first_chunk", "t_first_token"))
+        # the histogram's clock skips a cold compile; the stamps do not
+        assert 0 < m["ttft_seconds"] \
+            <= a["t_first_token"] - a["t_admit"] + 1e-5
+    # the request submitted under a distributed trace carries its id
+    ids = [s["args"].get("trace_id") for s in spans]
+    assert sum(i is not None for i in ids) == 1
+    # several chunks: the long prompts' prefill took more than one
+    # dispatch, so first chunk and first token are different dispatches
+    long_ = [s["args"] for s in spans if s["args"]["prompt_len"] == 40]
+    assert long_[0]["t_first_token"] > long_[0]["t_first_chunk"]
+
+
+def test_spans_sum_to_the_counters_and_to_what_was_received(served):
+    events, reqs, c, _ = served
+    disp = _by(events, "serving.dispatch")
+    tokens = sum(d["args"]["tokens"] for d in disp)
+    prefill = sum(d["args"]["prefill_tokens"] for d in disp)
+    caps = sum(d["args"]["t_cap"] for d in disp)
+    emitted = sum(e["args"]["emitted"] for e in _by(events,
+                                                    "serving.apply"))
+    received = sum(len(r.output_ids) for r in reqs)
+    assert emitted == received == c["generated"] == NEW * len(reqs)
+    assert prefill == sum(PROMPTS) + 12 == c["prefill_total"] \
+        == c["tokens"]["prefill"]
+    assert tokens - prefill == c["tokens"]["decode"]
+    assert caps - tokens == c["tokens"]["pad"]
+    kinds = [d["args"]["kind"] for d in disp]
+    assert kinds.count("mixed") == c["dispatches"]["mixed"]
+    assert kinds.count("decode") == c["dispatches"]["decode"]
+    assert c["dispatches"]["scan"] == 0
+    assert c["queue_wait"].count == len(reqs)
+
+
+def test_decode_scan_has_the_same_shape(model):
+    om.default_registry().clear()
+    engine = _engine(model)
+    reqs = [Request(list(range(1, 6)), max_new_tokens=12),
+            Request(list(range(3, 12)), max_new_tokens=12)]
+    otrace.clear()
+    for r in reqs:
+        engine.add_request(r)
+    while any(not r.done for r in reqs):
+        assert engine.decode_many(8) > 0
+    events = otrace.get_events()
+    scans = [d for d in _by(events, "serving.dispatch")
+             if d["args"]["kind"] == "scan"]
+    assert scans and len(_by(events, "serving.decode_scan")) == len(scans)
+    for d in scans:
+        kids = sorted((e for e in events if e["name"] in PHASES
+                       and e["args"]["step"] == d["args"]["step"]),
+                      key=lambda e: e["ts"])
+        assert [k["name"] for k in kids] == list(PHASES)
+        assert d["args"]["prefill_tokens"] == 0
+        assert d["args"]["tokens"] == d["args"]["rows"] \
+            * (d["args"]["t_cap"] // 4)
+    assert _value("serving_dispatches_total", "scan") == len(scans)
+    assert sum(e["args"]["emitted"] for e in _by(events, "serving.apply")) \
+        == sum(len(r.output_ids) for r in reqs) == 24
+    # no cluster: the engine's own admission is the submission
+    for s in _by(events, "serving.request"):
+        assert s["args"]["t_submit"] == s["args"]["t_admit"]
+
+
+def test_metrics_off_records_nothing_and_serves_the_same(model,
+                                                         monkeypatch):
+    want = _engine(model).generate([[1, 2, 3, 4]], max_new_tokens=4)
+    monkeypatch.setenv("PADDLE_TPU_METRICS", "0")
+    otrace.clear()
+    got = _engine(model).generate([[1, 2, 3, 4]], max_new_tokens=4)
+    assert [list(o) for o in got] == [list(o) for o in want]
+    assert otrace.get_events() == []
