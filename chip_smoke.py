@@ -4,6 +4,12 @@
 One process drives the two main paths through the entry points a user
 calls, at published widths, on one TPU:
 
+- ``walk``: the float rope-fused ragged paged-attention kernel alone,
+  at Mistral-7B's head shape over the benchmark's pool (4,097 pages of
+  16): chat-open's two programs, the same rows behind tables 66, 161
+  and 521 pages wide. It is held against
+  ``fused_ragged_paged_attention_xla``, must answer alike at every
+  width and must take the same time a layer to within 10%;
 - ``serve``: Llama-3-8B widths (hidden 4096, FFN 14336, 32q/8kv, vocab
   128256), depth cut to 8 layers, seeded bf16 weights;
   ``LlamaServingEngine`` with its default (rope-fused) program answers
@@ -272,6 +278,146 @@ def phase_serve(seed, rehearse, on_chip):
 
 
 # ---------------------------------------------------------------------------
+# walk: the float rope-fused ragged kernel alone, at Mistral-7B's head
+# shape, the same rows behind tables of three widths
+# ---------------------------------------------------------------------------
+WALK_WIDTHS = (66, 161, 521)
+# the same rows at every width must cost the same to within this
+WALK_WIDTH_RTOL = 0.10
+
+
+def walk_rows(rng, r_cap, t_cap, qblock, decode_rows, chunks, max_ctx,
+              page_size):
+    """One dispatch's row metadata the way `_dispatch_rows` lays it out:
+    ``decode_rows`` sequences one token each, then ``chunks`` rows that
+    continue ONE prompt (consecutive q_starts, one write span), then
+    inactive rows. Returns the [R] arrays and the packed positions."""
+    kv = np.zeros(r_cap, np.int32)
+    ql = np.zeros(r_cap, np.int32)
+    kv[:decode_rows] = rng.randint(max_ctx // 8, max_ctx, decode_rows)
+    ql[:decode_rows] = 1
+    first = int(rng.randint(0, max_ctx - chunks * qblock))
+    for c in range(chunks):
+        kv[decode_rows + c] = first + (c + 1) * qblock
+        ql[decode_rows + c] = qblock
+    qs = kv - ql
+    live = decode_rows + chunks
+    assert int(ql.sum()) <= t_cap and live <= r_cap
+    wf = np.concatenate([[0], np.cumsum(ql)[:-1]]).astype(np.int32)
+    ws, we = qs.copy(), kv.copy()
+    ws[decode_rows:live] = first
+    wf[decode_rows:live] = decode_rows
+    we[decode_rows:live] = first + chunks * qblock
+    pos = np.zeros(t_cap, np.int32)
+    for i in range(live):
+        f = wf[i] + qs[i] - ws[i]
+        pos[f:f + ql[i]] = np.arange(qs[i], kv[i])
+    pages = -(-kv // page_size)
+    pages[decode_rows:live] = pages[live - 1] if chunks else 0
+    return kv, qs, ql, ws, wf, we, pos, pages
+
+
+def phase_walk(seed, rehearse, on_chip):
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import ragged_paged_attention as rpa
+
+    if rehearse:
+        h, hk, d, page, pool, dt = 4, 2, 16, 8, 96, jnp.float32
+        widths, layers, reps = (5, 9, 13), 2, 1
+        programs = {"mixed": (8, 24, 8, 4, 2), "decode": (6, 6, 1, 6, 0)}
+    else:
+        h, hk, d, page, pool, dt = 32, 8, 128, 16, 4097, jnp.bfloat16
+        widths, layers, reps = WALK_WIDTHS, 16, 5
+        # chat-open's two programs: 36 rows x 128 tokens with 32-token
+        # chunks beside the decode rows, and 32 rows of one token
+        programs = {"mixed": (36, 128, 32, 24, 3),
+                    "decode": (32, 32, 1, 24, 0)}
+    rng = np.random.RandomState(seed)
+    trash = pool - 1
+    key = jax.random.PRNGKey(seed)
+    kp, vp = (jax.random.normal(k, (pool, hk, page, d), jnp.float32)
+              .astype(dt) for k in jax.random.split(key))
+    timings = {}
+    for name, (r_cap, t_cap, qb, n_dec, n_chunk) in programs.items():
+        kv, qs, ql, ws, wf, we, pos, pages = walk_rows(
+            rng, r_cap, t_cap, qb, n_dec, n_chunk, min(widths) * page,
+            page)
+        # distinct live pages a sequence; the chunk rows share a table
+        ids = rng.permutation(trash)
+        owner = np.arange(r_cap)
+        owner[n_dec:n_dec + n_chunk] = n_dec
+        starts = np.concatenate([[0], np.cumsum(pages)[:-1]])
+        q, nk, nv = (jnp.asarray(rng.randn(t_cap, n, d), dt)
+                     for n in (h, hk, hk))
+        sin, cos = rpa.rope_tables(jnp.asarray(pos), d, 1e6)
+        outs = {}
+        for width in widths:
+            tables = np.full((r_cap, width), trash, np.int32)
+            for i in range(r_cap):
+                o = owner[i]
+                tables[i, :pages[o]] = ids[starts[o]:starts[o] + pages[o]]
+            args = (q, nk, nv, kp, vp, jnp.asarray(tables),
+                    *(jnp.asarray(a) for a in (kv, qs, ql, ws, wf, we)))
+
+            @jax.jit
+            def stack(q, nk, nv, kp, vp, *meta):
+                # `layers` calls chained through the pools, as the
+                # layers of one step program are
+                for _ in range(layers):
+                    out, kp, vp = rpa._fused_rope_impl(
+                        q, nk, nv, kp, vp, *meta, sin, cos,
+                        dump_page=trash, scale=d ** -0.5, qblock=qb)
+                return out, kp, vp
+
+            out = jax.block_until_ready(stack(*args))
+            best = float("inf")
+            for _ in range(reps):
+                t0 = time.perf_counter()
+                jax.block_until_ready(stack(*args))
+                best = min(best, time.perf_counter() - t0)
+            outs[width] = out
+            timings[f"{name}.{width}"] = 1e3 * best / layers
+        base = outs[widths[0]]
+        for width in widths[1:]:
+            if not all(bool(jnp.array_equal(a, b))
+                       for a, b in zip(base, outs[width])):
+                raise RuntimeError(
+                    f"{name}: width {width} answers otherwise than "
+                    f"width {widths[0]} on the same rows")
+        ref = rpa.fused_ragged_paged_attention_xla(
+            *args, trash, scale=d ** -0.5, rope_sin=sin, rope_cos=cos,
+            qblock=qb)
+        f32 = lambda a: a.astype(jnp.float32)        # noqa: E731
+        err = float(jnp.max(jnp.abs(f32(base[0]) - f32(ref[0]))))
+        # the pages the kernel wrote against the reference's scatter:
+        # equal but for what the two compilations of the rotation
+        # round otherwise (an FMA contracted or not, one ulp)
+        pool_err = float(jnp.max(jnp.abs(f32(base[1]) - f32(ref[1]))))
+        timings[f"{name}.out_err"] = err
+        timings[f"{name}.pool_err"] = pool_err
+        timings[f"{name}.pool_share_unequal"] = float(
+            jnp.mean(base[1] != ref[1]))
+        tol = 1e-5 if rehearse else 2e-2
+        if not err <= tol * max(1.0, float(jnp.max(jnp.abs(f32(ref[0]))))) \
+                or not pool_err <= tol * float(jnp.max(jnp.abs(f32(ref[1])))):
+            raise RuntimeError(f"{name}: kernel and XLA reference differ: "
+                               f"out {err}, pools {pool_err}")
+        ms = [timings[f"{name}.{w}"] for w in widths]
+        if on_chip and max(ms) > (1 + WALK_WIDTH_RTOL) * min(ms):
+            raise RuntimeError(f"{name}: the kernel's time follows the "
+                               f"table's width: {ms} ms at {widths}")
+    emit(phase="walk", device=device_info(),
+         shapes=dict(heads=h, kv_heads=hk, head_dim=d, page_size=page,
+                     num_pages=pool, widths=list(widths),
+                     programs={k: dict(zip(("rows", "tokens", "qblock",
+                                            "decode_rows", "chunk_rows"),
+                                           v))
+                               for k, v in programs.items()}),
+         ms_a_layer=timings)
+
+
+# ---------------------------------------------------------------------------
 # train
 # ---------------------------------------------------------------------------
 def train_config(rehearse):
@@ -486,6 +632,9 @@ def main():
                     help="tiny sizes on whatever backend there is; "
                          "never prints the success line, exits "
                          f"{REHEARSAL_EXIT}")
+    ap.add_argument("--phase", default="all",
+                    choices=("all", "walk", "serve", "train"),
+                    help="one chip: run only this phase")
     args = ap.parse_args()
 
     import jax
@@ -502,9 +651,11 @@ def main():
     if args.chips == 4:
         phase_mesh(args.seed, args.rehearse, on_chip)
     else:
-        phase_serve(args.seed, args.rehearse, on_chip)
-        gc.collect()        # the 8B-width weights leave before training
-        phase_train(args.seed, args.rehearse, on_chip)
+        for name, phase in (("walk", phase_walk), ("serve", phase_serve),
+                            ("train", phase_train)):
+            if args.phase in ("all", name):
+                phase(args.seed, args.rehearse, on_chip)
+                gc.collect()    # a phase's weights leave before the next
     emit(phase="all", seconds=time.perf_counter() - t0,
          cache=cache_stats())
     if args.rehearse:

@@ -574,10 +574,12 @@ class LlamaServingEngine:
         cfg = model.config
         self.max_batch = max_batch
         self.page_size = page_size
-        # Keep block tables as narrow as the workload allows: the Pallas
-        # ragged grid is (R, Hk, width), so a table sized to the whole
-        # pool pays a grid step (and an HBM->VMEM page fetch) per UNUSED
-        # table slot. max_pages_per_seq is the knob.
+        # max_pages_per_seq sizes the block tables (the longest context
+        # a sequence may hold). The default float program's time does
+        # not follow it: its kernel walks the pages a row's kv_len
+        # holds, not the table's width. The other ragged programs
+        # (int8 pages, fused_rope=False, fused_kv=False) still run a
+        # grid step a table slot, so there narrow tables are faster.
         #
         # Chunked-prefill scheduler knobs:
         # - chunk_budget: token budget per mixed dispatch — the sum of
@@ -2764,10 +2766,16 @@ class LlamaServingEngine:
             kind = "mixed" if needs_mixed else "decode"
             tokens = sum(row[3] for row in rows)
             prefill = sum(row[3] for row in rows if not row[5])
+            # what the attention kernel walks (the pages the rows'
+            # contexts hold) against the slots of the tables it is given
+            r_cap = self.rows_cap if needs_mixed else self.max_batch
             disp.set(rows=len(rows),
                      decode_rows=sum(1 for row in rows if row[5]),
                      prefill_tokens=prefill, tokens=tokens, t_cap=t_cap,
-                     kind=kind)
+                     kind=kind,
+                     kv_pages=self._kv_pages(row[2] + row[3]
+                                             for row in rows),
+                     table_slots=r_cap * self.width)
             self._count_dispatch(kind, prefill, tokens - prefill,
                                 t_cap - tokens)
             return len(rows), emitted
@@ -2809,6 +2817,10 @@ class LlamaServingEngine:
             # concurrent admission can't consume the pages between
             # _relieve_pressure's proof and the extend
             return self._schedule_rows()
+
+    def _kv_pages(self, kv_lens):
+        """Pages that hold contexts of these lengths, summed."""
+        return sum(-(-n // self.page_size) for n in kv_lens)
 
     def _count_dispatch(self, kind, prefill, decode, pad):
         """Count one dispatched program and what filled its slots."""
@@ -2951,8 +2963,12 @@ class LlamaServingEngine:
                 self._set_pool_gauges()
                 applied.set(emitted=served)
             tokens, t_cap = len(live) * n, self.max_batch * n
+            # a scan's contexts grow a token a tick: its first tick's
             disp.set(rows=len(live), decode_rows=len(live),
-                     prefill_tokens=0, tokens=tokens, t_cap=t_cap)
+                     prefill_tokens=0, tokens=tokens, t_cap=t_cap,
+                     kv_pages=self._kv_pages(start_lens[sid] + 1
+                                             for sid in sids),
+                     table_slots=self.max_batch * self.width)
             self._count_dispatch("scan", 0, tokens, t_cap - tokens)
             return served
 

@@ -42,19 +42,36 @@ Shapes (R rows, each a prefill chunk or a decode step of one sequence):
 
 Semantics: query token qi of row r sits at absolute position
 ``p = q_starts[r] + qi`` and attends kv positions ``[0, p]`` (causal)
-clipped to ``[0, kv_lens[r])``. A decode row (q_len 1,
-q_start = kv_len - 1) reduces EXACTLY to `paged_attention`'s math — the
-same online-softmax update in the same order — so decode tokens are
-bitwise-identical to the decode-only kernel. Two chunks of the same
+clipped to ``[0, kv_lens[r])``. In the per-page programs a decode row
+(q_len 1, q_start = kv_len - 1) reduces EXACTLY to `paged_attention`'s
+math — the same online-softmax update in the same order — so their
+decode tokens are bitwise-identical to the decode-only kernel (the
+float rope-fused program's are equal to rounding, below). Two chunks of the same
 sequence may appear as two rows of one batch (same block table,
 consecutive q_starts): their K/V must already be in the pool, which the
 serving engine guarantees by scattering every row's K/V before the
 attention of any row.
 
-The kernel runs grid (R, Hk, W) with one online-softmax accumulator in
-VMEM scratch per (row, kv-head); the prefetched block table picks which
-HBM page each grid step streams into VMEM, and pages at or past
-``kv_lens[r]`` are skipped. Inference-only: no VJP.
+The per-page programs (`_ragged_kernel`, `_fused_kernel` and the three
+``_q8`` ones) run grid (R, Hk, W) with one online-softmax accumulator
+in VMEM scratch per (row, kv-head); the prefetched block table picks
+which HBM page each grid step streams into VMEM, and a step at or past
+``kv_lens[r]`` skips the arithmetic but still pays its step and its
+page fetch: their time follows the table's width W. Inference-only: no
+VJP.
+
+The float rope-fused program (`_fused_rope_kernel`, the serving
+engine's default) walks the K/V a row HOLDS instead: grid (R,), the
+pools left in HBM, and inside a row a loop of ``ceil(kv_lens[r] /
+(B*page))`` trips over blocks of B pages that the kernel fetches
+itself through the table (one DMA a page for all kv heads, the next
+block in flight while this one is computed) — no trip for an inactive
+row, the same trips whatever W is. It makes ONE softmax update a block
+and head, not one a page, so its attention output equals the XLA
+reference's and the per-page programs' to float rounding (1e-5
+relative in f32), no longer bit for bit, and one of its decode rows no
+longer reduces bitwise to `paged_attention`; the pool bytes it writes
+stay bitwise (the write path's arithmetic is the per-page programs').
 
 Fused KV write (`fused_ragged_paged_attention`): the first step toward
 the per-layer decode megakernel (ROADMAP item 2; MPK arXiv 2512.22219,
@@ -83,8 +100,10 @@ positions below ``w_start[r]`` come from the streamed page. The HBM
 write-back itself is done ONCE per page, by the sequence's LAST row in
 the dispatch (``kv_lens[r] == w_end[r]``) — no page is the write
 target of two grid steps, so no copy-out ordering between steps is
-ever required. Grid steps whose page holds no new token write to the
-caller-designated ``dump_page`` (the serving engine's trash page).
+ever required. Grid steps of the per-page programs whose page holds
+no new token write to the caller-designated ``dump_page`` (the serving
+engine's trash page); the float rope-fused program writes a page by a
+DMA of its own and a step with nothing to write writes nothing.
 The q8 path quantizes the fresh rows in-kernel with bitwise the same
 math as ``quantize_kv_int8`` (per-head-per-slot symmetric absmax
 scales into the ``[P, Hk, page, 1]`` sidecars), so fused and unfused
@@ -110,9 +129,9 @@ rotate_half(x) * sin`` in f32, cast back to the model dtype — before
 the write/attention math, with bitwise the same value chain as the
 unfused ``fused_rotary_position_embedding`` + scatter pipeline: the
 transcendentals live in the XLA-computed tables, so the kernel adds
-only IEEE-exact multiplies/adds and greedy outputs and pool bytes stay
-bitwise across all three paths (rope-fused / PR-13 fused-KV /
-two-op). ``qblock`` (the row-block width the caller's metadata was
+only IEEE-exact multiplies/adds and the pool bytes stay bitwise across
+all three paths (rope-fused / PR-13 fused-KV / two-op); the int8
+rope-fused program's outputs do too, the float one's to rounding. ``qblock`` (the row-block width the caller's metadata was
 built for) becomes an explicit argument because packed q no longer
 carries it. This deletes the per-layer rope elementwise op (2 HBM
 round trips per layer: q and k) and the per-layer q gather from the
@@ -184,12 +203,14 @@ def supported(q, k_pages, v_pages, block_tables, kv_lens, q_starts,
 
 def _softmax_accumulate(q, k, v, page_start, q_start, q_len, ctx,
                         group, acc_ref, m_ref, l_ref):
-    """ONE page step of the shared online-softmax update: causal/
-    ragged masking, running max/sum rescale, accumulator update. Every
-    kernel in this module calls exactly this body — the engine's
-    cross-path bitwise parity contract requires the accumulation math
-    to be maintained in ONE place, never per-kernel copies. ``q``
-    ``[QB*G, D]`` is pre-scaled f32; ``k``/``v`` ``[page, D]`` f32."""
+    """ONE step of the shared online-softmax update over the K/V
+    positions ``[page_start, page_start + len(k))``: causal/ragged
+    masking, running max/sum rescale, accumulator update. Every kernel
+    in this module calls exactly this body — the engine's cross-path
+    parity contract requires the accumulation math to be maintained in
+    ONE place, never per-kernel copies. ``q`` ``[rows, D]`` is
+    pre-scaled f32; ``k``/``v`` f32, ``[page, D]`` from the per-page
+    programs and ``[B*page, D]`` from the float rope-fused walk."""
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
     kpos = page_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -699,14 +720,28 @@ def _rot_half(x):
     return jnp.concatenate([-x[..., h:], x[..., :h]], axis=-1)
 
 
+@jax.jit
+def _rope_rows(x, sin, cos):
+    """The table-driven rotation of packed rows ``[T, heads, D]``
+    (tables ``[T, D]`` f32), out in ``x``'s dtype: the unfused
+    `_apply_rope` chain. Jitted ON PURPOSE: XLA contracts the mul+add
+    chain into an FMA under jit but not in eager dispatch (a 1-ulp
+    difference), so whoever must agree with a jitted chain bit for bit
+    (`fused_ragged_paged_attention_xla`, `_fused_rope_impl`) calls
+    this one."""
+    xf = x.astype(jnp.float32)
+    sin, cos = (tb.astype(jnp.float32)[:, None, :] for tb in (sin, cos))
+    return (xf * cos + _rot_half(xf) * sin).astype(x.dtype)
+
+
 def _rope_k_page(nk_ref, sin_ref, cos_ref, f0, page_size, dtype):
     """Rope one replay slice of the packed pre-rope K rows: the SAME
     ``f0`` offset picks the rows and their positions' sin/cos (the
     tables are padded identically), and the rotated rows cast back
     through the MODEL ``dtype`` — exactly `_apply_rope`'s output (the
-    rows arrive widened to f32, see `_pack_new_rows`). Shared by the
-    fp and q8 rope kernels so the parity-critical rotation chain lives
-    in one place (like `_softmax_accumulate`)."""
+    rows arrive widened to f32, see `_pack_new_rows`). The int8
+    rope-fused kernel's; the float one is handed new_k roped already
+    (`_rope_rows`, the same chain)."""
     sin_k = sin_ref[pl.ds(f0, page_size), :]
     cos_k = cos_ref[pl.ds(f0, page_size), :]
     k_new = nk_ref[0, pl.ds(f0, page_size), :]
@@ -735,73 +770,174 @@ def _rope_q_block(q_ref, sin_ref, cos_ref, q_starts_ref, w_starts_ref,
         .astype(jnp.float32) * scale                  # [QB*G, D]
 
 
+_WALK_TOKENS = 128
+_WALK_VMEM_BYTES = 8 << 20
+
+
+def _walk_pages(page_size, hk, d, itemsize):
+    """Pages a K/V block of `_fused_rope_kernel`'s walk holds: about
+    ``_WALK_TOKENS`` tokens (one lane width of scores), halved while
+    the two double-buffered ``[2, Hk, B*page, D]`` VMEM buffers would
+    pass ``_WALK_VMEM_BYTES``. Derived from the shapes alone: nothing
+    for a caller to set."""
+    b = max(1, _WALK_TOKENS // page_size)
+    while b > 1 and 4 * hk * b * page_size * d * itemsize \
+            > _WALK_VMEM_BYTES:
+        b //= 2
+    return b
+
+
 def _fused_rope_kernel(tables_ref, kv_lens_ref, q_starts_ref,
                        q_lens_ref, w_starts_ref, w_flats_ref,
-                       w_ends_ref, q_ref, k_ref, v_ref, nk_ref, nv_ref,
-                       sin_ref, cos_ref, o_ref, ko_ref, vo_ref,
-                       acc_ref, m_ref, l_ref, q_s, *, page_size, group,
-                       scale, pad, qblock, dtype):
-    """Rope-fused variant of `_fused_kernel`: q and new_k arrive
-    PRE-rope in packed layouts (q ``[Hk, tpad, G, D]`` head-major,
-    new_k ``[Hk, tpad, D]`` in the MODEL dtype), the sin/cos tables
-    ride whole in VMEM aligned to the same padded packed axis, and the
-    rotation — ``x * cos + rotate_half(x) * sin`` in f32, cast back to
-    the model dtype — happens here, feeding bitwise the same values
-    into the write/attention math the post-rope kernel would have been
-    handed. No transcendentals in-kernel: the tables carry them, so
-    Mosaic and XLA compute identical bits. The roped q block is
-    computed ONCE per (row, kv-head) into the ``q_s`` scratch — it
-    depends only on the row, never on the page step."""
+                       w_ends_ref, q_ref, k_hbm, v_hbm, nk_ref, nv_ref,
+                       sin_ref, cos_ref, o_ref, ko_hbm, vo_hbm,
+                       kbuf, vbuf, fsem, wsem, acc_ref, m_ref, l_ref,
+                       q_s, *, page_size, bpages, group, scale, qblock,
+                       dtype):
+    """The float rope-fused program: grid ``(R,)``, one step a row,
+    the kv heads looped inside. The pools stay in HBM; the row walks
+    its OWN context in blocks of ``bpages`` pages: ``ceil(kv_len /
+    (bpages*page))`` trips of a `fori_loop`, none for an inactive row,
+    whatever the table's width. A block's pages come through the
+    scalar-prefetched table by one DMA a page (all kv heads of a page
+    are one contiguous piece of ``[P, Hk, page, D]``) into slot
+    ``i % 2`` of ``kbuf``/``vbuf`` ``[2, Hk, bpages*page, D]``, the
+    next block in flight while this one is computed; pages past the
+    context are not fetched. q arrives PRE-rope and packed (``[Hk,
+    T+QB, G, D]``, an f32 container of model-dtype values) with its
+    sin/cos tables ``[T+QB, D]`` whole in VMEM, and its rotation —
+    ``x * cos + rotate_half(x) * sin`` in f32, cast back to the model
+    dtype — happens here with no transcendentals (the tables carry
+    them). new_k/new_v ``[Hk, tpad, D]`` arrive as the pools store
+    them: `_fused_rope_impl` has roped new_k's few rows on the way in
+    (`_rope_rows`), once a call and not once a reader.
+
+    A block that reaches into this dispatch's write span ``[w_start,
+    kv_len)`` overlays the fresh rows from the packed operands in the
+    VMEM buffer (every reader replays; HBM is never trusted for them),
+    and the sequence's LAST row then writes each such page back once,
+    by a DMA from the buffer to the page; no other step writes
+    anything. One `_softmax_accumulate` update a block and head."""
     r = pl.program_id(0)
-    p = pl.program_id(2)
-    num_pages = pl.num_programs(2)
-
-    @pl.when(p == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        q_s[...] = _rope_q_block(q_ref, sin_ref, cos_ref, q_starts_ref,
-                                 w_starts_ref, w_flats_ref, r, pad,
-                                 qblock, group, scale, dtype)
-
-    ctx = kv_lens_ref[r]
+    hk = kbuf.shape[1]
+    bt = bpages * page_size
+    width = tables_ref.shape[1]
+    kv_len = kv_lens_ref[r]
+    q_len = q_lens_ref[r]
+    q_start = q_starts_ref[r]
     ws = w_starts_ref[r]
-    page_start = p * page_size
+    # a context longer than its table (never from the engine) is
+    # attended as far as the table reaches, as the XLA reference does
+    ctx = jnp.minimum(kv_len, width * page_size)
+    nblk = jnp.where(q_len > 0, pl.cdiv(ctx, bt), 0)
 
-    @pl.when(page_start < ctx)
-    def _compute():
-        tpad = nk_ref.shape[1]
-        f0 = jnp.clip(w_flats_ref[r] + page_start - ws + pad, 0,
-                      tpad - page_size)
-        spos = page_start + jax.lax.broadcasted_iota(
-            jnp.int32, (page_size, 1), 0)
-        fresh = (spos >= ws) & (spos < ctx)
-        # rope the fresh K rows in VMEM (shared chain: `_rope_k_page`),
-        # then cast on to the pool dtype, matching what the unfused
-        # scatter would have stored
-        k_rot = _rope_k_page(nk_ref, sin_ref, cos_ref, f0, page_size,
-                             dtype)
-        k_pg = jnp.where(fresh, k_rot.astype(ko_ref.dtype), k_ref[0, 0])
-        v_pg = jnp.where(
-            fresh,
-            nv_ref[0, pl.ds(f0, page_size), :].astype(v_ref.dtype),
-            v_ref[0, 0])
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
 
-        _softmax_accumulate(q_s[...], k_pg.astype(jnp.float32),
-                            v_pg.astype(jnp.float32), page_start,
-                            q_starts_ref[r], q_lens_ref[r], ctx, group,
-                            acc_ref, m_ref, l_ref)
+    npages = pl.cdiv(ctx, page_size)
 
-        @pl.when((ctx == w_ends_ref[r]) & (page_start + page_size > ws)
-                 & (q_lens_ref[r] > 0))
-        def _writeback():
-            ko_ref[0, 0] = k_pg
-            vo_ref[0, 0] = v_pg
+    def page_dmas(act, i, slot, pools, sem, into_vmem, lo=0):
+        """``start`` or ``wait`` (``act``) the per-page copies of block
+        ``i`` between the pools and slot ``slot`` of the buffers: table
+        slots ``[max(lo, first of the block), min(end of the block,
+        pages the row holds))``, the same for both acts."""
+        def one(pg, carry):
+            pid = tables_ref[r, pg]
+            for s, (pool, buf) in enumerate(zip(pools, (kbuf, vbuf))):
+                piece = buf.at[slot, :, pl.ds(pl.multiple_of(
+                    (pg - i * bpages) * page_size, page_size),
+                    page_size), :]
+                src, dst = (pool.at[pid], piece) if into_vmem \
+                    else (piece, pool.at[pid])
+                getattr(pltpu.make_async_copy(src, dst, sem.at[s, slot]),
+                        act)()
+            return carry
 
-    @pl.when(p == num_pages - 1)
-    def _finish():
-        _softmax_finish(o_ref, acc_ref, l_ref)
+        jax.lax.fori_loop(jnp.maximum(lo, i * bpages),
+                          jnp.minimum((i + 1) * bpages, npages), one, 0)
+
+    fetch = functools.partial(page_dmas, pools=(k_hbm, v_hbm), sem=fsem,
+                              into_vmem=True)
+    # the pages that overlap the write span [w_start, kv_len)
+    write = functools.partial(page_dmas, pools=(ko_hbm, vo_hbm), sem=wsem,
+                              into_vmem=False, lo=ws // page_size)
+
+    @pl.when(nblk > 0)
+    def _row():
+        fetch("start", 0, 0)
+        # the row's query tokens sit contiguously on the packed axis
+        # at w_flat + (q_start - w_start), as do their sin/cos rows:
+        # rope + scale them once for all kv heads
+        tq = q_ref.shape[1]
+        f0q = jnp.clip(w_flats_ref[r] + q_start - ws, 0, tq - qblock)
+        qv = q_ref[:, pl.ds(f0q, qblock), :, :]       # [Hk, QB, G, D]
+        sin_q = sin_ref[pl.ds(f0q, qblock), :][None, :, None, :]
+        cos_q = cos_ref[pl.ds(f0q, qblock), :][None, :, None, :]
+        q_rot = (qv * cos_q + _rot_half(qv) * sin_q).astype(dtype)
+        q_s[...] = q_rot.reshape(hk, qblock * group, qv.shape[-1]) \
+            .astype(jnp.float32) * scale
+
+    last_row = (kv_len == w_ends_ref[r])
+
+    def block(i, carry):
+        slot = i % 2
+        block_start = i * bt
+        fetch("wait", i, slot)
+
+        @pl.when(i + 1 < nblk)
+        def _prefetch():
+            fetch("start", i + 1, 1 - slot)
+
+        replay = block_start + bt > ws
+        kpos = block_start + jax.lax.broadcasted_iota(
+            jnp.int32, (bt, 1), 0)
+
+        @pl.when(replay)
+        def _overlay():
+            # positions [w_start, kv_len) were produced by rows <= r
+            # of THIS dispatch: position pos lives at packed index
+            # w_flat + pos - w_start (+ the left pad of one block),
+            # roped already and rounded to the pool dtype: what the
+            # unfused scatter stores, bit for bit
+            tpad = nk_ref.shape[1]
+            f0 = jnp.clip(w_flats_ref[r] + block_start - ws + bt, 0,
+                          tpad - bt)
+            fresh = (kpos >= ws) & (kpos < kv_len)
+            kbuf[slot] = jnp.where(
+                fresh[None],
+                nk_ref[:, pl.ds(f0, bt), :].astype(kbuf.dtype),
+                kbuf[slot])
+            vbuf[slot] = jnp.where(
+                fresh[None],
+                nv_ref[:, pl.ds(f0, bt), :].astype(vbuf.dtype),
+                vbuf[slot])
+
+            @pl.when(last_row)
+            def _write():
+                write("start", i, slot)
+
+        for h in range(hk):
+            # nothing at or past the context is used: a slot there may
+            # hold anything (a NaN would survive the zero weight of the
+            # P.V dot)
+            _softmax_accumulate(
+                q_s[h], kbuf[slot, h].astype(jnp.float32),
+                jnp.where(kpos < ctx, vbuf[slot, h].astype(jnp.float32),
+                          0.0),
+                block_start, q_start, q_len, ctx, group, acc_ref.at[h],
+                m_ref.at[h], l_ref.at[h])
+
+        @pl.when(replay & last_row)
+        def _written():
+            write("wait", i, slot)
+
+        return carry
+
+    jax.lax.fori_loop(0, nblk, block, 0)
+    for h in range(hk):
+        _softmax_finish(o_ref.at[:, pl.ds(h, 1)], acc_ref.at[h],
+                        l_ref.at[h])
 
 
 def _fused_rope_kernel_q8(tables_ref, kv_lens_ref, q_starts_ref,
@@ -1017,59 +1153,62 @@ def _make_fused_q8(scale, page_size, qb, group, tpad, dump_page,
 
 
 @functools.lru_cache(maxsize=32)
-def _make_fused_rope(scale, page_size, qblock, group, tpad, dump_page,
-                     dtype, interpret):
-    wmap = _fused_write_map(page_size, dump_page)
+def _make_fused_rope(scale, page_size, bpages, qblock, group, dtype,
+                     interpret):
+    bt = bpages * page_size
 
     def call(qp, k_pages, v_pages, nk, nv, sin, cos, tables, kv_lens,
              q_starts, q_lens, w_starts, w_flats, w_ends):
-        hk, _, g, d = qp.shape
+        hk, tq, g, d = qp.shape
+        tpad = nk.shape[1]
         r = tables.shape[0]
         qbg = qblock * group
-        max_pages = tables.shape[1]
+        whole = lambda *shape: pl.BlockSpec(       # noqa: E731
+            shape, lambda ri, *refs: (0,) * len(shape))
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=7,
-            grid=(r, hk, max_pages),
+            grid=(r,),
             in_specs=[
-                # pre-rope packed q rides whole, head-major, per kv-head
-                pl.BlockSpec((1, tpad, g, d),
-                             lambda ri, hi, pi, *refs: (hi, 0, 0, 0)),
-                pl.BlockSpec((1, 1, page_size, d),
-                             lambda ri, hi, pi, tables, *refs:
-                             (tables[ri, pi], hi, 0, 0)),
-                pl.BlockSpec((1, 1, page_size, d),
-                             lambda ri, hi, pi, tables, *refs:
-                             (tables[ri, pi], hi, 0, 0)),
-                pl.BlockSpec((1, tpad, d),
-                             lambda ri, hi, pi, *refs: (hi, 0, 0)),
-                pl.BlockSpec((1, tpad, d),
-                             lambda ri, hi, pi, *refs: (hi, 0, 0)),
-                # the per-dispatch sin/cos tables are position-aligned
-                # to the SAME padded packed axis and shared by every
-                # grid step (constant index map -> fetched once)
-                pl.BlockSpec((tpad, d),
-                             lambda ri, hi, pi, *refs: (0, 0)),
-                pl.BlockSpec((tpad, d),
-                             lambda ri, hi, pi, *refs: (0, 0)),
+                # the packed operands ride whole (constant index map:
+                # fetched once a call); the pools stay in HBM and the
+                # kernel fetches the pages a row holds itself
+                whole(hk, tq, g, d),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+                whole(hk, tpad, d),
+                whole(hk, tpad, d),
+                whole(tq, d),
+                whole(tq, d),
             ],
             out_specs=[
-                pl.BlockSpec((1, 1, qbg, d),
-                             lambda ri, hi, pi, *refs: (ri, hi, 0, 0)),
-                pl.BlockSpec((1, 1, page_size, d), wmap),
-                pl.BlockSpec((1, 1, page_size, d), wmap),
+                pl.BlockSpec((1, hk, qbg, d),
+                             lambda ri, *refs: (ri, 0, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
             scratch_shapes=[
-                pltpu.VMEM((qbg, d), jnp.float32),
-                pltpu.VMEM((qbg, 1), jnp.float32),
-                pltpu.VMEM((qbg, 1), jnp.float32),
-                # the row's roped+scaled q block, computed once per
-                # (row, kv-head) and reused across the page loop
-                pltpu.VMEM((qbg, d), jnp.float32),
+                pltpu.VMEM((2, hk, bt, d), k_pages.dtype),
+                pltpu.VMEM((2, hk, bt, d), v_pages.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),     # fetch [k/v, slot]
+                pltpu.SemaphoreType.DMA((2, 2)),     # write-back
+                pltpu.VMEM((hk, qbg, d), jnp.float32),
+                pltpu.VMEM((hk, qbg, 1), jnp.float32),
+                pltpu.VMEM((hk, qbg, 1), jnp.float32),
+                # the row's roped+scaled q block, all kv heads
+                pltpu.VMEM((hk, qbg, d), jnp.float32),
             ],
         )
+        # VMEM: the pipeline holds two copies of every blocked
+        # operand; tiles pad G and the softmax columns
+        f32 = 4
+        operands = hk * tq * -(-g // 8) * 8 * d * f32 \
+            + 2 * hk * tpad * d * f32 + 2 * tq * d * f32 \
+            + hk * qbg * d * jnp.dtype(dtype).itemsize
+        scratch = 4 * hk * bt * d * k_pages.dtype.itemsize \
+            + hk * qbg * (2 * d + 2 * 128) * f32
         return pl.pallas_call(
             functools.partial(_fused_rope_kernel, page_size=page_size,
-                              group=group, scale=scale, pad=page_size,
+                              bpages=bpages, group=group, scale=scale,
                               qblock=qblock, dtype=dtype),
             grid_spec=grid_spec,
             out_shape=[
@@ -1079,12 +1218,22 @@ def _make_fused_rope(scale, page_size, qblock, group, tpad, dump_page,
             ],
             # inputs 0-6 scalar prefetch, 7 packed q, 8/9 the pools
             input_output_aliases={8: 1, 9: 2},
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                vmem_limit_bytes=min(
+                    100 << 20, max(32 << 20,
+                                   2 * operands + scratch + (8 << 20)))),
+            # Pallas's generic interpreter runs the DMAs and semaphores
+            # too; its TPU interpreter (`pltpu.InterpretParams()`,
+            # NaN-filled VMEM) is ~100x slower and a test's to ask for
             interpret=interpret,
             name="paddle_tpu.ragged_attn_fused_rope",
         )(tables, kv_lens, q_starts, q_lens, w_starts, w_flats, w_ends,
           qp, k_pages, v_pages, nk, nv, sin, cos)
 
-    return call
+    # jitted, so that the layers of one step program share ONE trace
+    # and ONE lowering of the kernel (they call it with equal shapes)
+    return jax.jit(call)
 
 
 @functools.lru_cache(maxsize=32)
@@ -1163,18 +1312,19 @@ def _make_fused_rope_q8(scale, page_size, qblock, group, tpad,
     return call
 
 
-def _pack_new_rows(new, t, page_size, tpad, dtype):
-    """[T, Hk, D] packed rows -> [Hk, tpad, D] head-major with a
-    page_size left pad, so the kernels' clipped affine slice
-    ``pl.ds(w_flat + page_start - w_start + pad, page_size)`` is always
-    in bounds whenever any slot of the page is fresh. The values are
+def _pack_new_rows(new, t, pad, tpad, dtype):
+    """[T, Hk, D] packed rows -> [Hk, tpad, D] head-major with ``pad``
+    rows of left pad (a page for the per-page programs, a block of the
+    walk for the float rope-fused one: the length of a replay slice),
+    so the kernels' clipped affine slice ``pl.ds(w_flat + start -
+    w_start + pad, pad)`` is always in bounds whenever any slot it
+    covers is fresh. The values are
     rounded to ``dtype`` and handed over in an f32 container: that
     slice starts at a run-time sublane offset, which Mosaic takes
     unaligned only from a 32-bit array, and 16-bit -> f32 -> 16-bit is
     exact, so the kernel narrows back without changing a bit."""
     nk = jnp.swapaxes(new.astype(dtype).astype(jnp.float32), 0, 1)
-    return jnp.pad(nk, ((0, 0), (page_size, tpad - t - page_size),
-                        (0, 0)))
+    return jnp.pad(nk, ((0, 0), (pad, tpad - t - pad), (0, 0)))
 
 
 def _fused_impl(q, new_k, new_v, k_pages, v_pages, block_tables,
@@ -1239,22 +1389,22 @@ def _fused_impl_q8(q, new_k, new_v, k_pages, v_pages, k_scale, v_scale,
     return out, kp, vp, ks, vs
 
 
-def _pack_new_q(q, t, group, page_size, tpad):
+def _pack_new_q(q, t, group, pad, tpad):
     """Pre-rope packed q ``[T, H, D]`` -> ``[Hk, tpad, G, D]``
-    head-major with the same page_size left pad as `_pack_new_rows`,
-    so one affine offset addresses q rows, K/V rows and the sin/cos
-    tables alike; widened to f32 for the same reason as the rows."""
+    head-major with ``pad`` rows of left pad (as `_pack_new_rows`
+    where one affine offset addresses q rows, K/V rows and the sin/cos
+    tables alike; none where q has an offset of its own); widened to
+    f32 for the same reason as the rows."""
     hk = q.shape[1] // group
     d = q.shape[-1]
     q4 = q.astype(jnp.float32).reshape(t, hk, group, d) \
         .transpose(1, 0, 2, 3)
-    return jnp.pad(q4, ((0, 0), (page_size, tpad - t - page_size),
-                        (0, 0), (0, 0)))
+    return jnp.pad(q4, ((0, 0), (pad, tpad - t - pad), (0, 0), (0, 0)))
 
 
-def _pack_rope_table(tb, t, page_size, tpad):
+def _pack_rope_table(tb, t, pad, tpad):
     return jnp.pad(tb.astype(jnp.float32),
-                   ((page_size, tpad - t - page_size), (0, 0)))
+                   ((pad, tpad - t - pad), (0, 0)))
 
 
 def _rope_tpad(t, page_size, qblock):
@@ -1269,24 +1419,35 @@ def _fused_rope_impl(q, new_k, new_v, k_pages, v_pages, block_tables,
                      kv_lens, q_starts, q_lens, w_starts, w_flats,
                      w_ends, rope_sin, rope_cos, dump_page, scale,
                      qblock):
+    """``dump_page`` is part of the fused programs' common signature
+    and unused here: a step of this program that has nothing to write
+    writes nothing."""
     t, h, d = q.shape
     hk = k_pages.shape[1]
     group = h // hk
     page_size = k_pages.shape[2]
     r = block_tables.shape[0]
-    tpad = _rope_tpad(t, page_size, qblock)
-    # q and new_k stay in the MODEL dtype: the kernel ropes them in
-    # f32 and casts back through the model dtype (the `_apply_rope`
-    # output) before the pool-dtype store — new_v needs no rope and
-    # pre-casts to the pool dtype exactly like the post-rope kernel
-    qp = _pack_new_q(q, t, group, page_size, tpad)
-    nk = _pack_new_rows(new_k, t, page_size, tpad, new_k.dtype)
-    nv = _pack_new_rows(new_v, t, page_size, tpad, v_pages.dtype)
-    sin = _pack_rope_table(rope_sin, t, page_size, tpad)
-    cos = _pack_rope_table(rope_cos, t, page_size, tpad)
-    call = _make_fused_rope(scale, page_size, qblock, group, tpad,
-                            int(dump_page), jnp.dtype(q.dtype),
-                            _interpret())
+    bpages = _walk_pages(page_size, hk, d, k_pages.dtype.itemsize)
+    bt = bpages * page_size
+    # the replay slice is a whole block of the walk, so new_k/new_v
+    # take a block of left pad and a block of right pad
+    # (`_pack_new_rows`); q and its tables are sliced by row blocks
+    # from the row's first token on and take one row block on the right
+    tpad = -(-(t + 2 * bt) // 8) * 8
+    tq = t + qblock
+    # q stays PRE-rope in the model dtype (the kernel ropes a row's
+    # block where it reads it). new_k is T rows for all readers: roped
+    # here, through the model dtype (the `_apply_rope` output), then
+    # pre-cast to the pool dtype like new_v and like the post-rope
+    # kernel's operands
+    qp = _pack_new_q(q, t, group, 0, tq)
+    nk = _pack_new_rows(_rope_rows(new_k, rope_sin, rope_cos), t, bt,
+                        tpad, k_pages.dtype)
+    nv = _pack_new_rows(new_v, t, bt, tpad, v_pages.dtype)
+    sin = _pack_rope_table(rope_sin, t, 0, tq)
+    cos = _pack_rope_table(rope_cos, t, 0, tq)
+    call = _make_fused_rope(scale, page_size, bpages, qblock, group,
+                            jnp.dtype(q.dtype), _interpret())
     tables = jnp.clip(block_tables.astype(jnp.int32), 0,
                       k_pages.shape[0] - 1)
     out, kp, vp = call(qp, k_pages, v_pages, nk, nv, sin, cos, tables,
@@ -1462,23 +1623,10 @@ def fused_ragged_paged_attention_xla(q, new_k, new_v, k_pages, v_pages,
     (q, new_k, new_v, k_pages, v_pages, block_tables, kv_lens,
      q_starts, q_lens, w_starts, w_flats) = unwrap
     if rope_sin is not None:
-        sin = jnp.asarray(getattr(rope_sin, "_data", rope_sin),
-                          jnp.float32)
-        cos = jnp.asarray(getattr(rope_cos, "_data", rope_cos),
-                          jnp.float32)
-
-        @jax.jit
-        def _rope(x):                       # [T, heads, D], table [T, D]
-            # jitted ON PURPOSE: XLA contracts the mul+add chain into
-            # an FMA under jit but not in eager dispatch (1-ulp
-            # difference), and the Pallas kernel this reference is
-            # proven against always runs as a jitted computation
-            xf = x.astype(jnp.float32)
-            out = xf * cos[:, None, :] + _rot_half(xf) * sin[:, None, :]
-            return out.astype(x.dtype)
-
-        q_rot = np.asarray(_rope(q))
-        new_k = _rope(new_k)
+        sin = getattr(rope_sin, "_data", rope_sin)
+        cos = getattr(rope_cos, "_data", rope_cos)
+        q_rot = np.asarray(_rope_rows(q, sin, cos))
+        new_k = _rope_rows(new_k, sin, cos)
         # pack the roped q into the row blocks the metadata implies:
         # row r's tokens sit at packed [w_flat + q_start - w_start, +n)
         r_rows = block_tables.shape[0]

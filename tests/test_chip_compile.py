@@ -147,6 +147,33 @@ def test_ragged_programs_compile_bf16(chip_compile, program, page_size):
                    RAGGED_KERNELS[program])
 
 
+# the engine's default float program at Mistral-7B's head shape and
+# the benchmark's pool: both of chat-open's programs (36 rows x 128
+# tokens in 32-token blocks; 32 decode rows) behind tables of the two
+# serving cells' widths and of a long-context cell's. Its walk is
+# bounded by kv_lens, so the width only sizes the table in SMEM.
+MISTRAL_POOL = (4097, 8, 16, 128)
+
+
+@pytest.mark.parametrize("width", [66, 161, 521])
+@pytest.mark.parametrize("rows,tokens,qblock", [(36, 128, 32), (32, 32, 1)])
+def test_walk_compiles_at_mistral_widths(chip_compile, rows, tokens,
+                                         qblock, width):
+    fn = functools.partial(rpa._fused_rope_impl, dump_page=4096,
+                           scale=D ** -0.5, qblock=qblock)
+    compiled = chip_compile(
+        fn, ((tokens, 32, 128), BF16), ((tokens, 8, 128), BF16),
+        ((tokens, 8, 128), BF16), (MISTRAL_POOL, BF16),
+        (MISTRAL_POOL, BF16), ((rows, width), I32), *[((rows,), I32)] * 6,
+        ((tokens, 128), F32), ((tokens, 128), F32))
+    _assert_kernel(compiled, "ragged_attn_fused_rope")
+    # the one custom call a layer whose result holds both pools: what
+    # the benchmark's attention roofline finds the kernel by
+    assert re.search(r"bf16\[4097,8,16,128\][^\n]*bf16\[4097,8,16,128\]"
+                     r"[^\n]*custom_call_target=\"tpu_custom_call\"",
+                     compiled.as_text())
+
+
 # ---------------------------------------------------------------------------
 # training: flash attention fwd+bwd, fused CE, and the quantized /
 # grouped matmuls

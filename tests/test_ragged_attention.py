@@ -5,9 +5,12 @@ The exact-parity contract mirrors `test_paged_attention`: both paths
 compute f32 softmax attention over the same paged pool, so outputs must
 agree to float rounding on EVERY position — including the kernel's
 defined zeros on padded query rows and inactive rows. Decode rows
-(q_len 1) must additionally reproduce the decode-only `paged_attention`
-kernel bit-for-bit, because the serving engine replaced that dispatch
-path with this kernel.
+(q_len 1) of the per-page programs must additionally reproduce the
+decode-only `paged_attention` kernel bit-for-bit. The float rope-fused
+program (the engine's default) walks a row's K/V a block of pages at a
+time, so its OUTPUT is held to the reference and to the per-page
+programs to float rounding (1e-5 relative in f32), while the pool bytes
+it writes stay bitwise.
 """
 
 import numpy as np
@@ -494,10 +497,11 @@ def test_fused_rope_matches_rope_then_write_then_read():
 
 def test_fused_rope_bitwise_vs_post_rope_kernel():
     """Given identical rope bits (the jitted table chain), the rope-
-    fused kernel must produce BITWISE the PR-13 fused kernel's outputs
-    and pools — the in-kernel rotation adds only IEEE-exact ops. This
-    is the engine's fused_rope=0 byte-for-byte fallback at kernel
-    level, decode rows included."""
+    fused kernel must produce BITWISE the PR-13 fused kernel's pools —
+    the in-kernel rotation adds only IEEE-exact ops — and its outputs
+    to float rounding (the walk accumulates a block at a time). This
+    is the engine's fused_rope=0 fallback at kernel level, decode rows
+    included."""
     rng = np.random.RandomState(31)
     kp, vp = _pool(rng, num_pages=16)
     dump = 15
@@ -520,12 +524,14 @@ def test_fused_rope_bitwise_vs_post_rope_kernel():
     out_13, kp13, vp13 = map(_unwrap, RPA.fused_ragged_paged_attention(
         jnp.asarray(qr), k_rot, new_v, kp, vp, tables, kv, qs, ql, ws,
         wf, we, dump))
-    assert np.array_equal(out_f, out_13)
+    # the output is accumulated a block of pages at a time here and a
+    # page at a time there: equal to float rounding, decode row (row
+    # 2) included; the pool bytes stay bitwise
+    _assert_parity(jnp.asarray(out_f), jnp.asarray(out_13))
+    _assert_parity(jnp.asarray(out_f[2]), jnp.asarray(out_13[2]))
     live = [i for i in range(16) if i != dump]
     assert np.array_equal(kpf[live], kp13[live])
     assert np.array_equal(vpf[live], vp13[live])
-    # the decode row (row 2) named explicitly: serving decode contract
-    assert np.array_equal(out_f[2], out_13[2])
 
 
 def test_fused_rope_all_decode_rows():
@@ -652,6 +658,172 @@ def test_fused_rope_supported_gates():
                                          ones, ones, ones, ones, ones,
                                          ones, 31, rope_sin=tb,
                                          rope_cos=tb)
+
+
+# ----------------------------------------------------------------------
+# the float rope-fused program's walk: bounded by kv_lens, a block of
+# pages a trip, pages fetched by the kernel itself
+# ----------------------------------------------------------------------
+PAGE = 8
+BLOCK = RPA._walk_pages(PAGE, 2, 16, 4) * PAGE      # tokens a trip
+
+
+def _walk_case(rng, seqs, width, qb, tail=10_000, poison=False):
+    """A dispatch over a fresh pool. ``seqs`` is a list of ``(prior,
+    chunks)``: a sequence holding ``prior`` tokens in the pool whose
+    next ``chunks`` (a list of lengths) are this dispatch's rows, in
+    order; no chunks means one inactive row. Live pages are distinct,
+    table tails hold ``tail``. With ``poison`` every page no row
+    holds, every slot at or past a sequence's ``prior`` (what this
+    dispatch writes too) and the trash page, where the tails then
+    point, are NaN. Returns the kernel's arguments and qblock."""
+    hk, g, d = 2, 2, 16
+    kv, qs, ql, ws, wf, we, owner = [], [], [], [], [], [], []
+    t = 0
+    for si, (prior, chunks) in enumerate(seqs):
+        end, at = prior + sum(chunks), prior
+        for c in chunks or [0]:
+            live = bool(chunks)
+            kv.append((at + c) if live else 0)
+            ql.append(c)
+            qs.append(at if live else 0)
+            ws.append(prior if live else 0)
+            wf.append(t if live else 0)
+            we.append(end if live else 0)
+            owner.append(si)
+            at += c
+        t += sum(chunks)
+    held = [-(-(p + sum(c)) // PAGE) if c else 0 for p, c in seqs]
+    assert max(held) <= width and max(ql) <= qb
+    pool = sum(held) + 3
+    dump = pool - 1
+    ids = rng.permutation(pool - 1)
+    start = np.concatenate([[0], np.cumsum(held)])
+    tables = np.full((len(kv), width), tail, np.int32)
+    for i, si in enumerate(owner):
+        tables[i, :held[si]] = ids[start[si]:start[si] + held[si]]
+    kp = rng.randn(pool, hk, PAGE, d).astype(np.float32)
+    vp = rng.randn(pool, hk, PAGE, d).astype(np.float32)
+    if poison:
+        clean = np.zeros((pool, PAGE), bool)
+        for si, (prior, chunks) in enumerate(seqs):
+            n = prior if chunks else 0       # not what is written now
+            for j in range(held[si]):
+                clean[ids[start[si] + j], :max(0, min(PAGE,
+                                                      n - j * PAGE))] = 1
+        kp[~clean[:, None, :].repeat(hk, 1)] = np.nan
+        vp[~clean[:, None, :].repeat(hk, 1)] = np.nan
+        tables[tables == tail] = dump
+    t = max(t, 1)
+    arr = lambda a: jnp.asarray(np.asarray(a, np.int32))    # noqa: E731
+    pos = np.zeros(t, np.int32)
+    for i in range(len(kv)):
+        f = wf[i] + qs[i] - ws[i]
+        pos[f:f + ql[i]] = np.arange(qs[i], kv[i])
+    sin, cos = RPA.rope_tables(jnp.asarray(pos), d, 10000.0)
+    args = (jnp.asarray(rng.randn(t, hk * g, d), jnp.float32),
+            jnp.asarray(rng.randn(t, hk, d), jnp.float32),
+            jnp.asarray(rng.randn(t, hk, d), jnp.float32),
+            jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(tables),
+            arr(kv), arr(qs), arr(ql), arr(ws), arr(wf), arr(we), dump)
+    return args, dict(rope_sin=sin, rope_cos=cos, qblock=qb)
+
+
+def _assert_walk_parity(args, kw):
+    out_f, kpf, vpf = map(_unwrap, RPA.fused_ragged_paged_attention(
+        *args, **kw))
+    out_x, kpx, vpx = map(np.asarray,
+                          RPA.fused_ragged_paged_attention_xla(
+                              *args, **kw))
+    _assert_parity(jnp.asarray(out_f), jnp.asarray(out_x))
+    live = [i for i in range(kpf.shape[0]) if i != args[-1]]
+    assert np.array_equal(kpf[live], kpx[live])         # BITWISE
+    assert np.array_equal(vpf[live], vpx[live])
+    # padded query rows and inactive rows are defined zeros
+    ql = np.asarray(args[8])
+    for i, n in enumerate(ql):
+        assert float(np.max(np.abs(out_f[i, n:]), initial=0.0)) == 0.0
+    return out_f
+
+
+WALK_KV = {"0": 0, "1": 1, "block-1": BLOCK - 1, "block": BLOCK,
+           "block+1": BLOCK + 1, "full": 10 ** 9}
+
+
+@pytest.mark.parametrize("width", [161, 66, 5])
+@pytest.mark.parametrize("kv", list(WALK_KV))
+def test_walk_bounded_by_kv_len(kv, width):
+    """The walk against the write-then-read reference at the edges of
+    a block and of the table: a decode row and a chunk row of context
+    ``kv`` with an inactive row between them and a live anchor behind,
+    under tables that are no multiple of the block's pages."""
+    n = min(WALK_KV[kv], width * PAGE)
+    rng = np.random.RandomState(40 + width + n % 97)
+    qb = 8
+    seqs = [(n - 1, [1]) if n else (0, []),          # decode row
+            (0, []),                                 # inactive
+            (n - min(n, qb), [min(n, qb)]) if n else (0, []),
+            (16, [1])]                               # anchor
+    _assert_walk_parity(*_walk_case(rng, seqs, width, qb))
+
+
+@pytest.mark.parametrize("width", [161, 66])
+def test_walk_two_chunks_across_a_block_edge(width):
+    """Two chunks of ONE sequence in one dispatch whose write span
+    straddles a block edge: the later chunk replays what the earlier
+    wrote from the packed rows, in two different blocks of its walk,
+    and the last row writes pages of both blocks."""
+    rng = np.random.RandomState(50 + width)
+    seqs = [(BLOCK - 12, [8, 8]), (0, []), (BLOCK + 30, [1]),
+            (3, [5])]
+    _assert_walk_parity(*_walk_case(rng, seqs, width, 8))
+
+
+def test_walk_all_decode_batch():
+    """qblock 1, every row one token: the scan tick's shape, contexts
+    on both sides of a block edge."""
+    rng = np.random.RandomState(60)
+    seqs = [(n - 1, [1]) for n in (1, BLOCK - 1, BLOCK, BLOCK + 1, 300)]
+    seqs.insert(2, (0, []))
+    _assert_walk_parity(*_walk_case(rng, seqs, 66, 1))
+
+
+@pytest.mark.parametrize("qb", [1, 8])
+def test_walk_uses_nothing_past_the_context(qb):
+    """Every page no row holds, every slot past a row's kv_len and
+    every table tail is NaN: the output is finite and equal, bit for
+    bit, to the one over a clean pool, and no NaN page is written."""
+    seqs = [(BLOCK - 3, [min(qb, 5)]), (0, []), (BLOCK + 9, [1]),
+            (2, [qb])]
+    runs = []
+    for poison in (False, True):
+        rng = np.random.RandomState(70 + qb)
+        args, kw = _walk_case(rng, seqs, 66, qb, poison=poison)
+        runs.append((args, map(_unwrap, RPA.fused_ragged_paged_attention(
+            *args, **kw))))
+    (_, (out_a, kpa, _)), (args, (out_b, kpb, vpb)) = runs
+    assert np.all(np.isfinite(out_b))
+    assert np.array_equal(out_a, out_b)
+    # what was written is what the clean run wrote; what was NaN and
+    # not fresh stays NaN (a written page keeps its other slots)
+    fresh = ~np.isnan(kpb) & np.isnan(np.asarray(args[3]))
+    assert fresh.any()
+    assert np.array_equal(kpa[fresh], kpb[fresh])
+    assert np.array_equal(np.isnan(kpb) | fresh,
+                          np.isnan(np.asarray(args[3])))
+
+
+def test_walk_under_the_tpu_interpreter(monkeypatch):
+    """The same program under Pallas's TPU interpreter, which starts
+    VMEM as NaN and raises on a read out of bounds (the generic
+    interpreter the suite runs under zero-fills): a buffer slot no DMA
+    filled, or a table slot past the row's pages, would show."""
+    from jax.experimental.pallas import tpu as pltpu
+    monkeypatch.setattr(RPA, "_interpret",
+                        lambda: pltpu.InterpretParams())
+    rng = np.random.RandomState(80)
+    seqs = [(BLOCK - 4, [8, 3]), (0, []), (BLOCK + 1, [1])]
+    _assert_walk_parity(*_walk_case(rng, seqs, 40, 8))
 
 
 def test_table_tail_garbage_is_clamped():

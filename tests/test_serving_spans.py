@@ -221,6 +221,57 @@ def test_decode_scan_has_the_same_shape(model):
         assert s["args"]["t_submit"] == s["args"]["t_admit"]
 
 
+def test_dispatch_says_what_the_kernel_walks(model):
+    """``serving.dispatch`` carries ``kv_pages``, the pages the
+    scheduled rows' contexts hold (counted here from the allocator's
+    own tables), and ``table_slots``, the slots of the tables the
+    program is given: the share of the second that the rope-fused
+    kernel's walk no longer visits."""
+    om.default_registry().clear()
+    engine = _engine(model)
+    want = {}
+
+    def pages(sid, kv_len):
+        return len(set(engine.alloc.page_positions(sid, 0,
+                                                   kv_len)[0].tolist()))
+
+    rows_of, scan_of = engine._dispatch_rows, engine._dispatch_scan
+
+    def spy_rows(rows, cow):
+        want[engine._dispatch_count - 1] = sum(
+            pages(sid, start + n) for _, sid, start, n, _, _ in rows)
+        return rows_of(rows, cow)
+
+    def spy_scan(n, live, sids, last_tok, start_lens, cow):
+        want[engine._dispatch_count - 1] = sum(
+            pages(sid, start_lens[sid] + 1) for sid in sids)
+        return scan_of(n, live, sids, last_tok, start_lens, cow)
+
+    engine._dispatch_rows, engine._dispatch_scan = spy_rows, spy_scan
+    reqs = [Request(list(range(1, n + 1)), max_new_tokens=6)
+            for n in (40, 5, 23)]
+    otrace.clear()
+    for r in reqs:
+        engine.add_request(r)
+    while any(r._prefilled < len(r.prompt_ids) for r in reqs):
+        engine.step()
+    engine.step()
+    while any(not r.done for r in reqs):
+        assert engine.decode_many(4) > 0
+    disp = _by(otrace.get_events(), "serving.dispatch")
+    kinds = {d["args"]["kind"] for d in disp}
+    assert kinds == {"mixed", "decode", "scan"}
+    for d in disp:
+        a = d["args"]
+        assert a["kv_pages"] == want[a["step"]] > 0
+        assert a["table_slots"] == 16 * (
+            engine.rows_cap if a["kind"] == "mixed" else engine.max_batch)
+        assert a["kv_pages"] <= a["table_slots"]
+    # three sequences of at most 46 tokens in pages of 8: the walk is
+    # a small part of the tables
+    assert max(d["args"]["kv_pages"] for d in disp) <= 3 * 6
+
+
 def test_metrics_off_records_nothing_and_serves_the_same(model,
                                                          monkeypatch):
     want = _engine(model).generate([[1, 2, 3, 4]], max_new_tokens=4)
