@@ -1,6 +1,7 @@
-"""Where the benchmark touches the program: building the model through
-its constructor, handing it the seeded weights, reading its counters.
-Everything else the benchmark needs is its own."""
+"""Where the benchmark touches the program: handing it the seeded weights,
+reading its counters. Its constructor and its parameters' names are the
+family's (``perfbench/families``). Everything else the benchmark needs
+is its own."""
 
 import numpy as np
 
@@ -21,56 +22,12 @@ def cache_stats():
     return cw.persistent_cache_stats()
 
 
-def llama_config(cfg, **extra):
-    from paddle_tpu.models import LlamaConfig
-    return LlamaConfig(
-        vocab_size=cfg["vocab_size"], hidden_size=cfg["hidden_size"],
-        intermediate_size=cfg["intermediate_size"],
-        num_hidden_layers=cfg["num_hidden_layers"],
-        num_attention_heads=cfg["num_attention_heads"],
-        num_key_value_heads=cfg["num_key_value_heads"],
-        max_position_embeddings=cfg["max_position_embeddings"],
-        rms_norm_eps=cfg["rms_norm_eps"], rope_theta=cfg["rope_theta"],
-        tie_word_embeddings=cfg.get("tie_word_embeddings", False),
-        **extra)
-
-
-def build_model(cfg, dtype, **extra):
-    """The program's own constructor (its eager per-parameter init is
-    part of set-up until the program can skip it)."""
-    import paddle_tpu as paddle
-    from paddle_tpu.models import LlamaForCausalLM
-    paddle.set_default_dtype(dtype)
-    try:
-        model = LlamaForCausalLM(llama_config(cfg, **extra))
-    finally:
-        paddle.set_default_dtype("float32")
-    return model
-
-
-def leaves(model):
-    """``{benchmark leaf name: the program's parameter}``."""
-    out = {"embed": model.model.embed_tokens.weight,
-           "head": model.lm_head.weight, "norm": model.model.norm.weight}
-    for i, layer in enumerate(model.model.layers):
-        a, m = layer.self_attn, layer.mlp
-        out.update({
-            f"layers.{i}.q": a.q_proj.weight, f"layers.{i}.k": a.k_proj.weight,
-            f"layers.{i}.v": a.v_proj.weight, f"layers.{i}.o": a.o_proj.weight,
-            f"layers.{i}.gate": m.gate_proj.weight,
-            f"layers.{i}.up": m.up_proj.weight,
-            f"layers.{i}.down": m.down_proj.weight,
-            f"layers.{i}.ln1": layer.input_layernorm.weight,
-            f"layers.{i}.ln2": layer.post_attention_layernorm.weight})
-    return out
-
-
-def assign_weights(model, cfg, seed, dtype, keep=True):
+def assign_weights(family, model, cfg, seed, dtype, keep=True):
     """Give the program the seeded weights, layer by layer so that at
     most one layer lies twice on the device. Returns the arrays (the
-    benchmark's own) when ``keep``."""
-    from . import weights
-    params = leaves(model)
+    benchmark's own: ``{"ends": {leaf: array}, "layers": [{leaf:
+    array}]}``) when ``keep``, and the program's parameter count."""
+    params = family.leaves(model, cfg)
 
     def put(name, arr):
         p = params[name]
@@ -79,13 +36,12 @@ def assign_weights(model, cfg, seed, dtype, keep=True):
                              f"benchmark {tuple(arr.shape)}")
         p._data = arr
 
-    kept = {"layers": []}
-    ends = weights.ends(cfg, seed, dtype)
+    ends = family.ends(cfg, seed, dtype)
     for k, a in ends.items():
         put(k, a)
-    kept.update(ends)
-    for i in range(cfg["num_hidden_layers"]):
-        lw = weights.layer(cfg, seed, i, dtype)
+    kept = {"ends": ends, "layers": []}
+    for i in range(family.layer_count(cfg)):
+        lw = family.layer(cfg, seed, i, dtype)
         for k, a in lw.items():
             put(f"layers.{i}.{k}", a)
         kept["layers"].append(lw)

@@ -29,8 +29,8 @@ def serve_work(run):
     n = len(steps(run))
     if not n:
         return None
-    return costs.serve_work(run.cfg, n, run.facts["prefill"],
-                            run.facts["decode"])
+    return run.family.serve_work(run.cfg, n, run.facts["prefill"],
+                                 run.facts["decode"])
 
 
 def kernel_roofline(run, pattern, flops, nbytes, name):

@@ -8,7 +8,7 @@ import time
 
 import numpy as np
 
-from . import costs, program, reference, stats, trace as tr, traffic
+from . import program, stats, trace as tr, traffic
 
 pc = time.perf_counter
 
@@ -86,30 +86,27 @@ def build(run):
     engine with this cell's two step shapes warm."""
     import jax
     from paddle_tpu.inference.cluster import ServingCluster
-    from paddle_tpu.inference.serving import LlamaServingEngine
     from paddle_tpu.observability import compile_watch as cw
 
     cw.enable_persistent_cache()
-    cfg, mix = run.cfg, run.mix
+    cfg, mix, family = run.cfg, run.mix, run.family
     t = pc()
-    model = program.build_model(cfg, cfg["torch_dtype"])
+    model = family.build_model(cfg, cfg["torch_dtype"])
     model.eval()
     run.note(phase="build_model", seconds=pc() - t)
     t = pc()
     run.weights, n = program.assign_weights(
-        model, cfg, run.seed, cfg["torch_dtype"])
+        family, model, cfg, run.seed, cfg["torch_dtype"])
     jax.block_until_ready(run.weights)
-    if n != costs.total_params(cfg):
+    if n != family.total_params(cfg):
         raise RuntimeError(f"program holds {n} parameters, the "
-                           f"configuration {costs.total_params(cfg)}")
+                           f"configuration {family.total_params(cfg)}")
     run.note(phase="seed_weights", seconds=pc() - t, parameters=n)
     box = {}
 
     def factory():
-        e = LlamaServingEngine(model, **mix["engine"])
-        e.prewarm(mixed=[e.chunk_budget, e.max_batch])
-        box["engine"] = e
-        return e
+        box["engine"] = family.engine(model, mix)
+        return box["engine"]
 
     t = pc()
     cluster = ServingCluster(factory, num_replicas=1,
@@ -367,11 +364,10 @@ def served_gaps(run, sample, quant=None):
         ids[i, len(p):len(p) + len(o)] = o
         rows[i, :len(o)] = np.arange(len(p) - 1, len(p) - 1 + len(o))
         mask[i, :len(o)] = True
-    w = run.weights
-    ends = {k: w[k] for k in ("embed", "head", "norm")}
+    w, logits = run.weights, run.family.served_logits
     block = int(chk["block"])
-    ref = reference.served_logits(cfg, ids, rows, lambda i: w["layers"][i],
-                                  ends, None, block)
+    ref = logits(cfg, ids, rows, lambda i: w["layers"][i], w["ends"], None,
+                 block)
     best = ref.max(-1)
     out = {}
     served = np.zeros_like(rows)
@@ -380,9 +376,8 @@ def served_gaps(run, sample, quant=None):
     took = np.take_along_axis(ref, served[:, :, None], -1)[:, :, 0]
     out["served"] = (best - took)[mask]
     if quant:
-        low = reference.served_logits(cfg, ids, rows,
-                                      lambda i: w["layers"][i], ends,
-                                      quant, block)
+        low = logits(cfg, ids, rows, lambda i: w["layers"][i], w["ends"],
+                     quant, block)
         first = low.argmax(-1)
         took = np.take_along_axis(ref, first[:, :, None], -1)[:, :, 0]
         out["control"] = (best - took)[mask]
@@ -474,7 +469,7 @@ def run(run):
         read_trace(run, sent, facts)
     # free the program's state before the reference runs
     run.cluster.stop()
-    run.engine.k_pools = run.engine.v_pools = None
+    run.family.release(run.engine)
     run.cluster = run.engine = run.model = None
     gc.collect()
     run.checks, ok = check(run, done)

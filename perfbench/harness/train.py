@@ -8,7 +8,7 @@ import time
 
 import numpy as np
 
-from . import costs, program, reference, stats, trace as tr, traffic
+from . import program, stats, trace as tr, traffic
 
 pc = time.perf_counter
 CHECK_STEPS = 3
@@ -31,12 +31,11 @@ def _norms(arrays):
     return [float(v) for v in f(arrays)]
 
 
-def _delta_norms(cfg, seed, params):
+def _delta_norms(family, cfg, seed, params):
     """Norm of each leaf's change from the seed's weights (made again
     leaf by leaf: the step donated the ones the program was given)."""
     import jax
     import jax.numpy as jnp
-    from . import weights
 
     @jax.jit
     def f(new, old):
@@ -44,10 +43,10 @@ def _delta_norms(cfg, seed, params):
             new[k].astype(jnp.float32) - old[k]))) for k in old}
 
     out = {}
-    ends = weights.ends(cfg, seed, "float32")
+    ends = family.ends(cfg, seed, "float32")
     out.update(f({k: params[k]._data for k in ends}, ends))
-    for i in range(cfg["num_hidden_layers"]):
-        lw = weights.layer(cfg, seed, i, "float32")
+    for i in range(family.layer_count(cfg)):
+        lw = family.layer(cfg, seed, i, "float32")
         got = f({k: params[f"layers.{i}.{k}"]._data for k in lw}, lw)
         out.update({f"layers.{i}.{k}": v for k, v in got.items()})
     return {k: float(v) for k, v in out.items()}
@@ -62,7 +61,7 @@ def build(run):
     from paddle_tpu.observability import compile_watch as cw
 
     cw.enable_persistent_cache()
-    cfg, mix = run.cfg, run.mix
+    cfg, mix, family = run.cfg, run.mix, run.family
     seq, batch = int(mix["seq_len"]), int(mix["batch"])
     os.makedirs(run.work_dir, exist_ok=True)
     path = os.path.join(run.work_dir, f"corpus.{run.cell['name']}.bin")
@@ -79,7 +78,7 @@ def build(run):
                 np.ascontiguousarray(ids[:, 1:]))
 
     t = pc()
-    model = program.build_model(cfg, "float32")
+    model = family.build_model(cfg, "float32")
     lr, b1, b2, eps, wd = hyper(mix)
     opt = paddle.optimizer.AdamW(learning_rate=lr, beta1=b1, beta2=b2,
                                  epsilon=eps, weight_decay=wd,
@@ -104,11 +103,11 @@ def build(run):
     step(paddle.to_tensor(wids[:, :-1]), paddle.to_tensor(wids[:, 1:]))
     run.note(phase="build_and_eager_warmup", seconds=pc() - t)
     t = pc()
-    _, n = program.assign_weights(model, cfg, run.seed, "float32",
+    _, n = program.assign_weights(family, model, cfg, run.seed, "float32",
                                   keep=False)
-    if n != costs.total_params(cfg):
+    if n != family.total_params(cfg):
         raise RuntimeError(f"program holds {n} parameters, the "
-                           f"configuration {costs.total_params(cfg)}")
+                           f"configuration {family.total_params(cfg)}")
     for name, acc in opt.state_dict().items():
         if name == "LR_Scheduler":
             continue
@@ -124,7 +123,7 @@ def build(run):
     run.step, run.next_batch, run.feed = step, next_batch, feed
     run.model, run.opt = model, opt
     # the first steps, through the window's own call and feed
-    params = program.leaves(model)
+    params = family.leaves(model, cfg)
     names = list(params)
     t = pc()
     losses = [run.step(*next_batch())]
@@ -136,7 +135,7 @@ def build(run):
     del m1
     for _ in range(CHECK_STEPS - 1):
         losses.append(run.step(*next_batch()))
-    delta = _delta_norms(cfg, run.seed, params)
+    delta = _delta_norms(family, cfg, run.seed, params)
     run.program_readings = {"loss": [float(l) for l in losses],
                             "grad": grad, "delta": delta}
     run.note(phase="check_steps", losses=run.program_readings["loss"])
@@ -233,7 +232,8 @@ def check(run):
     limits = run.mix["check"]["limits"]
     t = pc()
     batches, hp = run.fed[:CHECK_STEPS], hyper(run.mix)
-    ref = reference.train_readings(run.cfg, run.seed, batches, hp)
+    readings = run.family.train_readings
+    ref = readings(run.cfg, run.seed, batches, hp)
     held = run.program_readings
     if run.control:
         planted = {"int8": dict(quant="int8"),
@@ -242,8 +242,7 @@ def check(run):
         nums, _ = compare(held, ref)
         run.note(phase="control", in_the_programs_place=run.control,
                  **{"program_" + k: v for k, v in nums.items()})
-        held = reference.train_readings(run.cfg, run.seed, batches, hp,
-                                        **planted)
+        held = readings(run.cfg, run.seed, batches, hp, **planted)
     nums, where = compare(held, ref)
     run.note(phase="reference", seconds=pc() - t, losses=ref["loss"],
              held_losses=held["loss"], loss1_gap=nums["loss1_gap"],
@@ -272,7 +271,7 @@ def run(run):
     wall = facts["t_end"] - facts["t_open"]
     run.e2e = {"train_tok_s": facts["tokens"] / wall,
                "setup_s": run.setup_s}
-    flops = costs.train_flops_per_token(cfg, int(mix["seq_len"]))
+    flops = run.family.train_flops_per_token(cfg, int(mix["seq_len"]))
     run.note(phase="window", steps=facts["steps"], wall_seconds=wall,
              step_seconds=wall / facts["steps"],
              compiles_in_window=facts["compiles_in_window"],

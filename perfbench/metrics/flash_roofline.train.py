@@ -4,7 +4,7 @@ traced steps over the summed device time of the flash kernels' events
 (forward, dQ, dK/dV). PATTERN was written after reading a trace by hand
 (PERF.md, section 3)."""
 
-from harness import costs, readers
+from harness import readers
 
 # No pallas_call of the program passes name=, so the profiler calls a
 # kernel by its HLO text. The flash kernels are the custom calls to
@@ -19,9 +19,10 @@ def read(run):
     if not n:
         return None
     seq, rows = run.facts["seq_len"], run.facts["sequences_per_step"]
-    pattern = PATTERN.format(batch=rows, seq=seq,
-                             dim=costs.head_dim(run.cfg))
+    cfg, fam = run.cfg, run.family
+    dim = cfg.get("head_dim") or \
+        cfg["hidden_size"] // cfg["num_attention_heads"]
+    pattern = PATTERN.format(batch=rows, seq=seq, dim=dim)
     return readers.kernel_roofline(
-        run, pattern, costs.train_attn_flops(run.cfg, seq, rows) * n,
-        costs.train_attn_bytes(run.cfg, seq, rows) * n,
-        "flash_roofline.train")
+        run, pattern, fam.train_attn_flops(cfg, seq, rows) * n,
+        fam.train_attn_bytes(cfg, seq, rows) * n, "flash_roofline.train")

@@ -11,9 +11,10 @@ def read(run):
     ws, n = readers.window_s(run), run.facts.get("steps_traced")
     if run.peaks is None or not ws or not n:
         return None
-    flops = costs.train_flops_per_token(run.cfg, run.facts["seq_len"]) \
+    fam = run.family
+    flops = fam.train_flops_per_token(run.cfg, run.facts["seq_len"]) \
         * run.facts["tokens_per_step"] * n
-    nbytes = 2 * 16 * costs.total_params(run.cfg) * n
+    nbytes = 2 * 16 * fam.total_params(run.cfg) * n
     least, bound = costs.least_seconds(flops, nbytes, run.peaks)
     run.note(metric="step_mfu", least_seconds=least, bound=bound,
              window_s=ws, steps=n, flops=flops, bytes=nbytes)

@@ -7,10 +7,12 @@
 Everything about a cell is data: ``BENCHMARK.json`` names its
 configuration (``perfbench/configs``), its traffic mix
 (``perfbench/mixes``) and its per-layer metrics (one reader each in
-``perfbench/metrics``). Without the chips the cell asks for the run
-fails and prints no result. ``--rehearse 1`` walks the control flow on
-whatever backend there is at the sizes the mix's ``rehearse`` block
-gives; it prints no metric and exits 4.
+``perfbench/metrics``); the configuration names its model family (one
+module each in ``perfbench/families``). Without the chips the cell asks
+for the run fails and prints no result. ``--rehearse 1`` walks the
+control flow on whatever backend there is at the sizes the ``rehearse``
+blocks of the configuration and of the mix give; it prints no metric and
+exits 4.
 
 The last line of standard output is the result (``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device``, with ``--trace 1``
@@ -140,11 +142,13 @@ def execute(argv=None):
         else float(spec["run_seconds"])
     sweep = [float(x) for x in args.sweep.split(",") if x]
     if args.rehearse:
-        deep_update(cfg, mix.get("rehearse", {}).get("config", {}))
+        deep_update(cfg, cfg.get("rehearse", {}))
         deep_update(mix, mix.get("rehearse", {}).get("mix", {}))
 
-    from harness import peaks, selfcheck
+    from harness import family, peaks, selfcheck
+    fam = family.load(cfg, config["file"])
     selfcheck.run()
+    fam.selfcheck()
 
     import jax
     devs = jax.devices()
@@ -163,7 +167,7 @@ def execute(argv=None):
     run = Run(spec=spec, cell=cell, cfg=cfg, mix=mix, seed=args.seed,
               seconds=seconds, trace=bool(args.trace), chips=cell["chips"],
               device=device, t0=_T0, control=args.control,
-              sweep=sweep, rehearse=bool(args.rehearse),
+              sweep=sweep, rehearse=bool(args.rehearse), family=fam,
               work_dir=work_dir, trace_dir=trace_dir,
               peaks=None if args.rehearse else peaks.peak(device["kind"]))
     from harness import serve, train

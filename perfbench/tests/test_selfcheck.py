@@ -4,7 +4,7 @@ import os
 import pytest
 
 import run as bench
-from harness import selfcheck, stats, traffic
+from harness import family, selfcheck, stats, traffic
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -48,6 +48,8 @@ def test_benchmark_json_names_files_that_exist():
         spec = json.load(f)
     for c in spec["configs"]:
         assert os.path.isfile(os.path.join(ROOT, c["file"]))
+        with open(os.path.join(ROOT, c["file"])) as f:
+            assert os.path.isfile(family.path_of(json.load(f)["family"]))
     for w in spec["workloads"]:
         assert os.path.isfile(os.path.join(
             ROOT, "perfbench", "mixes", w["traffic"] + ".json"))
