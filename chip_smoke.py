@@ -10,6 +10,11 @@ calls, at published widths, on one TPU:
   and 521 pages wide. It is held against
   ``fused_ragged_paged_attention_xla``, must answer alike at every
   width and must take the same time a layer to within 10%;
+- ``latent``: the ragged paged LATENT attention kernel alone, 32 heads
+  over a 640-lane row (512 + 64 in use) and 24,577 pages of 16:
+  docqa-closed's two programs behind tables 161, 521 and 1,057 pages
+  wide, held to ``ragged_mla_attention``'s XLA formulation and to the
+  same two rules;
 - ``serve``: Llama-3-8B widths (hidden 4096, FFN 14336, 32q/8kv, vocab
   128256), depth cut to 8 layers, seeded bf16 weights;
   ``LlamaServingEngine`` with its default (rope-fused) program answers
@@ -317,6 +322,29 @@ def walk_rows(rng, r_cap, t_cap, qblock, decode_rows, chunks, max_ctx,
     return kv, qs, ql, ws, wf, we, pos, pages
 
 
+def walk_tables(r_cap, width, trash, owner, starts, pages, ids):
+    """Block tables ``[r_cap, width]`` of one dispatch: row ``i`` holds
+    its owner's pages, the rest names the trash page."""
+    tables = np.full((r_cap, width), trash, np.int32)
+    for i in range(r_cap):
+        o = owner[i]
+        tables[i, :pages[o]] = ids[starts[o]:starts[o] + pages[o]]
+    return tables
+
+
+def best_ms(fn, args, reps):
+    """(the first call's result, the fastest of ``reps`` further calls
+    in ms)."""
+    import jax
+    out = jax.block_until_ready(fn(*args))
+    best = float("inf")
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn(*args))
+        best = min(best, time.perf_counter() - t0)
+    return out, 1e3 * best
+
+
 def phase_walk(seed, rehearse, on_chip):
     import jax
     import jax.numpy as jnp
@@ -353,10 +381,8 @@ def phase_walk(seed, rehearse, on_chip):
         sin, cos = rpa.rope_tables(jnp.asarray(pos), d, 1e6)
         outs = {}
         for width in widths:
-            tables = np.full((r_cap, width), trash, np.int32)
-            for i in range(r_cap):
-                o = owner[i]
-                tables[i, :pages[o]] = ids[starts[o]:starts[o] + pages[o]]
+            tables = walk_tables(r_cap, width, trash, owner, starts, pages,
+                                 ids)
             args = (q, nk, nv, kp, vp, jnp.asarray(tables),
                     *(jnp.asarray(a) for a in (kv, qs, ql, ws, wf, we)))
 
@@ -370,14 +396,8 @@ def phase_walk(seed, rehearse, on_chip):
                         dump_page=trash, scale=d ** -0.5, qblock=qb)
                 return out, kp, vp
 
-            out = jax.block_until_ready(stack(*args))
-            best = float("inf")
-            for _ in range(reps):
-                t0 = time.perf_counter()
-                jax.block_until_ready(stack(*args))
-                best = min(best, time.perf_counter() - t0)
-            outs[width] = out
-            timings[f"{name}.{width}"] = 1e3 * best / layers
+            outs[width], ms = best_ms(stack, args, reps)
+            timings[f"{name}.{width}"] = ms / layers
         base = outs[widths[0]]
         for width in widths[1:]:
             if not all(bool(jnp.array_equal(a, b))
@@ -414,6 +434,97 @@ def phase_walk(seed, rehearse, on_chip):
                                             "decode_rows", "chunk_rows"),
                                            v))
                                for k, v in programs.items()}),
+         ms_a_layer=timings)
+
+
+def phase_latent(seed, rehearse, on_chip):
+    """The ragged paged LATENT attention kernel alone, at the expert
+    family's head shape (32 heads over a 640-lane row, 512 + 64 in use)
+    behind tables of three widths: the same answers and the same time
+    at every width, and the XLA formulation's answers."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import ragged_mla_attention as mla
+
+    if rehearse:
+        h, rank, rope, page, pool, dt = 4, 32, 8, 8, 96, jnp.float32
+        widths, layers, reps = (5, 9, 13), 2, 1
+        programs = {"mixed": (8, 24, 8, 4, 2), "decode": (6, 6, 1, 6, 0)}
+    else:
+        h, rank, rope, page, pool, dt = 32, 512, 64, 16, 24577, jnp.bfloat16
+        widths, layers, reps = (161, 521, 1057), 5, 5
+        # docqa-closed's two programs: 80 rows x 1,024 tokens with
+        # 32-token chunks beside 48 decode rows, and 48 rows of a token
+        programs = {"mixed": (80, 1024, 32, 48, 30),
+                    "decode": (48, 48, 1, 48, 0)}
+    w = mla.latent_row_width(rank, rope)
+    scale = (rank // 4 + rope) ** -0.5
+    rng = np.random.RandomState(seed)
+    trash = pool - 1
+    lanes = jnp.arange(w) < rank + rope
+
+    def rows_of(key, shape):
+        return jnp.where(lanes, jax.random.normal(
+            key, (*shape, w), jnp.float32), 0).astype(dt)
+
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(seed), 3)
+    lp = rows_of(k1, (pool, page))
+    timings = {}
+    for name, (r_cap, t_cap, qb, n_dec, n_chunk) in programs.items():
+        kv, qs, ql, ws, wf, we, _, pages = walk_rows(
+            rng, r_cap, t_cap, qb, n_dec, n_chunk, min(widths) * page,
+            page)
+        ids = rng.permutation(trash)
+        owner = np.arange(r_cap)
+        owner[n_dec:n_dec + n_chunk] = n_dec
+        starts = np.concatenate([[0], np.cumsum(pages)[:-1]])
+        q, new = rows_of(k2, (t_cap, h)), rows_of(k3, (t_cap,))
+        outs, narrow = {}, None
+        for width in widths:
+            tables = walk_tables(r_cap, width, trash, owner, starts, pages,
+                                 ids)
+            args = (q, new, lp, jnp.asarray(tables),
+                    *(jnp.asarray(a) for a in (kv, qs, ql, ws, wf, we)))
+            # the XLA formulation gathers a table's whole width: it is
+            # given the narrowest
+            narrow = narrow or args
+
+            @jax.jit
+            def stack(q, new, lp, *meta):
+                for _ in range(layers):
+                    out, lp = mla._kernel_impl(q, new, lp, *meta, rank,
+                                               scale, qb)
+                return out, lp
+
+            outs[width], ms = best_ms(stack, args, reps)
+            timings[f"{name}.{width}"] = ms / layers
+        base = outs[widths[0]]
+        for width in widths[1:]:
+            if not all(bool(jnp.array_equal(a, b))
+                       for a, b in zip(base, outs[width])):
+                raise RuntimeError(
+                    f"{name}: width {width} answers otherwise than "
+                    f"width {widths[0]} on the same rows")
+        ref = mla._xla_impl(*narrow, rank, scale, qb)
+        f32 = lambda a: a.astype(jnp.float32)        # noqa: E731
+        live = jnp.asarray(ql > 0)[:, None, None, None]
+        err = float(jnp.max(jnp.abs(jnp.where(
+            live, f32(base[0]) - f32(ref[0]), 0))))
+        unequal = float(jnp.mean(base[1][:trash] != ref[1][:trash]))
+        timings[f"{name}.out_err"] = err
+        timings[f"{name}.pool_share_unequal"] = unequal
+        tol = 1e-5 if rehearse else 2e-2
+        if unequal or not err <= tol * max(
+                1.0, float(jnp.max(jnp.abs(f32(ref[0]))))):
+            raise RuntimeError(f"{name}: latent kernel and XLA formulation "
+                               f"differ: out {err}, pages {unequal}")
+        ms = [timings[f"{name}.{x}"] for x in widths]
+        if on_chip and max(ms) > (1 + WALK_WIDTH_RTOL) * min(ms):
+            raise RuntimeError(f"{name}: the latent kernel's time follows "
+                               f"the table's width: {ms} ms at {widths}")
+    emit(phase="latent", device=device_info(),
+         shapes=dict(heads=h, kv_rank=rank, rope=rope, row=w,
+                     page_size=page, num_pages=pool, widths=list(widths)),
          ms_a_layer=timings)
 
 
@@ -633,7 +744,7 @@ def main():
                          "never prints the success line, exits "
                          f"{REHEARSAL_EXIT}")
     ap.add_argument("--phase", default="all",
-                    choices=("all", "walk", "serve", "train"),
+                    choices=("all", "walk", "latent", "serve", "train"),
                     help="one chip: run only this phase")
     args = ap.parse_args()
 
@@ -651,8 +762,8 @@ def main():
     if args.chips == 4:
         phase_mesh(args.seed, args.rehearse, on_chip)
     else:
-        for name, phase in (("walk", phase_walk), ("serve", phase_serve),
-                            ("train", phase_train)):
+        for name, phase in (("walk", phase_walk), ("latent", phase_latent),
+                            ("serve", phase_serve), ("train", phase_train)):
             if args.phase in ("all", name):
                 phase(args.seed, args.rehearse, on_chip)
                 gc.collect()    # a phase's weights leave before the next

@@ -280,8 +280,7 @@ def _worker_exit():
 # ---------------------------------------------------------------------
 def _build_model(model_spec):
     import paddle_tpu as paddle
-    from ..models import LlamaForCausalLM, tiny_llama_config
-    from ..models.llama import LlamaConfig
+    from .. import models
 
     factory = model_spec.get("factory")
     if factory:
@@ -293,15 +292,20 @@ def _build_model(model_spec):
     seed = model_spec.get("seed")
     if seed is not None:
         paddle.seed(int(seed))
+    # kind -> (config from keywords, model class)
+    kinds = {
+        "tiny_llama": (models.tiny_llama_config, models.LlamaForCausalLM),
+        "llama": (models.LlamaConfig, models.LlamaForCausalLM),
+        "tiny_mla_moe": (models.tiny_mla_moe_config,
+                         models.MlaMoeForCausalLM),
+        "mla_moe": (models.MlaMoeConfig, models.MlaMoeForCausalLM),
+    }
     kind = model_spec.get("kind", "tiny_llama")
-    cfg_kw = model_spec.get("config", {})
-    if kind == "tiny_llama":
-        cfg = tiny_llama_config(**cfg_kw)
-    elif kind == "llama":
-        cfg = LlamaConfig(**cfg_kw)
-    else:
-        raise ValueError(f"unknown model kind {kind!r}")
-    m = LlamaForCausalLM(cfg)
+    if kind not in kinds:
+        raise ValueError(f"unknown model kind {kind!r} (one of "
+                         f"{sorted(kinds)})")
+    config, model = kinds[kind]
+    m = model(config(**model_spec.get("config", {})))
     m.eval()
     return m
 

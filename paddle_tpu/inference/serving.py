@@ -131,25 +131,23 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..framework.tensor import Tensor, no_grad, run_op
-from ..incubate.nn import functional as FI
 from ..observability import compile_watch as _cw
 from ..observability import flight_recorder as _fr
 from ..observability import metrics as _om
 from ..observability import tracing as _tracing
 from ..observability.trace import record as _record_span
 from ..observability.trace import span as _span
-from ..ops.ragged_paged_attention import (fused_ragged_paged_attention,
-                                          fused_rope_geometry_ok,
-                                          ragged_paged_attention,
-                                          rope_tables)
+from ..ops.ragged_paged_attention import fused_rope_geometry_ok
 from ..testing import faults as _faults
 from .kv_tier import KvPageTier, TierError
-from .paged_cache import PageAllocator, quantize_kv_int8
+from .layer_step import (ServingStep, _page_write,  # noqa: F401
+                         _page_write_q8, _token_gather)
+from .paged_cache import PageAllocator
 from .sampling import SamplingParams, sampled_next_tokens
 from .speculative import NGramDrafter
 
 __all__ = ["LlamaServingEngine", "Request", "AdmissionError",
-           "DeadlineExceeded"]
+           "DeadlineExceeded", "UnsupportedServingFeature"]
 
 
 class AdmissionError(MemoryError):
@@ -192,6 +190,11 @@ class AdmissionError(MemoryError):
         return (type(self), (self.reason, self.live, self.max_batch,
                              self.free_pages, self.num_pages,
                              self.retries, self.retry_after))
+
+
+class UnsupportedServingFeature(NotImplementedError):
+    """An engine feature was asked for that a layer kind of the model
+    cannot serve yet (``str(exc)`` names the feature and the layer)."""
 
 
 class DeadlineExceeded(TimeoutError):
@@ -396,75 +399,6 @@ def _fatal_guard(origin):
     return deco
 
 
-def _last_writer_values(new, page_ids, offs, page_slots):
-    """Pin LAST-WRITER-WINS semantics for a scatter whose (page, slot)
-    targets may repeat within one dispatch (padding tokens all aim at
-    the trash page; a chunk-boundary replay may legally re-write a
-    slot): XLA's scatter leaves duplicate-index ordering
-    implementation-defined, so instead of trusting it every duplicate's
-    update VALUE is replaced by the last writer's — identical updates
-    are order-independent by construction. The fused kernel pins the
-    same semantics (the sequence's last row owns the page write), so
-    both paths leave bitwise-identical slots. O(T^2) int compare on the
-    packed token axis — noise next to the model math."""
-    t = page_ids.shape[0]
-    key = page_ids.astype(jnp.int32) * page_slots + offs.astype(jnp.int32)
-    eq = key[:, None] == key[None, :]
-    idx_last = jnp.argmax(
-        jnp.where(eq, jnp.arange(t, dtype=jnp.int32)[None, :], -1),
-        axis=1)
-    return new[idx_last]
-
-
-def _page_write(pages, new, page_ids, offs):
-    """Functional scatter of ``new [B, Hk, D]`` into head-major ``pages
-    [P, Hk, page, D]`` at (page_ids[b], h, offs[b]) — one token per live
-    sequence. Duplicate targets resolve last-writer-wins (see
-    `_last_writer_values`)."""
-    def fn(pages, new, page_ids, offs):
-        new = _last_writer_values(new, page_ids, offs, pages.shape[2])
-        hidx = jnp.arange(pages.shape[1])[None, :]
-        return pages.at[page_ids[:, None], hidx, offs[:, None]].set(
-            new.astype(pages.dtype))
-
-    return run_op("paged_kv_write", fn, (pages, new, page_ids, offs),
-                  differentiable=False)
-
-
-def _page_write_q8(pages, scales, new, page_ids, offs):
-    """Quantizing scatter for int8 pools: ``new [B, Hk, D]`` float K/V
-    is int8-quantized per head (symmetric, absmax) and scattered into
-    ``pages [P, Hk, page, D]`` int8, with the per-head scale landing in
-    the ``scales [P, Hk, page, 1]`` sidecar at the same (page, head,
-    slot). A slot's (int8, scale) pair is always the LAST writer's —
-    duplicates are rewritten to the last value before the scatter (see
-    `_last_writer_values`), so a twice-written slot's sidecar can never
-    mix one write's int8 with another's scale."""
-    def fn(pages, scales, new, page_ids, offs):
-        new = _last_writer_values(new, page_ids, offs, pages.shape[2])
-        q, s = quantize_kv_int8(new)             # [B, Hk, D], [B, Hk]
-        hidx = jnp.arange(pages.shape[1])[None, :]
-        pages = pages.at[page_ids[:, None], hidx, offs[:, None]].set(q)
-        scales = scales.at[
-            page_ids[:, None], hidx, offs[:, None], 0].set(s)
-        return pages, scales
-
-    return run_op("paged_kv_write_q8", fn,
-                  (pages, scales, new, page_ids, offs),
-                  differentiable=False)
-
-
-def _token_gather(x, idx):
-    """Gather rows of ``x`` by an integer index array — the mixed
-    program's pack/unpack between the flat token axis [T, ...] and the
-    ragged kernel's row-blocked layout [R, QB, ...]."""
-    def fn(x, idx):
-        return x[idx.astype(jnp.int32)]
-
-    return run_op("serving_token_gather", fn, (x, idx),
-                  differentiable=False)
-
-
 class Request:
     """One generation request (seq_id is assigned by the engine).
 
@@ -661,7 +595,6 @@ class LlamaServingEngine:
         wbytes, _, welems = serving_weight_bytes(model)
         self.weight_bytes_per_param = wbytes / max(welems, 1)
         dt = model.parameters()[0].dtype
-        hk, d = cfg.num_key_value_heads, cfg.head_dim
         # int8 KV pages (ROADMAP item 3b): quantize on write, dequantize
         # inside the ragged kernel's kv loop. Halves (bf16) / quarters
         # (f32) the HBM bytes a cached token costs, so the same pool
@@ -676,22 +609,35 @@ class LlamaServingEngine:
                 f"got {kv_dtype!r}")
         self.kv_quant = kv_dtype == "int8"
         pool_dt = jnp.int8 if self.kv_quant else jnp.dtype(str(dt))
-        # head-major [P, Hk, page, D] — the Pallas kernel's tiling layout
-        shape = (num_pages, hk, page_size, d)
-        self.k_pools = [Tensor(jnp.zeros(shape, pool_dt))
-                        for _ in range(cfg.num_hidden_layers)]
-        self.v_pools = [Tensor(jnp.zeros(shape, pool_dt))
-                        for _ in range(cfg.num_hidden_layers)]
+        # every layer states what it keeps per token: a list of (heads,
+        # width), one entry a pool (all layers of a model alike). A
+        # pool with heads is head-major [P, Hk, page, D] (the K/V
+        # kernels' tiling layout), one without [P, page, W] (a latent
+        # row all heads share). The first pool of each layer is held in
+        # ``k_pools``, the second, where there is one, in ``v_pools``.
+        specs = [layer.serving_cache() for layer in model.model.layers]
+        if any(sp != specs[0] for sp in specs) or len(specs[0]) > 2:
+            raise UnsupportedServingFeature(
+                "layers that keep different caches (or more than two "
+                "pools a layer) in one model")
+
+        def pool_shape(heads, width, last=None):
+            last = width if last is None else last
+            return (num_pages, page_size, last) if heads is None \
+                else (num_pages, heads, page_size, last)
+
+        pools = [[Tensor(jnp.zeros(pool_shape(*sp), pool_dt))
+                  for _ in specs] for sp in specs[0]]
+        self.k_pools = pools[0]
+        self.v_pools = pools[1] if len(pools) > 1 else []
         # per-head per-slot dequant scales ride sidecar arrays indexed
         # by the SAME page ids, so prefix-shared pages carry their
         # scales for free and a COW page copy copies both
-        sshape = (num_pages, hk, page_size, 1)
-        self.k_scales = [Tensor(jnp.zeros(sshape, jnp.float32))
-                         for _ in range(cfg.num_hidden_layers)] \
-            if self.kv_quant else []
-        self.v_scales = [Tensor(jnp.zeros(sshape, jnp.float32))
-                         for _ in range(cfg.num_hidden_layers)] \
-            if self.kv_quant else []
+        scales = [[Tensor(jnp.zeros(pool_shape(*sp, last=1), jnp.float32))
+                   for _ in specs] if self.kv_quant else []
+                  for sp in specs[0]]
+        self.k_scales = scales[0]
+        self.v_scales = scales[1] if len(scales) > 1 else []
         # self-speculative decoding (ROADMAP item 3a): an n-gram /
         # prompt-lookup drafter proposes up to spec_k tokens per live
         # decoder; the scheduler packs each speculating row into the
@@ -760,10 +706,12 @@ class LlamaServingEngine:
         self._spec_idle = 0     # consecutive no-proposal probes
         self._live: dict[int, Request] = {}
         self._m = _serving_metrics()
-        n_layers = cfg.num_hidden_layers
-        tok_bytes = 2 * hk * d * jnp.dtype(pool_dt).itemsize * n_layers
-        if self.kv_quant:
-            tok_bytes += 2 * hk * 4 * n_layers     # f32 scale sidecars
+        # bytes a cached token costs over all layers, as allocated (a
+        # latent row's pad lanes included)
+        tok_bytes = sum(
+            (heads or 1) * (width * jnp.dtype(pool_dt).itemsize
+                            + (4 if self.kv_quant else 0))
+            for sp in specs for heads, width in sp)
         self.kv_bytes_per_token = tok_bytes
         self._m["kv_bytes"].set(tok_bytes)
         self._m["weight_bytes"].set(self.weight_bytes_per_param)
@@ -786,7 +734,23 @@ class LlamaServingEngine:
             if kv_tier else None
         if self.tier is not None and self.prefix is not None:
             self.prefix.demote = self._demote_prefix_page
+        # a layer kind names the engine features its pages do not reach
+        # yet: asked for, each is refused here by name, never served by
+        # a silent fallback
+        asked = {"kv_dtype=int8": self.kv_quant,
+                 "kv_tier": self.tier is not None,
+                 "fused_kv=False": not self.fused_kv,
+                 "fused_rope=False": not self.fused_rope,
+                 "spec_k": bool(self.spec_k),
+                 "weight_dtype=int8": self.weight_quant}
+        for layer in model.model.layers:
+            for what in getattr(layer, "serving_unsupported", ()):
+                if asked.get(what):
+                    raise UnsupportedServingFeature(
+                        f"{what} does not reach the pages of "
+                        f"{type(layer).__name__} yet")
         self._next_id = 0
+        self._layer_stats = None    # the last dispatch's layer counters
         # ONE traced mixed-program function covers every dispatch; its
         # per-signature cache holds the chunk_budget-token shape and the
         # [max_batch]-token decode-only shape. Scanned multi-tick
@@ -1050,24 +1014,6 @@ class LlamaServingEngine:
     # ------------------------------------------------------------------
     # the mixed program: prefill chunks + decode rows, one dispatch
     # ------------------------------------------------------------------
-    def _rope_tables(self, pos):
-        """Per-dispatch rotary sin/cos tables ``[T, D]`` f32, one row
-        per packed token — computed ONCE per dispatch (inside the
-        traced program, from the packed positions) and shared across
-        every layer. Bitwise the values
-        `fused_rotary_position_embedding` derives from
-        ``position_ids``, so swapping the per-layer derivation for
-        this shared table never moves an output bit."""
-        cfg = self.model.config
-        d = cfg.head_dim
-        base = float(cfg.rope_theta)
-
-        def fn(p):
-            return rope_tables(p, d, base)
-
-        return run_op("serving_rope_tables", fn, (pos,),
-                      differentiable=False)
-
     def _mixed_forward(self, tokens, pos, page_ids, offs, row_tok,
                        flat_idx, last_idx, tables, kv_lens, q_starts,
                        q_lens, w_starts, w_flats, w_ends, temps, top_ps,
@@ -1075,11 +1021,12 @@ class LlamaServingEngine:
                        k_pools, v_pools, k_scales, v_scales):
         """ONE token-packed model step: embed [1, T] real tokens (a mix
         of prefill-chunk tokens, speculative verify tokens and decode
-        tokens, back to back with no inter-row padding), scatter every
-        token's post-rope K/V into the page pools (int8-quantized with
-        scale sidecars when ``kv_quant``), run the Pallas
-        ragged-paged-attention kernel over the per-row ``(q_start,
-        q_len, kv_len)`` metadata, and read the greedy next token:
+        tokens, back to back with no inter-row padding), ask every
+        layer for its own step over its own pages
+        (``layer.serving_step``: the layer writes what it keeps of the
+        step's tokens and attends through its pages over the per-row
+        ``(q_start, q_len, kv_len)`` metadata; see
+        :mod:`.layer_step`), and read the greedy next token:
         a speculative engine (``spec_k > 0``) takes the argmax at
         EVERY packed position — position ``t`` of the [T] return is
         the argmax continuation after token ``t``, what verification
@@ -1101,154 +1048,56 @@ class LlamaServingEngine:
         position — so the draw at a position never depends on how it
         was dispatched (step, scan tick, or speculative verify row).
 
-        With ``fused_kv`` (the default) the per-layer scatter + read
-        pair collapses into ONE `fused_ragged_paged_attention` call:
-        the kernel writes each row's K/V into its pages in-grid (the
-        sequence's last row owns the write-back; every reader row
-        replays this dispatch's writes from the packed rows, so later
-        chunks of one prompt attend earlier chunks of the SAME
-        dispatch without an HBM round trip). ``w_starts``/``w_flats``/
-        ``w_ends`` [R] carry the write-span metadata; ``page_ids``/
-        ``offs`` still enter the program for the unfused path (and are
-        inert, never touched, under fusion).
-
-        With ``fused_rope`` on top (the default when ``fused_kv`` is
-        on) the separate rope op disappears too: the kernel takes
-        PRE-rope q (still packed ``[T, H, D]`` — no host-side
-        ``_token_gather`` pack; each row's tokens are contiguous at
-        its write offset, so the kernel slices them via the
-        scalar-prefetched metadata) and pre-rope packed k, plus
-        per-dispatch sin/cos tables computed once and shared across
-        all layers, and applies the rotation in VMEM before the
-        write/attention math — rope + write + attention in one Pallas
-        program, bitwise the fallback chain. ``row_tok`` stays an
-        input for the fallback paths (inert under rope fusion).
+        ``w_starts``/``w_flats``/``w_ends`` [R] carry the write-span
+        metadata of the programs that write pages inside their
+        attention kernel (per row: the first position of its sequence
+        this dispatch writes, that position's packed index, the
+        sequence's final kv_len); ``page_ids``/``offs``/``row_tok``
+        serve the programs that scatter first (inert otherwise).
 
         tokens/pos [1, T]; page_ids/offs/flat_idx [T]; row_tok [R, QB];
         last_idx/kv_lens/q_starts/q_lens/w_starts/w_flats/w_ends/
         temps/top_ps/top_ks/seeds/cmodes [R]; slot_ids/slot_vals
-        [R, B]; tables [R, W]; k/v_scales are empty lists for float
-        pools.
+        [R, B]; tables [R, W]; ``k_pools`` hold each layer's first
+        pool, ``v_pools`` its second (an empty list where a layer
+        keeps one), k/v_scales are empty lists for float pools.
         Returns (next token ids — 1-D [T] when speculative, 1-D [R]
         otherwise — new k_pools, new v_pools, new k_scales,
-        new v_scales)."""
+        new v_scales, and the layers' counters: a list, empty or of
+        one int32 array ``[layers that count, 2, 1]``)."""
         from ..tensor import search
 
         m = self.model.model
-        cfg = self.model.config
         t = tokens.shape[1]
         r_rows, qb = row_tok.shape[0], row_tok.shape[1]
         x = m.embed_tokens(tokens)                       # [1, T, H]
-        # per-dispatch rotary sin/cos tables [T, D], computed ONCE and
-        # shared by every layer: the rope-fused kernel consumes them
-        # directly (no transcendentals in-kernel — Mosaic and XLA then
-        # agree bit for bit), and the fallback paths feed them to
-        # fused_rotary_position_embedding via sin=/cos= instead of
-        # re-deriving the trig tables from the positions in every
-        # layer (2 x n_layers redundant elementwise chains per trace)
-        rsin, rcos = self._rope_tables(pos)
-        new_k, new_v, new_ks, new_vs = [], [], [], []
+        # the step's metadata, and the tables its layers share (rotary
+        # sin/cos are made once a dispatch, not once a layer)
+        step = ServingStep(self, pos, page_ids, offs, row_tok, flat_idx,
+                           tables, kv_lens, q_starts, q_lens, w_starts,
+                           w_flats, w_ends)
+        # every layer runs its own step over its own pages; the pools
+        # it stated come first, then their scale sidecars
+        held = [p for p in (k_pools, v_pools, k_scales, v_scales) if p]
+        new_pools = [[] for _ in held]
+        stats = []
         for li, layer in enumerate(m.layers):
-            h = layer.input_layernorm(x)
-            att = layer.self_attn
-            q = att.q_proj(h).reshape([1, t, att.num_heads, att.head_dim])
-            k = att.k_proj(h).reshape([1, t, att.num_kv_heads,
-                                       att.head_dim])
-            v = att.v_proj(h).reshape([1, t, att.num_kv_heads,
-                                       att.head_dim])
-            if not self.fused_rope:
-                # fallback paths apply rope as a separate elementwise
-                # op, from the shared per-dispatch tables
-                q, k, v = FI.fused_rotary_position_embedding(
-                    q, k, v, sin=rsin, cos=rcos)
-            k2 = k.reshape([t, att.num_kv_heads, att.head_dim])
-            v2 = v.reshape([t, att.num_kv_heads, att.head_dim])
-            if self.fused_rope:
-                # rope + page write + attention in ONE kernel: q stays
-                # PRE-rope in the packed token layout — the kernel
-                # slices each row's contiguous tokens through the
-                # scalar-prefetched write metadata, so the host-side
-                # _token_gather q pack is gone along with the
-                # per-layer rope round trip for q AND k
-                q3 = q.reshape([t, att.num_heads, att.head_dim])
-                if self.kv_quant:
-                    attn4, kp, vp, ksc, vsc = \
-                        fused_ragged_paged_attention(
-                            q3, k2, v2, k_pools[li], v_pools[li],
-                            tables, kv_lens, q_starts, q_lens,
-                            w_starts, w_flats, w_ends, self.trash_page,
-                            k_scale=k_scales[li],
-                            v_scale=v_scales[li], rope_sin=rsin,
-                            rope_cos=rcos, qblock=qb)
-                    new_ks.append(ksc)
-                    new_vs.append(vsc)
-                else:
-                    attn4, kp, vp = fused_ragged_paged_attention(
-                        q3, k2, v2, k_pools[li], v_pools[li], tables,
-                        kv_lens, q_starts, q_lens, w_starts, w_flats,
-                        w_ends, self.trash_page, rope_sin=rsin,
-                        rope_cos=rcos, qblock=qb)
-                new_k.append(kp)
-                new_v.append(vp)
-                attn = _token_gather(
-                    attn4.reshape([r_rows * qb, att.num_heads,
-                                   att.head_dim]), flat_idx)
-                x = x + att.o_proj(attn.reshape([1, t, -1]))
-                x = x + layer.mlp(layer.post_attention_layernorm(x))
-                continue
-            # pack the flat token axis into the kernel's [R, QB] row
-            # blocks
-            q4 = _token_gather(
-                q.reshape([t, att.num_heads, att.head_dim]), row_tok)
-            if self.fused_kv:
-                # ONE kernel writes this dispatch's K/V into the pages
-                # AND attends through them (in-grid replay keeps later
-                # chunks of one prompt coherent with earlier rows of
-                # the same dispatch) — no separate scatter, no HBM
-                # round trip between producer and consumer
-                if self.kv_quant:
-                    attn4, kp, vp, ksc, vsc = \
-                        fused_ragged_paged_attention(
-                            q4, k2, v2, k_pools[li], v_pools[li],
-                            tables, kv_lens, q_starts, q_lens,
-                            w_starts, w_flats, w_ends, self.trash_page,
-                            k_scale=k_scales[li], v_scale=v_scales[li])
-                    new_ks.append(ksc)
-                    new_vs.append(vsc)
-                else:
-                    attn4, kp, vp = fused_ragged_paged_attention(
-                        q4, k2, v2, k_pools[li], v_pools[li], tables,
-                        kv_lens, q_starts, q_lens, w_starts, w_flats,
-                        w_ends, self.trash_page)
-                new_k.append(kp)
-                new_v.append(vp)
-            else:
-                # unfused reference path (PADDLE_TPU_FUSED_KV=0):
-                # scatter every row's K/V first, then attend — a later
-                # chunk of the same sequence attends what the scatter
-                # just wrote
-                if self.kv_quant:
-                    kp, ksc = _page_write_q8(k_pools[li], k_scales[li],
-                                             k2, page_ids, offs)
-                    vp, vsc = _page_write_q8(v_pools[li], v_scales[li],
-                                             v2, page_ids, offs)
-                    new_ks.append(ksc)
-                    new_vs.append(vsc)
-                else:
-                    kp = _page_write(k_pools[li], k2, page_ids, offs)
-                    vp = _page_write(v_pools[li], v2, page_ids, offs)
-                    ksc = vsc = None
-                new_k.append(kp)
-                new_v.append(vp)
-                attn4 = ragged_paged_attention(q4, kp, vp, tables,
-                                               kv_lens, q_starts,
-                                               q_lens, k_scale=ksc,
-                                               v_scale=vsc)
-            attn = _token_gather(
-                attn4.reshape([r_rows * qb, att.num_heads,
-                               att.head_dim]), flat_idx)
-            x = x + att.o_proj(attn.reshape([1, t, -1]))
-            x = x + layer.mlp(layer.post_attention_layernorm(x))
+            x, pages, st = layer.serving_step(x, step,
+                                              [p[li] for p in held])
+            for out, page in zip(new_pools, pages):
+                out.append(page)
+            if st is not None:
+                stats.append(st)
+        it = iter(new_pools)
+        new_k, new_v, new_ks, new_vs = (
+            next(it) if p else [] for p in (k_pools, v_pools, k_scales,
+                                            v_scales))
+        if len(stats) > 1:
+            # one array, so the host reads the layers' counters in one
+            # copy beside the tokens
+            stats = [run_op("serving_layer_stats",
+                            lambda *a: jnp.concatenate(a), tuple(stats),
+                            differentiable=False)]
         x = m.norm(x)
         # returned 1-D ([T] or [R]): a 2-D [1, T] int64 output would
         # exactly match the donated ``tokens`` input's aval and XLA
@@ -1303,7 +1152,7 @@ class LlamaServingEngine:
             else:
                 nxt = search.argmax(logits, axis=-1).astype("int64") \
                     .reshape([r_rows])
-        return nxt, new_k, new_v, new_ks, new_vs
+        return nxt, new_k, new_v, new_ks, new_vs, stats
 
     def _ensure_mixed_compiled(self):
         if self._mixed_static is None:
@@ -1379,16 +1228,10 @@ class LlamaServingEngine:
         pools copy the scale sidecars WITH the page: a copied page that
         kept stale scales would dequantize to garbage for its new
         owner."""
-        for li in range(len(self.k_pools)):
-            kd = self.k_pools[li]._data
-            vd = self.v_pools[li]._data
-            self.k_pools[li] = Tensor(kd.at[new].set(kd[old]))
-            self.v_pools[li] = Tensor(vd.at[new].set(vd[old]))
-            if self.kv_quant:
-                ks = self.k_scales[li]._data
-                vs = self.v_scales[li]._data
-                self.k_scales[li] = Tensor(ks.at[new].set(ks[old]))
-                self.v_scales[li] = Tensor(vs.at[new].set(vs[old]))
+        for pools in (self.k_pools, self.v_pools, self.k_scales,
+                      self.v_scales):
+            for li, pool in enumerate(pools):
+                pools[li] = Tensor(pool._data.at[new].set(pool._data[old]))
 
     # ------------------------------------------------------------------
     # chunked-prefill scheduler: rows -> one mixed dispatch
@@ -1696,7 +1539,7 @@ class LlamaServingEngine:
         try:
             with no_grad(), _span("serving.mixed_step", rows=len(rows),
                                   tokens=int(t), prefill=needs_mixed):
-                nxt, new_k, new_v, new_ks, new_vs = sf(
+                nxt, new_k, new_v, new_ks, new_vs, stats = sf(
                     Tensor(jnp.asarray(tokens)),
                     Tensor(jnp.asarray(pos)),
                     Tensor(jnp.asarray(page_ids)),
@@ -1731,6 +1574,7 @@ class LlamaServingEngine:
         self.k_pools, self.v_pools = list(new_k), list(new_v)
         if self.kv_quant:
             self.k_scales, self.v_scales = list(new_ks), list(new_vs)
+        self._layer_stats = stats[0] if stats else None
         return nxt, flat_start, dur, cold, needs_mixed, t_cap
 
     def _apply_rows(self, rows, out, flat_start, dur, cold, needs_mixed):
@@ -1970,7 +1814,7 @@ class LlamaServingEngine:
         sf = self._ensure_mixed_compiled()
         samp = self._sample_arrays([], r_cap)
         with no_grad():
-            _, wk, wv, wks, wvs = sf(
+            _, wk, wv, wks, wvs, _ = sf(
                 Tensor(jnp.asarray(np.zeros((1, t_cap), np.int64))),
                 Tensor(jnp.asarray(np.zeros((1, t_cap), np.int32))),
                 Tensor(jnp.asarray(np.full((t_cap,), self.trash_page,
@@ -2021,12 +1865,11 @@ class LlamaServingEngine:
     def _adopt_scan_pools(self, out):
         """Reassign the donated pool (and scale-sidecar) arrays a scan
         dispatch returned after its token block."""
-        nl = len(self.k_pools)
-        self.k_pools = list(out[1:1 + nl])
-        self.v_pools = list(out[1 + nl:1 + 2 * nl])
-        if self.kv_quant:
-            self.k_scales = list(out[1 + 2 * nl:1 + 3 * nl])
-            self.v_scales = list(out[1 + 3 * nl:1 + 4 * nl])
+        at = 1
+        for name in ("k_pools", "v_pools", "k_scales", "v_scales"):
+            n = len(getattr(self, name))
+            setattr(self, name, list(out[at:at + n]))
+            at += n
 
     def prewarm(self, mixed=None, scans=None):
         """Compile this engine's serving programs BEFORE traffic
@@ -2757,6 +2600,9 @@ class LlamaServingEngine:
                     self._dispatch_rows(rows, cow)
             with _span("serving.wait", step=step):
                 out = np.asarray(nxt._data).reshape(-1)      # [t_cap]
+                # the expert layers' counters came with the tokens
+                layer_stats = None if self._layer_stats is None \
+                    else np.asarray(self._layer_stats._data)
             with _span("serving.apply", step=step) as applied:
                 emitted = self._apply_rows(rows, out, flat_start, dur,
                                            cold, needs_mixed)
@@ -2776,6 +2622,14 @@ class LlamaServingEngine:
                      kv_pages=self._kv_pages(row[2] + row[3]
                                              for row in rows),
                      table_slots=r_cap * self.width)
+            if layer_stats is not None:
+                # per expert layer [experts that got a row, rows of the
+                # largest group]: the medians over the layers; and the
+                # latent rows the dispatch's contexts hold
+                med = np.median(layer_stats.reshape(-1, 2), axis=0)
+                disp.set(experts_touched=float(med[0]),
+                         expert_rows_max=float(med[1]),
+                         latent_rows=sum(row[2] + row[3] for row in rows))
             self._count_dispatch(kind, prefill, tokens - prefill,
                                 t_cap - tokens)
             return len(rows), emitted
@@ -2870,7 +2724,7 @@ class LlamaServingEngine:
                 pids = tab[rows, jnp.clip(start // page, 0,
                                           tab.shape[1] - 1)]
                 offs = (start % page).astype(jnp.int32)
-                nxt, nk, nv, nks, nvs = self._mixed_forward(
+                nxt, nk, nv, nks, nvs, _ = self._mixed_forward(
                     Tensor(tok.reshape(1, b)),
                     Tensor(start.reshape(1, b)),
                     Tensor(pids), Tensor(offs), Tensor(row_tok),

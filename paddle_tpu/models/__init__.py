@@ -10,6 +10,10 @@ from .llama import (  # noqa: F401
     LlamaConfig, LlamaMLP, LlamaMoEMLP, LlamaAttention, LlamaDecoderLayer, LlamaModel,
     LlamaForCausalLM, shard_llama, llama3_8b_config, tiny_llama_config,
 )
+from .mla_moe import (  # noqa: F401
+    MlaMoeConfig, MlaAttention, MlaMoeMLP, MlaMoeDecoderLayer, MlaMoeModel,
+    MlaMoeForCausalLM, tiny_mla_moe_config,
+)
 from .llama_pipe import LlamaForCausalLMPipe  # noqa: F401
 from .bert import (  # noqa: F401
     BertConfig, BertModel, BertForSequenceClassification,
@@ -21,6 +25,8 @@ __all__ = [
     "LlamaConfig", "LlamaMLP", "LlamaMoEMLP", "LlamaAttention", "LlamaDecoderLayer",
     "LlamaModel", "LlamaForCausalLM", "shard_llama", "llama3_8b_config",
     "tiny_llama_config", "LlamaForCausalLMPipe",
+    "MlaMoeConfig", "MlaAttention", "MlaMoeMLP", "MlaMoeDecoderLayer",
+    "MlaMoeModel", "MlaMoeForCausalLM", "tiny_mla_moe_config",
     "BertConfig", "BertModel", "BertForSequenceClassification",
     "BertForTokenClassification", "ErnieModel",
     "ErnieForSequenceClassification", "ernie_base_config",

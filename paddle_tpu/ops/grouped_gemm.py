@@ -59,7 +59,10 @@ except ImportError:  # pragma: no cover
 from ..framework.tensor import run_op
 
 __all__ = ["grouped_gemm", "grouped_gemm_xla", "supported",
-           "grouped_gemm_q8", "grouped_gemm_q8_xla", "supported_q8"]
+           "grouped_gemm_q8", "grouped_gemm_q8_xla", "supported_q8",
+           "grouped_gemm_packed", "grouped_gemm_packed_xla",
+           "supported_packed", "pack_by_expert", "packed_block_m",
+           "packed_rows"]
 
 #: VMEM budget for one grid step's blocks (x tile + w tile + out tile),
 #: kept well under the ~16 MB/core ceiling (see pallas_guide.md)
@@ -480,3 +483,204 @@ def grouped_gemm_q8_xla(x, w_q, scales, group_sizes, block):
 
     return run_op("grouped_gemm_q8_xla", fn,
                   (x, w_q, scales, group_sizes), differentiable=False)
+
+
+# ---------------------------------------------------------------------------
+# packed variant: rows sorted by expert and PACKED, each expert's group
+# rounded up to whole row tiles, instead of a stride of C rows an expert.
+# With many small experts (256 experts, 8 a token) the strided buffer is
+# E*n rows of which n*k are real; the packed one is n*k rows plus at most
+# one tile of padding for each expert that got a row. A row tile belongs
+# to ONE expert (`tile_expert`, scalar-prefetched), so the grid is
+# (n tiles, row tiles): consecutive tiles of one expert keep its weight
+# block resident, an expert with no row has no tile and its weights are
+# never read, and tiles past `num_tiles` are skipped without a copy.
+# ---------------------------------------------------------------------------
+def packed_block_m(n_rows, num_experts, sublane=16):
+    """Row tile of the packed layout for ``n_rows`` assignments over
+    ``num_experts``: the power of two next above the mean group, between
+    one packed sublane tile and 128."""
+    mean = max(1, -(-int(n_rows) // int(num_experts)))
+    bm = 1 << (mean - 1).bit_length()
+    return int(min(128, max(sublane, bm)))
+
+
+def packed_rows(n_rows, num_experts, block_m):
+    """Static row count of the packed buffer: every assignment, plus
+    less than a tile of padding for each expert that can hold one."""
+    worst = n_rows + min(num_experts, n_rows) * (block_m - 1)
+    return -(-worst // block_m) * block_m
+
+
+def pack_by_expert(expert_ids, num_experts, block_m):
+    """Lay ``expert_ids [n, k]`` (token ``i``'s ``k`` experts) out packed
+    by expert (traceable). Returns a dict: ``row_token [M]`` the token
+    each packed row holds (``n`` = none: a zero row), ``dest [n, k]``
+    the packed row of each assignment, ``tile_expert [M / block_m]``,
+    ``num_tiles [1]`` the tiles in use, ``counts [E]`` rows an expert."""
+    n, k = expert_ids.shape
+    nk, e = n * k, int(num_experts)
+    m = packed_rows(nk, e, block_m)
+    # an id of ``num_experts`` drops the assignment: it gets no row, is
+    # not counted, and its ``dest`` is row 0 (give it weight 0)
+    flat = jnp.clip(expert_ids.reshape(-1).astype(jnp.int32), 0, e)
+    counts_all = jnp.zeros((e + 1,), jnp.int32).at[flat].add(1)
+    counts = counts_all[:e]
+    padded = -(-counts // block_m) * block_m
+    ends = jnp.cumsum(padded)
+    pstart = jnp.append(ends - padded, 0)
+    start = jnp.cumsum(counts_all) - counts_all
+    order = jnp.argsort(flat, stable=True)
+    se = flat[order]
+    dest_sorted = jnp.where(
+        se < e,
+        pstart[se] + jnp.arange(nk, dtype=jnp.int32) - start[se], m)
+    dest = jnp.zeros((nk,), jnp.int32).at[order].set(dest_sorted)
+    row_token = jnp.full((m,), n, jnp.int32).at[dest].set(
+        jnp.arange(nk, dtype=jnp.int32) // k, mode="drop")
+    dest = jnp.where(dest < m, dest, 0)
+    num_tiles = (ends[-1] // block_m).astype(jnp.int32)
+    tiles = jnp.arange(m // block_m, dtype=jnp.int32)
+    # a tile past the last one in use names that one's expert, so the
+    # weight block's index does not move and nothing is fetched for it
+    live = jnp.minimum(tiles, jnp.maximum(num_tiles - 1, 0))
+    tile_expert = jnp.clip(jnp.searchsorted(
+        ends, live * block_m, side="right"), 0, e - 1).astype(jnp.int32)
+    return {"row_token": row_token, "dest": dest.reshape(n, k),
+            "tile_expert": tile_expert,
+            "num_tiles": num_tiles.reshape(1), "counts": counts}
+
+
+def _packed_block_n(k, n, itemsize):
+    """The whole N where one weight block (double-buffered) fits the
+    budget, else the largest multiple of 128 dividing N that does."""
+    if 2 * k * n * itemsize <= _VMEM_BUDGET or n % 128:
+        return n
+    bn = n
+    while bn > 128 and (2 * k * bn * itemsize > _VMEM_BUDGET or n % bn):
+        bn -= 128
+    return bn
+
+
+def supported_packed(x, w, block_m):
+    """Pallas preconditions of the packed kernel: a TPU backend (the
+    CPU takes the XLA formulation, as the strided kernel does), M whole
+    row tiles, K and N whole lane tiles."""
+    if not _HAS_PLTPU or _interpret():
+        return False
+    (m, k), (e, kw, n) = _shape_of(x), _shape_of(w)
+    sub = 32 // jnp.dtype(getattr(x, "_data", x).dtype).itemsize
+    return kw == k and e > 0 and m % block_m == 0 and block_m % sub == 0 \
+        and k % 128 == 0 and n % 128 == 0
+
+
+def _gg_packed_kernel(te_ref, nt_ref, x_ref, w_ref, o_ref):
+    mi = pl.program_id(1)
+
+    @pl.when(mi < nt_ref[0])
+    def _compute():
+        # operands as stored (bf16 on the chip), f32 accumulation; the
+        # padding rows of a tile are zero rows of x
+        o_ref[...] = jax.lax.dot_general(
+            x_ref[...], w_ref[0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32).astype(o_ref.dtype)
+
+    @pl.when(mi >= nt_ref[0])
+    def _skip():
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+
+@functools.lru_cache(maxsize=64)
+def _make_packed(m, k, n, e, block_m, block_n, dtype, interpret):
+    mt, nt = m // block_m, n // block_n
+
+    def live(mi, nt_ref):
+        return jnp.minimum(mi, jnp.maximum(nt_ref[0] - 1, 0))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(nt, mt),
+        in_specs=[
+            pl.BlockSpec((block_m, k),
+                         lambda ni, mi, te, nt_: (live(mi, nt_), 0)),
+            pl.BlockSpec((1, k, block_n),
+                         lambda ni, mi, te, nt_: (te[mi], 0, ni)),
+        ],
+        out_specs=pl.BlockSpec((block_m, block_n),
+                               lambda ni, mi, te, nt_: (mi, ni)),
+    )
+    item = jnp.dtype(dtype).itemsize
+    vmem = 2 * (block_m * k + k * block_n + block_m * block_n) * item \
+        + block_m * block_n * 4
+
+    def call(x, w, tile_expert, num_tiles):
+        return pl.pallas_call(
+            _gg_packed_kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((m, n), dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary"),
+                vmem_limit_bytes=min(100 << 20,
+                                     max(32 << 20, vmem + (8 << 20)))),
+            interpret=interpret,
+            name="paddle_tpu.grouped_gemm_packed",
+        )(tile_expert, num_tiles, x, w)
+
+    return call
+
+
+def _packed_kernel_impl(x, w, tile_expert, num_tiles, block_m):
+    m, k = x.shape
+    e, _, n = w.shape
+    bn = _packed_block_n(k, n, jnp.dtype(x.dtype).itemsize)
+    call = _make_packed(m, k, n, e, int(block_m), bn, jnp.dtype(x.dtype),
+                        _interpret())
+    return call(x, w.astype(x.dtype), tile_expert.astype(jnp.int32),
+                num_tiles.astype(jnp.int32))
+
+
+def _packed_xla_impl(x, w, tile_expert, num_tiles, block_m):
+    """The same mathematics in XLA: the padded group sizes, recovered
+    from the tiles' experts, drive ``lax.ragged_dot`` (rows past the
+    tiles in use come out zero)."""
+    m, _ = x.shape
+    e = w.shape[0]
+    tiles = jnp.arange(m // block_m, dtype=jnp.int32)
+    used = (tiles < num_tiles[0]).astype(jnp.int32)
+    padded = jnp.zeros((e,), jnp.int32).at[tile_expert].add(
+        used * block_m)
+    rows = jnp.arange(m, dtype=jnp.int32)[:, None]
+    x = jnp.where(rows < num_tiles[0] * block_m, x, jnp.zeros_like(x))
+    y = jax.lax.ragged_dot(x, w.astype(x.dtype), padded,
+                           preferred_element_type=jnp.float32)
+    y = jnp.where(rows < num_tiles[0] * block_m, y, 0.0)
+    return y.astype(x.dtype)
+
+
+def _grouped_packed(x, w, tile_expert, num_tiles, block_m,
+                    use_kernel=None):
+    """Raw-array packed grouped GEMM (inference only): ``y[r] = x[r] @
+    w[tile_expert[r // block_m]]`` for rows of the tiles in use, zeros
+    past them. The Pallas program where `supported_packed`, else XLA."""
+    if use_kernel is None:
+        use_kernel = supported_packed(x, w, block_m)
+    impl = _packed_kernel_impl if use_kernel else _packed_xla_impl
+    return impl(x, w, tile_expert, num_tiles, int(block_m))
+
+
+def grouped_gemm_packed(x, w, tile_expert, num_tiles, block_m):
+    """Tensor-level packed grouped GEMM (see `pack_by_expert`)."""
+    def fn(x, w, te, nt):
+        return _grouped_packed(x, w, te, nt, block_m)
+
+    return run_op("grouped_gemm_packed", fn,
+                  (x, w, tile_expert, num_tiles), differentiable=False)
+
+
+def grouped_gemm_packed_xla(x, w, tile_expert, num_tiles, block_m):
+    """XLA formulation of the packed grouped GEMM (the parity bar)."""
+    def fn(x, w, te, nt):
+        return _grouped_packed(x, w, te, nt, block_m, use_kernel=False)
+
+    return run_op("grouped_gemm_packed_xla", fn,
+                  (x, w, tile_expert, num_tiles), differentiable=False)

@@ -26,6 +26,7 @@ from jax.sharding import SingleDeviceSharding
 from paddle_tpu.ops import flash_attention as fa
 from paddle_tpu.ops import fused_linear_cross_entropy as flce
 from paddle_tpu.ops import grouped_gemm as gg
+from paddle_tpu.ops import ragged_mla_attention as mla
 from paddle_tpu.ops import ragged_paged_attention as rpa
 from paddle_tpu.quant import kernels as qk
 
@@ -69,7 +70,7 @@ def chip_compile(one_chip, no_persistent_cache, monkeypatch):
     """``compile(fn, *specs) -> compiled``: ``fn`` jitted and compiled
     for one described v5e with every kernel module off interpret mode.
     ``specs`` are ``(shape, dtype)`` pairs."""
-    for mod in (rpa, fa, flce, gg, qk):
+    for mod in (rpa, fa, flce, gg, qk, mla):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
     def compile_(fn, *specs):
@@ -277,3 +278,35 @@ def test_grouped_gemm_compiles_bf16(chip_compile):
     _assert_kernel(chip_compile(
         gg._grouped_impl, ((e * c, k), BF16), ((e, k, n), BF16),
         ((e,), I32)), "grouped_gemm")
+
+
+@pytest.mark.parametrize("m,k,n,block_m", [
+    (16128, 2048, 768, 32),     # a 1,024-token dispatch: gate and up
+    (16128, 768, 2048, 32),     # ... and down
+    (4224, 2048, 768, 16),      # the 48-token decode-only dispatch
+])
+def test_packed_grouped_gemm_compiles_at_published_widths(chip_compile, m,
+                                                          k, n, block_m):
+    """256 experts of width 768 over hidden 2048: the packed rows of the
+    latent-attention expert family's two step shapes."""
+    assert gg.packed_rows(1024 * 8, 256, 32) == 16128
+    assert gg.packed_rows(48 * 8, 256, 16) == 4224
+    _assert_kernel(chip_compile(
+        functools.partial(gg._packed_kernel_impl, block_m=block_m),
+        ((m, k), BF16), ((256, k, n), BF16), ((m // block_m,), I32),
+        ((1,), I32)), "grouped_gemm_packed")
+
+
+@pytest.mark.parametrize("tokens,rows,qblock", [(1024, 80, 32), (48, 48, 1)])
+def test_latent_attention_compiles_at_published_widths(chip_compile,
+                                                       tokens, rows,
+                                                       qblock):
+    """32 heads over a 640-lane latent row (512 + 64 in use), pages of
+    16, a table 1,057 pages wide: the docqa cell's two step shapes."""
+    width = mla.latent_row_width(512, 64)
+    _assert_kernel(chip_compile(
+        functools.partial(mla._kernel_impl, v_width=512,
+                          scale=192 ** -0.5, qblock=qblock),
+        ((tokens, 32, width), BF16), ((tokens, width), BF16),
+        ((24577, 16, width), BF16), ((rows, 1057), I32),
+        *[((rows,), I32)] * 6), "ragged_mla_attn")

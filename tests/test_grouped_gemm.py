@@ -231,3 +231,78 @@ class TestGroupedMoEDispatch:
         out = moe(paddle.to_tensor(
             np.random.RandomState(3).randn(32, 8).astype(np.float32)))
         assert tuple(out.shape) == (32, 8)      # still functional
+
+
+# ---------------------------------------------------------------------------
+# packed rows (rows sorted by expert, groups rounded to whole row tiles)
+# ---------------------------------------------------------------------------
+from paddle_tpu.ops import grouped_gemm as gg  # noqa: E402
+
+def _packed_case(n, k, e, kdim, ndim, bm, empty=(), seed=0):
+    rng = np.random.default_rng(seed)
+    live = [x for x in range(e) if x not in empty]
+    ids = np.stack([rng.permutation(live)[:k] for _ in range(n)]) \
+        .astype(np.int32)
+    x = rng.normal(size=(n, kdim)).astype(np.float32)
+    w = rng.normal(size=(e, kdim, ndim)).astype(np.float32)
+    pk = gg.pack_by_expert(jnp.asarray(ids), e, bm)
+    xp = jnp.concatenate([jnp.asarray(x), jnp.zeros((1, kdim))])[
+        pk["row_token"]]
+    return ids, x, w, pk, xp
+
+
+@pytest.mark.parametrize("n,k,e,bm,empty", [
+    (37, 3, 8, 8, (5,)),            # an empty expert, ragged tails
+    (5, 2, 16, 8, tuple(range(6, 16))),     # most experts empty
+    (64, 2, 4, 16, ()),             # groups of several tiles
+    (1, 1, 4, 8, (0, 1, 2)),        # one row in all
+])
+@pytest.mark.parametrize("impl", ["kernel", "xla"])
+def test_packed_equals_the_strided_reference(n, k, e, bm, empty, impl):
+    """The packed grouped GEMM (Pallas, interpreted here, and its XLA
+    formulation) against ``grouped_gemm_xla`` over the same assignments
+    laid out strided: the same rows of y, exactly the same mathematics
+    (f32 operands here, so 1e-4 covers the order of the sums)."""
+    ids, x, w, pk, xp = _packed_case(n, k, e, 128, 256, bm, empty)
+    fn = gg._packed_kernel_impl if impl == "kernel" else gg._packed_xla_impl
+    y = np.asarray(fn(xp, jnp.asarray(w), pk["tile_expert"],
+                      pk["num_tiles"], bm))
+    got = y[np.asarray(pk["dest"])]                      # [n, k, N]
+    # the strided layout: stride n rows an expert
+    counts = np.zeros(e, np.int32)
+    strided = np.zeros((e * n, 128), np.float32)
+    where = np.zeros((n, k), np.int32)
+    for t in range(n):
+        for j in range(k):
+            ex = ids[t, j]
+            where[t, j] = ex * n + counts[ex]
+            strided[where[t, j]] = x[t]
+            counts[ex] += 1
+    want = np.asarray(gg.grouped_gemm_xla(
+        jnp.asarray(strided), jnp.asarray(w), jnp.asarray(counts))._data)
+    assert np.abs(got - want[where]).max() < 1e-4
+    assert list(np.asarray(pk["counts"])) == list(counts)
+    assert all(counts[x] == 0 for x in empty)
+    # rows past the tiles in use are zero, and a tile names one expert
+    used = int(pk["num_tiles"][0]) * bm
+    assert not y[used:].any()
+    assert used == int(sum(-(-c // bm) * bm for c in counts))
+
+
+def test_packed_rows_are_far_fewer_than_strided_rows():
+    # 1,024 tokens, 8 of 256 experts each: 16,128 packed rows against
+    # 262,144 strided ones
+    bm = gg.packed_block_m(1024 * 8, 256)
+    assert bm == 32
+    assert gg.packed_rows(1024 * 8, 256, bm) == 16128
+    assert gg.packed_block_m(48 * 8, 256) == 16
+    assert gg.packed_rows(48 * 8, 256, 16) == 4224
+
+
+def test_a_dropped_assignment_gets_no_row():
+    ids = jnp.asarray([[0, 2], [4, 4], [1, 3]], jnp.int32)   # 4 = none
+    pk = gg.pack_by_expert(ids, 4, 8)
+    assert list(np.asarray(pk["counts"])) == [1, 1, 1, 1]
+    assert int(pk["num_tiles"][0]) == 4
+    assert sorted(np.asarray(pk["row_token"])[
+        np.asarray(pk["row_token"]) < 3]) == [0, 0, 2, 2]

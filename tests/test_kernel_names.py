@@ -14,6 +14,7 @@ from paddle_tpu.ops import flash_attention as fa
 from paddle_tpu.ops import fused_linear_cross_entropy as flce
 from paddle_tpu.ops import grouped_gemm as gg
 from paddle_tpu.ops import paged_attention as pa
+from paddle_tpu.ops import ragged_mla_attention as mla
 from paddle_tpu.ops import ragged_paged_attention as rpa
 from paddle_tpu.quant import kernels as qk
 
@@ -84,6 +85,18 @@ SITES = {
             functools.partial(gg._q8_impl, block=128),
             [((4 * 8, 128), BF16), ((4, 128, 128), I8), ((4, 1, 128), F32),
              ((4,), I32)])),
+    "grouped_gemm.py packed": (
+        "paddle_tpu.grouped_gemm_packed", lambda: (
+            functools.partial(gg._packed_kernel_impl, block_m=16),
+            [((6 * 16, 128), BF16), ((4, 128, 128), BF16), ((6,), I32),
+             ((1,), I32)])),
+    "ragged_mla_attention.py": (
+        "paddle_tpu.ragged_mla_attn", lambda: (
+            functools.partial(mla._kernel_impl, v_width=128, scale=0.1,
+                              qblock=QB),
+            [((T, H, 256), BF16), ((T, 256), BF16),
+             ((PAGES, PAGE, 256), BF16), ((R, WIDTH), I32)]
+            + [((R,), I32)] * 6)),
     "paged_attention.py": (
         "paddle_tpu.paged_attn_decode", lambda: (
             functools.partial(pa._paged_impl, scale=D ** -0.5),
@@ -125,7 +138,7 @@ def test_pallas_call_site_carries_its_name(site):
 def test_names_are_distinct_and_cover_every_site():
     import pathlib
     names = [n for n, _ in SITES.values()]
-    assert len(set(names)) == len(names) == 14
+    assert len(set(names)) == len(names) == 16
     root = pathlib.Path(fa.__file__).parent.parent
     calls = named = 0
     for path in root.rglob("*.py"):
@@ -133,4 +146,4 @@ def test_names_are_distinct_and_cover_every_site():
         calls += len(re.findall(r"\bpl\.pallas_call\(", text))
         named += len(re.findall(r"\bname=(?:\"paddle_tpu\.|KERNEL_NAME)",
                                 text))
-    assert calls == named == 14
+    assert calls == named == 16

@@ -280,3 +280,106 @@ def test_metrics_off_records_nothing_and_serves_the_same(model,
     got = _engine(model).generate([[1, 2, 3, 4]], max_new_tokens=4)
     assert [list(o) for o in got] == [list(o) for o in want]
     assert otrace.get_events() == []
+
+
+# ---------------------------------------------------------------------------
+# expert layers' counters on the dispatch span (latent-attention experts)
+# ---------------------------------------------------------------------------
+def _reference_routing(fam, cfg, w, ids):
+    """Experts the family's plain reference routes each position of one
+    sequence to, per expert layer: ``[layers, T, k]``."""
+    import jax.numpy as jnp
+    d, eps = fam.dims(cfg), float(cfg["rms_norm_eps"])
+    x = jnp.take(w["ends"]["embed"], jnp.asarray(ids), axis=0) \
+        .astype(jnp.float32)
+    valid = jnp.ones((len(ids),), bool)
+    chosen = []
+    for i in range(cfg["num_hidden_layers"]):
+        lw = w["layers"][i]
+        q, k, v = fam.attn_operands(x, lw, tuple(sorted(d.items())), eps,
+                                    float(cfg["rope_theta"]), None)
+        x, y = fam.attn_out(x, fam.attn_block(q, k, v, 0), lw, eps, None)
+        if fam.is_dense(cfg, i):
+            x = x + fam.swiglu(y, lw["gate"], lw["up"], lw["down"], None)
+            continue
+        idx, _ = fam.route(y, lw["router"], lw["router_bias"], valid,
+                           d["k"], float(cfg["routed_scaling_factor"]),
+                           bool(cfg["norm_topk_prob"]))
+        chosen.append(np.asarray(idx))
+        x = x + fam.moe_ffn(y, lw, cfg, valid, None)
+    return np.stack(chosen)
+
+
+def test_dispatch_carries_the_expert_layers_counters():
+    """``experts_touched`` and ``expert_rows_max`` (medians over the
+    expert layers of what the step program counted) equal what the
+    family's plain reference routes the dispatch's own tokens to, and
+    ``latent_rows`` the rows the allocator holds for its contexts."""
+    import os
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    for p in (root, os.path.join(root, "perfbench")):
+        if p not in sys.path:
+            sys.path.insert(0, p)
+    import run as bench
+    from harness import family, program
+
+    cfg = bench.load_json("perfbench", "configs", "joyai-llm-flash-l5.json")
+    bench.deep_update(cfg, cfg["rehearse"])
+    fam = family.load(cfg, "joyai-llm-flash-l5")
+    mla = fam.build_model(cfg, "float32")
+    mla.eval()
+    w, _ = program.assign_weights(fam, mla, cfg, 11, "float32")
+    om.default_registry().clear()
+    engine = LlamaServingEngine(mla, max_batch=4, page_size=8, num_pages=65,
+                                max_pages_per_seq=16, chunk_budget=16,
+                                chunk_block=8)
+    seen = {}
+    rows_of = engine._dispatch_rows
+
+    def spy(rows, cow):
+        seen[engine._dispatch_count - 1] = [
+            (r, start, n, len(engine.alloc._tables[sid]))
+            for r, sid, start, n, _, _ in rows]
+        return rows_of(rows, cow)
+
+    engine._dispatch_rows = spy
+    rng = np.random.default_rng(12)
+    reqs = [Request(list(rng.integers(1, cfg["vocab_size"], n)),
+                    max_new_tokens=5) for n in (40, 5, 23)]
+    otrace.clear()
+    for r in reqs:
+        engine.add_request(r)
+    while any(not r.done for r in reqs):
+        engine.step()
+    routing = {id(r): _reference_routing(
+        fam, cfg, w, list(r.prompt_ids) + list(r.output_ids))
+        for r in reqs}
+    disp = _by(otrace.get_events(), "serving.dispatch")
+    assert {d["args"]["kind"] for d in disp} == {"mixed", "decode"}
+    experts = cfg["n_routed_experts"]
+    for d in disp:
+        a = d["args"]
+        rows = seen[a["step"]]
+        per_layer = []
+        for layer in range(len(routing[id(reqs[0])])):
+            ids = np.concatenate([
+                routing[id(r)][layer, start:start + n].reshape(-1)
+                for r, start, n, _ in rows])
+            counts = np.bincount(ids, minlength=experts)
+            per_layer.append([(counts > 0).sum(), counts.max()])
+        med = np.median(np.asarray(per_layer), axis=0)
+        assert a["experts_touched"] == med[0]
+        assert a["expert_rows_max"] == med[1]
+        assert a["latent_rows"] == sum(start + n for _, start, n, _ in rows)
+        assert all(pages * 8 >= start + n for _, start, n, pages in rows)
+    assert max(d["args"]["experts_touched"] for d in disp) <= experts
+    engine.close()
+
+
+def test_a_decoder_without_expert_layers_sets_no_expert_counters(served):
+    disp = _by(served[0], "serving.dispatch")
+    assert disp
+    for d in disp:
+        assert not {"experts_touched", "expert_rows_max",
+                    "latent_rows"} & set(d["args"])
