@@ -293,10 +293,14 @@ WALK_WIDTH_RTOL = 0.10
 
 def walk_rows(rng, r_cap, t_cap, qblock, decode_rows, chunks, max_ctx,
               page_size):
-    """One dispatch's row metadata the way `_dispatch_rows` lays it out:
+    """One dispatch's row metadata with the values `_dispatch_rows` gives
+    the fields ``kv_lens q_starts q_lens w_starts w_flats w_ends pos``:
     ``decode_rows`` sequences one token each, then ``chunks`` rows that
     continue ONE prompt (consecutive q_starts, one write span), then
-    inactive rows. Returns the [R] arrays and the packed positions."""
+    inactive rows. The engine writes them into one staged buffer
+    (`DispatchLayout`) its program takes apart; the kernels here are fed
+    directly, so they stay separate arrays. Returns the [R] arrays and
+    the packed positions."""
     kv = np.zeros(r_cap, np.int32)
     ql = np.zeros(r_cap, np.int32)
     kv[:decode_rows] = rng.randint(max_ctx // 8, max_ctx, decode_rows)

@@ -14,13 +14,15 @@ host reads with the tokens."""
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..framework.tensor import run_op
 from ..ops.ragged_paged_attention import rope_tables
 from .paged_cache import quantize_kv_int8
 
-__all__ = ["ServingStep"]
+__all__ = ["DispatchLayout", "ServingStep"]
 
 
 def _last_writer_values(new, page_ids, offs, page_slots):
@@ -90,6 +92,88 @@ def _token_gather(x, idx):
 
     return run_op("serving_token_gather", fn, (x, idx),
                   differentiable=False)
+
+
+class DispatchLayout:
+    """Where each host-built field of one dispatch lies in the ONE flat
+    int32 buffer the host hands the step program: the 21 arrays
+    :meth:`LlamaServingEngine._mixed_forward` takes before its pools,
+    in its argument order, back to back with no padding. The host fills
+    :meth:`views` of a buffer from :meth:`new` and transfers it once;
+    the program takes it apart again with :meth:`unpack` at static
+    offsets. The float32 fields ride as their bit patterns (a numpy
+    view on the host, a bitcast on the device: exact).
+
+    ``t_cap`` packed tokens, ``r_cap`` rows of at most ``qb`` query
+    tokens, tables ``width`` wide, ``sample_slots`` bias slots a row;
+    ``trash_page`` fills what no row claims."""
+
+    def __init__(self, t_cap, r_cap, qb, width, sample_slots, trash_page):
+        t, r, b = int(t_cap), int(r_cap), int(sample_slots)
+        i32, f32 = np.int32, np.float32
+        # (name, shape, dtype, what an unused slot reads)
+        spec = (
+            ("tokens", (1, t), i32, 0),
+            ("pos", (1, t), i32, 0),
+            ("page_ids", (t,), i32, trash_page),
+            ("offs", (t,), i32, 0),
+            ("row_tok", (r, int(qb)), i32, 0),
+            ("flat_idx", (t,), i32, r * int(qb) - 1),
+            ("last_idx", (r,), i32, 0),
+            ("tables", (r, int(width)), i32, trash_page),
+            ("kv_lens", (r,), i32, 0),
+            ("q_starts", (r,), i32, 0),
+            ("q_lens", (r,), i32, 0),
+            ("w_starts", (r,), i32, 0),
+            ("w_flats", (r,), i32, 0),
+            ("w_ends", (r,), i32, 0),
+            ("temps", (r,), f32, 0.0),
+            ("top_ps", (r,), f32, 1.0),
+            ("top_ks", (r,), i32, 0),
+            ("seeds", (r,), i32, 0),
+            ("slot_ids", (r, b), i32, -1),
+            ("slot_vals", (r, b), f32, 0.0),
+            ("cmodes", (r,), i32, 0),
+        )
+        self.shape = (t, r, int(qb), int(width), b)
+        fields, at = [], 0
+        for name, shape, dtype, _ in spec:
+            end = at + int(np.prod(shape))
+            fields.append((name, at, end, shape, np.dtype(dtype)))
+            at = end
+        #: ``(name, start, stop, shape, dtype)`` of every field, in words
+        self.fields = tuple(fields)
+        self.size = at
+        self.nbytes = 4 * at
+        blank = np.empty((at,), np.int32)
+        views = self.views(blank)
+        for name, _, _, fill in spec:
+            views[name][...] = fill
+        blank.setflags(write=False)
+        self._blank = blank
+
+    def new(self):
+        """A fresh host buffer with every field at its fill value. One
+        a dispatch: the transfer may read the host memory after
+        ``device_put`` returns, and the CPU backend may alias it."""
+        return self._blank.copy()
+
+    def views(self, buf):
+        """``{name: array}``: each field as a writable view of ``buf``
+        in its own shape and dtype."""
+        return {name: buf[at:end].view(dtype).reshape(shape)
+                for name, at, end, shape, dtype in self.fields}
+
+    def unpack(self, packed):
+        """The fields of a device buffer ``[size]`` int32, as a tuple in
+        order (traceable: static slices, reshapes and bitcasts)."""
+        out = []
+        for _, at, end, shape, dtype in self.fields:
+            a = packed[at:end].reshape(shape)
+            if dtype != np.int32:
+                a = jax.lax.bitcast_convert_type(a, dtype)
+            out.append(a)
+        return tuple(out)
 
 
 class ServingStep:

@@ -140,8 +140,8 @@ from ..observability.trace import span as _span
 from ..ops.ragged_paged_attention import fused_rope_geometry_ok
 from ..testing import faults as _faults
 from .kv_tier import KvPageTier, TierError
-from .layer_step import (ServingStep, _page_write,  # noqa: F401
-                         _page_write_q8, _token_gather)
+from .layer_step import (DispatchLayout, ServingStep,  # noqa: F401
+                         _page_write, _page_write_q8, _token_gather)
 from .paged_cache import PageAllocator
 from .sampling import SamplingParams, sampled_next_tokens
 from .speculative import NGramDrafter
@@ -756,6 +756,7 @@ class LlamaServingEngine:
         # [max_batch]-token decode-only shape. Scanned multi-tick
         # variants (lax.scan over the same function) key by tick count.
         self._mixed_static = None
+        self._layouts: dict[int, DispatchLayout] = {}   # t_cap -> layout
         self._scan_static: dict[int, object] = {}   # ticks -> program
         self._warmed_keys: set = set()  # ("mixed", T) / ("scan", k)
         self._mixed_bytes: dict[int, float] = {}  # t_cap -> hbm bytes
@@ -1099,12 +1100,13 @@ class LlamaServingEngine:
                             lambda *a: jnp.concatenate(a), tuple(stats),
                             differentiable=False)]
         x = m.norm(x)
-        # returned 1-D ([T] or [R]): a 2-D [1, T] int64 output would
-        # exactly match the donated ``tokens`` input's aval and XLA
-        # would alias the output into it — but that buffer is
-        # zero-copy-backed by the caller's host array, so the alias is
-        # a use-after-free. No input carries a 1-D int64 aval, so
-        # these shapes always get a fresh buffer.
+        # returned 1-D ([T] or [R]): XLA aliases an output into a
+        # donated input of the same aval, and a host-built input is
+        # zero-copy-backed by the caller's numpy array, so such an
+        # alias is a use-after-free. The mixed program's one host-built
+        # input is the packed [size] buffer, longer than T; the scan's
+        # token input is 2-D [B, 1]. These shapes always get a fresh
+        # buffer.
         if self.spec_k:
             logits = self.model._logits(x)               # [1, T, V]
             if self.sample_enabled:
@@ -1154,6 +1156,53 @@ class LlamaServingEngine:
                     .reshape([r_rows])
         return nxt, new_k, new_v, new_ks, new_vs, stats
 
+    def _dispatch_layout(self, t_cap):
+        """The layout of the host buffer a dispatch of ``t_cap`` packed
+        tokens hands its program, or None for a token count that is
+        neither of this engine's two program shapes: the chunk-budget
+        shape ``(chunk_budget, rows_cap, chunk_block)`` and the
+        decode-only shape ``(max_batch, max_batch, 1)``."""
+        lay = self._layouts.get(t_cap)
+        if lay is None:
+            if t_cap == self.chunk_budget:
+                r_cap, qb = self.rows_cap, self.chunk_block
+            elif t_cap == self.max_batch:
+                r_cap, qb = self.max_batch, 1
+            else:
+                return None
+            lay = self._layouts[t_cap] = DispatchLayout(
+                t_cap, r_cap, qb, self.width, self.sample_slots,
+                self.trash_page)
+        return lay
+
+    def _mixed_packed(self, packed, k_pools, v_pools, k_scales, v_scales):
+        """The compiled entry of the mixed program: ``packed`` is one
+        dispatch's metadata as :class:`DispatchLayout` lays it out
+        (int32 ``[size]``); it is taken apart at static offsets into the
+        21 tensors :meth:`_mixed_forward` takes. The buffer's length
+        names the program shape: the chunk-budget layout is always the
+        longer (``chunk_budget >= 2 * max_batch``, ``rows_cap >
+        max_batch``)."""
+        layout = self._dispatch_layout(self.chunk_budget)
+        if packed.shape[0] != layout.size:
+            layout = self._dispatch_layout(self.max_batch)
+        return self._mixed_forward(
+            *[Tensor(a) for a in layout.unpack(packed._data)],
+            k_pools, v_pools, k_scales, v_scales)
+
+    def _run_mixed(self, buf):
+        """Hand the mixed program one host buffer (its only transfer)
+        and adopt the donated pools it returns. Returns ``(next tokens,
+        the layers' counters)``, both still on the device."""
+        sf = self._ensure_mixed_compiled()
+        nxt, new_k, new_v, new_ks, new_vs, stats = sf(
+            Tensor(jnp.asarray(buf)), self.k_pools, self.v_pools,
+            self.k_scales, self.v_scales)
+        self.k_pools, self.v_pools = list(new_k), list(new_v)
+        if self.kv_quant:
+            self.k_scales, self.v_scales = list(new_ks), list(new_vs)
+        return nxt, stats
+
     def _ensure_mixed_compiled(self):
         if self._mixed_static is None:
             from ..jit import StaticFunction
@@ -1167,7 +1216,7 @@ class LlamaServingEngine:
             # alias assignment scrambles the pass-through outputs across
             # the donated buffers, corrupting the model in place.
             self._mixed_static = StaticFunction(
-                self._mixed_forward, state=[self.model], warmup="once",
+                self._mixed_packed, state=[self.model], warmup="once",
                 donate=False, donate_inputs=True,
                 name="serving.mixed_step")
             self._mixed_static._warmed_any = True
@@ -1189,8 +1238,8 @@ class LlamaServingEngine:
                 return
             compiled = None
             # match the executable by its signature: the FIRST leaf of
-            # a mixed-program signature is the [1, T] token input, so
-            # its shape identifies the dispatch's t_cap exactly. A
+            # a mixed-program signature is the packed host buffer, whose
+            # length identifies the dispatch's t_cap exactly. A
             # signature whose AOT slot is None (aot unsupported /
             # AOT_MISMATCH demotion) is skipped — misattributing some
             # OTHER shape's bytes here would poison the exact
@@ -1199,7 +1248,8 @@ class LlamaServingEngine:
                 if c is None:
                     continue
                 shapes = sig[0]
-                if shapes and shapes[0][0] == (1, t_cap):
+                if shapes and shapes[0][0] == (
+                        self._dispatch_layout(t_cap).size,):
                     compiled = c
                     break
             if compiled is None:
@@ -1379,21 +1429,28 @@ class LlamaServingEngine:
                 budget -= n
         return rows, cow
 
-    def _sample_arrays(self, reqs, r_cap):
+    def _sample_arrays(self, reqs, r_cap, into=None):
         """Host-built per-row sampler metadata for one dispatch:
         ``reqs`` is a <= r_cap list of requests (None entries and the
         padding tail stay inert greedy rows). Constraint hooks run
         HERE, once per scheduled dispatch — a raising hook degrades to
         unconstrained (counted), an oversized allowed set truncates to
-        the engine's static ``sample_slots`` width (counted)."""
+        the engine's static ``sample_slots`` width (counted). The seven
+        arrays are written in place where ``into`` (the views of a
+        dispatch's buffer, at their fill values) holds them, made here
+        for the scan's callers."""
         b = self.sample_slots
-        temps = np.zeros((r_cap,), np.float32)
-        top_ps = np.ones((r_cap,), np.float32)
-        top_ks = np.zeros((r_cap,), np.int32)
-        seeds = np.zeros((r_cap,), np.int32)
-        slot_ids = np.full((r_cap, b), -1, np.int32)
-        slot_vals = np.zeros((r_cap, b), np.float32)
-        cmodes = np.zeros((r_cap,), np.int32)
+        if into is None:
+            into = {"temps": np.zeros((r_cap,), np.float32),
+                    "top_ps": np.ones((r_cap,), np.float32),
+                    "top_ks": np.zeros((r_cap,), np.int32),
+                    "seeds": np.zeros((r_cap,), np.int32),
+                    "slot_ids": np.full((r_cap, b), -1, np.int32),
+                    "slot_vals": np.zeros((r_cap, b), np.float32),
+                    "cmodes": np.zeros((r_cap,), np.int32)}
+        temps, top_ps, top_ks, seeds, slot_ids, slot_vals, cmodes = (
+            into[k] for k in ("temps", "top_ps", "top_ks", "seeds",
+                              "slot_ids", "slot_vals", "cmodes"))
         if not self.sample_enabled:
             return (temps, top_ps, top_ks, seeds, slot_ids, slot_vals,
                     cmodes)
@@ -1439,19 +1496,18 @@ class LlamaServingEngine:
     def _dispatch_rows(self, rows, cow):
         """Build and enqueue ONE mixed program over an already-scheduled
         row list (caller holds the dispatch locks): copy-on-write, the
-        host-built metadata, its transfer, the enqueue. Returns what
-        :meth:`_apply_rows` needs: ``(next tokens still on the device,
-        each row's first index in the T axis, enqueue seconds, cold,
-        needs_mixed, t_cap)``."""
+        host-built metadata, its one transfer, the enqueue. Returns
+        what :meth:`_apply_rows` needs and what the spans say: ``(next
+        tokens still on the device, each row's first index in the T
+        axis, enqueue seconds, cold, needs_mixed, t_cap, bytes handed
+        to the device)``."""
         # speculative verify rows are multi-token decode rows: they
         # need the chunk-shaped program exactly like prefill chunks do
         needs_mixed = any(n > 1 or not is_dec
                           for _, _, _, n, _, is_dec in rows)
-        if needs_mixed:
-            t_cap, r_cap, qb = (self.chunk_budget, self.rows_cap,
-                                self.chunk_block)
-        else:
-            t_cap, r_cap, qb = self.max_batch, self.max_batch, 1
+        t_cap = self.chunk_budget if needs_mixed else self.max_batch
+        layout = self._dispatch_layout(t_cap)
+        _, r_cap, qb, _, _ = layout.shape
         for old, new in cow:
             self._copy_page(old, new)
         key = ("mixed", t_cap)
@@ -1482,26 +1538,25 @@ class LlamaServingEngine:
         for r, _, _, _, _, is_dec in rows:
             if not is_dec and r._t_first_chunk is None:
                 r._t_first_chunk = now
-        # host-built metadata: reads of the allocator's tables are safe
-        # here — cross-thread releases defer past the whole _entry
-        tokens = np.zeros((1, t_cap), np.int64)
-        pos = np.zeros((1, t_cap), np.int32)
-        page_ids = np.full((t_cap,), self.trash_page, np.int32)
-        offs = np.zeros((t_cap,), np.int32)
-        row_tok = np.zeros((r_cap, qb), np.int32)
-        flat_idx = np.full((t_cap,), r_cap * qb - 1, np.int32)
-        last_idx = np.zeros((r_cap,), np.int32)
-        tables = np.full((r_cap, self.width), self.trash_page, np.int32)
-        kv_lens = np.zeros((r_cap,), np.int32)
-        q_starts = np.zeros((r_cap,), np.int32)
-        q_lens = np.zeros((r_cap,), np.int32)
+        # host-built metadata, written into ONE fresh buffer the
+        # program takes apart again (a buffer kept for the next dispatch
+        # could change under a transfer still reading it): reads of the
+        # allocator's tables are safe here — cross-thread releases
+        # defer past the whole _entry
+        buf = layout.new()
+        f = layout.views(buf)
+        tokens, pos, page_ids, offs = (f["tokens"], f["pos"],
+                                       f["page_ids"], f["offs"])
+        row_tok, flat_idx, last_idx = (f["row_tok"], f["flat_idx"],
+                                       f["last_idx"])
+        tables, kv_lens, q_starts, q_lens = (f["tables"], f["kv_lens"],
+                                             f["q_starts"], f["q_lens"])
         # fused-write metadata: per row, the first position of its
         # sequence written by THIS dispatch, that position's packed
         # index, and the sequence's final kv_len (rows of one sequence
         # are consecutive, so one forward pass collects all three)
-        w_starts = np.zeros((r_cap,), np.int32)
-        w_flats = np.zeros((r_cap,), np.int32)
-        w_ends = np.zeros((r_cap,), np.int32)
+        w_starts, w_flats, w_ends = (f["w_starts"], f["w_flats"],
+                                     f["w_ends"])
         seq_first: dict[int, tuple] = {}     # sid -> (w_start, w_flat)
         seq_last: dict[int, int] = {}        # sid -> w_end
         t = 0
@@ -1528,10 +1583,8 @@ class LlamaServingEngine:
         for i, (r, sid, start, n, toks, is_dec) in enumerate(rows):
             w_starts[i], w_flats[i] = seq_first[sid]
             w_ends[i] = seq_last[sid]
-        (temps, top_ps, top_ks, seeds, slot_ids, slot_vals,
-         cmodes) = self._sample_arrays([row[0] for row in rows], r_cap)
+        self._sample_arrays([row[0] for row in rows], r_cap, into=f)
         self._record_shape("mixed", t_cap)
-        sf = self._ensure_mixed_compiled()
         self._arm_watchdog(cold)
         with self._lock:
             self._in_dispatch = True
@@ -1539,30 +1592,7 @@ class LlamaServingEngine:
         try:
             with no_grad(), _span("serving.mixed_step", rows=len(rows),
                                   tokens=int(t), prefill=needs_mixed):
-                nxt, new_k, new_v, new_ks, new_vs, stats = sf(
-                    Tensor(jnp.asarray(tokens)),
-                    Tensor(jnp.asarray(pos)),
-                    Tensor(jnp.asarray(page_ids)),
-                    Tensor(jnp.asarray(offs)),
-                    Tensor(jnp.asarray(row_tok)),
-                    Tensor(jnp.asarray(flat_idx)),
-                    Tensor(jnp.asarray(last_idx)),
-                    Tensor(jnp.asarray(tables)),
-                    Tensor(jnp.asarray(kv_lens)),
-                    Tensor(jnp.asarray(q_starts)),
-                    Tensor(jnp.asarray(q_lens)),
-                    Tensor(jnp.asarray(w_starts)),
-                    Tensor(jnp.asarray(w_flats)),
-                    Tensor(jnp.asarray(w_ends)),
-                    Tensor(jnp.asarray(temps)),
-                    Tensor(jnp.asarray(top_ps)),
-                    Tensor(jnp.asarray(top_ks)),
-                    Tensor(jnp.asarray(seeds)),
-                    Tensor(jnp.asarray(slot_ids)),
-                    Tensor(jnp.asarray(slot_vals)),
-                    Tensor(jnp.asarray(cmodes)),
-                    self.k_pools, self.v_pools,
-                    self.k_scales, self.v_scales)
+                nxt, stats = self._run_mixed(buf)
         finally:
             with self._lock:
                 self._in_dispatch = False
@@ -1571,11 +1601,8 @@ class LlamaServingEngine:
             self._warmed_keys.add(key)
         self._note_mixed_bytes(t_cap)
         self._flush_deferred()
-        self.k_pools, self.v_pools = list(new_k), list(new_v)
-        if self.kv_quant:
-            self.k_scales, self.v_scales = list(new_ks), list(new_vs)
         self._layer_stats = stats[0] if stats else None
-        return nxt, flat_start, dur, cold, needs_mixed, t_cap
+        return nxt, flat_start, dur, cold, needs_mixed, t_cap, buf.nbytes
 
     def _apply_rows(self, rows, out, flat_start, dur, cold, needs_mixed):
         """Apply one mixed dispatch's next tokens ``out`` (``[t_cap]``,
@@ -1805,38 +1832,11 @@ class LlamaServingEngine:
         replace ours. Returns False for a token count that doesn't
         match this engine's geometry (a stale registry entry)."""
         t_cap = int(t_cap)
-        if t_cap == self.chunk_budget:
-            r_cap, qb = self.rows_cap, self.chunk_block
-        elif t_cap == self.max_batch:
-            r_cap, qb = self.max_batch, 1
-        else:
+        layout = self._dispatch_layout(t_cap)
+        if layout is None:
             return False
-        sf = self._ensure_mixed_compiled()
-        samp = self._sample_arrays([], r_cap)
         with no_grad():
-            _, wk, wv, wks, wvs, _ = sf(
-                Tensor(jnp.asarray(np.zeros((1, t_cap), np.int64))),
-                Tensor(jnp.asarray(np.zeros((1, t_cap), np.int32))),
-                Tensor(jnp.asarray(np.full((t_cap,), self.trash_page,
-                                           np.int32))),
-                Tensor(jnp.asarray(np.zeros((t_cap,), np.int32))),
-                Tensor(jnp.asarray(np.zeros((r_cap, qb), np.int32))),
-                Tensor(jnp.asarray(np.zeros((t_cap,), np.int32))),
-                Tensor(jnp.asarray(np.zeros((r_cap,), np.int32))),
-                Tensor(jnp.asarray(np.full((r_cap, self.width),
-                                           self.trash_page, np.int32))),
-                Tensor(jnp.asarray(np.zeros((r_cap,), np.int32))),
-                Tensor(jnp.asarray(np.zeros((r_cap,), np.int32))),
-                Tensor(jnp.asarray(np.zeros((r_cap,), np.int32))),
-                Tensor(jnp.asarray(np.zeros((r_cap,), np.int32))),
-                Tensor(jnp.asarray(np.zeros((r_cap,), np.int32))),
-                Tensor(jnp.asarray(np.zeros((r_cap,), np.int32))),
-                *[Tensor(jnp.asarray(a)) for a in samp],
-                self.k_pools, self.v_pools,
-                self.k_scales, self.v_scales)
-        self.k_pools, self.v_pools = list(wk), list(wv)
-        if self.kv_quant:
-            self.k_scales, self.v_scales = list(wks), list(wvs)
+            self._run_mixed(layout.new())
         self._warmed_keys.add(("mixed", t_cap))
         self._warm_dispatches += 1
         self._record_shape("mixed", t_cap)
@@ -2595,9 +2595,11 @@ class LlamaServingEngine:
                     sched.cancel()
                     disp.cancel()
                     return 0, 0
-            with _span("serving.build", step=step):
-                nxt, flat_start, dur, cold, needs_mixed, t_cap = \
+            with _span("serving.build", step=step) as build:
+                nxt, flat_start, dur, cold, needs_mixed, t_cap, nbytes = \
                     self._dispatch_rows(rows, cow)
+                # what the host handed the program: one staged buffer
+                build.set(h2d_arrays=1, h2d_bytes=nbytes)
             with _span("serving.wait", step=step):
                 out = np.asarray(nxt._data).reshape(-1)      # [t_cap]
                 # the expert layers' counters came with the tokens
@@ -2790,9 +2792,12 @@ class LlamaServingEngine:
                     sched.cancel()
                     disp.cancel()
                     return 0
-            with _span("serving.build", step=step):
-                out, dur, cold = self._dispatch_scan(
+            with _span("serving.build", step=step) as build:
+                out, dur, cold, h2d = self._dispatch_scan(
                     n, live, sids, last_tok, start_lens, cow)
+                # the scan still takes its arrays one by one
+                build.set(h2d_arrays=len(h2d),
+                          h2d_bytes=sum(a.nbytes for a in h2d))
             with _span("serving.wait", step=step):
                 all_tokens = np.asarray(out[0]._data)        # one D2H
             with _span("serving.apply", step=step) as applied:
@@ -2862,7 +2867,8 @@ class LlamaServingEngine:
 
     def _dispatch_scan(self, n, live, sids, last_tok, start_lens, cow):
         """Build and enqueue the ``n``-tick scan over the planned rows.
-        Returns ``(the program's outputs, enqueue seconds, cold)``."""
+        Returns ``(the program's outputs, enqueue seconds, cold, the
+        arrays handed to the device)``."""
         for old, new in cow:
             self._copy_page(old, new)
         # as in step(): each new scan length compiles on its first
@@ -2879,8 +2885,8 @@ class LlamaServingEngine:
             tables[i, :len(t)] = t
             lens[i] = start_lens[sid] + 1       # first new token incl.
             tokens[i, 0] = last_tok[i]
-        (temps, top_ps, top_ks, seeds, slot_ids, slot_vals,
-         cmodes) = self._sample_arrays(live, b)
+        h2d = [jnp.asarray(a) for a in (
+            tokens, tables, lens, *self._sample_arrays(live, b))]
         sf = self._ensure_scan_compiled(n)
         self._arm_watchdog(cold)
         with self._lock:
@@ -2888,18 +2894,8 @@ class LlamaServingEngine:
         try:
             with no_grad(), _span("serving.decode_scan",
                                   live=len(live), ticks=n):
-                out = sf(
-                    Tensor(jnp.asarray(tokens)),
-                    Tensor(jnp.asarray(tables)),
-                    Tensor(jnp.asarray(lens)),
-                    Tensor(jnp.asarray(temps)),
-                    Tensor(jnp.asarray(top_ps)),
-                    Tensor(jnp.asarray(top_ks)),
-                    Tensor(jnp.asarray(seeds)),
-                    Tensor(jnp.asarray(slot_ids)),
-                    Tensor(jnp.asarray(slot_vals)),
-                    Tensor(jnp.asarray(cmodes)),
-                    self.k_pools, self.v_pools,
+                out = sf(*[Tensor(a) for a in h2d],
+                         self.k_pools, self.v_pools,
                     self.k_scales, self.v_scales)
         finally:
             with self._lock:
@@ -2909,7 +2905,7 @@ class LlamaServingEngine:
             self._warmed_keys.add(key)
         self._flush_deferred()
         self._adopt_scan_pools(out)
-        return out, dur, cold
+        return out, dur, cold, h2d
 
     def _scan_fits(self, live, n):
         """Largest scan <= n whose page reservations fit the pool and

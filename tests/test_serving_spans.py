@@ -121,6 +121,27 @@ def test_every_dispatch_has_its_four_phases_under_one_step(served):
             and m["ts"] + m["dur"] <= b["ts"] + b["dur"] + 1e-3
 
 
+def test_every_build_says_what_it_handed_the_device(served, model):
+    """``serving.build`` carries ``h2d_arrays``, the host arrays the
+    step program was handed this dispatch (one staged buffer), and
+    ``h2d_bytes``, that buffer's size: the layout's, by program shape."""
+    events = served[0]
+    engine = _engine(model)
+    nbytes = {kind: engine._dispatch_layout(t_cap).nbytes
+              for kind, t_cap in (("mixed", 16), ("decode", 4))}
+    engine.close()
+    assert nbytes["mixed"] > nbytes["decode"] > 0
+    kind_of = {d["args"]["step"]: d["args"]["kind"]
+               for d in _by(events, "serving.dispatch")}
+    builds = _by(events, "serving.build")
+    assert len(builds) == len(kind_of)
+    for b in builds:
+        assert b["args"]["h2d_arrays"] == 1
+        assert b["args"]["h2d_bytes"] == nbytes[kind_of[b["args"]["step"]]]
+    assert {kind_of[b["args"]["step"]] for b in builds} \
+        == {"mixed", "decode"}
+
+
 def test_replica_tick_wraps_each_dispatch_and_idle_turns_are_dark(served):
     events = served[0]
     ticks, disp = _by(events, "replica.tick"), _by(events,
@@ -213,6 +234,11 @@ def test_decode_scan_has_the_same_shape(model):
         assert d["args"]["prefill_tokens"] == 0
         assert d["args"]["tokens"] == d["args"]["rows"] \
             * (d["args"]["t_cap"] // 4)
+        # the scan still takes its arrays one by one: last tokens,
+        # tables, lengths and the sampler's seven
+        build = kids[1]["args"]
+        assert build["h2d_arrays"] == 10
+        assert build["h2d_bytes"] == 4 * (4 + 4 * 16 + 4 + 5 * 4 + 2 * 4 * 8)
     assert _value("serving_dispatches_total", "scan") == len(scans)
     assert sum(e["args"]["emitted"] for e in _by(events, "serving.apply")) \
         == sum(len(r.output_ids) for r in reqs) == 24
