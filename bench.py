@@ -52,7 +52,6 @@ BENCH_SCHEMA_VERSION = 1
 #: snapshot so a regression diff can rule out "different config"
 _PROVENANCE_KNOBS = (
     "PADDLE_TPU_METRICS", "PADDLE_TPU_SERVING_Q8",
-    "PADDLE_TPU_FUSED_KV", "PADDLE_TPU_FUSED_ROPE",
 )
 
 
@@ -315,446 +314,6 @@ def bench_paged(batch=8, heads=16, kv_heads=8, dim=128, page=64,
         "paged_speedup": round(xla_ms / pallas_ms, 3),
         "paged_parity_ok": bool(err < 0.05 * max(scale, 1.0)),
     }
-
-
-def bench_ragged(rows=8, qb=16, heads=16, kv_heads=8, dim=128, page=64,
-                 ctx=2048, iters=50):
-    """Ragged paged-attention kernel (mixed prefill chunks + decode
-    rows, ONE dispatch) vs the XLA gather reference, on device — the
-    `paged_parity_ok`-style gate for the chunked serving engine's
-    kernel."""
-    import jax
-    import jax.numpy as jnp
-    from paddle_tpu.ops import ragged_paged_attention as RPA
-
-    rng = np.random.RandomState(0)
-    dt = jnp.bfloat16 if jax.default_backend() == "tpu" else jnp.float32
-    max_pages = ctx // page
-    num_pages = rows * max_pages + 8
-    q = jnp.asarray(rng.randn(rows, qb, heads, dim), dt)
-    kp = jnp.asarray(rng.randn(num_pages, kv_heads, page, dim), dt)
-    vp = jnp.asarray(rng.randn(num_pages, kv_heads, page, dim), dt)
-    perm = rng.permutation(num_pages)[:rows * max_pages]
-    tables = jnp.asarray(perm.reshape(rows, max_pages), jnp.int32)
-    # half the rows decode (q_len 1), half are ragged prefill chunks
-    q_lens = np.asarray([1 if i % 2 else 1 + rng.randint(qb)
-                         for i in range(rows)], np.int32)
-    kv = rng.randint(ctx // 2, ctx + 1, (rows,)).astype(np.int32)
-    kv = np.maximum(kv, q_lens)
-    q_starts = kv - q_lens
-    kv_lens = jnp.asarray(kv)
-    q_starts = jnp.asarray(q_starts)
-    q_lens = jnp.asarray(q_lens)
-
-    def timeit(f):
-        g = jax.jit(f)
-        out = g(q, kp, vp, tables, kv_lens, q_starts, q_lens)
-        jax.block_until_ready(out)
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            out = g(q, kp, vp, tables, kv_lens, q_starts, q_lens)
-        jax.block_until_ready(out)
-        return (time.perf_counter() - t0) / iters * 1e3, out
-
-    def pallas_path(q, kp, vp, tables, kl, qs, ql):
-        return RPA._ragged_impl(q, kp, vp, tables, kl, qs, ql,
-                                scale=1.0 / float(np.sqrt(dim)))
-
-    pallas_ms, o_p = timeit(pallas_path)
-    xla_ms, o_x = timeit(RPA.ragged_paged_attention_xla)
-    err = float(jnp.max(jnp.abs(o_p.astype(jnp.float32)
-                                - o_x.astype(jnp.float32))))
-    scale = float(jnp.max(jnp.abs(o_x.astype(jnp.float32))))
-    return {
-        "ragged_pallas_ms": round(pallas_ms, 3),
-        "ragged_xla_ms": round(xla_ms, 3),
-        "ragged_speedup": round(xla_ms / pallas_ms, 3),
-        "ragged_parity_ok": bool(err < 0.05 * max(scale, 1.0)),
-    }
-
-
-def _fused_bench_case(rng, rows, qb, kv_heads, dim, page, ctx, dt):
-    """Shared fused-kernel bench geometry (bench_fused_kv and
-    bench_fused_rope): pools, disjoint per-row tables (dump page never
-    referenced), half-decode/half-chunk row metadata, the w-metadata
-    the fused contract needs, and the per-token scatter targets of the
-    unfused reference path."""
-    import jax.numpy as jnp
-
-    max_pages = ctx // page
-    num_pages = rows * max_pages + 8
-    dump = num_pages - 1
-    kp = jnp.asarray(rng.randn(num_pages, kv_heads, page, dim), dt)
-    vp = jnp.asarray(rng.randn(num_pages, kv_heads, page, dim), dt)
-    perm = rng.permutation(num_pages - 1)[:rows * max_pages]
-    tables = jnp.asarray(perm.reshape(rows, max_pages), jnp.int32)
-    q_lens = np.asarray([1 if i % 2 else 1 + rng.randint(qb)
-                         for i in range(rows)], np.int32)
-    kv = rng.randint(ctx // 2, ctx + 1, (rows,)).astype(np.int32)
-    kv = np.maximum(kv, q_lens)
-    q_starts = kv - q_lens
-    w_starts = q_starts.copy()
-    w_flats = np.concatenate([[0], np.cumsum(q_lens)[:-1]]) \
-        .astype(np.int32)
-    w_ends = kv.copy()
-    t_total = int(q_lens.sum())
-    new_k = jnp.asarray(rng.randn(t_total, kv_heads, dim), dt)
-    new_v = jnp.asarray(rng.randn(t_total, kv_heads, dim), dt)
-    pg = np.concatenate([
-        np.asarray(tables)[i, np.arange(q_starts[i], kv[i]) // page]
-        for i in range(rows)]).astype(np.int32)
-    offs = np.concatenate([np.arange(q_starts[i], kv[i]) % page
-                           for i in range(rows)]).astype(np.int32)
-    args_i32 = [jnp.asarray(a) for a in
-                (kv, q_starts, q_lens, w_starts, w_flats, w_ends)]
-    return dict(num_pages=num_pages, dump=dump, kp=kp, vp=vp,
-                perm=perm, tables=tables, q_lens=q_lens, kv=kv,
-                q_starts=q_starts, w_flats=w_flats, t_total=t_total,
-                new_k=new_k, new_v=new_v, pg=pg, offs=offs,
-                args_i32=args_i32,
-                scale=1.0 / float(np.sqrt(dim)))
-
-
-def bench_fused_kv(model, rows=8, qb=16, heads=16, kv_heads=8, dim=128,
-                   page=64, ctx=2048, iters=50, on_tpu=True):
-    """Fused in-kernel KV page write (ROADMAP item 2, first stage) vs
-    the unfused two-op path (scatter + ragged read), at two levels:
-
-    - kernel microbench: ONE `fused_ragged_paged_attention` dispatch vs
-      the `paged_kv_write` scatter followed by `ragged_paged_attention`
-      over the same rows (`fused_kernel_ms` / `unfused_kernel_ms`).
-    - engine e2e: `serving_chunked_tokens_per_sec`-style throughput
-      under PADDLE_TPU_FUSED_KV on vs off, plus each path's
-      `serving_mixed_hbm_bytes` (static cost_analysis of the mixed
-      program) and their delta.
-
-    Gates: ``fused_parity_ok`` — greedy engine outputs BITWISE equal
-    fused vs unfused (fp), q8 kernel within the existing 5%-of-scale
-    bar vs the write-then-read XLA reference, and non-trash pool bytes
-    identical across paths. ``fused_hbm_decreased`` is asserted into
-    ``fused_hbm_ok`` only on TPU: the CPU interpret-mode lowering of
-    the Pallas call inflates cost_analysis with emulation machinery
-    (aliasing copies, per-step slices) that does not exist in the
-    compiled custom call, so off-chip the delta is recorded but the
-    strict-decrease claim rides ROADMAP item 1's on-chip sweep."""
-    import jax
-    import jax.numpy as jnp
-    from paddle_tpu.inference.serving import LlamaServingEngine, \
-        _page_write
-    from paddle_tpu.ops import ragged_paged_attention as RPA
-
-    rng = np.random.RandomState(0)
-    dt = jnp.bfloat16 if jax.default_backend() == "tpu" else jnp.float32
-    case = _fused_bench_case(rng, rows, qb, kv_heads, dim, page, ctx,
-                             dt)
-    num_pages, dump = case["num_pages"], case["dump"]
-    kp, vp, perm, tables = (case[k] for k in
-                            ("kp", "vp", "perm", "tables"))
-    new_k, new_v, pg, offs = (case[k] for k in
-                              ("new_k", "new_v", "pg", "offs"))
-    args_i32, scale = case["args_i32"], case["scale"]
-    q = jnp.asarray(rng.randn(rows, qb, heads, dim), dt)
-
-    def fused_path(q, nk, nv, kp, vp):
-        return RPA._fused_impl(q, nk, nv, kp, vp, tables, *args_i32,
-                               dump, scale)
-
-    def unfused_path(q, nk, nv, kp, vp):
-        kp2 = _page_write(kp, nk, jnp.asarray(pg), jnp.asarray(offs))
-        vp2 = _page_write(vp, nv, jnp.asarray(pg), jnp.asarray(offs))
-        kp2 = getattr(kp2, "_data", kp2)
-        vp2 = getattr(vp2, "_data", vp2)
-        out = RPA._ragged_impl(q, kp2, vp2, tables, args_i32[0],
-                               args_i32[1], args_i32[2], scale)
-        return out, kp2, vp2
-
-    def timeit(f):
-        g = jax.jit(f)
-        out = g(q, new_k, new_v, kp, vp)
-        jax.block_until_ready(out)
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            out = g(q, new_k, new_v, kp, vp)
-        jax.block_until_ready(out)
-        return (time.perf_counter() - t0) / iters * 1e3, out
-
-    fused_ms, (o_f, kpf, vpf) = timeit(fused_path)
-    unfused_ms, (o_u, kpu, vpu) = timeit(unfused_path)
-    live = np.asarray(sorted(set(perm.tolist())))
-    pools_equal = bool(
-        np.array_equal(np.asarray(kpf)[live], np.asarray(kpu)[live])
-        and np.array_equal(np.asarray(vpf)[live], np.asarray(vpu)[live]))
-    out_equal = bool(np.array_equal(np.asarray(o_f), np.asarray(o_u)))
-
-    # q8 kernel parity at the existing 5%-of-scale bar vs the
-    # write-then-read XLA reference
-    kq = jnp.asarray(rng.randint(-127, 128,
-                                 (num_pages, kv_heads, page, dim)),
-                     jnp.int8)
-    vq = jnp.asarray(rng.randint(-127, 128,
-                                 (num_pages, kv_heads, page, dim)),
-                     jnp.int8)
-    ks = jnp.asarray(np.abs(rng.randn(num_pages, kv_heads, page, 1))
-                     .astype(np.float32) * 0.02)
-    vs = jnp.asarray(np.abs(rng.randn(num_pages, kv_heads, page, 1))
-                     .astype(np.float32) * 0.02)
-    q8_args = (jnp.asarray(np.asarray(q, np.float32)),
-               jnp.asarray(np.asarray(new_k, np.float32)),
-               jnp.asarray(np.asarray(new_v, np.float32)),
-               kq, vq, tables, *args_i32, dump)
-    o8f = RPA.fused_ragged_paged_attention(*q8_args, k_scale=ks,
-                                           v_scale=vs)[0]
-    o8x = RPA.fused_ragged_paged_attention_xla(*q8_args, k_scale=ks,
-                                               v_scale=vs)[0]
-    o8f = np.asarray(getattr(o8f, "_data", o8f), np.float32)
-    o8x = np.asarray(o8x, np.float32)
-    err8 = float(np.max(np.abs(o8f - o8x)))
-    bar8 = 0.05 * max(float(np.max(np.abs(o8x))), 1.0)
-
-    # engine e2e under both paths: same workload, fused on vs off
-    model.eval()
-    rng2 = np.random.RandomState(1)
-    v = model.config.vocab_size
-    prompts = [rng2.randint(0, v, (int(rng2.randint(16, 96)),)).tolist()
-               for _ in range(8 if on_tpu else 3)]
-    n_new = 32 if on_tpu else 6
-
-    def e2e(fused):
-        # fused_rope pinned OFF: this bench measures stage 1 (the
-        # fused KV write) against the two-op path — the engine default
-        # would silently swap in the rope-fused program and the
-        # 'fused' metrics would no longer mean PR-13's program
-        engine = LlamaServingEngine(
-            model, max_batch=8 if on_tpu else 2, page_size=64,
-            num_pages=72 if on_tpu else 24, max_pages_per_seq=8,
-            decode_ticks=16, fused_kv=fused, fused_rope=False)
-        engine.generate(prompts, max_new_tokens=2)        # compile
-        t0 = time.perf_counter()
-        outs = engine.generate(prompts, max_new_tokens=n_new)
-        dt_ = time.perf_counter() - t0
-        # read THIS engine's cached analysis (budget-shape mixed
-        # program, the largest t_cap) rather than the process-global
-        # gauge: the gauge retains whatever engine last set it, so a
-        # failed attribution in one run would silently compare against
-        # a stale value from another. None (not 0.0) when no analysis
-        # exists (METRICS=0: no AOT executables to cost-analyze) so a
-        # 0-vs-0 comparison can't report a spurious gate failure.
-        hbm = engine._mixed_bytes.get(max(engine._mixed_bytes)) \
-            if engine._mixed_bytes else None
-        engine.close()
-        return outs, sum(len(o) for o in outs) / dt_, hbm
-
-    outs_f, tps_f, hbm_f = e2e(True)
-    outs_u, tps_u, hbm_u = e2e(False)
-    model.train()
-    parity = bool(out_equal and pools_equal and err8 < bar8
-                  and outs_f == outs_u)
-    res = {
-        "fused_kernel_ms": round(fused_ms, 3),
-        "unfused_kernel_ms": round(unfused_ms, 3),
-        "fused_kernel_speedup": round(unfused_ms / fused_ms, 3),
-        "fused_parity_ok": parity,
-        "serving_fused_tokens_per_sec": round(tps_f, 1),
-        "serving_unfused_tokens_per_sec": round(tps_u, 1),
-        "fused_e2e_speedup": round(tps_f / max(tps_u, 1e-9), 3),
-    }
-    if hbm_f is not None and hbm_u is not None:
-        res.update({
-            "serving_mixed_hbm_bytes_fused": hbm_f,
-            "serving_mixed_hbm_bytes_unfused": hbm_u,
-            "fused_hbm_bytes_delta": hbm_u - hbm_f,
-            "fused_hbm_decreased": bool(hbm_f < hbm_u),
-        })
-        if on_tpu:
-            res["fused_hbm_ok"] = bool(hbm_f < hbm_u)
-    return res
-
-
-def bench_fused_rope(model, rows=8, qb=16, heads=16, kv_heads=8,
-                     dim=128, page=64, ctx=2048, iters=50, on_tpu=True):
-    """Fused rotary embedding (ROADMAP item 2, second stage) — rope +
-    KV write + attention in ONE Pallas program — vs the PR-13 fused-KV
-    path (separate rope op + q row-pack) and the fully-unfused two-op
-    path, at two levels:
-
-    - kernel microbench: one rope-fused dispatch vs rope + pack +
-      `fused_ragged_paged_attention` vs rope + scatter + ragged read
-      (`fused_rope_kernel_ms` / `fused_kv_kernel_ms` /
-      `unfused_rope_kernel_ms`).
-    - engine e2e: tok/s under PADDLE_TPU_FUSED_ROPE on / off (PR-13) /
-      PADDLE_TPU_FUSED_KV off, plus each variant's
-      `serving_mixed_hbm_bytes` (omitted under METRICS=0, matching
-      `bench_fused_kv`).
-
-    Gates: ``fused_rope_parity_ok`` — greedy engine outputs token-
-    exact across all three variants, fp kernel outputs AND live pool
-    bytes BITWISE rope-fused vs PR-13, q8 kernel within the existing
-    5%-of-scale bar vs the rope-then-write-then-read XLA reference.
-    ``fused_rope_hbm_ok`` (strict decrease vs the PR-13 program — the
-    per-layer rope reads/writes and the q pack gone from the static
-    cost analysis) is asserted on TPU only: CPU interpret-mode
-    lowering inflates cost_analysis with emulation machinery."""
-    import jax
-    import jax.numpy as jnp
-    from paddle_tpu.inference.serving import LlamaServingEngine, \
-        _page_write
-    from paddle_tpu.ops import ragged_paged_attention as RPA
-
-    rng = np.random.RandomState(0)
-    dt = jnp.bfloat16 if jax.default_backend() == "tpu" else jnp.float32
-    case = _fused_bench_case(rng, rows, qb, kv_heads, dim, page, ctx,
-                             dt)
-    num_pages, dump = case["num_pages"], case["dump"]
-    kp, vp, perm, tables = (case[k] for k in
-                            ("kp", "vp", "perm", "tables"))
-    new_k, new_v, pg, offs = (case[k] for k in
-                              ("new_k", "new_v", "pg", "offs"))
-    args_i32, scale = case["args_i32"], case["scale"]
-    q_lens, kv, q_starts, w_flats, t_total = (
-        case[k] for k in ("q_lens", "kv", "q_starts", "w_flats",
-                          "t_total"))
-    q_packed = jnp.asarray(rng.randn(t_total, heads, dim), dt)
-    pos = np.concatenate([np.arange(q_starts[i], kv[i])
-                          for i in range(rows)]).astype(np.int32)
-    sin, cos = RPA.rope_tables(jnp.asarray(pos), dim, 10000.0)
-    # row-block gather indices for the PR-13 variant: token j of row i
-    # sits at packed w_flats[i] + j (pad slot t_total reads zeros)
-    ridx = np.full((rows, qb), t_total, np.int64)
-    for i in range(rows):
-        ridx[i, :q_lens[i]] = w_flats[i] + np.arange(q_lens[i])
-    ridx = jnp.asarray(ridx)
-
-    def _rope(x):
-        xf = x.astype(jnp.float32)
-        h = dim // 2
-        rot = jnp.concatenate([-xf[..., h:], xf[..., :h]], -1)
-        return (xf * cos[:, None, :] + rot * sin[:, None, :]) \
-            .astype(x.dtype)
-
-    def rope_fused_path(q, nk, nv, kp, vp):
-        return RPA._fused_rope_impl(q, nk, nv, kp, vp, tables,
-                                    *args_i32, sin, cos, dump, scale,
-                                    qb)
-
-    def pr13_path(q, nk, nv, kp, vp):
-        qr = jnp.pad(_rope(q), ((0, 1), (0, 0), (0, 0)))[ridx]
-        return RPA._fused_impl(qr, _rope(nk), nv, kp, vp, tables,
-                               *args_i32, dump, scale)
-
-    def unfused_path(q, nk, nv, kp, vp):
-        qr = jnp.pad(_rope(q), ((0, 1), (0, 0), (0, 0)))[ridx]
-        nk2 = _rope(nk)
-        kp2 = _page_write(kp, nk2, jnp.asarray(pg), jnp.asarray(offs))
-        vp2 = _page_write(vp, nv, jnp.asarray(pg), jnp.asarray(offs))
-        kp2 = getattr(kp2, "_data", kp2)
-        vp2 = getattr(vp2, "_data", vp2)
-        out = RPA._ragged_impl(qr, kp2, vp2, tables, args_i32[0],
-                               args_i32[1], args_i32[2], scale)
-        return out, kp2, vp2
-
-    def timeit(f):
-        g = jax.jit(f)
-        out = g(q_packed, new_k, new_v, kp, vp)
-        jax.block_until_ready(out)
-        t0 = time.perf_counter()
-        for _ in range(iters):
-            out = g(q_packed, new_k, new_v, kp, vp)
-        jax.block_until_ready(out)
-        return (time.perf_counter() - t0) / iters * 1e3, out
-
-    rope_ms, (o_r, kpr, vpr) = timeit(rope_fused_path)
-    pr13_ms, (o_13, kp13, vp13) = timeit(pr13_path)
-    unf_ms, (o_u, kpu, vpu) = timeit(unfused_path)
-    live = np.asarray(sorted(set(perm.tolist())))
-    kern_bitwise = bool(
-        np.array_equal(np.asarray(o_r), np.asarray(o_13))
-        and np.array_equal(np.asarray(kpr)[live], np.asarray(kp13)[live])
-        and np.array_equal(np.asarray(vpr)[live], np.asarray(vp13)[live]))
-    kern_vs_unfused = bool(
-        np.array_equal(np.asarray(kpr)[live], np.asarray(kpu)[live])
-        and np.array_equal(np.asarray(vpr)[live], np.asarray(vpu)[live])
-        and np.array_equal(np.asarray(o_r), np.asarray(o_u)))
-
-    # q8 at the existing 5%-of-scale bar vs the rope-then-write-then-
-    # read reference
-    kq = jnp.asarray(rng.randint(-127, 128,
-                                 (num_pages, kv_heads, page, dim)),
-                     jnp.int8)
-    vq = jnp.asarray(np.roll(np.asarray(kq), 1, axis=0))
-    ks = jnp.asarray(np.abs(rng.randn(num_pages, kv_heads, page, 1))
-                     .astype(np.float32) * 0.02)
-    vs = jnp.asarray(np.roll(np.asarray(ks), 1, axis=0))
-    q8_args = (jnp.asarray(np.asarray(q_packed, np.float32)),
-               jnp.asarray(np.asarray(new_k, np.float32)),
-               jnp.asarray(np.asarray(new_v, np.float32)),
-               kq, vq, tables, *args_i32, dump)
-    o8f = RPA.fused_ragged_paged_attention(
-        *q8_args, k_scale=ks, v_scale=vs, rope_sin=sin, rope_cos=cos,
-        qblock=qb)[0]
-    o8x = RPA.fused_ragged_paged_attention_xla(
-        *q8_args, k_scale=ks, v_scale=vs, rope_sin=sin, rope_cos=cos,
-        qblock=qb)[0]
-    o8f = np.asarray(getattr(o8f, "_data", o8f), np.float32)
-    o8x = np.asarray(o8x, np.float32)
-    err8 = float(np.max(np.abs(o8f - o8x)))
-    bar8 = 0.05 * max(float(np.max(np.abs(o8x))), 1.0)
-
-    # engine e2e under the three programs: same workload
-    model.eval()
-    rng2 = np.random.RandomState(1)
-    v = model.config.vocab_size
-    prompts = [rng2.randint(0, v, (int(rng2.randint(16, 96)),)).tolist()
-               for _ in range(8 if on_tpu else 3)]
-    n_new = 32 if on_tpu else 6
-
-    def e2e(**kw):
-        engine = LlamaServingEngine(
-            model, max_batch=8 if on_tpu else 2, page_size=64,
-            num_pages=72 if on_tpu else 24, max_pages_per_seq=8,
-            decode_ticks=16, **kw)
-        engine.generate(prompts, max_new_tokens=2)        # compile
-        t0 = time.perf_counter()
-        outs = engine.generate(prompts, max_new_tokens=n_new)
-        dt_ = time.perf_counter() - t0
-        # each engine's own budget-shape analysis (None under
-        # METRICS=0) — see bench_fused_kv for why not the global gauge
-        hbm = engine._mixed_bytes.get(max(engine._mixed_bytes)) \
-            if engine._mixed_bytes else None
-        engine.close()
-        return outs, sum(len(o) for o in outs) / dt_, hbm
-
-    # every arm pins BOTH knobs explicitly: an ambient
-    # PADDLE_TPU_FUSED_ROPE=0 / PADDLE_TPU_FUSED_KV=0 in the bench
-    # environment must not silently swap which program an arm measures
-    outs_r, tps_r, hbm_r = e2e(fused_kv=True, fused_rope=True)
-    outs_13, tps_13, hbm_13 = e2e(fused_kv=True, fused_rope=False)
-    outs_u, tps_u, hbm_u = e2e(fused_kv=False, fused_rope=False)
-    model.train()
-    parity = bool(kern_bitwise and kern_vs_unfused and err8 < bar8
-                  and outs_r == outs_13 == outs_u)
-    res = {
-        "fused_rope_kernel_ms": round(rope_ms, 3),
-        "fused_kv_kernel_ms": round(pr13_ms, 3),
-        "unfused_rope_kernel_ms": round(unf_ms, 3),
-        "fused_rope_kernel_speedup": round(pr13_ms / rope_ms, 3),
-        "fused_rope_parity_ok": parity,
-        "serving_fused_rope_tokens_per_sec": round(tps_r, 1),
-        "serving_fused_kv_tokens_per_sec": round(tps_13, 1),
-        "serving_unfused_rope_tokens_per_sec": round(tps_u, 1),
-        "fused_rope_e2e_speedup": round(tps_r / max(tps_13, 1e-9), 3),
-    }
-    if hbm_r is not None and hbm_13 is not None:
-        res.update({
-            "serving_mixed_hbm_bytes_fused_rope": hbm_r,
-            "serving_mixed_hbm_bytes_fused_kv": hbm_13,
-            "fused_rope_hbm_bytes_delta": hbm_13 - hbm_r,
-            "fused_rope_hbm_decreased": bool(hbm_r < hbm_13),
-        })
-        if hbm_u is not None:
-            res["serving_mixed_hbm_bytes_unfused_rope"] = hbm_u
-        if on_tpu:
-            res["fused_rope_hbm_ok"] = bool(hbm_r < hbm_13)
-    return res
 
 
 def bench_serving(model, n_requests=24, new_tokens=48, max_batch=16,
@@ -1098,8 +657,9 @@ def bench_kv_int8(model, on_tpu=True):
 
     ref = jax.jit(RPA.ragged_paged_attention_xla)(
         q, kf, vf, tables, kv_lens, q_starts, q_lens)
-    got = jax.jit(_q8_attention_fn(RPA))(
-        q, kq, vq, ks, vs, tables, kv_lens, q_starts, q_lens)
+    got = jax.jit(RPA.ragged_paged_attention_xla)(
+        q, kq, vq, tables, kv_lens, q_starts, q_lens, k_scale=ks,
+        v_scale=vs)
     err = float(jnp.max(jnp.abs(got.astype(jnp.float32)
                                 - ref.astype(jnp.float32))))
     scale = float(jnp.max(jnp.abs(ref.astype(jnp.float32))))
@@ -1132,16 +692,6 @@ def bench_kv_int8(model, on_tpu=True):
         "kv_int8_tokens_per_sec": round(
             sum(len(o) for o in outs) / dt_q8, 1),
     }
-
-
-def _q8_attention_fn(RPA):
-    """jit-able int8 ragged attention entry (module-level impl, scale
-    operands positional)."""
-    def fn(q, kq, vq, ks, vs, tables, kv_lens, q_starts, q_lens):
-        return RPA._ragged_impl_q8(
-            q, kq, vq, ks, vs, tables, kv_lens, q_starts, q_lens,
-            scale=1.0 / float(np.sqrt(q.shape[-1])))
-    return fn
 
 
 def bench_weight_int8(model, on_tpu=True):
@@ -1944,11 +1494,6 @@ def main():
         lambda: bench_paged(batch=2, heads=4, kv_heads=2, dim=32,
                             page=8, ctx=64, iters=2))
     _run_section(
-        result, "ragged",
-        bench_ragged if on_tpu else
-        lambda: bench_ragged(rows=4, qb=8, heads=4, kv_heads=2,
-                             dim=32, page=8, ctx=64, iters=2))
-    _run_section(
         result, "decode",
         lambda: bench_decode(_model(), batch=16 if on_tpu else 1,
                              prompt=128 if on_tpu else 16,
@@ -1965,20 +1510,6 @@ def main():
             max_batch=16 if on_tpu else 2,
             decode_ceiling=result.get("decode_tokens_per_sec"),
             on_tpu=on_tpu))
-    _run_section(
-        result, "fused_kv",
-        (lambda: bench_fused_kv(_model(), on_tpu=True)) if on_tpu else
-        lambda: bench_fused_kv(_model(), rows=4, qb=8, heads=4,
-                               kv_heads=2, dim=32, page=8, ctx=64,
-                               iters=2, on_tpu=False),
-        label="fused-kv")
-    _run_section(
-        result, "fused_rope",
-        (lambda: bench_fused_rope(_model(), on_tpu=True)) if on_tpu
-        else lambda: bench_fused_rope(_model(), rows=4, qb=8, heads=4,
-                                      kv_heads=2, dim=32, page=8,
-                                      ctx=64, iters=2, on_tpu=False),
-        label="fused-rope")
     _run_section(result, "cluster",
                  lambda: bench_prefix_cluster(_model(), on_tpu=on_tpu),
                  label="prefix/cluster")

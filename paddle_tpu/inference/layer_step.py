@@ -20,73 +20,14 @@ import numpy as np
 
 from ..framework.tensor import run_op
 from ..ops.ragged_paged_attention import rope_tables
-from .paged_cache import quantize_kv_int8
 
 __all__ = ["DispatchLayout", "ServingStep"]
 
 
-def _last_writer_values(new, page_ids, offs, page_slots):
-    """Pin LAST-WRITER-WINS semantics for a scatter whose (page, slot)
-    targets may repeat within one dispatch (padding tokens all aim at
-    the trash page; a chunk-boundary replay may legally re-write a
-    slot): XLA's scatter leaves duplicate-index ordering
-    implementation-defined, so instead of trusting it every duplicate's
-    update VALUE is replaced by the last writer's — identical updates
-    are order-independent by construction. The fused kernel pins the
-    same semantics (the sequence's last row owns the page write), so
-    both paths leave bitwise-identical slots. O(T^2) int compare on the
-    packed token axis — noise next to the model math."""
-    t = page_ids.shape[0]
-    key = page_ids.astype(jnp.int32) * page_slots + offs.astype(jnp.int32)
-    eq = key[:, None] == key[None, :]
-    idx_last = jnp.argmax(
-        jnp.where(eq, jnp.arange(t, dtype=jnp.int32)[None, :], -1),
-        axis=1)
-    return new[idx_last]
-
-
-def _page_write(pages, new, page_ids, offs):
-    """Functional scatter of ``new [B, Hk, D]`` into head-major ``pages
-    [P, Hk, page, D]`` at (page_ids[b], h, offs[b]) — one token per live
-    sequence. Duplicate targets resolve last-writer-wins (see
-    `_last_writer_values`)."""
-    def fn(pages, new, page_ids, offs):
-        new = _last_writer_values(new, page_ids, offs, pages.shape[2])
-        hidx = jnp.arange(pages.shape[1])[None, :]
-        return pages.at[page_ids[:, None], hidx, offs[:, None]].set(
-            new.astype(pages.dtype))
-
-    return run_op("paged_kv_write", fn, (pages, new, page_ids, offs),
-                  differentiable=False)
-
-
-def _page_write_q8(pages, scales, new, page_ids, offs):
-    """Quantizing scatter for int8 pools: ``new [B, Hk, D]`` float K/V
-    is int8-quantized per head (symmetric, absmax) and scattered into
-    ``pages [P, Hk, page, D]`` int8, with the per-head scale landing in
-    the ``scales [P, Hk, page, 1]`` sidecar at the same (page, head,
-    slot). A slot's (int8, scale) pair is always the LAST writer's —
-    duplicates are rewritten to the last value before the scatter (see
-    `_last_writer_values`), so a twice-written slot's sidecar can never
-    mix one write's int8 with another's scale."""
-    def fn(pages, scales, new, page_ids, offs):
-        new = _last_writer_values(new, page_ids, offs, pages.shape[2])
-        q, s = quantize_kv_int8(new)             # [B, Hk, D], [B, Hk]
-        hidx = jnp.arange(pages.shape[1])[None, :]
-        pages = pages.at[page_ids[:, None], hidx, offs[:, None]].set(q)
-        scales = scales.at[
-            page_ids[:, None], hidx, offs[:, None], 0].set(s)
-        return pages, scales
-
-    return run_op("paged_kv_write_q8", fn,
-                  (pages, scales, new, page_ids, offs),
-                  differentiable=False)
-
-
 def _token_gather(x, idx):
-    """Gather rows of ``x`` by an integer index array — the mixed
-    program's pack/unpack between the flat token axis [T, ...] and the
-    ragged kernel's row-blocked layout [R, QB, ...]."""
+    """Gather rows of ``x`` by an integer index array: the mixed
+    program's unpack from the ragged kernel's row-blocked layout
+    [R, QB, ...] to the flat token axis [T, ...]."""
     def fn(x, idx):
         return x[idx.astype(jnp.int32)]
 
@@ -96,7 +37,7 @@ def _token_gather(x, idx):
 
 class DispatchLayout:
     """Where each host-built field of one dispatch lies in the ONE flat
-    int32 buffer the host hands the step program: the 21 arrays
+    int32 buffer the host hands the step program: the 18 arrays
     :meth:`LlamaServingEngine._mixed_forward` takes before its pools,
     in its argument order, back to back with no padding. The host fills
     :meth:`views` of a buffer from :meth:`new` and transfers it once;
@@ -115,9 +56,6 @@ class DispatchLayout:
         spec = (
             ("tokens", (1, t), i32, 0),
             ("pos", (1, t), i32, 0),
-            ("page_ids", (t,), i32, trash_page),
-            ("offs", (t,), i32, 0),
-            ("row_tok", (r, int(qb)), i32, 0),
             ("flat_idx", (t,), i32, r * int(qb) - 1),
             ("last_idx", (r,), i32, 0),
             ("tables", (r, int(width)), i32, trash_page),
@@ -179,20 +117,18 @@ class DispatchLayout:
 class ServingStep:
     """The metadata of one packed step (see `LlamaServingEngine.
     _mixed_forward` for the shapes): ``tokens`` packed tokens in
-    ``rows`` rows of at most ``qblock`` query tokens, and the engine's
-    program switches. Tables a layer kind needs (rotary sin/cos) are
-    made once a step and shared by its layers."""
+    ``rows`` rows of at most ``qblock`` query tokens, and what the
+    engine decided of its pools (``kv_quant``, ``trash_page``). Tables
+    a layer kind needs (rotary sin/cos) are made once a step and shared
+    by its layers."""
 
-    def __init__(self, engine, pos, page_ids, offs, row_tok, flat_idx,
-                 tables, kv_lens, q_starts, q_lens, w_starts, w_flats,
-                 w_ends):
-        self.pos, self.page_ids, self.offs = pos, page_ids, offs
-        self.row_tok, self.flat_idx, self.tables = row_tok, flat_idx, tables
+    def __init__(self, engine, qblock, pos, flat_idx, tables, kv_lens,
+                 q_starts, q_lens, w_starts, w_flats, w_ends):
+        self.pos, self.flat_idx, self.tables = pos, flat_idx, tables
         self.kv_lens, self.q_starts, self.q_lens = kv_lens, q_starts, q_lens
         self.w_starts, self.w_flats, self.w_ends = w_starts, w_flats, w_ends
         self.tokens = pos.shape[1]
-        self.rows, self.qblock = row_tok.shape[0], row_tok.shape[1]
-        self.fused_rope, self.fused_kv = engine.fused_rope, engine.fused_kv
+        self.rows, self.qblock = tables.shape[0], int(qblock)
         self.kv_quant, self.trash_page = engine.kv_quant, engine.trash_page
         self._tables = {}
 
@@ -224,10 +160,6 @@ class ServingStep:
             "valid", "serving_token_valid",
             lambda ql: jnp.arange(t, dtype=jnp.int32)
             < jnp.sum(ql.astype(jnp.int32)), self.q_lens)
-
-    def pack(self, x):
-        """Packed token axis ``[T, ...]`` -> row blocks ``[R, QB, ...]``."""
-        return _token_gather(x, self.row_tok)
 
     def unpack(self, x):
         """Flattened row blocks ``[R * QB, ...]`` -> ``[T, ...]``."""

@@ -137,11 +137,9 @@ from ..observability import metrics as _om
 from ..observability import tracing as _tracing
 from ..observability.trace import record as _record_span
 from ..observability.trace import span as _span
-from ..ops.ragged_paged_attention import fused_rope_geometry_ok
 from ..testing import faults as _faults
 from .kv_tier import KvPageTier, TierError
-from .layer_step import (DispatchLayout, ServingStep,  # noqa: F401
-                         _page_write, _page_write_q8, _token_gather)
+from .layer_step import DispatchLayout, ServingStep, _token_gather
 from .paged_cache import PageAllocator
 from .sampling import SamplingParams, sampled_next_tokens
 from .speculative import NGramDrafter
@@ -367,8 +365,7 @@ def _serving_metrics():
         "mixed_hbm": _om.gauge(
             "serving_mixed_hbm_bytes",
             "static cost_analysis bytes accessed of the mixed-program "
-            "executable most recently dispatched (fused KV writes show "
-            "as a strict decrease vs PADDLE_TPU_FUSED_KV=0)"),
+            "executable most recently dispatched"),
     }
 
 
@@ -499,9 +496,8 @@ class LlamaServingEngine:
                  stuck_min_timeout=30.0, prefix_cache=True,
                  prefix_cache_pages=None, prewarm=None, kv_dtype=None,
                  spec_k=None, spec_ngram=3, drafter_factory=None,
-                 sampling=None, sample_slots=8, fused_kv=None,
-                 fused_rope=None, weight_dtype=None, weight_block=None,
-                 kv_tier=None, kv_tier_bytes=None):
+                 sampling=None, sample_slots=8, weight_dtype=None,
+                 weight_block=None, kv_tier=None, kv_tier_bytes=None):
         if num_pages is None:
             num_pages = max_batch * 24 + 8
         self.model = model
@@ -509,10 +505,9 @@ class LlamaServingEngine:
         self.max_batch = max_batch
         self.page_size = page_size
         # max_pages_per_seq sizes the block tables (the longest context
-        # a sequence may hold). The default float program's time does
-        # not follow it: its kernel walks the pages a row's kv_len
-        # holds, not the table's width. The other ragged programs
-        # (int8 pages, fused_rope=False, fused_kv=False) still run a
+        # a sequence may hold). The float program's time does not
+        # follow it: its kernel walks the pages a row's kv_len holds,
+        # not the table's width. The int8-page program still runs a
         # grid step a table slot, so there narrow tables are faster.
         #
         # Chunked-prefill scheduler knobs:
@@ -648,35 +643,6 @@ class LlamaServingEngine:
         if spec_k is None:
             spec_k = int(os.environ.get("PADDLE_TPU_SPEC_K", "0") or 0)
         self.spec_k = max(0, min(int(spec_k), self.chunk_block - 1))
-        # fused KV page write (ROADMAP item 2, first stage): the mixed
-        # program writes each token's post-rope K/V into its page
-        # INSIDE the ragged attention kernel instead of a separate
-        # scatter op per layer — one HBM round trip less per layer.
-        # PADDLE_TPU_FUSED_KV=0 restores the two-op path byte for byte
-        # (the fallback runbook lives in the README); both paths are
-        # greedy token-exact by construction.
-        if fused_kv is None:
-            fused_kv = os.environ.get(
-                "PADDLE_TPU_FUSED_KV", "1").lower() \
-                not in ("0", "false", "off")
-        self.fused_kv = bool(fused_kv)
-        # fused rotary embedding (ROADMAP item 2, second stage): the
-        # mixed program feeds PRE-rope packed q/k straight into the
-        # rope-fused kernel — rope happens in VMEM next to the page
-        # write and attention, deleting the per-layer rope elementwise
-        # op (2 HBM round trips per layer) AND the per-layer host-side
-        # q row-block gather. Requires the fused KV write (the rope
-        # rides its replay metadata); PADDLE_TPU_FUSED_ROPE=0 restores
-        # the PR-13 fused-KV path byte for byte. Geometry the rope
-        # kernel can't serve (odd head_dim, Pallas unavailable)
-        # demotes to the fused-KV path instead of crashing or crawling
-        # through an unsupported interpret lowering.
-        if fused_rope is None:
-            fused_rope = os.environ.get(
-                "PADDLE_TPU_FUSED_ROPE", "1").lower() \
-                not in ("0", "false", "off")
-        self.fused_rope = bool(fused_rope) and self.fused_kv \
-            and fused_rope_geometry_ok(cfg.head_dim)
         # per-request sampling (ROADMAP item 4): the mixed program
         # grows a vectorized per-row sample step next to the argmax —
         # every sampler knob is runtime data ([R]-shaped arrays), so
@@ -739,16 +705,17 @@ class LlamaServingEngine:
         # a silent fallback
         asked = {"kv_dtype=int8": self.kv_quant,
                  "kv_tier": self.tier is not None,
-                 "fused_kv=False": not self.fused_kv,
-                 "fused_rope=False": not self.fused_rope,
                  "spec_k": bool(self.spec_k),
-                 "weight_dtype=int8": self.weight_quant}
+                 "weight_dtype=int8": self.weight_quant,
+                 # no option: a layer names it where its one serving
+                 # program cannot rotate heads of that width
+                 f"head_dim={cfg.head_dim}": True}
         for layer in model.model.layers:
             for what in getattr(layer, "serving_unsupported", ()):
                 if asked.get(what):
                     raise UnsupportedServingFeature(
-                        f"{what} does not reach the pages of "
-                        f"{type(layer).__name__} yet")
+                        f"{type(layer).__name__} cannot serve {what} "
+                        f"yet")
         self._next_id = 0
         self._layer_stats = None    # the last dispatch's layer counters
         # ONE traced mixed-program function covers every dispatch; its
@@ -1015,11 +982,11 @@ class LlamaServingEngine:
     # ------------------------------------------------------------------
     # the mixed program: prefill chunks + decode rows, one dispatch
     # ------------------------------------------------------------------
-    def _mixed_forward(self, tokens, pos, page_ids, offs, row_tok,
-                       flat_idx, last_idx, tables, kv_lens, q_starts,
-                       q_lens, w_starts, w_flats, w_ends, temps, top_ps,
-                       top_ks, seeds, slot_ids, slot_vals, cmodes,
-                       k_pools, v_pools, k_scales, v_scales):
+    def _mixed_forward(self, tokens, pos, flat_idx, last_idx, tables,
+                       kv_lens, q_starts, q_lens, w_starts, w_flats,
+                       w_ends, temps, top_ps, top_ks, seeds, slot_ids,
+                       slot_vals, cmodes, k_pools, v_pools, k_scales,
+                       v_scales):
         """ONE token-packed model step: embed [1, T] real tokens (a mix
         of prefill-chunk tokens, speculative verify tokens and decode
         tokens, back to back with no inter-row padding), ask every
@@ -1050,13 +1017,13 @@ class LlamaServingEngine:
         was dispatched (step, scan tick, or speculative verify row).
 
         ``w_starts``/``w_flats``/``w_ends`` [R] carry the write-span
-        metadata of the programs that write pages inside their
-        attention kernel (per row: the first position of its sequence
-        this dispatch writes, that position's packed index, the
-        sequence's final kv_len); ``page_ids``/``offs``/``row_tok``
-        serve the programs that scatter first (inert otherwise).
+        metadata of the layers' attention kernels, which write pages
+        themselves (per row: the first position of its sequence this
+        dispatch writes, that position's packed index, the sequence's
+        final kv_len). The query block QB is the one of the dispatch
+        layout that has T tokens.
 
-        tokens/pos [1, T]; page_ids/offs/flat_idx [T]; row_tok [R, QB];
+        tokens/pos [1, T]; flat_idx [T];
         last_idx/kv_lens/q_starts/q_lens/w_starts/w_flats/w_ends/
         temps/top_ps/top_ks/seeds/cmodes [R]; slot_ids/slot_vals
         [R, B]; tables [R, W]; ``k_pools`` hold each layer's first
@@ -1070,13 +1037,12 @@ class LlamaServingEngine:
 
         m = self.model.model
         t = tokens.shape[1]
-        r_rows, qb = row_tok.shape[0], row_tok.shape[1]
+        r_rows, qb = tables.shape[0], self._dispatch_layout(t).shape[2]
         x = m.embed_tokens(tokens)                       # [1, T, H]
         # the step's metadata, and the tables its layers share (rotary
         # sin/cos are made once a dispatch, not once a layer)
-        step = ServingStep(self, pos, page_ids, offs, row_tok, flat_idx,
-                           tables, kv_lens, q_starts, q_lens, w_starts,
-                           w_flats, w_ends)
+        step = ServingStep(self, qb, pos, flat_idx, tables, kv_lens,
+                           q_starts, q_lens, w_starts, w_flats, w_ends)
         # every layer runs its own step over its own pages; the pools
         # it stated come first, then their scale sidecars
         held = [p for p in (k_pools, v_pools, k_scales, v_scales) if p]
@@ -1179,7 +1145,7 @@ class LlamaServingEngine:
         """The compiled entry of the mixed program: ``packed`` is one
         dispatch's metadata as :class:`DispatchLayout` lays it out
         (int32 ``[size]``); it is taken apart at static offsets into the
-        21 tensors :meth:`_mixed_forward` takes. The buffer's length
+        18 tensors :meth:`_mixed_forward` takes. The buffer's length
         names the program shape: the chunk-budget layout is always the
         longer (``chunk_budget >= 2 * max_batch``, ``rows_cap >
         max_batch``)."""
@@ -1545,10 +1511,8 @@ class LlamaServingEngine:
         # defer past the whole _entry
         buf = layout.new()
         f = layout.views(buf)
-        tokens, pos, page_ids, offs = (f["tokens"], f["pos"],
-                                       f["page_ids"], f["offs"])
-        row_tok, flat_idx, last_idx = (f["row_tok"], f["flat_idx"],
-                                       f["last_idx"])
+        tokens, pos = f["tokens"], f["pos"]
+        flat_idx, last_idx = f["flat_idx"], f["last_idx"]
         tables, kv_lens, q_starts, q_lens = (f["tables"], f["kv_lens"],
                                              f["q_starts"], f["q_lens"])
         # fused-write metadata: per row, the first position of its
@@ -1567,12 +1531,8 @@ class LlamaServingEngine:
             kv_lens[i] = start + n
             q_starts[i] = start
             q_lens[i] = n
-            pg, of = self.alloc.page_positions(sid, start, n)
             tokens[0, t:t + n] = toks
             pos[0, t:t + n] = start + np.arange(n)
-            page_ids[t:t + n] = pg
-            offs[t:t + n] = of
-            row_tok[i, :n] = np.arange(t, t + n)
             flat_idx[t:t + n] = i * qb + np.arange(n)
             flat_start.append(t)
             if sid not in seq_first:
@@ -1791,12 +1751,6 @@ class LlamaServingEngine:
                  # serving program, and the slot width shapes the bias
                  # arrays — both fork the compiled surface
                  bool(self.sample_enabled), self.sample_slots,
-                 # fused vs unfused engines compile different mixed
-                 # programs (in-kernel write vs scatter + read): a
-                 # prewarm recipe must never cross the two; same for
-                 # the rope-fused program (pre-rope packed operands +
-                 # in-kernel rotation vs the separate rope op)
-                 bool(self.fused_kv), bool(self.fused_rope),
                  # weight-only int8 forks every serving program: the
                  # projections trade one bf16 weight input for an int8
                  # weight + scale-sidecar pair (and the block size
@@ -2700,8 +2654,6 @@ class LlamaServingEngine:
         carry on device."""
         import jax
 
-        page = self.page_size
-
         def fn(tokens, tables, lens, temps, top_ps, top_ks, seeds,
                slot_ids, slot_vals, cmodes, k_pools, v_pools, k_scales,
                v_scales):
@@ -2712,7 +2664,6 @@ class LlamaServingEngine:
             ksp = [x._data for x in k_scales]
             vsp = [x._data for x in v_scales]
             rows = jnp.arange(b, dtype=jnp.int32)
-            row_tok = rows.reshape(b, 1)
             ones = jnp.ones((b,), jnp.int32)
             # sampler params are scan-invariant per row; the fold
             # position advances with the length carry, so tick i of a
@@ -2723,13 +2674,9 @@ class LlamaServingEngine:
             def body(carry, _):
                 tok, lc, kc, vc, ksc, vsc = carry
                 start = (lc - 1).astype(jnp.int32)
-                pids = tab[rows, jnp.clip(start // page, 0,
-                                          tab.shape[1] - 1)]
-                offs = (start % page).astype(jnp.int32)
                 nxt, nk, nv, nks, nvs, _ = self._mixed_forward(
                     Tensor(tok.reshape(1, b)),
                     Tensor(start.reshape(1, b)),
-                    Tensor(pids), Tensor(offs), Tensor(row_tok),
                     Tensor(rows), Tensor(rows), Tensor(tab),
                     Tensor(lc.astype(jnp.int32)), Tensor(start),
                     Tensor(ones),

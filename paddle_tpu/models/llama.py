@@ -494,98 +494,46 @@ class LlamaDecoderLayer(nn.Layer):
         a = self.self_attn
         return [(a.num_kv_heads, a.head_dim), (a.num_kv_heads, a.head_dim)]
 
+    @property
+    def serving_unsupported(self):
+        """What the engine must refuse for these pages, by name: a
+        ``head_dim`` the one serving program cannot rotate."""
+        from ..ops.ragged_paged_attention import fused_rope_geometry_ok
+
+        d = self.self_attn.head_dim
+        return () if fused_rope_geometry_ok(d) else (f"head_dim={d}",)
+
     def serving_step(self, x, step, pages):
         """One packed step of this layer over its pages (``[K, V]``, then
         their int8 scale sidecars where the engine quantizes pages):
-        ``(x, pages, None)``. With ``step.fused_kv`` the per-layer
-        scatter + read pair is ONE `fused_ragged_paged_attention` call
-        (the kernel writes each row's K/V into its pages in-grid);
-        with ``step.fused_rope`` on top the kernel also takes PRE-rope
-        packed q/k and rotates in VMEM: bitwise the fallback chain."""
-        from ..inference.layer_step import _page_write, _page_write_q8
-        from ..ops.ragged_paged_attention import (
-            fused_ragged_paged_attention, ragged_paged_attention)
+        ``(x, pages, None)``. Rope, page write and attention are ONE
+        `fused_ragged_paged_attention` call: q and k stay PRE-rope in
+        the packed token layout, the kernel slices each row's
+        contiguous tokens through the scalar-prefetched write metadata,
+        rotates them in VMEM by the step's shared sin/cos tables (no
+        transcendentals in-kernel: Mosaic and XLA then agree bit for
+        bit) and writes each row's K/V into its pages in-grid."""
+        from ..ops.ragged_paged_attention import \
+            fused_ragged_paged_attention
 
         att = self.self_attn
-        t, r_rows, qb = step.tokens, step.rows, step.qblock
-        k_pool, v_pool = pages[0], pages[1]
-        k_scale, v_scale = pages[2:4] if step.kv_quant else (None, None)
-        tables, kv_lens = step.tables, step.kv_lens
-        q_starts, q_lens = step.q_starts, step.q_lens
-        w_starts, w_flats, w_ends = step.w_starts, step.w_flats, step.w_ends
-        # the rope-fused kernel consumes the step's shared sin/cos
-        # tables directly (no transcendentals in-kernel: Mosaic and XLA
-        # then agree bit for bit); the fallback paths feed them to
-        # fused_rotary_position_embedding via sin=/cos=
+        t, qb = step.tokens, step.qblock
         rsin, rcos = step.rope(att.head_dim, float(att.config.rope_theta))
         h = self.input_layernorm(x)
-        q = att.q_proj(h).reshape([1, t, att.num_heads, att.head_dim])
-        k = att.k_proj(h).reshape([1, t, att.num_kv_heads, att.head_dim])
-        v = att.v_proj(h).reshape([1, t, att.num_kv_heads, att.head_dim])
-        if not step.fused_rope:
-            # fallback paths apply rope as a separate elementwise op
-            q, k, v = FI.fused_rotary_position_embedding(
-                q, k, v, sin=rsin, cos=rcos)
-        k2 = k.reshape([t, att.num_kv_heads, att.head_dim])
-        v2 = v.reshape([t, att.num_kv_heads, att.head_dim])
-        ksc = vsc = None
-        if step.fused_rope:
-            # rope + page write + attention in ONE kernel: q stays
-            # PRE-rope in the packed token layout; the kernel slices
-            # each row's contiguous tokens through the scalar-prefetched
-            # write metadata
-            q3 = q.reshape([t, att.num_heads, att.head_dim])
-            if step.kv_quant:
-                attn4, kp, vp, ksc, vsc = fused_ragged_paged_attention(
-                    q3, k2, v2, k_pool, v_pool, tables, kv_lens, q_starts,
-                    q_lens, w_starts, w_flats, w_ends, step.trash_page,
-                    k_scale=k_scale, v_scale=v_scale, rope_sin=rsin,
-                    rope_cos=rcos, qblock=qb)
-            else:
-                attn4, kp, vp = fused_ragged_paged_attention(
-                    q3, k2, v2, k_pool, v_pool, tables, kv_lens, q_starts,
-                    q_lens, w_starts, w_flats, w_ends, step.trash_page,
-                    rope_sin=rsin, rope_cos=rcos, qblock=qb)
-        else:
-            # pack the flat token axis into the kernel's [R, QB] rows
-            q4 = step.pack(q.reshape([t, att.num_heads, att.head_dim]))
-            if step.fused_kv:
-                # ONE kernel writes this dispatch's K/V into the pages
-                # AND attends through them (in-grid replay keeps later
-                # chunks of one prompt coherent with earlier rows of
-                # the same dispatch)
-                if step.kv_quant:
-                    attn4, kp, vp, ksc, vsc = \
-                        fused_ragged_paged_attention(
-                            q4, k2, v2, k_pool, v_pool, tables, kv_lens,
-                            q_starts, q_lens, w_starts, w_flats, w_ends,
-                            step.trash_page, k_scale=k_scale,
-                            v_scale=v_scale)
-                else:
-                    attn4, kp, vp = fused_ragged_paged_attention(
-                        q4, k2, v2, k_pool, v_pool, tables, kv_lens,
-                        q_starts, q_lens, w_starts, w_flats, w_ends,
-                        step.trash_page)
-            else:
-                # unfused reference path: scatter every row's K/V
-                # first, then attend: a later chunk of the same
-                # sequence attends what the scatter just wrote
-                if step.kv_quant:
-                    kp, ksc = _page_write_q8(k_pool, k_scale, k2,
-                                             step.page_ids, step.offs)
-                    vp, vsc = _page_write_q8(v_pool, v_scale, v2,
-                                             step.page_ids, step.offs)
-                else:
-                    kp = _page_write(k_pool, k2, step.page_ids, step.offs)
-                    vp = _page_write(v_pool, v2, step.page_ids, step.offs)
-                attn4 = ragged_paged_attention(q4, kp, vp, tables,
-                                               kv_lens, q_starts, q_lens,
-                                               k_scale=ksc, v_scale=vsc)
-        attn = step.unpack(attn4.reshape([r_rows * qb, att.num_heads,
+        q = att.q_proj(h).reshape([t, att.num_heads, att.head_dim])
+        k = att.k_proj(h).reshape([t, att.num_kv_heads, att.head_dim])
+        v = att.v_proj(h).reshape([t, att.num_kv_heads, att.head_dim])
+        sidecars = dict(k_scale=pages[2], v_scale=pages[3]) \
+            if step.kv_quant else {}
+        attn4, *pages = fused_ragged_paged_attention(
+            q, k, v, pages[0], pages[1], step.tables, step.kv_lens,
+            step.q_starts, step.q_lens, step.w_starts, step.w_flats,
+            step.w_ends, step.trash_page, rope_sin=rsin, rope_cos=rcos,
+            qblock=qb, **sidecars)
+        attn = step.unpack(attn4.reshape([step.rows * qb, att.num_heads,
                                           att.head_dim]))
         x = x + att.o_proj(attn.reshape([1, t, -1]))
         x = x + self.mlp(self.post_attention_layernorm(x))
-        pages = [kp, vp] + ([ksc, vsc] if step.kv_quant else [])
         return x, pages, None
 
 

@@ -353,8 +353,7 @@ class MlaMoeDecoderLayer(nn.Layer):
 
     # -- what the serving engine asks of a layer -----------------------
     #: engine features that do not reach latent pages yet
-    serving_unsupported = ("kv_dtype=int8", "kv_tier", "fused_kv=False",
-                           "fused_rope=False", "spec_k",
+    serving_unsupported = ("kv_dtype=int8", "kv_tier", "spec_k",
                            "weight_dtype=int8")
 
     def serving_cache(self):

@@ -5,29 +5,35 @@ single TPU kernel that consumes a batch of variable-length prefill
 chunks AND single-token decode rows over a shared paged KV pool, so a
 serving scheduler never has to serialize the two phases into separate
 dispatches. This is the kernel behind the chunked-prefill serving
-engine (`paddle_tpu/inference/serving.py`): every engine step is one
-dispatch of this kernel over rows described by per-row
-``(query_len, kv_len)`` metadata, whether the row is a 128-token
-prompt chunk or one decode token.
+engine (`paddle_tpu/inference/serving.py`): every layer of every engine
+step is one call of `fused_ragged_paged_attention` over rows described
+by per-row ``(query_len, kv_len)`` metadata, whether the row is a
+128-token prompt chunk or one decode token. The call ropes q and the
+new K, writes the new K/V rows into their pages and attends through
+them, all in one Pallas program: there is one program a pool dtype
+(float pools: `_fused_rope_kernel`; int8 pools with scale sidecars:
+`_fused_rope_kernel_q8`) and nothing selects between programs.
 
-Shapes (R rows, each a prefill chunk or a decode step of one sequence):
-  q             [R, QB, H, D]       per-row query block; rows are padded
-                                    to the static block QB — entries at
-                                    qi >= q_lens[r] are padding and come
-                                    back as zeros
+Shapes (T packed tokens in R rows, each a prefill chunk or a decode
+step of one sequence, rows padded to the static query block QB):
+  q             [T, H, D]           PRE-rope, in the packed token
+                                    layout: row r's query tokens sit
+                                    contiguously at ``w_flats[r] +
+                                    q_starts[r] - w_starts[r]``
+  new_k, new_v  [T, Hk, D]          the dispatch's packed K/V rows
+                                    (new_k PRE-rope)
+  rope_sin/cos  [T, D] f32          per-dispatch tables, one row a
+                                    packed token (`rope_tables`: neox
+                                    duplicated-half layout, computed
+                                    once a dispatch, shared by layers)
   k_pages       [P, Hk, page, D]    global pool, head-major (same layout
                                     as `paged_attention`)
   v_pages       [P, Hk, page, D]
-  k_scale       [P, Hk, page, 1]    OPTIONAL f32 dequant sidecars for
-  v_scale       [P, Hk, page, 1]    int8 pools: per-head per-slot
-                                    symmetric scales written by
-                                    `quantize_kv_int8` — the kernel's
-                                    kv loop dequantizes
-                                    ``int8 * scale`` in f32 before the
-                                    softmax, so int8 pages halve (bf16)
-                                    or quarter (f32) HBM page bytes
-                                    with no change to the attention
-                                    math's accumulation order
+  k_scale       [P, Hk, page, 1]    f32 dequant sidecars of int8 pools:
+  v_scale       [P, Hk, page, 1]    per-head per-slot symmetric scales,
+                                    the math of `quantize_kv_int8`; the
+                                    kernel dequantizes ``int8 * scale``
+                                    in f32 before the softmax
   block_tables  [R, W] int32        page ids per ROW's sequence (tail
                                     entries clamped into [0, P))
   kv_lens       [R] int32           total context of the row's sequence
@@ -38,57 +44,23 @@ Shapes (R rows, each a prefill chunk or a decode step of one sequence):
   q_lens        [R] int32           valid query tokens in the row
                                     (1 for decode rows, up to QB for
                                     prefill chunks)
-  -> out        [R, QB, H, D]
+  w_starts      [R] int32           first position of the row's sequence
+                                    that THIS dispatch writes
+  w_flats       [R] int32           that position's packed index
+  w_ends        [R] int32           the sequence's final kv_len in this
+                                    dispatch (its last row writes back)
+  -> out        [R, QB, H, D]       entries at qi >= q_lens[r] are zeros
+     and the updated pools (and sidecars), aliased onto the inputs
 
 Semantics: query token qi of row r sits at absolute position
 ``p = q_starts[r] + qi`` and attends kv positions ``[0, p]`` (causal)
-clipped to ``[0, kv_lens[r])``. In the per-page programs a decode row
-(q_len 1, q_start = kv_len - 1) reduces EXACTLY to `paged_attention`'s
-math — the same online-softmax update in the same order — so their
-decode tokens are bitwise-identical to the decode-only kernel (the
-float rope-fused program's are equal to rounding, below). Two chunks of the same
-sequence may appear as two rows of one batch (same block table,
-consecutive q_starts): their K/V must already be in the pool, which the
-serving engine guarantees by scattering every row's K/V before the
-attention of any row.
-
-The per-page programs (`_ragged_kernel`, `_fused_kernel` and the three
-``_q8`` ones) run grid (R, Hk, W) with one online-softmax accumulator
-in VMEM scratch per (row, kv-head); the prefetched block table picks
-which HBM page each grid step streams into VMEM, and a step at or past
-``kv_lens[r]`` skips the arithmetic but still pays its step and its
-page fetch: their time follows the table's width W. Inference-only: no
-VJP.
-
-The float rope-fused program (`_fused_rope_kernel`, the serving
-engine's default) walks the K/V a row HOLDS instead: grid (R,), the
-pools left in HBM, and inside a row a loop of ``ceil(kv_lens[r] /
-(B*page))`` trips over blocks of B pages that the kernel fetches
-itself through the table (one DMA a page for all kv heads, the next
-block in flight while this one is computed) — no trip for an inactive
-row, the same trips whatever W is. It makes ONE softmax update a block
-and head, not one a page, so its attention output equals the XLA
-reference's and the per-page programs' to float rounding (1e-5
-relative in f32), no longer bit for bit, and one of its decode rows no
-longer reduces bitwise to `paged_attention`; the pool bytes it writes
-stay bitwise (the write path's arithmetic is the per-page programs').
-
-Fused KV write (`fused_ragged_paged_attention`): the first step toward
-the per-layer decode megakernel (ROADMAP item 2; MPK arXiv 2512.22219,
-Neptune arXiv 2510.08726). The serving engine's unfused step scatters
-the current tokens' post-rope K/V into the pools with a separate XLA
-op, then this kernel re-reads the same pages through the same block
-tables — an HBM round trip per layer at exactly the producer/consumer
-locality boundary both papers name. The fused variant takes the packed
-new K/V rows (``new_k/new_v [T, Hk, D]``, the flat token axis of the
-mixed dispatch) plus per-row write metadata and performs the page write
-INSIDE the Pallas program, returning the updated pools through
-aliased outputs (`input_output_aliases`), so the scatter op — and its
-round trip — disappears.
+clipped to ``[0, kv_lens[r])``. Two chunks of the same sequence may
+appear as two rows of one batch (same block table, consecutive
+q_starts). Inference-only: no VJP.
 
 Ordering contract (the subtlety): later prefill chunks of one prompt
 may sit in the SAME grid as the rows that produce the K/V they must
-attend. The kernel does not rely on in-kernel write-then-read
+attend. The kernels do not rely on in-kernel write-then-read
 visibility at all — pipelined page fetches may legally race in-kernel
 writes. Instead every row REPLAYS the dispatch's writes on read:
 positions ``[w_start[r], kv_lens[r])`` of row r's sequence were
@@ -96,46 +68,43 @@ written by rows <= r of this dispatch and are overlaid from the packed
 ``new_k/new_v`` rows (their flat indices are affine in the position:
 chunks of one sequence are packed contiguously in position order, so
 position p lives at flat index ``w_flat[r] + p - w_start[r]``); only
-positions below ``w_start[r]`` come from the streamed page. The HBM
+positions below ``w_start[r]`` come from the fetched page. The HBM
 write-back itself is done ONCE per page, by the sequence's LAST row in
 the dispatch (``kv_lens[r] == w_end[r]``) — no page is the write
-target of two grid steps, so no copy-out ordering between steps is
-ever required. Grid steps of the per-page programs whose page holds
-no new token write to the caller-designated ``dump_page`` (the serving
-engine's trash page); the float rope-fused program writes a page by a
-DMA of its own and a step with nothing to write writes nothing.
-The q8 path quantizes the fresh rows in-kernel with bitwise the same
-math as ``quantize_kv_int8`` (per-head-per-slot symmetric absmax
-scales into the ``[P, Hk, page, 1]`` sidecars), so fused and unfused
-pools agree bit for bit.
+target of two steps, so no copy-out ordering between steps is ever
+required.
 
-`ragged_paged_attention_xla` stays a WRITE-THEN-READ exact-parity
-reference on purpose: two dependent XLA ops have unambiguous
-sequential semantics, which is what the fused kernel's replay must be
-proven against (`fused_ragged_paged_attention_xla` composes them).
+The rotation happens in VMEM — ``x * cos + rotate_half(x) * sin`` in
+f32, cast back to the model dtype — before the write/attention math:
+the transcendentals live in the XLA-computed tables, so the kernels
+add only IEEE-exact multiplies and adds, and the pool bytes they write
+are bitwise those of the rope-then-scatter reference.
 
-Fused rotary embedding (ROADMAP item 2, second stage): passing
-``rope_sin``/``rope_cos`` — per-dispatch ``[T, D]`` f32 tables, one row
-per PACKED token (``sin(pos * inv_freq)`` with the neox duplicated-half
-layout, computed ONCE per dispatch and shared by every layer) — makes
-the fused kernel consume PRE-rope operands: ``q`` arrives in the packed
-token layout ``[T, H, D]`` (no host-side row-block gather; each row's
-query tokens sit contiguously on the packed axis at
-``w_flat[r] + q_start[r] - w_start[r]``, the same affine replay index
-the KV overlay already uses, so the kernel slices them with the
-scalar-prefetched metadata) and ``new_k`` is the pre-rope packed K.
-The kernel applies the rotation in VMEM — ``x * cos +
-rotate_half(x) * sin`` in f32, cast back to the model dtype — before
-the write/attention math, with bitwise the same value chain as the
-unfused ``fused_rotary_position_embedding`` + scatter pipeline: the
-transcendentals live in the XLA-computed tables, so the kernel adds
-only IEEE-exact multiplies/adds and the pool bytes stay bitwise across
-all three paths (rope-fused / PR-13 fused-KV / two-op); the int8
-rope-fused program's outputs do too, the float one's to rounding. ``qblock`` (the row-block width the caller's metadata was
-built for) becomes an explicit argument because packed q no longer
-carries it. This deletes the per-layer rope elementwise op (2 HBM
-round trips per layer: q and k) and the per-layer q gather from the
-mixed program.
+The float program (`_fused_rope_kernel`, every float-pool engine's)
+walks the K/V a row HOLDS: grid (R,), the pools left in HBM, and
+inside a row a loop of ``ceil(kv_lens[r] / (B*page))`` trips over
+blocks of B pages that the kernel fetches itself through the table
+(one DMA a page for all kv heads, the next block in flight while this
+one is computed) — no trip for an inactive row, the same trips
+whatever W is. It makes ONE softmax update a block and head, so its
+attention output equals the XLA reference's to float rounding (1e-5
+relative in f32); a page is written by a DMA of its own and a step
+with nothing to write writes nothing.
+
+The int8 program (`_fused_rope_kernel_q8`) is still a per-page grid
+(R, Hk, W) with one online-softmax accumulator in VMEM scratch per
+(row, kv-head): the prefetched block table picks which HBM page each
+grid step streams into VMEM, and a step at or past ``kv_lens[r]``
+skips the arithmetic but still pays its step and its page fetch, so
+its time follows the table's width W. Steps whose page holds no new
+token write to the caller-designated ``dump_page`` (the serving
+engine's trash page). It quantizes the fresh rows in-kernel with
+bitwise the math of ``quantize_kv_int8``.
+
+`ragged_paged_attention_xla` and `fused_ragged_paged_attention_xla`
+(rope, THEN scatter, THEN read) are the reference the tests hold both
+programs to: dependent XLA ops have unambiguous sequential semantics,
+which is what the kernels' replay must reproduce.
 """
 
 from __future__ import annotations
@@ -156,8 +125,7 @@ except ImportError:  # pragma: no cover
 
 from ..framework.tensor import run_op
 
-__all__ = ["ragged_paged_attention", "ragged_paged_attention_xla",
-           "supported", "fused_ragged_paged_attention",
+__all__ = ["ragged_paged_attention_xla", "fused_ragged_paged_attention",
            "fused_ragged_paged_attention_xla", "fused_supported",
            "fused_rope_geometry_ok", "rope_tables"]
 
@@ -168,49 +136,15 @@ def _interpret():
     return jax.default_backend() != "tpu"
 
 
-def supported(q, k_pages, v_pages, block_tables, kv_lens, q_starts,
-              q_lens, k_scale=None, v_scale=None):
-    if not _HAS_PLTPU:
-        return False
-    if (k_scale is None) != (v_scale is None):
-        return False
-    if k_scale is not None:
-        ks = getattr(k_scale, "_data", k_scale)
-        vs = getattr(v_scale, "_data", v_scale)
-        want = tuple(getattr(k_pages, "_data", k_pages).shape[:3]) + (1,)
-        if tuple(ks.shape) != want or tuple(vs.shape) != want:
-            return False
-    qs = getattr(q, "_data", q).shape
-    ks = getattr(k_pages, "_data", k_pages).shape
-    bt = getattr(block_tables, "_data", block_tables).shape
-    shapes1 = [getattr(a, "_data", a).shape
-               for a in (kv_lens, q_starts, q_lens)]
-    if len(qs) != 4 or len(ks) != 4 or len(bt) != 2 \
-            or any(len(s) != 1 for s in shapes1):
-        return False
-    r, qb, h, d = qs
-    p, hk, page_size, dk = ks
-    if getattr(v_pages, "_data", v_pages).shape != tuple(ks):
-        return False
-    if d != dk or hk == 0 or h % hk or bt[0] != r:
-        return False
-    if any(s[0] != r for s in shapes1):
-        return False
-    if d % 8 or d > 256 or page_size % 8 or qb < 1:
-        return False
-    return True
-
-
 def _softmax_accumulate(q, k, v, page_start, q_start, q_len, ctx,
                         group, acc_ref, m_ref, l_ref):
     """ONE step of the shared online-softmax update over the K/V
     positions ``[page_start, page_start + len(k))``: causal/ragged
-    masking, running max/sum rescale, accumulator update. Every kernel
-    in this module calls exactly this body — the engine's cross-path
-    parity contract requires the accumulation math to be maintained in
-    ONE place, never per-kernel copies. ``q`` ``[rows, D]`` is
-    pre-scaled f32; ``k``/``v`` f32, ``[page, D]`` from the per-page
-    programs and ``[B*page, D]`` from the float rope-fused walk."""
+    masking, running max/sum rescale, accumulator update. Both kernels
+    call exactly this body: the accumulation math is maintained in ONE
+    place, never per-kernel copies. ``q`` ``[rows, D]`` is pre-scaled
+    f32; ``k``/``v`` f32, ``[page, D]`` from the int8 program's
+    per-page grid and ``[B*page, D]`` from the float program's walk."""
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
     kpos = page_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -246,246 +180,11 @@ def _softmax_finish(o_ref, acc_ref, l_ref):
     o_ref[0, 0] = jnp.where(l > 0.0, out, 0.0).astype(o_ref.dtype)
 
 
-def _ragged_kernel(tables_ref, kv_lens_ref, q_starts_ref, q_lens_ref,
-                   q_ref, k_ref, v_ref, o_ref,
-                   acc_ref, m_ref, l_ref, *, page_size, group, scale):
-    r = pl.program_id(0)
-    p = pl.program_id(2)
-    num_pages = pl.num_programs(2)
-
-    @pl.when(p == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    ctx = kv_lens_ref[r]
-    page_start = p * page_size
-
-    @pl.when(page_start < ctx)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale      # [QB*G, D]
-        k = k_ref[0, 0].astype(jnp.float32)              # [page, D]
-        v = v_ref[0, 0].astype(jnp.float32)
-        _softmax_accumulate(q, k, v, page_start, q_starts_ref[r],
-                            q_lens_ref[r], ctx, group, acc_ref, m_ref,
-                            l_ref)
-
-    @pl.when(p == num_pages - 1)
-    def _finish():
-        _softmax_finish(o_ref, acc_ref, l_ref)
-
-
-def _ragged_kernel_q8(tables_ref, kv_lens_ref, q_starts_ref, q_lens_ref,
-                      q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref,
-                      acc_ref, m_ref, l_ref, *, page_size, group, scale):
-    """Int8-pool variant: identical online-softmax math to
-    `_ragged_kernel`, with the streamed K/V page dequantized in f32
-    (``int8 * per-slot scale``) before the dot products. Kept separate
-    so the float path's decode-bitwise contract stays untouched."""
-    r = pl.program_id(0)
-    p = pl.program_id(2)
-    num_pages = pl.num_programs(2)
-
-    @pl.when(p == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    ctx = kv_lens_ref[r]
-    page_start = p * page_size
-
-    @pl.when(page_start < ctx)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale      # [QB*G, D]
-        # dequantize the page in VMEM: [page, D] int8 * [page, 1] f32
-        k = k_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0]
-        v = v_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0]
-        _softmax_accumulate(q, k, v, page_start, q_starts_ref[r],
-                            q_lens_ref[r], ctx, group, acc_ref, m_ref,
-                            l_ref)
-
-    @pl.when(p == num_pages - 1)
-    def _finish():
-        _softmax_finish(o_ref, acc_ref, l_ref)
-
-
-@functools.lru_cache(maxsize=32)
-def _make_ragged_q8(scale, page_size, qb, group, interpret):
-    def call(q4, k_pages, v_pages, k_scale, v_scale, tables, kv_lens,
-             q_starts, q_lens):
-        r, hk, qbg, d = q4.shape
-        max_pages = tables.shape[1]
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=(r, hk, max_pages),
-            in_specs=[
-                pl.BlockSpec((1, 1, qbg, d),
-                             lambda ri, hi, pi, *refs: (ri, hi, 0, 0)),
-                pl.BlockSpec((1, 1, page_size, d),
-                             lambda ri, hi, pi, tables, *refs:
-                             (tables[ri, pi], hi, 0, 0)),
-                pl.BlockSpec((1, 1, page_size, d),
-                             lambda ri, hi, pi, tables, *refs:
-                             (tables[ri, pi], hi, 0, 0)),
-                # the scale sidecars stream with their page
-                pl.BlockSpec((1, 1, page_size, 1),
-                             lambda ri, hi, pi, tables, *refs:
-                             (tables[ri, pi], hi, 0, 0)),
-                pl.BlockSpec((1, 1, page_size, 1),
-                             lambda ri, hi, pi, tables, *refs:
-                             (tables[ri, pi], hi, 0, 0)),
-            ],
-            out_specs=pl.BlockSpec(
-                (1, 1, qbg, d),
-                lambda ri, hi, pi, *refs: (ri, hi, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((qbg, d), jnp.float32),
-                pltpu.VMEM((qbg, 1), jnp.float32),
-                pltpu.VMEM((qbg, 1), jnp.float32),
-            ],
-        )
-        return pl.pallas_call(
-            functools.partial(_ragged_kernel_q8, page_size=page_size,
-                              group=group, scale=scale),
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((r, hk, qbg, d), q4.dtype),
-            interpret=interpret,
-            name="paddle_tpu.ragged_attn_q8",
-        )(tables, kv_lens, q_starts, q_lens, q4, k_pages, v_pages,
-          k_scale, v_scale)
-
-    return call
-
-
-@functools.lru_cache(maxsize=32)
-def _make_ragged(scale, page_size, qb, group, interpret):
-    def call(q4, k_pages, v_pages, tables, kv_lens, q_starts, q_lens):
-        r, hk, qbg, d = q4.shape
-        max_pages = tables.shape[1]
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=(r, hk, max_pages),
-            in_specs=[
-                pl.BlockSpec((1, 1, qbg, d),
-                             lambda ri, hi, pi, *refs: (ri, hi, 0, 0)),
-                # the prefetched block table picks the HBM page to stream
-                pl.BlockSpec((1, 1, page_size, d),
-                             lambda ri, hi, pi, tables, *refs:
-                             (tables[ri, pi], hi, 0, 0)),
-                pl.BlockSpec((1, 1, page_size, d),
-                             lambda ri, hi, pi, tables, *refs:
-                             (tables[ri, pi], hi, 0, 0)),
-            ],
-            out_specs=pl.BlockSpec(
-                (1, 1, qbg, d),
-                lambda ri, hi, pi, *refs: (ri, hi, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((qbg, d), jnp.float32),
-                pltpu.VMEM((qbg, 1), jnp.float32),
-                pltpu.VMEM((qbg, 1), jnp.float32),
-            ],
-        )
-        return pl.pallas_call(
-            functools.partial(_ragged_kernel, page_size=page_size,
-                              group=group, scale=scale),
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((r, hk, qbg, d), q4.dtype),
-            interpret=interpret,
-            name="paddle_tpu.ragged_attn",
-        )(tables, kv_lens, q_starts, q_lens, q4, k_pages, v_pages)
-
-    return call
-
-
-def _ragged_impl_q8(q, k_pages, v_pages, k_scale, v_scale, block_tables,
-                    kv_lens, q_starts, q_lens, scale):
-    r, qb, h, d = q.shape
-    hk = k_pages.shape[1]
-    group = h // hk
-    page_size = k_pages.shape[2]
-    q4 = q.reshape(r, qb, hk, group, d).transpose(0, 2, 1, 3, 4) \
-        .reshape(r, hk, qb * group, d)
-    call = _make_ragged_q8(scale, page_size, qb, group, _interpret())
-    tables = jnp.clip(block_tables.astype(jnp.int32), 0,
-                      k_pages.shape[0] - 1)
-    out = call(q4, k_pages, v_pages, k_scale.astype(jnp.float32),
-               v_scale.astype(jnp.float32), tables,
-               kv_lens.astype(jnp.int32), q_starts.astype(jnp.int32),
-               q_lens.astype(jnp.int32))
-    return out.reshape(r, hk, qb, group, d).transpose(0, 2, 1, 3, 4) \
-        .reshape(r, qb, h, d)
-
-
-def _ragged_impl(q, k_pages, v_pages, block_tables, kv_lens, q_starts,
-                 q_lens, scale):
-    r, qb, h, d = q.shape
-    hk = k_pages.shape[1]
-    group = h // hk
-    page_size = k_pages.shape[2]
-    # [R, QB, Hk, G, D] -> [R, Hk, QB*G, D]: one MXU operand per
-    # (row, kv-head) with the GQA group riding inside the query block
-    q4 = q.reshape(r, qb, hk, group, d).transpose(0, 2, 1, 3, 4) \
-        .reshape(r, hk, qb * group, d)
-    call = _make_ragged(scale, page_size, qb, group, _interpret())
-    # clamp table tails (see paged_attention): they feed the index map
-    tables = jnp.clip(block_tables.astype(jnp.int32), 0,
-                      k_pages.shape[0] - 1)
-    out = call(q4, k_pages, v_pages, tables, kv_lens.astype(jnp.int32),
-               q_starts.astype(jnp.int32), q_lens.astype(jnp.int32))
-    return out.reshape(r, hk, qb, group, d).transpose(0, 2, 1, 3, 4) \
-        .reshape(r, qb, h, d)
-
-
-def ragged_paged_attention(q, k_pages, v_pages, block_tables, kv_lens,
-                           q_starts, q_lens, scale=None, k_scale=None,
-                           v_scale=None):
-    """Mixed prefill+decode attention over the paged pool (see module
-    docstring). Tape-integrated but non-differentiable (serving path).
-    Pass ``k_scale``/``v_scale`` sidecars ([P, Hk, page, 1] f32) with
-    int8 pools — the kernel dequantizes inside its kv loop."""
-    if not supported(q, k_pages, v_pages, block_tables, kv_lens,
-                     q_starts, q_lens, k_scale, v_scale):
-        raise ValueError(
-            "ragged_paged_attention preconditions not met: need q "
-            "[R,QB,H,D], pages [P,Hk,page,D] (page % 8 == 0, D % 8 == 0, "
-            "D <= 256, H % Hk == 0), tables [R,max_pages], kv_lens/"
-            "q_starts/q_lens [R]; int8 pools need BOTH k_scale/v_scale "
-            "sidecars shaped [P,Hk,page,1]")
-    d = getattr(q, "_data", q).shape[-1]
-    s = scale if scale is not None else 1.0 / math.sqrt(d)
-
-    if k_scale is not None:
-        def fn_q8(q, kp, vp, ks, vs, bt, kl, qs, ql):
-            return _ragged_impl_q8(q, kp, vp, ks, vs, bt, kl, qs, ql, s)
-
-        return run_op("ragged_paged_attention_q8", fn_q8,
-                      (q, k_pages, v_pages, k_scale, v_scale,
-                       block_tables, kv_lens, q_starts, q_lens),
-                      differentiable=False)
-
-    def fn(q, kp, vp, bt, kl, qs, ql):
-        return _ragged_impl(q, kp, vp, bt, kl, qs, ql, s)
-
-    return run_op("ragged_paged_attention", fn,
-                  (q, k_pages, v_pages, block_tables, kv_lens, q_starts,
-                   q_lens), differentiable=False)
-
-
-# ----------------------------------------------------------------------
-# fused KV page write (ROADMAP item 2, first stage): the page write of
-# the current dispatch's tokens happens INSIDE the attention kernel —
-# see the module docstring for the replay/ordering contract.
-# ----------------------------------------------------------------------
-
 def fused_rope_geometry_ok(head_dim):
-    """Cheap static gate for the rope-fused kernel: Pallas must be
-    importable and the head_dim even (the neox rotation splits it in
-    half). The serving engine consults this at construction and
-    demotes ``fused_rope`` to the PR-13 fused-KV path (never a crash,
-    never an interpret-mode crawl through an unsupported lowering)
-    when it fails."""
+    """Cheap static gate of both programs: Pallas must be importable
+    and the head_dim even (the neox rotation splits it in half). The
+    serving engine consults it at construction and refuses a layer it
+    fails by name; there is no other program to fall back to."""
     return _HAS_PLTPU and head_dim % 2 == 0 and head_dim >= 2
 
 
@@ -495,9 +194,8 @@ def rope_tables(pos, head_dim, base):
     concat([ang, ang])``). Bitwise the same values
     `fused_rotary_position_embedding` derives from ``position_ids`` —
     the single source of the angle formula, computed ONCE per dispatch
-    and shared by every layer (fused kernel and unfused fallback
-    alike). ``pos`` is any integer array; it is flattened to ``[T]``.
-    Pure jnp — safe under jit/trace."""
+    and shared by every layer. ``pos`` is any integer array; it is
+    flattened to ``[T]``. Pure jnp — safe under jit/trace."""
     inv = 1.0 / (base ** (jnp.arange(0, head_dim, 2,
                                      dtype=jnp.float32) / head_dim))
     ang = pos.reshape(-1).astype(jnp.float32)[:, None] * inv  # [T, D/2]
@@ -507,133 +205,47 @@ def rope_tables(pos, head_dim, base):
 
 def fused_supported(q, new_k, new_v, k_pages, v_pages, block_tables,
                     kv_lens, q_starts, q_lens, w_starts, w_flats,
-                    w_ends, dump_page, k_scale=None, v_scale=None,
-                    rope_sin=None, rope_cos=None, qblock=None):
-    """Preconditions of the fused kernel: everything `supported`
-    checks, plus packed new-row operands ``new_k/new_v [T, Hk, D]``
-    (T >= 1), per-row write metadata ``w_starts/w_flats/w_ends [R]``
-    and a valid ``dump_page`` id (a page no live table references —
-    grid steps with nothing to write dump their page-sized output
-    there). With ``rope_sin``/``rope_cos`` (the rope-fused variant) q
-    switches to the packed pre-rope ``[T, H, D]`` layout, the tables
-    must be ``[T, D]`` and ``qblock`` (the row-block width) must be
-    given explicitly."""
-    if (rope_sin is None) != (rope_cos is None):
+                    w_ends, dump_page, *, rope_sin, rope_cos, qblock,
+                    k_scale=None, v_scale=None):
+    """Preconditions of `fused_ragged_paged_attention` (shapes in the
+    module docstring): packed pre-rope ``q [T, H, D]`` with ``new_k/
+    new_v [T, Hk, D]`` (T >= 1) and sin/cos tables ``[T, D]``, pools
+    ``[P, Hk, page, D]`` (page % 8 == 0, D % 8 == 0, D <= 256 and even,
+    H % Hk == 0), tables ``[R, W]``, the seven per-row arrays ``[R]``,
+    ``qblock >= 1``, a ``dump_page`` id inside the pool (a page no live
+    table references) and, for int8 pools, BOTH scale sidecars shaped
+    ``[P, Hk, page, 1]``."""
+    def shape(a):
+        return tuple(getattr(a, "_data", a).shape)
+
+    if not _HAS_PLTPU or (k_scale is None) != (v_scale is None):
         return False
-    if rope_sin is not None:
-        qs = getattr(q, "_data", q).shape
-        nk = getattr(new_k, "_data", new_k)
-        bt = getattr(block_tables, "_data", block_tables)
-        if len(qs) != 3 or len(nk.shape) != 3 or len(bt.shape) != 2:
-            return False
-        t, h, d = (int(x) for x in qs)
-        if qblock is None or int(qblock) < 1 or t != nk.shape[0]:
-            return False
-        if not fused_rope_geometry_ok(d):
-            return False
-        want = (t, d)
-        for tb in (rope_sin, rope_cos):
-            if tuple(getattr(tb, "_data", tb).shape) != want:
-                return False
-        hk = getattr(k_pages, "_data", k_pages).shape[1]
-        if hk == 0 or h % hk:
-            return False
-        # remaining checks ride the non-rope validation with a
-        # shape-only proxy for the row-blocked q the metadata implies
-        proxy = jax.ShapeDtypeStruct((bt.shape[0], int(qblock), h, d),
-                                     jnp.float32)
-        return fused_supported(proxy, new_k, new_v, k_pages, v_pages,
-                               block_tables, kv_lens, q_starts, q_lens,
-                               w_starts, w_flats, w_ends, dump_page,
-                               k_scale, v_scale)
-    if not supported(q, k_pages, v_pages, block_tables, kv_lens,
-                     q_starts, q_lens, k_scale, v_scale):
+    qs, ks, bt = shape(q), shape(k_pages), shape(block_tables)
+    if len(qs) != 3 or len(ks) != 4 or len(bt) != 2 \
+            or shape(v_pages) != ks:
         return False
-    r = getattr(q, "_data", q).shape[0]
-    p, hk, _, d = getattr(k_pages, "_data", k_pages).shape
-    for a in (w_starts, w_flats, w_ends):
-        if tuple(getattr(a, "_data", a).shape) != (r,):
-            return False
-    nk = getattr(new_k, "_data", new_k)
-    nv = getattr(new_v, "_data", new_v)
-    if len(nk.shape) != 3 or tuple(nk.shape) != tuple(nv.shape):
+    t, h, d = qs
+    p, hk, page_size, dk = ks
+    r = bt[0]
+    if k_scale is not None and not (
+            shape(k_scale) == shape(v_scale) == ks[:3] + (1,)):
         return False
-    t, nhk, nd = nk.shape
-    if t < 1 or nhk != hk or nd != d:
+    if d != dk or hk == 0 or h % hk:
+        return False
+    if d % 8 or d > 256 or page_size % 8 \
+            or not fused_rope_geometry_ok(d):
+        return False
+    if any(shape(a) != (r,) for a in (kv_lens, q_starts, q_lens,
+                                      w_starts, w_flats, w_ends)):
+        return False
+    if t < 1 or shape(new_k) != (t, hk, d) or shape(new_v) != (t, hk, d):
+        return False
+    if shape(rope_sin) != (t, d) or shape(rope_cos) != (t, d):
         return False
     try:
-        dp = int(dump_page)
+        return int(qblock) >= 1 and 0 <= int(dump_page) < p
     except (TypeError, ValueError):
         return False
-    return 0 <= dp < p
-
-
-def _fused_kernel(tables_ref, kv_lens_ref, q_starts_ref, q_lens_ref,
-                  w_starts_ref, w_flats_ref, w_ends_ref,
-                  q_ref, k_ref, v_ref, nk_ref, nv_ref,
-                  o_ref, ko_ref, vo_ref,
-                  acc_ref, m_ref, l_ref, *, page_size, group, scale,
-                  pad):
-    r = pl.program_id(0)
-    p = pl.program_id(2)
-    num_pages = pl.num_programs(2)
-
-    @pl.when(p == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    ctx = kv_lens_ref[r]
-    ws = w_starts_ref[r]
-    page_start = p * page_size
-
-    @pl.when(page_start < ctx)
-    def _compute():
-        # replay this dispatch's writes over the streamed page: slots
-        # at positions [w_start, ctx) were produced by rows <= r of
-        # THIS grid and must be read from the packed new rows, never
-        # from HBM — a pipelined page fetch may legally race the
-        # write-back. Chunks of one sequence are packed contiguously
-        # in position order, so position pos lives at packed index
-        # w_flat + pos - w_start (shifted by the left pad).
-        tpad = nk_ref.shape[1]
-        f0 = jnp.clip(w_flats_ref[r] + page_start - ws + pad, 0,
-                      tpad - page_size)
-        spos = page_start + jax.lax.broadcasted_iota(
-            jnp.int32, (page_size, 1), 0)
-        fresh = (spos >= ws) & (spos < ctx)
-        # the packed rows ride in an f32 container (see
-        # `_pack_new_rows`); narrowing back to the pool dtype is exact
-        k_pg = jnp.where(
-            fresh,
-            nk_ref[0, pl.ds(f0, page_size), :].astype(k_ref.dtype),
-            k_ref[0, 0])
-        v_pg = jnp.where(
-            fresh,
-            nv_ref[0, pl.ds(f0, page_size), :].astype(v_ref.dtype),
-            v_ref[0, 0])
-
-        q = q_ref[0, 0].astype(jnp.float32) * scale      # [QB*G, D]
-        _softmax_accumulate(q, k_pg.astype(jnp.float32),
-                            v_pg.astype(jnp.float32), page_start,
-                            q_starts_ref[r], q_lens_ref[r], ctx, group,
-                            acc_ref, m_ref, l_ref)
-
-        # in-kernel page write: ONLY the sequence's last row of this
-        # grid (kv_len == w_end) writes, exactly once per page — the
-        # out index map routes every other step to the dump page. The
-        # condition here must mirror `_fused_write_map` bit for bit: a
-        # step whose map picked a real page MUST fully write the block.
-        @pl.when((ctx == w_ends_ref[r]) & (page_start + page_size > ws)
-                 & (q_lens_ref[r] > 0))
-        def _writeback():
-            ko_ref[0, 0] = k_pg.astype(ko_ref.dtype)
-            vo_ref[0, 0] = v_pg.astype(vo_ref.dtype)
-
-    @pl.when(p == num_pages - 1)
-    def _finish():
-        _softmax_finish(o_ref, acc_ref, l_ref)
 
 
 def _quantize_rows(xf):
@@ -649,68 +261,6 @@ def _quantize_rows(xf):
     sc = jnp.maximum(amax, 1e-8) * jnp.float32(1.0 / 127.0)
     q = jnp.clip(jnp.round(xf / sc), -127.0, 127.0)
     return q, sc
-
-
-def _fused_kernel_q8(tables_ref, kv_lens_ref, q_starts_ref, q_lens_ref,
-                     w_starts_ref, w_flats_ref, w_ends_ref,
-                     q_ref, k_ref, v_ref, ks_ref, vs_ref, nk_ref, nv_ref,
-                     o_ref, ko_ref, vo_ref, kso_ref, vso_ref,
-                     acc_ref, m_ref, l_ref, *, page_size, group, scale,
-                     pad):
-    """Int8-pool fused variant: fresh rows are quantized IN the kernel
-    (same bits as `_page_write_q8`'s `quantize_kv_int8`), the softmax
-    reads their dequantized values, and the int8 page + scale sidecar
-    write back together."""
-    r = pl.program_id(0)
-    p = pl.program_id(2)
-    num_pages = pl.num_programs(2)
-
-    @pl.when(p == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    ctx = kv_lens_ref[r]
-    ws = w_starts_ref[r]
-    page_start = p * page_size
-
-    @pl.when(page_start < ctx)
-    def _compute():
-        tpad = nk_ref.shape[1]
-        f0 = jnp.clip(w_flats_ref[r] + page_start - ws + pad, 0,
-                      tpad - page_size)
-        spos = page_start + jax.lax.broadcasted_iota(
-            jnp.int32, (page_size, 1), 0)
-        fresh = (spos >= ws) & (spos < ctx)
-        k_qn, k_scn = _quantize_rows(nk_ref[0, pl.ds(f0, page_size), :])
-        v_qn, v_scn = _quantize_rows(nv_ref[0, pl.ds(f0, page_size), :])
-        # dequantized page view: fresh slots read quantize->dequantize
-        # (NOT the raw float) so the fused step is bitwise what the
-        # unfused engine computes after its quantizing scatter
-        k = jnp.where(fresh, k_qn * k_scn,
-                      k_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0])
-        v = jnp.where(fresh, v_qn * v_scn,
-                      v_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0])
-
-        q = q_ref[0, 0].astype(jnp.float32) * scale
-        _softmax_accumulate(q, k, v, page_start, q_starts_ref[r],
-                            q_lens_ref[r], ctx, group, acc_ref, m_ref,
-                            l_ref)
-
-        @pl.when((ctx == w_ends_ref[r]) & (page_start + page_size > ws)
-                 & (q_lens_ref[r] > 0))
-        def _writeback():
-            ko_ref[0, 0] = jnp.where(fresh, k_qn.astype(jnp.int8),
-                                     k_ref[0, 0])
-            vo_ref[0, 0] = jnp.where(fresh, v_qn.astype(jnp.int8),
-                                     v_ref[0, 0])
-            kso_ref[0, 0] = jnp.where(fresh, k_scn, ks_ref[0, 0])
-            vso_ref[0, 0] = jnp.where(fresh, v_scn, vs_ref[0, 0])
-
-    @pl.when(p == num_pages - 1)
-    def _finish():
-        _softmax_finish(o_ref, acc_ref, l_ref)
 
 
 def _rot_half(x):
@@ -947,11 +497,12 @@ def _fused_rope_kernel_q8(tables_ref, kv_lens_ref, q_starts_ref,
                           o_ref, ko_ref, vo_ref, kso_ref, vso_ref,
                           acc_ref, m_ref, l_ref, q_s, *, page_size,
                           group, scale, pad, qblock, dtype):
-    """Int8-pool rope-fused variant: rope the fresh rows (as in
-    `_fused_rope_kernel`, incl. the model-dtype round trip), THEN
-    quantize them in-kernel with bitwise `quantize_kv_int8` math —
-    the quantizer consumes exactly what the unfused engine's
-    post-rope `_page_write_q8` would."""
+    """The int8-pool program, a per-page grid ``(R, Hk, W)``: rope the
+    fresh rows (through the model dtype, as the float program's new_k
+    is), THEN quantize them in-kernel with bitwise `quantize_kv_int8`
+    math: the quantizer consumes exactly what a rope-then-quantize-
+    then-scatter pipeline's would
+    (`fused_ragged_paged_attention_xla`)."""
     r = pl.program_id(0)
     p = pl.program_id(2)
     num_pages = pl.num_programs(2)
@@ -978,7 +529,7 @@ def _fused_rope_kernel_q8(tables_ref, kv_lens_ref, q_starts_ref,
             jnp.int32, (page_size, 1), 0)
         fresh = (spos >= ws) & (spos < ctx)
         # shared rotation chain, then the exact f32 widening the
-        # unfused engine's post-rope quantizer consumes
+        # reference's post-rope quantizer consumes
         k_rot = _rope_k_page(nk_ref, sin_ref, cos_ref, f0, page_size,
                              dtype).astype(jnp.float32)
         k_qn, k_scn = _quantize_rows(k_rot)
@@ -1011,7 +562,7 @@ def _fused_write_map(page_size, dump_page):
     """Out-spec index map for the pool write-back: the page the step
     writes when it IS the sequence's last row and the page overlaps the
     dispatch's write span ``[w_start, kv_len)``, else ``dump_page``.
-    Must mirror the kernels' ``_writeback`` condition exactly."""
+    Must mirror the int8 kernel's ``_writeback`` condition exactly."""
     def wmap(ri, hi, pi, tables, kv_lens, q_starts, q_lens, w_starts,
              w_flats, w_ends):
         ctx = kv_lens[ri]
@@ -1021,135 +572,6 @@ def _fused_write_map(page_size, dump_page):
         return jnp.where(written, tables[ri, pi], dump_page), hi, 0, 0
 
     return wmap
-
-
-@functools.lru_cache(maxsize=32)
-def _make_fused(scale, page_size, qb, group, tpad, dump_page,
-                interpret):
-    wmap = _fused_write_map(page_size, dump_page)
-
-    def call(q4, k_pages, v_pages, nk, nv, tables, kv_lens, q_starts,
-             q_lens, w_starts, w_flats, w_ends):
-        r, hk, qbg, d = q4.shape
-        max_pages = tables.shape[1]
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=7,
-            grid=(r, hk, max_pages),
-            in_specs=[
-                pl.BlockSpec((1, 1, qbg, d),
-                             lambda ri, hi, pi, *refs: (ri, hi, 0, 0)),
-                pl.BlockSpec((1, 1, page_size, d),
-                             lambda ri, hi, pi, tables, *refs:
-                             (tables[ri, pi], hi, 0, 0)),
-                pl.BlockSpec((1, 1, page_size, d),
-                             lambda ri, hi, pi, tables, *refs:
-                             (tables[ri, pi], hi, 0, 0)),
-                # the dispatch's packed new K/V rows ride whole in VMEM
-                pl.BlockSpec((1, tpad, d),
-                             lambda ri, hi, pi, *refs: (hi, 0, 0)),
-                pl.BlockSpec((1, tpad, d),
-                             lambda ri, hi, pi, *refs: (hi, 0, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, 1, qbg, d),
-                             lambda ri, hi, pi, *refs: (ri, hi, 0, 0)),
-                pl.BlockSpec((1, 1, page_size, d), wmap),
-                pl.BlockSpec((1, 1, page_size, d), wmap),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((qbg, d), jnp.float32),
-                pltpu.VMEM((qbg, 1), jnp.float32),
-                pltpu.VMEM((qbg, 1), jnp.float32),
-            ],
-        )
-        return pl.pallas_call(
-            functools.partial(_fused_kernel, page_size=page_size,
-                              group=group, scale=scale, pad=page_size),
-            grid_spec=grid_spec,
-            out_shape=[
-                jax.ShapeDtypeStruct((r, hk, qbg, d), q4.dtype),
-                jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
-                jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype),
-            ],
-            # the pools pass through in place: inputs 0-6 are the
-            # scalar-prefetch operands, 7 is q4, 8/9 the pools
-            input_output_aliases={8: 1, 9: 2},
-            interpret=interpret,
-            name="paddle_tpu.ragged_attn_fused",
-        )(tables, kv_lens, q_starts, q_lens, w_starts, w_flats, w_ends,
-          q4, k_pages, v_pages, nk, nv)
-
-    return call
-
-
-@functools.lru_cache(maxsize=32)
-def _make_fused_q8(scale, page_size, qb, group, tpad, dump_page,
-                   interpret):
-    # ONE routing map for pages AND scale sidecars: the kernel writes
-    # a page's int8 block and its scale block under the same condition,
-    # so their out-spec routing must be the same closure, not two that
-    # could drift apart
-    wmap = _fused_write_map(page_size, dump_page)
-
-    def call(q4, k_pages, v_pages, k_scale, v_scale, nk, nv, tables,
-             kv_lens, q_starts, q_lens, w_starts, w_flats, w_ends):
-        r, hk, qbg, d = q4.shape
-        max_pages = tables.shape[1]
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=7,
-            grid=(r, hk, max_pages),
-            in_specs=[
-                pl.BlockSpec((1, 1, qbg, d),
-                             lambda ri, hi, pi, *refs: (ri, hi, 0, 0)),
-                pl.BlockSpec((1, 1, page_size, d),
-                             lambda ri, hi, pi, tables, *refs:
-                             (tables[ri, pi], hi, 0, 0)),
-                pl.BlockSpec((1, 1, page_size, d),
-                             lambda ri, hi, pi, tables, *refs:
-                             (tables[ri, pi], hi, 0, 0)),
-                pl.BlockSpec((1, 1, page_size, 1),
-                             lambda ri, hi, pi, tables, *refs:
-                             (tables[ri, pi], hi, 0, 0)),
-                pl.BlockSpec((1, 1, page_size, 1),
-                             lambda ri, hi, pi, tables, *refs:
-                             (tables[ri, pi], hi, 0, 0)),
-                pl.BlockSpec((1, tpad, d),
-                             lambda ri, hi, pi, *refs: (hi, 0, 0)),
-                pl.BlockSpec((1, tpad, d),
-                             lambda ri, hi, pi, *refs: (hi, 0, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, 1, qbg, d),
-                             lambda ri, hi, pi, *refs: (ri, hi, 0, 0)),
-                pl.BlockSpec((1, 1, page_size, d), wmap),
-                pl.BlockSpec((1, 1, page_size, d), wmap),
-                pl.BlockSpec((1, 1, page_size, 1), wmap),
-                pl.BlockSpec((1, 1, page_size, 1), wmap),
-            ],
-            scratch_shapes=[
-                pltpu.VMEM((qbg, d), jnp.float32),
-                pltpu.VMEM((qbg, 1), jnp.float32),
-                pltpu.VMEM((qbg, 1), jnp.float32),
-            ],
-        )
-        return pl.pallas_call(
-            functools.partial(_fused_kernel_q8, page_size=page_size,
-                              group=group, scale=scale, pad=page_size),
-            grid_spec=grid_spec,
-            out_shape=[
-                jax.ShapeDtypeStruct((r, hk, qbg, d), q4.dtype),
-                jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
-                jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype),
-                jax.ShapeDtypeStruct(k_scale.shape, k_scale.dtype),
-                jax.ShapeDtypeStruct(v_scale.shape, v_scale.dtype),
-            ],
-            input_output_aliases={8: 1, 9: 2, 10: 3, 11: 4},
-            interpret=interpret,
-            name="paddle_tpu.ragged_attn_fused_q8",
-        )(tables, kv_lens, q_starts, q_lens, w_starts, w_flats, w_ends,
-          q4, k_pages, v_pages, k_scale, v_scale, nk, nv)
-
-    return call
 
 
 @functools.lru_cache(maxsize=32)
@@ -1314,8 +736,8 @@ def _make_fused_rope_q8(scale, page_size, qblock, group, tpad,
 
 def _pack_new_rows(new, t, pad, tpad, dtype):
     """[T, Hk, D] packed rows -> [Hk, tpad, D] head-major with ``pad``
-    rows of left pad (a page for the per-page programs, a block of the
-    walk for the float rope-fused one: the length of a replay slice),
+    rows of left pad (a page for the int8 program, a block of the
+    walk for the float one: the length of a replay slice),
     so the kernels' clipped affine slice ``pl.ds(w_flat + start -
     w_start + pad, pad)`` is always in bounds whenever any slot it
     covers is fresh. The values are
@@ -1325,68 +747,6 @@ def _pack_new_rows(new, t, pad, tpad, dtype):
     exact, so the kernel narrows back without changing a bit."""
     nk = jnp.swapaxes(new.astype(dtype).astype(jnp.float32), 0, 1)
     return jnp.pad(nk, ((0, 0), (pad, tpad - t - pad), (0, 0)))
-
-
-def _fused_impl(q, new_k, new_v, k_pages, v_pages, block_tables,
-                kv_lens, q_starts, q_lens, w_starts, w_flats, w_ends,
-                dump_page, scale):
-    r, qb, h, d = q.shape
-    hk = k_pages.shape[1]
-    group = h // hk
-    page_size = k_pages.shape[2]
-    t = new_k.shape[0]
-    tpad = -(-(t + 2 * page_size) // 8) * 8
-    q4 = q.reshape(r, qb, hk, group, d).transpose(0, 2, 1, 3, 4) \
-        .reshape(r, hk, qb * group, d)
-    # cast packed rows to the POOL dtype before the kernel: a fresh
-    # slot must read exactly what the unfused scatter would have
-    # stored (write-as-pool-dtype, read back) for decode-bitwise parity
-    nk = _pack_new_rows(new_k, t, page_size, tpad, k_pages.dtype)
-    nv = _pack_new_rows(new_v, t, page_size, tpad, v_pages.dtype)
-    call = _make_fused(scale, page_size, qb, group, tpad,
-                       int(dump_page), _interpret())
-    tables = jnp.clip(block_tables.astype(jnp.int32), 0,
-                      k_pages.shape[0] - 1)
-    out, kp, vp = call(q4, k_pages, v_pages, nk, nv, tables,
-                       kv_lens.astype(jnp.int32),
-                       q_starts.astype(jnp.int32),
-                       q_lens.astype(jnp.int32),
-                       w_starts.astype(jnp.int32),
-                       w_flats.astype(jnp.int32),
-                       w_ends.astype(jnp.int32))
-    out = out.reshape(r, hk, qb, group, d).transpose(0, 2, 1, 3, 4) \
-        .reshape(r, qb, h, d)
-    return out, kp, vp
-
-
-def _fused_impl_q8(q, new_k, new_v, k_pages, v_pages, k_scale, v_scale,
-                   block_tables, kv_lens, q_starts, q_lens, w_starts,
-                   w_flats, w_ends, dump_page, scale):
-    r, qb, h, d = q.shape
-    hk = k_pages.shape[1]
-    group = h // hk
-    page_size = k_pages.shape[2]
-    t = new_k.shape[0]
-    tpad = -(-(t + 2 * page_size) // 8) * 8
-    q4 = q.reshape(r, qb, hk, group, d).transpose(0, 2, 1, 3, 4) \
-        .reshape(r, hk, qb * group, d)
-    # f32 packed rows: the in-kernel quantizer consumes exactly what
-    # `quantize_kv_int8` would (x.astype(f32))
-    nk = _pack_new_rows(new_k, t, page_size, tpad, jnp.float32)
-    nv = _pack_new_rows(new_v, t, page_size, tpad, jnp.float32)
-    call = _make_fused_q8(scale, page_size, qb, group, tpad,
-                          int(dump_page), _interpret())
-    tables = jnp.clip(block_tables.astype(jnp.int32), 0,
-                      k_pages.shape[0] - 1)
-    out, kp, vp, ks, vs = call(
-        q4, k_pages, v_pages, k_scale.astype(jnp.float32),
-        v_scale.astype(jnp.float32), nk, nv, tables,
-        kv_lens.astype(jnp.int32), q_starts.astype(jnp.int32),
-        q_lens.astype(jnp.int32), w_starts.astype(jnp.int32),
-        w_flats.astype(jnp.int32), w_ends.astype(jnp.int32))
-    out = out.reshape(r, hk, qb, group, d).transpose(0, 2, 1, 3, 4) \
-        .reshape(r, qb, h, d)
-    return out, kp, vp, ks, vs
 
 
 def _pack_new_q(q, t, group, pad, tpad):
@@ -1474,8 +834,7 @@ def _fused_rope_impl_q8(q, new_k, new_v, k_pages, v_pages, k_scale,
     tpad = _rope_tpad(t, page_size, qblock)
     # both packed rows keep the MODEL dtype: the kernel ropes k, round
     # trips through the model dtype and widens to f32 for the bitwise
-    # `quantize_kv_int8` math (an exact widening — identical to the
-    # post-rope kernel's f32 pack)
+    # `quantize_kv_int8` math (an exact widening)
     qp = _pack_new_q(q, t, group, page_size, tpad)
     nk = _pack_new_rows(new_k, t, page_size, tpad, new_k.dtype)
     nv = _pack_new_rows(new_v, t, page_size, tpad, new_v.dtype)
@@ -1500,96 +859,53 @@ def _fused_rope_impl_q8(q, new_k, new_v, k_pages, v_pages, k_scale,
 def fused_ragged_paged_attention(q, new_k, new_v, k_pages, v_pages,
                                  block_tables, kv_lens, q_starts,
                                  q_lens, w_starts, w_flats, w_ends,
-                                 dump_page, scale=None, k_scale=None,
-                                 v_scale=None, rope_sin=None,
-                                 rope_cos=None, qblock=None):
-    """Ragged paged attention WITH the KV page write fused in (see
-    module docstring): writes ``new_k/new_v [T, Hk, D]`` — the
-    dispatch's packed post-rope K/V rows — into each row's pages inside
-    the kernel and attends through them, returning
-    ``(out, k_pages, v_pages)`` (plus updated scale sidecars on the q8
-    path). Per-row write metadata: ``w_starts[r]`` is the first
-    position of row r's sequence written by THIS dispatch,
+                                 dump_page, *, rope_sin, rope_cos,
+                                 qblock, scale=None, k_scale=None,
+                                 v_scale=None):
+    """Rope, KV page write and ragged paged attention in ONE kernel
+    (see module docstring): ropes the packed PRE-rope ``q [T, H, D]``
+    and ``new_k [T, Hk, D]`` by the per-dispatch ``rope_sin``/
+    ``rope_cos`` ``[T, D]`` f32 tables (:func:`rope_tables`) in VMEM,
+    writes ``new_k/new_v`` into each row's pages and attends through
+    them, returning ``(out [R, qblock, H, D], k_pages, v_pages)`` —
+    plus the updated scale sidecars for int8 pools (``k_scale``/
+    ``v_scale`` given). Per-row write metadata: ``w_starts[r]`` is the
+    first position of row r's sequence written by THIS dispatch,
     ``w_flats[r]`` that position's index on the packed token axis,
     ``w_ends[r]`` the sequence's final kv_len in this dispatch (so the
-    last row owns the write-back). ``dump_page`` is a page id no live
-    table references; steps with nothing to write dump there and its
-    contents are undefined after the call.
-
-    With ``rope_sin``/``rope_cos`` (per-dispatch ``[T, D]`` f32 tables
-    from :func:`rope_tables`) the call is the ROPE-FUSED variant:
-    ``q`` arrives PRE-rope in the packed ``[T, H, D]`` token layout
-    (the kernel slices each row's contiguous tokens via the write
-    metadata — no host-side row-block gather), ``new_k`` is the
-    pre-rope packed K, and the rotation happens in VMEM before the
-    write/attention math, bitwise the unfused
-    `fused_rotary_position_embedding` chain. ``qblock`` (the row-block
-    width the metadata was built for) is required, and the returned
-    attention output keeps the ``[R, qblock, H, D]`` row-block
-    layout."""
+    last row owns the write-back). ``qblock`` is the row-block width
+    the metadata was built for. ``dump_page`` is a page id no live
+    table references; the int8 program's steps with nothing to write
+    dump there and its contents are undefined after the call.
+    Tape-integrated but non-differentiable (serving path)."""
     if not fused_supported(q, new_k, new_v, k_pages, v_pages,
                            block_tables, kv_lens, q_starts, q_lens,
                            w_starts, w_flats, w_ends, dump_page,
-                           k_scale, v_scale, rope_sin, rope_cos,
-                           qblock):
+                           rope_sin=rope_sin, rope_cos=rope_cos,
+                           qblock=qblock, k_scale=k_scale,
+                           v_scale=v_scale):
         raise ValueError(
-            "fused_ragged_paged_attention preconditions not met: the "
-            "`ragged_paged_attention` contract, plus new_k/new_v "
-            "[T,Hk,D] (T >= 1), w_starts/w_flats/w_ends [R] and a "
-            "dump_page id inside the pool; the rope-fused variant "
-            "additionally needs packed q [T,H,D], rope_sin/rope_cos "
-            "[T,D] and an explicit qblock >= 1")
+            "fused_ragged_paged_attention preconditions not met: need "
+            "packed q [T,H,D], new_k/new_v [T,Hk,D] (T >= 1), rope_sin/"
+            "rope_cos [T,D], pages [P,Hk,page,D] (page % 8 == 0, "
+            "D % 8 == 0, D <= 256, H % Hk == 0), tables [R,max_pages], "
+            "kv_lens/q_starts/q_lens/w_starts/w_flats/w_ends [R], "
+            "qblock >= 1 and a dump_page id inside the pool; int8 pools "
+            "need BOTH k_scale/v_scale sidecars shaped [P,Hk,page,1]")
     d = getattr(q, "_data", q).shape[-1]
-    s = scale if scale is not None else 1.0 / math.sqrt(d)
-    dp = int(dump_page)
-
-    if rope_sin is not None:
-        qb = int(qblock)
-        if k_scale is not None:
-            def fn_rope_q8(q, nk, nv, kp, vp, ks, vs, bt, kl, qs, ql,
-                           wss, wfs, wes, rs, rc):
-                return _fused_rope_impl_q8(q, nk, nv, kp, vp, ks, vs,
-                                           bt, kl, qs, ql, wss, wfs,
-                                           wes, rs, rc, dp, s, qb)
-
-            return run_op("fused_rope_ragged_paged_attention_q8",
-                          fn_rope_q8,
-                          (q, new_k, new_v, k_pages, v_pages, k_scale,
-                           v_scale, block_tables, kv_lens, q_starts,
-                           q_lens, w_starts, w_flats, w_ends, rope_sin,
-                           rope_cos), differentiable=False)
-
-        def fn_rope(q, nk, nv, kp, vp, bt, kl, qs, ql, wss, wfs, wes,
-                    rs, rc):
-            return _fused_rope_impl(q, nk, nv, kp, vp, bt, kl, qs, ql,
-                                    wss, wfs, wes, rs, rc, dp, s, qb)
-
-        return run_op("fused_rope_ragged_paged_attention", fn_rope,
-                      (q, new_k, new_v, k_pages, v_pages, block_tables,
-                       kv_lens, q_starts, q_lens, w_starts, w_flats,
-                       w_ends, rope_sin, rope_cos),
-                      differentiable=False)
-
+    static = dict(dump_page=int(dump_page), qblock=int(qblock),
+                  scale=scale if scale is not None else 1.0 / math.sqrt(d))
+    rows = (block_tables, kv_lens, q_starts, q_lens, w_starts, w_flats,
+            w_ends, rope_sin, rope_cos)
     if k_scale is not None:
-        def fn_q8(q, nk, nv, kp, vp, ks, vs, bt, kl, qs, ql, wss, wfs,
-                  wes):
-            return _fused_impl_q8(q, nk, nv, kp, vp, ks, vs, bt, kl,
-                                  qs, ql, wss, wfs, wes, dp, s)
-
-        return run_op("fused_ragged_paged_attention_q8", fn_q8,
+        return run_op("fused_rope_ragged_paged_attention_q8",
+                      functools.partial(_fused_rope_impl_q8, **static),
                       (q, new_k, new_v, k_pages, v_pages, k_scale,
-                       v_scale, block_tables, kv_lens, q_starts,
-                       q_lens, w_starts, w_flats, w_ends),
-                      differentiable=False)
-
-    def fn(q, nk, nv, kp, vp, bt, kl, qs, ql, wss, wfs, wes):
-        return _fused_impl(q, nk, nv, kp, vp, bt, kl, qs, ql, wss, wfs,
-                           wes, dp, s)
-
-    return run_op("fused_ragged_paged_attention", fn,
-                  (q, new_k, new_v, k_pages, v_pages, block_tables,
-                   kv_lens, q_starts, q_lens, w_starts, w_flats,
-                   w_ends), differentiable=False)
+                       v_scale) + rows, differentiable=False)
+    return run_op("fused_rope_ragged_paged_attention",
+                  functools.partial(_fused_rope_impl, **static),
+                  (q, new_k, new_v, k_pages, v_pages) + rows,
+                  differentiable=False)
 
 
 def fused_ragged_paged_attention_xla(q, new_k, new_v, k_pages, v_pages,

@@ -101,76 +101,70 @@ def _assert_kernel(compiled, *names):
 
 
 # ---------------------------------------------------------------------------
-# serving: the six ragged paged-attention programs at Llama-3-8B head
-# geometry and the engine's defaults for max_batch=16
+# serving: the two ragged paged-attention programs (one a pool dtype) at
+# Llama-3-8B head geometry and the engine's defaults for max_batch=16
 # ---------------------------------------------------------------------------
 H, HK, D = 32, 8, 128
 R, T, QB = 18, 64, 32
 MAX_CTX = 1152
 
 
-def _ragged_specs(program, page_size):
-    pages = 4 * MAX_CTX // page_size
-    pool = (pages, HK, page_size, D)
-    sidecar = (pages, HK, page_size, 1)
-    rows = [((R,), I32)]
-    tables = ((R, MAX_CTX // page_size), I32)
-    q8 = program.endswith("q8")
+def _ragged_specs(q8, pool, rows, tokens, width):
+    """``(shape, dtype)`` of a ragged program's operands: packed q,
+    new K/V, the pools (int8 with f32 sidecars where ``q8``), tables,
+    the six per-row arrays, the sin/cos tables."""
+    h, d = 4 * pool[1], pool[3]
     pools = [(pool, I8 if q8 else BF16)] * 2 \
-        + ([(sidecar, F32)] * 2 if q8 else [])
-    new = [((T, HK, D), BF16)] * 2
-    if program.startswith("_ragged"):
-        return [((R, QB, H, D), BF16)] + pools + [tables] + rows * 3
-    q = ((T, H, D), BF16) if "rope" in program else ((R, QB, H, D), BF16)
-    rope = [((T, D), F32)] * 2 if "rope" in program else []
-    return [q] + new + pools + [tables] + rows * 6 + rope
+        + ([(pool[:3] + (1,), F32)] * 2 if q8 else [])
+    return [((tokens, h, d), BF16)] + [((tokens, pool[1], d), BF16)] * 2 \
+        + pools + [((rows, width), I32)] + [((rows,), I32)] * 6 \
+        + [((tokens, d), F32)] * 2
 
 
 RAGGED_KERNELS = {
     "_fused_rope_impl": "ragged_attn_fused_rope",
-    "_fused_impl": "ragged_attn_fused",
-    "_fused_rope_impl_q8": "ragged_attn_fused_rope_q8",
-    "_fused_impl_q8": "ragged_attn_fused_q8",
-    "_ragged_impl": "ragged_attn",
-    "_ragged_impl_q8": "ragged_attn_q8"}
+    "_fused_rope_impl_q8": "ragged_attn_fused_rope_q8"}
 
 
 @pytest.mark.parametrize("page_size", [16, 64])
 @pytest.mark.parametrize("program", list(RAGGED_KERNELS))
 def test_ragged_programs_compile_bf16(chip_compile, program, page_size):
-    kw = dict(scale=D ** -0.5)
-    if program.startswith("_fused"):
-        kw["dump_page"] = 0
-    if "rope" in program:
-        kw["qblock"] = QB
-    fn = functools.partial(getattr(rpa, program), **kw)
-    _assert_kernel(chip_compile(fn, *_ragged_specs(program, page_size)),
-                   RAGGED_KERNELS[program])
+    fn = functools.partial(getattr(rpa, program), scale=D ** -0.5,
+                           dump_page=0, qblock=QB)
+    pool = (4 * MAX_CTX // page_size, HK, page_size, D)
+    specs = _ragged_specs(program.endswith("q8"), pool, R, T,
+                          MAX_CTX // page_size)
+    _assert_kernel(chip_compile(fn, *specs), RAGGED_KERNELS[program])
 
 
-# the engine's default float program at Mistral-7B's head shape and
-# the benchmark's pool: both of chat-open's programs (36 rows x 128
-# tokens in 32-token blocks; 32 decode rows) behind tables of the two
-# serving cells' widths and of a long-context cell's. Its walk is
-# bounded by kv_lens, so the width only sizes the table in SMEM.
+# both programs at Mistral-7B's head shape and the benchmark's pool:
+# both of chat-open's step programs (36 rows x 128 tokens in 32-token
+# blocks; 32 decode rows) behind tables of the two serving cells' widths
+# and, for the float program, of a long-context cell's. The float walk
+# is bounded by kv_lens, so the width only sizes its table in SMEM; the
+# int8 program's grid has a step a table slot (ROADMAP S8c starts here).
 MISTRAL_POOL = (4097, 8, 16, 128)
 
 
-@pytest.mark.parametrize("width", [66, 161, 521])
+@pytest.mark.parametrize("program,width", [
+    ("_fused_rope_impl", 66), ("_fused_rope_impl", 161),
+    ("_fused_rope_impl", 521), ("_fused_rope_impl_q8", 66),
+    ("_fused_rope_impl_q8", 161)],
+    ids=["float-66", "float-161", "float-521", "int8-66", "int8-161"])
 @pytest.mark.parametrize("rows,tokens,qblock", [(36, 128, 32), (32, 32, 1)])
 def test_walk_compiles_at_mistral_widths(chip_compile, rows, tokens,
-                                         qblock, width):
-    fn = functools.partial(rpa._fused_rope_impl, dump_page=4096,
+                                         qblock, program, width):
+    q8 = program.endswith("q8")
+    fn = functools.partial(getattr(rpa, program), dump_page=4096,
                            scale=D ** -0.5, qblock=qblock)
     compiled = chip_compile(
-        fn, ((tokens, 32, 128), BF16), ((tokens, 8, 128), BF16),
-        ((tokens, 8, 128), BF16), (MISTRAL_POOL, BF16),
-        (MISTRAL_POOL, BF16), ((rows, width), I32), *[((rows,), I32)] * 6,
-        ((tokens, 128), F32), ((tokens, 128), F32))
-    _assert_kernel(compiled, "ragged_attn_fused_rope")
+        fn, *_ragged_specs(q8, MISTRAL_POOL, rows, tokens, width))
+    _assert_kernel(compiled, RAGGED_KERNELS[program])
     # the one custom call a layer whose result holds both pools: what
     # the benchmark's attention roofline finds the kernel by
-    assert re.search(r"bf16\[4097,8,16,128\][^\n]*bf16\[4097,8,16,128\]"
+    dt = "s8" if q8 else "bf16"
+    assert re.search(dt + r"\[4097,8,16,128\][^\n]*" + dt
+                     + r"\[4097,8,16,128\]"
                      r"[^\n]*custom_call_target=\"tpu_custom_call\"",
                      compiled.as_text())
 

@@ -1,5 +1,5 @@
 """One dispatch's host-built metadata rides to the device as ONE flat
-int32 buffer (ISSUE 31): `DispatchLayout` says where each of the 21
+int32 buffer (ISSUE 31): `DispatchLayout` says where each of the 18
 fields of ``_mixed_forward``'s argument list lies in it. What the host
 writes into the views comes back from the device-side unpack with its
 shape, its dtype and, for the float fields, its bits."""
@@ -10,7 +10,7 @@ import pytest
 
 from paddle_tpu.inference.layer_step import DispatchLayout
 
-FIELDS = ("tokens pos page_ids offs row_tok flat_idx last_idx tables "
+FIELDS = ("tokens pos flat_idx last_idx tables "
           "kv_lens q_starts q_lens w_starts w_flats w_ends temps top_ps "
           "top_ks seeds slot_ids slot_vals cmodes").split()
 FLOATS = ("temps", "top_ps", "slot_vals")
@@ -33,8 +33,6 @@ def _want(lay):
         "last_idx kv_lens q_starts q_lens w_starts w_flats w_ends top_ks "
         "seeds cmodes").split()}
     return dict(row, tokens=((1, t), i32, 0), pos=((1, t), i32, 0),
-                page_ids=((t,), i32, TRASH), offs=((t,), i32, 0),
-                row_tok=((r, qb), i32, 0),
                 flat_idx=((t,), i32, r * qb - 1),
                 tables=((r, w), i32, TRASH), temps=((r,), f32, 0.0),
                 top_ps=((r,), f32, 1.0), slot_ids=((r, b), i32, -1),
@@ -55,7 +53,7 @@ def _random_fill(lay, rng, buf):
             f[name][...] = v.astype(np.float32)
         elif name == "slot_ids":
             f[name][...] = rng.randint(-1, 129280, shape)
-        elif name in ("page_ids", "tables"):
+        elif name == "tables":
             f[name][...] = rng.randint(0, TRASH + 1, shape)
         else:
             f[name][...] = rng.randint(0, 2 ** 31 - 1, shape)
@@ -75,7 +73,7 @@ def test_offsets_do_not_overlap_and_cover_the_buffer(shape, width):
         at = stop
     assert at == lay.size and lay.nbytes == 4 * at
     t, r, qb, w, b = lay.shape
-    assert lay.size == 5 * t + r * (qb + w + 12 + 2 * b)
+    assert lay.size == 3 * t + r * (w + 12 + 2 * b)
     # every word of the buffer belongs to exactly one view
     buf = lay.new()
     owner = np.zeros(lay.size, np.int32)
@@ -96,7 +94,7 @@ def test_host_pack_device_unpack_gives_every_field_back(shape, width):
     wrote = _random_fill(lay, rng, buf)
     assert buf.dtype == np.int32 and buf.shape == (lay.size,)
     got = jax.jit(lay.unpack)(jax.numpy.asarray(buf))
-    assert len(got) == len(FIELDS) == 21
+    assert len(got) == len(FIELDS) == 18
     for name, a in zip(FIELDS, got):
         want_shape, want_dtype, _ = _want(lay)[name]
         a = np.asarray(a)

@@ -110,8 +110,13 @@ class TestPallasKernel:
         h, w, lab = _case(n=16, d=32, v=50)      # 50 % 16 != 0
         lse_x, pick_x = _xla_parts(h, w, lab, 16)
         lse_k, pick_k = _kernel_parts(h, w, lab, block_v=16)
-        np.testing.assert_array_equal(np.asarray(lse_x),
-                                      np.asarray(lse_k))
+        # one ulp on two rows, not bitwise: the last vocab tile is a
+        # 2-wide slice there and a masked 16-wide tile here, and
+        # XLA:CPU contracts the update ``s * exp(m - m_new) + sum``
+        # into an FMA or not by what it fuses the chain with (dot and
+        # sum alone agree bit for bit at both widths)
+        np.testing.assert_allclose(np.asarray(lse_x), np.asarray(lse_k),
+                                   rtol=2.5e-7, atol=0.0)
         np.testing.assert_array_equal(np.asarray(pick_x),
                                       np.asarray(pick_k))
 

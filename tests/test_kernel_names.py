@@ -24,25 +24,16 @@ R, T, QB, PAGE, PAGES, WIDTH = 3, 16, 8, 8, 12, 4
 
 
 def _ragged(program):
-    """(fn, specs) of one of the six ragged programs, as
+    """(fn, specs) of one of the two ragged programs, as
     tests/test_chip_compile.py builds them, at a toy size."""
     pool, sidecar = (PAGES, HK, PAGE, D), (PAGES, HK, PAGE, 1)
     q8 = program.endswith("q8")
     pools = [(pool, I8 if q8 else BF16)] * 2 \
         + ([(sidecar, F32)] * 2 if q8 else [])
-    rows, tables = [((R,), I32)], ((R, WIDTH), I32)
-    kw = dict(scale=D ** -0.5)
-    if program.startswith("_ragged"):
-        specs = [((R, QB, H, D), BF16)] + pools + [tables] + rows * 3
-    else:
-        kw["dump_page"] = 0
-        rope = "rope" in program
-        if rope:
-            kw["qblock"] = QB
-        q = ((T, H, D), BF16) if rope else ((R, QB, H, D), BF16)
-        specs = [q] + [((T, HK, D), BF16)] * 2 + pools + [tables] \
-            + rows * 6 + ([((T, D), F32)] * 2 if rope else [])
-    return functools.partial(getattr(rpa, program), **kw), specs
+    specs = [((T, H, D), BF16)] + [((T, HK, D), BF16)] * 2 + pools \
+        + [((R, WIDTH), I32)] + [((R,), I32)] * 6 + [((T, D), F32)] * 2
+    return functools.partial(getattr(rpa, program), scale=D ** -0.5,
+                             dump_page=0, qblock=QB), specs
 
 
 def _flash_grad():
@@ -54,15 +45,6 @@ def _flash_grad():
 
 #: site -> (its name, how to trace a program that holds it)
 SITES = {
-    "ragged_paged_attention.py plain": (
-        "paddle_tpu.ragged_attn", lambda: _ragged("_ragged_impl")),
-    "ragged_paged_attention.py plain q8": (
-        "paddle_tpu.ragged_attn_q8", lambda: _ragged("_ragged_impl_q8")),
-    "ragged_paged_attention.py fused": (
-        "paddle_tpu.ragged_attn_fused", lambda: _ragged("_fused_impl")),
-    "ragged_paged_attention.py fused q8": (
-        "paddle_tpu.ragged_attn_fused_q8",
-        lambda: _ragged("_fused_impl_q8")),
     "ragged_paged_attention.py fused rope": (
         "paddle_tpu.ragged_attn_fused_rope",
         lambda: _ragged("_fused_rope_impl")),
@@ -138,7 +120,7 @@ def test_pallas_call_site_carries_its_name(site):
 def test_names_are_distinct_and_cover_every_site():
     import pathlib
     names = [n for n, _ in SITES.values()]
-    assert len(set(names)) == len(names) == 16
+    assert len(set(names)) == len(names) == 12
     root = pathlib.Path(fa.__file__).parent.parent
     calls = named = 0
     for path in root.rglob("*.py"):
@@ -146,4 +128,4 @@ def test_names_are_distinct_and_cover_every_site():
         calls += len(re.findall(r"\bpl\.pallas_call\(", text))
         named += len(re.findall(r"\bname=(?:\"paddle_tpu\.|KERNEL_NAME)",
                                 text))
-    assert calls == named == 16
+    assert calls == named == 12

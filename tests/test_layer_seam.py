@@ -1,23 +1,21 @@
 """The serving engine asks each layer for its step
 (``layer.serving_step``) and its cache (``layer.serving_cache``) instead
 of owning one layer's mathematics. For the Llama layer the moved code is
-the code the engine held inline until PR 30: ``legacy_step`` below is that
-inline loop body, frozen here word for word, and an engine whose layers
-run it must leave the same tokens and the same pool bytes, bitwise, in
-all three of its programs, float and int8 pages."""
+the code the engine held inline until PR 30: ``legacy_step`` below is the
+rope-fused branch of that inline loop body (the one branch PR 32 kept),
+frozen here word for word, and an engine whose layers run it must leave
+the same tokens and the same pool bytes, bitwise, float and int8 pages."""
 
 import numpy as np
 import pytest
 
 import paddle_tpu as paddle
-from paddle_tpu.incubate.nn import functional as FI
-from paddle_tpu.inference.layer_step import (_page_write, _page_write_q8,
-                                             _token_gather)
+from paddle_tpu.inference.layer_step import _token_gather
 from paddle_tpu.inference.serving import LlamaServingEngine
 from paddle_tpu.models.llama import (LlamaConfig, LlamaDecoderLayer,
                                      LlamaForCausalLM)
-from paddle_tpu.ops.ragged_paged_attention import (
-    fused_ragged_paged_attention, ragged_paged_attention)
+from paddle_tpu.ops.ragged_paged_attention import \
+    fused_ragged_paged_attention
 
 
 def legacy_step(self, x, step, pages):
@@ -31,8 +29,7 @@ def legacy_step(self, x, step, pages):
     tables, kv_lens, q_starts, q_lens = (step.tables, step.kv_lens,
                                          step.q_starts, step.q_lens)
     w_starts, w_flats, w_ends = step.w_starts, step.w_flats, step.w_ends
-    page_ids, offs, row_tok, flat_idx = (step.page_ids, step.offs,
-                                         step.row_tok, step.flat_idx)
+    flat_idx = step.flat_idx
     trash = step.trash_page
     rsin, rcos = step.rope(layer.self_attn.head_dim,
                            float(layer.self_attn.config.rope_theta))
@@ -42,56 +39,26 @@ def legacy_step(self, x, step, pages):
     q = att.q_proj(h).reshape([1, t, att.num_heads, att.head_dim])
     k = att.k_proj(h).reshape([1, t, att.num_kv_heads, att.head_dim])
     v = att.v_proj(h).reshape([1, t, att.num_kv_heads, att.head_dim])
-    if not step.fused_rope:
-        q, k, v = FI.fused_rotary_position_embedding(
-            q, k, v, sin=rsin, cos=rcos)
     k2 = k.reshape([t, att.num_kv_heads, att.head_dim])
     v2 = v.reshape([t, att.num_kv_heads, att.head_dim])
-    if step.fused_rope:
-        q3 = q.reshape([t, att.num_heads, att.head_dim])
-        if step.kv_quant:
-            attn4, kp, vp, new_ks, new_vs = fused_ragged_paged_attention(
-                q3, k2, v2, k_pool, v_pool, tables, kv_lens, q_starts,
-                q_lens, w_starts, w_flats, w_ends, trash, k_scale=k_scale,
-                v_scale=v_scale, rope_sin=rsin, rope_cos=rcos, qblock=qb)
-        else:
-            attn4, kp, vp = fused_ragged_paged_attention(
-                q3, k2, v2, k_pool, v_pool, tables, kv_lens, q_starts,
-                q_lens, w_starts, w_flats, w_ends, trash, rope_sin=rsin,
-                rope_cos=rcos, qblock=qb)
-        attn = _token_gather(
-            attn4.reshape([r_rows * qb, att.num_heads, att.head_dim]),
-            flat_idx)
-        x = x + att.o_proj(attn.reshape([1, t, -1]))
-        x = x + layer.mlp(layer.post_attention_layernorm(x))
-        return x, [kp, vp] + ([new_ks, new_vs] if step.kv_quant else []), \
-            None
-    q4 = _token_gather(q.reshape([t, att.num_heads, att.head_dim]), row_tok)
-    if step.fused_kv:
-        if step.kv_quant:
-            attn4, kp, vp, new_ks, new_vs = fused_ragged_paged_attention(
-                q4, k2, v2, k_pool, v_pool, tables, kv_lens, q_starts,
-                q_lens, w_starts, w_flats, w_ends, trash, k_scale=k_scale,
-                v_scale=v_scale)
-        else:
-            attn4, kp, vp = fused_ragged_paged_attention(
-                q4, k2, v2, k_pool, v_pool, tables, kv_lens, q_starts,
-                q_lens, w_starts, w_flats, w_ends, trash)
+    q3 = q.reshape([t, att.num_heads, att.head_dim])
+    if step.kv_quant:
+        attn4, kp, vp, new_ks, new_vs = fused_ragged_paged_attention(
+            q3, k2, v2, k_pool, v_pool, tables, kv_lens, q_starts,
+            q_lens, w_starts, w_flats, w_ends, trash, k_scale=k_scale,
+            v_scale=v_scale, rope_sin=rsin, rope_cos=rcos, qblock=qb)
     else:
-        if step.kv_quant:
-            kp, new_ks = _page_write_q8(k_pool, k_scale, k2, page_ids, offs)
-            vp, new_vs = _page_write_q8(v_pool, v_scale, v2, page_ids, offs)
-        else:
-            kp = _page_write(k_pool, k2, page_ids, offs)
-            vp = _page_write(v_pool, v2, page_ids, offs)
-        attn4 = ragged_paged_attention(q4, kp, vp, tables, kv_lens,
-                                       q_starts, q_lens, k_scale=new_ks,
-                                       v_scale=new_vs)
+        attn4, kp, vp = fused_ragged_paged_attention(
+            q3, k2, v2, k_pool, v_pool, tables, kv_lens, q_starts,
+            q_lens, w_starts, w_flats, w_ends, trash, rope_sin=rsin,
+            rope_cos=rcos, qblock=qb)
     attn = _token_gather(
-        attn4.reshape([r_rows * qb, att.num_heads, att.head_dim]), flat_idx)
+        attn4.reshape([r_rows * qb, att.num_heads, att.head_dim]),
+        flat_idx)
     x = x + att.o_proj(attn.reshape([1, t, -1]))
     x = x + layer.mlp(layer.post_attention_layernorm(x))
-    return x, [kp, vp] + ([new_ks, new_vs] if step.kv_quant else []), None
+    return x, [kp, vp] + ([new_ks, new_vs] if step.kv_quant else []), \
+        None
 
 
 @pytest.fixture(scope="module")
@@ -119,9 +86,7 @@ def _run(model, **kw):
 
 
 @pytest.mark.parametrize("kv_dtype", [None, "int8"])
-@pytest.mark.parametrize("program", [
-    dict(), dict(fused_rope=False), dict(fused_kv=False)],
-    ids=["rope_fused", "fused_kv", "two_op"])
+@pytest.mark.parametrize("program", [dict()], ids=["rope_fused"])
 def test_llama_layer_step_is_the_engines_old_loop(model, monkeypatch,
                                                   program, kv_dtype):
     kw = dict(program, kv_dtype=kv_dtype)

@@ -259,8 +259,6 @@ def test_decode_scan_equals_the_stepped_decode(setup):
 @pytest.mark.parametrize("kw,what", [
     (dict(kv_dtype="int8"), "kv_dtype=int8"),
     (dict(kv_tier=True), "kv_tier"),
-    (dict(fused_kv=False), "fused_kv=False"),
-    (dict(fused_rope=False), "fused_rope=False"),
     (dict(spec_k=2), "spec_k"),
 ])
 def test_features_that_do_not_reach_latent_pages_are_refused(setup, kw,

@@ -1,9 +1,11 @@
 """A dispatch hands the step program ONE staged host buffer (ISSUE 31)
-where it handed it 21 arrays. ``legacy_*`` below are the engine's
-``_sample_arrays``, ``_dispatch_rows``, ``_warm_mixed`` and
+where it handed it one array a field. ``legacy_*`` below are the
+engine's ``_sample_arrays``, ``_dispatch_rows``, ``_warm_mixed`` and
 ``_ensure_mixed_compiled`` as they stood before (commit 985d725), frozen
-here word for word: an engine that runs them calls ``_mixed_forward``
-with its 21 tensors, one ``jnp.asarray`` each. The packed engine must
+here word for word but for the three fields (``page_ids``, ``offs``,
+``row_tok``) that PR 32 took away with the programs that read them: an
+engine that runs them calls ``_mixed_forward`` with its 18 tensors, one
+``jnp.asarray`` each. The packed engine must
 leave the same tokens and the same pool bytes, bitwise, over runs that
 mix chunked prefill with decode-only dispatches: float and int8 pages,
 speculation, sampled rows, the latent/expert model."""
@@ -26,7 +28,7 @@ from paddle_tpu.observability.trace import span as _span
 
 
 # ---------------------------------------------------------------------------
-# the 21-argument dispatch, frozen
+# the 18-argument dispatch, frozen
 # ---------------------------------------------------------------------------
 def legacy_sample_arrays(self, reqs, r_cap):
     b = self.sample_slots
@@ -124,9 +126,6 @@ def legacy_dispatch_rows(self, rows, cow):
     # here — cross-thread releases defer past the whole _entry
     tokens = np.zeros((1, t_cap), np.int64)
     pos = np.zeros((1, t_cap), np.int32)
-    page_ids = np.full((t_cap,), self.trash_page, np.int32)
-    offs = np.zeros((t_cap,), np.int32)
-    row_tok = np.zeros((r_cap, qb), np.int32)
     flat_idx = np.full((t_cap,), r_cap * qb - 1, np.int32)
     last_idx = np.zeros((r_cap,), np.int32)
     tables = np.full((r_cap, self.width), self.trash_page, np.int32)
@@ -150,12 +149,8 @@ def legacy_dispatch_rows(self, rows, cow):
         kv_lens[i] = start + n
         q_starts[i] = start
         q_lens[i] = n
-        pg, of = self.alloc.page_positions(sid, start, n)
         tokens[0, t:t + n] = toks
         pos[0, t:t + n] = start + np.arange(n)
-        page_ids[t:t + n] = pg
-        offs[t:t + n] = of
-        row_tok[i, :n] = np.arange(t, t + n)
         flat_idx[t:t + n] = i * qb + np.arange(n)
         flat_start.append(t)
         if sid not in seq_first:
@@ -180,9 +175,6 @@ def legacy_dispatch_rows(self, rows, cow):
             nxt, new_k, new_v, new_ks, new_vs, stats = sf(
                 Tensor(jnp.asarray(tokens)),
                 Tensor(jnp.asarray(pos)),
-                Tensor(jnp.asarray(page_ids)),
-                Tensor(jnp.asarray(offs)),
-                Tensor(jnp.asarray(row_tok)),
                 Tensor(jnp.asarray(flat_idx)),
                 Tensor(jnp.asarray(last_idx)),
                 Tensor(jnp.asarray(tables)),
@@ -231,10 +223,6 @@ def legacy_warm_mixed(self, t_cap):
         _, wk, wv, wks, wvs, _ = sf(
             Tensor(jnp.asarray(np.zeros((1, t_cap), np.int64))),
             Tensor(jnp.asarray(np.zeros((1, t_cap), np.int32))),
-            Tensor(jnp.asarray(np.full((t_cap,), self.trash_page,
-                                       np.int32))),
-            Tensor(jnp.asarray(np.zeros((t_cap,), np.int32))),
-            Tensor(jnp.asarray(np.zeros((r_cap, qb), np.int32))),
             Tensor(jnp.asarray(np.zeros((t_cap,), np.int32))),
             Tensor(jnp.asarray(np.zeros((r_cap,), np.int32))),
             Tensor(jnp.asarray(np.full((r_cap, self.width),
@@ -391,10 +379,8 @@ def _same(new, old, pools):
 
 
 @pytest.mark.parametrize("kv_dtype", [None, "int8"])
-@pytest.mark.parametrize("program", [
-    dict(), dict(fused_rope=False), dict(fused_kv=False)],
-    ids=["rope_fused", "fused_kv", "two_op"])
-def test_packed_dispatch_is_the_21_argument_dispatch(llama, monkeypatch,
+@pytest.mark.parametrize("program", [dict()], ids=["rope_fused"])
+def test_packed_dispatch_is_the_18_argument_dispatch(llama, monkeypatch,
                                                      program, kv_dtype):
     new, old = _both(llama, monkeypatch, kv_dtype=kv_dtype, **program)
     _same(new, old, 8 if kv_dtype else 4)
@@ -451,18 +437,14 @@ def test_unused_slots_of_every_dispatch_read_their_fill(llama):
         assert (f["q_lens"][:rows] > 0).all()           # rows lie first
         assert (f["tokens"][0, toks:] == 0).all()
         assert (f["pos"][0, toks:] == 0).all()
-        assert (f["page_ids"][toks:] == 64).all()       # the trash page
-        assert (f["offs"][toks:] == 0).all()
         assert (f["flat_idx"][toks:] == r_cap * qb - 1).all()
-        assert (f["tables"][rows:] == 64).all()
+        assert (f["tables"][rows:] == 64).all()          # the trash page
         for i in range(rows):
             # a sequence's pages first (at least its context's), then
             # the trash page to the table's end
             held = int((f["tables"][i] != 64).sum())
             assert held >= -(-int(f["kv_lens"][i]) // 8)
             assert (f["tables"][i, held:] == 64).all()
-            assert (f["row_tok"][i, f["q_lens"][i]:] == 0).all()
-        assert (f["row_tok"][rows:] == 0).all()
         for name in ("last_idx", "kv_lens", "q_starts", "w_starts",
                      "w_flats", "w_ends", "temps", "top_ks", "seeds",
                      "cmodes", "slot_vals"):

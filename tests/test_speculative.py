@@ -343,8 +343,9 @@ class TestSpecLifecycle:
 class TestInt8KV:
     def test_quantized_attention_parity_vs_float_pages(self):
         """Attention over int8 pages + scale sidecars matches float
-        pages within int8 tolerance — the kv_int8_parity contract, at
-        the kernel level (Pallas impl AND XLA reference)."""
+        pages within int8 tolerance: the kv_int8_parity contract, as
+        the XLA reference reads int8 pools (tests/test_ragged_attention
+        holds the int8 Pallas program to this reference tightly)."""
         import jax.numpy as jnp
         from paddle_tpu.inference.paged_cache import quantize_kv_int8
         from paddle_tpu.ops import ragged_paged_attention as RPA
@@ -370,16 +371,9 @@ class TestInt8KV:
         got_xla = RPA.ragged_paged_attention_xla(
             q, kq, vq, tables, kv, q_starts, q_lens,
             k_scale=ks, v_scale=vs)
-        got_pl = RPA._ragged_impl_q8(
-            q, kq, vq, ks, vs, tables, kv, q_starts, q_lens,
-            scale=1.0 / float(np.sqrt(d)))
         scale = float(jnp.max(jnp.abs(ref)))
-        for got in (got_xla, got_pl):
-            err = float(jnp.max(jnp.abs(got - ref)))
-            assert err < 0.05 * max(scale, 1.0), err
-        # and the two int8 paths agree with each other tightly
-        err = float(jnp.max(jnp.abs(got_pl - got_xla)))
-        assert err < 1e-4, err
+        err = float(jnp.max(jnp.abs(got_xla - ref)))
+        assert 0.0 < err < 0.05 * max(scale, 1.0), err
 
     def test_int8_engine_deterministic_and_spec_exact(self, model):
         """int8 outputs are deterministic across engines, and a

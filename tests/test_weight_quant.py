@@ -90,7 +90,15 @@ class TestKernel:
         q, s = quantize_weight(_rand(k, n, seed=4, scale=0.1), block)
         yk = _dequant_matmul(x, q, s, block, use_kernel=True)
         yx = _dequant_matmul(x, q, s, block, use_kernel=False)
-        np.testing.assert_array_equal(np.asarray(yk), np.asarray(yx))
+        if m == 1:
+            # a few ulp, not bitwise: XLA:CPU lowers the one-row dot
+            # to a matrix-vector product, the kernel pads the row to a
+            # tile and takes the matrix-matrix one, and the two add the
+            # 32 products in different orders
+            np.testing.assert_allclose(np.asarray(yk), np.asarray(yx),
+                                       rtol=1e-6, atol=0.0)
+        else:
+            np.testing.assert_array_equal(np.asarray(yk), np.asarray(yx))
 
     def test_bf16_x_exact_parity(self):
         x = _rand(9, 64, seed=5).astype(jnp.bfloat16)
