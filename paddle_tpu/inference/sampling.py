@@ -209,27 +209,37 @@ def sampled_next_tokens(logits, temps, top_ps, top_ks, seeds, positions,
                       & (slot_ids[:, :, None] >= 0), axis=1)    # [N, V]
     l = jnp.where((cmodes[:, None] == 1) & ~allowed, _MASKED, l)
     greedy = jnp.argmax(l, axis=-1)
-    # -- sampled branch (same arrays; rows select at the end) ----------
-    ls = l / jnp.maximum(temps, 1e-6)[:, None]
-    sl = jnp.sort(ls, axis=-1)[:, ::-1]                  # descending
-    kk = jnp.where(top_ks > 0, jnp.minimum(top_ks, v), v)
-    kth = jnp.take_along_axis(sl, (kk - 1)[:, None], axis=1)
-    sp = jax.nn.softmax(sl, axis=-1)
-    cum_before = jnp.cumsum(sp, axis=-1) - sp
-    # nucleus: keep the shortest prefix reaching top_p mass (the first
-    # token crossing the boundary included); the mask is a prefix of
-    # the sort, so its last kept value is a per-row logit cutoff
-    n_keep = jnp.maximum(
-        jnp.sum(cum_before < top_ps[:, None], axis=-1), 1)
-    pth = jnp.take_along_axis(sl, (n_keep - 1)[:, None], axis=1)
-    keep = ls >= jnp.maximum(kth, pth)
-    # counter-based randomness: key = fold_in(PRNGKey(seed), position)
-    # — a pure function of (seed, position), nothing else
-    def _gumbel(seed, pos):
-        key = jax.random.fold_in(jax.random.PRNGKey(seed), pos)
-        return jax.random.gumbel(key, (v,), dtype=jnp.float32)
 
-    g = jax.vmap(_gumbel)(seeds, positions)
-    z = jnp.where(keep, ls + g, -jnp.inf)
-    sampled = jnp.argmax(z, axis=-1)        # gumbel-max ~ softmax(keep)
-    return jnp.where(temps > 0, sampled, greedy).astype(jnp.int64)
+    def _sampled():
+        ls = l / jnp.maximum(temps, 1e-6)[:, None]
+        sl = jnp.sort(ls, axis=-1)[:, ::-1]              # descending
+        kk = jnp.where(top_ks > 0, jnp.minimum(top_ks, v), v)
+        kth = jnp.take_along_axis(sl, (kk - 1)[:, None], axis=1)
+        sp = jax.nn.softmax(sl, axis=-1)
+        cum_before = jnp.cumsum(sp, axis=-1) - sp
+        # nucleus: keep the shortest prefix reaching top_p mass (the
+        # first token crossing the boundary included); the mask is a
+        # prefix of the sort, so its last kept value is a per-row
+        # logit cutoff
+        n_keep = jnp.maximum(
+            jnp.sum(cum_before < top_ps[:, None], axis=-1), 1)
+        pth = jnp.take_along_axis(sl, (n_keep - 1)[:, None], axis=1)
+        keep = ls >= jnp.maximum(kth, pth)
+        # counter-based randomness: key = fold_in(PRNGKey(seed),
+        # position) — a pure function of (seed, position), nothing else
+        def _gumbel(seed, pos):
+            key = jax.random.fold_in(jax.random.PRNGKey(seed), pos)
+            return jax.random.gumbel(key, (v,), dtype=jnp.float32)
+
+        g = jax.vmap(_gumbel)(seeds, positions)
+        z = jnp.where(keep, ls + g, -jnp.inf)
+        sampled = jnp.argmax(z, axis=-1)    # gumbel-max ~ softmax(keep)
+        return jnp.where(temps > 0, sampled, greedy)
+
+    # what only a sampling row needs (the vocabulary sort above all)
+    # sits under a branch the device takes from ``temps``: a batch in
+    # which no row samples costs the argmax and nothing else, and one
+    # with a sampling row runs the same arithmetic on every row as a
+    # straight line would
+    return jax.lax.cond(jnp.any(temps > 0), _sampled,
+                        lambda: greedy).astype(jnp.int64)

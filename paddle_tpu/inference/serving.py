@@ -647,10 +647,12 @@ class LlamaServingEngine:
         # grows a vectorized per-row sample step next to the argmax —
         # every sampler knob is runtime data ([R]-shaped arrays), so
         # compiled shapes never fork per request config and greedy
-        # rows stay bitwise-exact. sampling=False restores the exact
-        # pre-sampling program (no vocab sort on the hot path) for
-        # greedy-only deployments; PADDLE_TPU_SAMPLING=0 is the fleet
-        # knob.
+        # rows stay bitwise-exact. A dispatch in which no row samples
+        # takes the argmax and nothing else (the vocab sort sits under
+        # a branch the device takes from ``temps``), so sampling=False,
+        # which restores the exact pre-sampling program, buys a
+        # greedy-only deployment no speed; PADDLE_TPU_SAMPLING=0 is the
+        # fleet knob.
         if sampling is None:
             sampling = os.environ.get(
                 "PADDLE_TPU_SAMPLING", "1").lower() \
@@ -1011,7 +1013,8 @@ class LlamaServingEngine:
         per-row sample step (:func:`sampled_next_tokens`): temperature
         / top-p / top-k / seed / bias-constraint slots ride as
         ``[R]``-shaped runtime arrays, greedy rows (temperature 0)
-        still take the bitwise argmax of the same logits, and the
+        still take the bitwise argmax of the same logits (a dispatch
+        with no sampling row computes nothing else), and the
         threefry key folds the request seed with the token's absolute
         position — so the draw at a position never depends on how it
         was dispatched (step, scan tick, or speculative verify row).
@@ -1466,7 +1469,7 @@ class LlamaServingEngine:
         what :meth:`_apply_rows` needs and what the spans say: ``(next
         tokens still on the device, each row's first index in the T
         axis, enqueue seconds, cold, needs_mixed, t_cap, bytes handed
-        to the device)``."""
+        to the device, rows that sample)``."""
         # speculative verify rows are multi-token decode rows: they
         # need the chunk-shaped program exactly like prefill chunks do
         needs_mixed = any(n > 1 or not is_dec
@@ -1544,6 +1547,9 @@ class LlamaServingEngine:
             w_starts[i], w_flats[i] = seq_first[sid]
             w_ends[i] = seq_last[sid]
         self._sample_arrays([row[0] for row in rows], r_cap, into=f)
+        # 0 is the false side of the sample step's branch: the program
+        # takes the argmax and nothing else
+        sampled = int(np.count_nonzero(f["temps"] > 0))
         self._record_shape("mixed", t_cap)
         self._arm_watchdog(cold)
         with self._lock:
@@ -1562,7 +1568,8 @@ class LlamaServingEngine:
         self._note_mixed_bytes(t_cap)
         self._flush_deferred()
         self._layer_stats = stats[0] if stats else None
-        return nxt, flat_start, dur, cold, needs_mixed, t_cap, buf.nbytes
+        return (nxt, flat_start, dur, cold, needs_mixed, t_cap,
+                buf.nbytes, sampled)
 
     def _apply_rows(self, rows, out, flat_start, dur, cold, needs_mixed):
         """Apply one mixed dispatch's next tokens ``out`` (``[t_cap]``,
@@ -1747,9 +1754,10 @@ class LlamaServingEngine:
                  # warm-up recipes
                  str(self.k_pools[0]._data.dtype)
                  if self.k_pools else dt, bool(self.spec_k),
-                 # the sample step adds inputs + a vocab sort to every
-                 # serving program, and the slot width shapes the bias
-                 # arrays — both fork the compiled surface
+                 # the sample step adds inputs + a branch that holds a
+                 # vocab sort to every serving program, and the slot
+                 # width shapes the bias arrays — both fork the compiled
+                 # surface
                  bool(self.sample_enabled), self.sample_slots,
                  # weight-only int8 forks every serving program: the
                  # projections trade one bf16 weight input for an int8
@@ -2550,8 +2558,8 @@ class LlamaServingEngine:
                     disp.cancel()
                     return 0, 0
             with _span("serving.build", step=step) as build:
-                nxt, flat_start, dur, cold, needs_mixed, t_cap, nbytes = \
-                    self._dispatch_rows(rows, cow)
+                (nxt, flat_start, dur, cold, needs_mixed, t_cap, nbytes,
+                 sampled) = self._dispatch_rows(rows, cow)
                 # what the host handed the program: one staged buffer
                 build.set(h2d_arrays=1, h2d_bytes=nbytes)
             with _span("serving.wait", step=step):
@@ -2574,7 +2582,7 @@ class LlamaServingEngine:
             disp.set(rows=len(rows),
                      decode_rows=sum(1 for row in rows if row[5]),
                      prefill_tokens=prefill, tokens=tokens, t_cap=t_cap,
-                     kind=kind,
+                     kind=kind, sampled_rows=sampled,
                      kv_pages=self._kv_pages(row[2] + row[3]
                                              for row in rows),
                      table_slots=r_cap * self.width)
@@ -2740,7 +2748,7 @@ class LlamaServingEngine:
                     disp.cancel()
                     return 0
             with _span("serving.build", step=step) as build:
-                out, dur, cold, h2d = self._dispatch_scan(
+                out, dur, cold, h2d, sampled = self._dispatch_scan(
                     n, live, sids, last_tok, start_lens, cow)
                 # the scan still takes its arrays one by one
                 build.set(h2d_arrays=len(h2d),
@@ -2772,6 +2780,7 @@ class LlamaServingEngine:
             # a scan's contexts grow a token a tick: its first tick's
             disp.set(rows=len(live), decode_rows=len(live),
                      prefill_tokens=0, tokens=tokens, t_cap=t_cap,
+                     sampled_rows=sampled,
                      kv_pages=self._kv_pages(start_lens[sid] + 1
                                              for sid in sids),
                      table_slots=self.max_batch * self.width)
@@ -2815,7 +2824,7 @@ class LlamaServingEngine:
     def _dispatch_scan(self, n, live, sids, last_tok, start_lens, cow):
         """Build and enqueue the ``n``-tick scan over the planned rows.
         Returns ``(the program's outputs, enqueue seconds, cold, the
-        arrays handed to the device)``."""
+        arrays handed to the device, rows that sample)``."""
         for old, new in cow:
             self._copy_page(old, new)
         # as in step(): each new scan length compiles on its first
@@ -2832,8 +2841,9 @@ class LlamaServingEngine:
             tables[i, :len(t)] = t
             lens[i] = start_lens[sid] + 1       # first new token incl.
             tokens[i, 0] = last_tok[i]
-        h2d = [jnp.asarray(a) for a in (
-            tokens, tables, lens, *self._sample_arrays(live, b))]
+        samp = self._sample_arrays(live, b)
+        sampled = int(np.count_nonzero(samp[0] > 0))        # temps
+        h2d = [jnp.asarray(a) for a in (tokens, tables, lens, *samp)]
         sf = self._ensure_scan_compiled(n)
         self._arm_watchdog(cold)
         with self._lock:
@@ -2852,7 +2862,7 @@ class LlamaServingEngine:
             self._warmed_keys.add(key)
         self._flush_deferred()
         self._adopt_scan_pools(out)
-        return out, dur, cold, h2d
+        return out, dur, cold, h2d, sampled
 
     def _scan_fits(self, live, n):
         """Largest scan <= n whose page reservations fit the pool and

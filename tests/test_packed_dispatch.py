@@ -206,7 +206,9 @@ def legacy_dispatch_rows(self, rows, cow):
         self.k_scales, self.v_scales = list(new_ks), list(new_vs)
     self._layer_stats = stats[0] if stats else None
     # the one edit: the caller now also asks for the bytes handed over
-    return nxt, flat_start, dur, cold, needs_mixed, t_cap, 0
+    # and (PR 33) for the rows that sample
+    return (nxt, flat_start, dur, cold, needs_mixed, t_cap, 0,
+            int(np.count_nonzero(temps > 0)))
 
 
 def legacy_warm_mixed(self, t_cap):

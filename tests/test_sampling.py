@@ -189,6 +189,189 @@ def test_sample_step_constraint_mask():
 
 
 # ---------------------------------------------------------------------------
+# the branch the device takes from ``temps`` (PR 33): bitwise the
+# straight line it replaced, and the sort lives under the conditional
+# ---------------------------------------------------------------------------
+def _frozen_sampled_next_tokens(logits, temps, top_ps, top_ks, seeds,
+                                positions, slot_ids, slot_vals, cmodes):
+    """``sampled_next_tokens`` as it stood before PR 33, frozen: the
+    sampled values of every row computed in a straight line and
+    selected at the end. The reference of the parity test below."""
+    import jax
+    import jax.numpy as jnp
+
+    masked = -1e30
+    n, v = logits.shape
+    l = logits.astype(jnp.float32)
+    rows = jnp.arange(n, dtype=jnp.int32)
+    l = l.at[rows[:, None], jnp.clip(slot_ids, 0, v - 1)].add(slot_vals)
+    tok = jnp.arange(v, dtype=jnp.int32)[None, None, :]
+    allowed = jnp.any((slot_ids[:, :, None] == tok)
+                      & (slot_ids[:, :, None] >= 0), axis=1)
+    l = jnp.where((cmodes[:, None] == 1) & ~allowed, masked, l)
+    greedy = jnp.argmax(l, axis=-1)
+    ls = l / jnp.maximum(temps, 1e-6)[:, None]
+    sl = jnp.sort(ls, axis=-1)[:, ::-1]
+    kk = jnp.where(top_ks > 0, jnp.minimum(top_ks, v), v)
+    kth = jnp.take_along_axis(sl, (kk - 1)[:, None], axis=1)
+    sp = jax.nn.softmax(sl, axis=-1)
+    cum_before = jnp.cumsum(sp, axis=-1) - sp
+    n_keep = jnp.maximum(
+        jnp.sum(cum_before < top_ps[:, None], axis=-1), 1)
+    pth = jnp.take_along_axis(sl, (n_keep - 1)[:, None], axis=1)
+    keep = ls >= jnp.maximum(kth, pth)
+
+    def _gumbel(seed, pos):
+        key = jax.random.fold_in(jax.random.PRNGKey(seed), pos)
+        return jax.random.gumbel(key, (v,), dtype=jnp.float32)
+
+    g = jax.vmap(_gumbel)(seeds, positions)
+    z = jnp.where(keep, ls + g, -jnp.inf)
+    sampled = jnp.argmax(z, axis=-1)
+    return jnp.where(temps > 0, sampled, greedy).astype(jnp.int64)
+
+
+_N, _V = 6, 257
+
+
+def _branch_case(name):
+    """Row parameters of one case of the parity test, [_N] rows over
+    a vocabulary of _V."""
+    temps = np.zeros((_N,), np.float32)
+    over = {"seeds": np.arange(_N, dtype=np.int32) * 7 + 3,
+            "positions": np.arange(_N, dtype=np.int32) * 5 + 11}
+    slot_ids = np.full((_N, 4), -1, np.int32)
+    slot_vals = np.zeros((_N, 4), np.float32)
+    if name == "one_sampling_row":
+        temps[2] = 0.8
+    elif name == "all_sampling":
+        temps[:] = np.linspace(0.3, 1.7, _N)
+    elif name == "greedy_bias":
+        slot_ids[1, :2] = [5, 200]
+        slot_vals[1, :2] = [40.0, -40.0]
+        slot_ids[4, 0] = 17
+        slot_vals[4, 0] = 25.0
+    elif name == "greedy_constraint":
+        slot_ids[0, :3] = [9, 10, 250]
+        slot_ids[3, :2] = [1, 2]
+        over["cmodes"] = np.array([1, 0, 0, 1, 0, 0], np.int32)
+    elif name == "top_k_top_p":
+        temps[:] = 1.0
+        temps[5] = 0.0                      # a greedy row beside them
+        over["top_ks"] = np.array([3, 0, 40, 0, 1, 0], np.int32)
+        over["top_ps"] = np.array([1.0, 0.5, 0.9, 0.05, 1.0, 1.0],
+                                  np.float32)
+    else:
+        assert name == "all_greedy"
+    return dict(over, temps=temps, slot_ids=slot_ids,
+                slot_vals=slot_vals)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", [
+    "all_greedy", "one_sampling_row", "all_sampling", "greedy_bias",
+    "greedy_constraint", "top_k_top_p"])
+def test_sample_step_branch_bitwise_vs_straight_line(case, dtype):
+    """Whichever side of the branch a batch takes, every row's token
+    is the one the straight-line sample step gave it."""
+    import jax
+    import jax.numpy as jnp
+
+    rng = np.random.RandomState(33)
+    logits = jnp.asarray(rng.randn(_N, _V).astype(np.float32) * 3.0,
+                         dtype=dtype)
+    args = _step_args(_N, _V, **_branch_case(case))
+    got = jax.jit(sampled_next_tokens)(logits, **args)
+    want = jax.jit(_frozen_sampled_next_tokens)(logits, **args)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+    if case == "one_sampling_row":
+        # the greedy rows beside a sampling row keep the all-greedy
+        # batch's tokens
+        alone = jax.jit(sampled_next_tokens)(
+            logits, **_step_args(_N, _V, **_branch_case("all_greedy")))
+        keep = np.asarray(args["temps"]) == 0
+        assert np.array_equal(np.asarray(got)[keep],
+                              np.asarray(alone)[keep])
+
+
+def _hlo_computations(text):
+    """{name: (body lines, names it calls through anything but a
+    conditional's branches, names a conditional in it branches to)}
+    of a compiled module's text."""
+    import re
+
+    comps, name, lines = {}, None, []
+    for line in text.splitlines():
+        m = re.match(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\{\s*$", line)
+        if m and name is None:
+            name, lines = m.group(1), []
+        elif line.startswith("}") and name is not None:
+            comps[name] = lines
+            name = None
+        elif name is not None:
+            lines.append(line)
+    out, names = {}, set(comps)
+    for cname, body in comps.items():
+        calls, branches = set(), set()
+        for line in body:
+            refs = set(re.findall(r"%([\w.\-]+)", line)) & names
+            if re.search(r"\bconditional\(", line):
+                branches |= refs
+            else:
+                calls |= refs
+        out[cname] = (body, calls, branches)
+    return out
+
+
+def _sorts_by_side(fn):
+    """(has a conditional, sort instructions the compiled ``fn`` can
+    reach without entering a conditional's branch, those it reaches
+    only through one)."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    text = jax.jit(fn).lower(
+        jnp.zeros((_N, _V), jnp.float32),
+        **_step_args(_N, _V)).compile().as_text()
+    comps = _hlo_computations(text)
+    entry = re.search(r"^ENTRY\s+%?([\w.\-]+)", text, re.M).group(1)
+
+    def reach(through_branches):
+        seen, todo = set(), [entry]
+        while todo:
+            c = todo.pop()
+            if c in seen:
+                continue
+            seen.add(c)
+            _, calls, branches = comps[c]
+            todo += calls | (branches if through_branches else set())
+        return seen
+
+    def sorts(names):
+        return [ln for c in names for ln in comps[c][0]
+                if re.search(r"\bsort\(", ln)]
+
+    outside = reach(False)
+    return (any(comps[c][2] for c in outside), sorts(outside),
+            sorts(reach(True) - outside))
+
+
+def test_sample_step_sort_lives_under_the_conditional():
+    """The compiled sample step holds a ``conditional``, and its
+    vocabulary sort is reachable only through that conditional's
+    branch computations: a batch in which no row samples cannot run
+    it. The frozen straight line, read the same way, sorts outside."""
+    has_cond, outside, inside = _sorts_by_side(sampled_next_tokens)
+    assert has_cond and not outside and inside
+    has_cond, outside, inside = _sorts_by_side(
+        _frozen_sampled_next_tokens)
+    assert not has_cond and outside and not inside
+
+
+# ---------------------------------------------------------------------------
 # greedy stays bitwise against the pre-sampling program
 # ---------------------------------------------------------------------------
 def test_greedy_bitwise_vs_sampling_off(model):

@@ -11,6 +11,7 @@ import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu.inference.cluster import ServingCluster
+from paddle_tpu.inference.sampling import SamplingParams
 from paddle_tpu.inference.serving import LlamaServingEngine, Request
 from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from paddle_tpu.observability import metrics as om
@@ -296,6 +297,65 @@ def test_dispatch_says_what_the_kernel_walks(model):
     # three sequences of at most 46 tokens in pages of 8: the walk is
     # a small part of the tables
     assert max(d["args"]["kv_pages"] for d in disp) <= 3 * 6
+
+
+@pytest.mark.parametrize("case", ["greedy", "mixed", "scan"])
+def test_dispatch_says_how_many_rows_sample(model, case):
+    """``serving.dispatch`` carries ``sampled_rows``, the dispatch's
+    rows whose request has ``temperature > 0`` (counted here from the
+    scheduled rows' own requests): 0 is the side of the sample step's
+    branch that takes the argmax and nothing else."""
+    om.default_registry().clear()
+    engine = _engine(model)
+    want = {}
+
+    def samples(r):
+        return r.sampling is not None and r.sampling.temperature > 0
+
+    rows_of, scan_of = engine._dispatch_rows, engine._dispatch_scan
+
+    def spy_rows(rows, cow):
+        want[engine._dispatch_count - 1] = sum(
+            samples(row[0]) for row in rows)
+        return rows_of(rows, cow)
+
+    def spy_scan(n, live, *rest):
+        want[engine._dispatch_count - 1] = sum(samples(r) for r in live)
+        return scan_of(n, live, *rest)
+
+    engine._dispatch_rows, engine._dispatch_scan = spy_rows, spy_scan
+    hot = SamplingParams(temperature=0.9, top_p=0.9, seed=5)
+    # a bias is no temperature: such a row is a greedy row
+    params = {"greedy": [None, SamplingParams(logit_bias={7: 3.0}), None],
+              "mixed": [hot, None, hot],
+              "scan": [None, hot, None]}[case]
+    reqs = [Request(list(range(1, n + 1)), max_new_tokens=6, sampling=sp)
+            for n, sp in zip((40, 5, 23), params)]
+    otrace.clear()
+    for r in reqs:
+        engine.add_request(r)
+    while any(not r.done for r in reqs):
+        if case == "scan":
+            assert engine.decode_many(4) > 0
+        else:
+            engine.step()
+    disp = _by(otrace.get_events(), "serving.dispatch")
+    assert disp and len(disp) == len(want)
+    for d in disp:
+        a = d["args"]
+        assert a["sampled_rows"] == want[a["step"]] <= a["rows"]
+    seen = {d["args"]["sampled_rows"] for d in disp}
+    kinds = {d["args"]["kind"] for d in disp}
+    if case == "greedy":
+        assert seen == {0}
+    elif case == "mixed":
+        # the 40-token prompt is several rows of one dispatch, each
+        # its request's; decode-only steps carry both sampling rows
+        assert max(seen) >= 2 and kinds >= {"mixed", "decode"}
+    else:
+        # the sampling request retires first: later scans carry none
+        assert {d["args"]["sampled_rows"] for d in disp
+                if d["args"]["kind"] == "scan"} == {0, 1}
 
 
 def test_metrics_off_records_nothing_and_serves_the_same(model,
