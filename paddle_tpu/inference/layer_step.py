@@ -10,7 +10,27 @@ with no head axis) and runs its own step over its pages
 `ServingStep` the engine hands it. ``pages`` are the layer's pools in
 the order it stated them (then their int8 scale sidecars where the
 engine quantizes pages); ``stats`` is None or a small int32 array the
-host reads with the tokens."""
+host reads with the tokens.
+
+Layers of one model may keep different things. Beside the list above
+(every layer alike: the Llama and the latent layers' statement), a layer
+may state one of:
+
+- `PagedKV` ``(heads, width, window=None)``: a K and a V pool ``[P,
+  heads, page, width]``. Without a window they hold the whole context
+  behind the engine's block tables; with one, a RING of pages a sequence
+  slot (``ServingStep.ring_tables``) that holds the window, one
+  dispatch's chunk and a page, whatever the context;
+- `SlotState` ``(shapes)``: arrays of fixed shape a sequence slot
+  (``[slots + 1, *shape]``, the last slot for rows that are none), which
+  live and die with the sequence (``ServingStep.slots``);
+- `SharedPages` ``(layer)``: it keeps nothing and reads (never writes)
+  the pools of layer ``layer``, as that layer's step left them;
+- ``None``: it keeps nothing.
+
+A layer may also hand something on to later layers of the same step:
+``serving_step`` then returns a fourth value, a dict, which the engine
+merges into ``ServingStep.carry``."""
 
 from __future__ import annotations
 
@@ -21,7 +41,31 @@ import numpy as np
 from ..framework.tensor import run_op
 from ..ops.ragged_paged_attention import rope_tables
 
-__all__ = ["DispatchLayout", "ServingStep"]
+__all__ = ["DispatchLayout", "ServingStep", "PagedKV", "SlotState",
+           "SharedPages"]
+
+
+class PagedKV:
+    """A K and a V pool of ``heads`` heads ``width`` wide; with
+    ``window`` only the last ``window`` tokens are ever read."""
+
+    def __init__(self, heads, width, window=None):
+        self.heads, self.width, self.window = int(heads), int(width), window
+
+
+class SlotState:
+    """Arrays a sequence keeps whatever its length: ``shapes`` is a list
+    of ``(shape, dtype)``, one pool each."""
+
+    def __init__(self, shapes):
+        self.shapes = [(tuple(s), jnp.dtype(d)) for s, d in shapes]
+
+
+class SharedPages:
+    """This layer reads the pools that layer ``layer`` keeps."""
+
+    def __init__(self, layer):
+        self.layer = int(layer)
 
 
 def _token_gather(x, idx):
@@ -49,7 +93,8 @@ class DispatchLayout:
     tokens, tables ``width`` wide, ``sample_slots`` bias slots a row;
     ``trash_page`` fills what no row claims."""
 
-    def __init__(self, t_cap, r_cap, qb, width, sample_slots, trash_page):
+    def __init__(self, t_cap, r_cap, qb, width, sample_slots, trash_page,
+                 trash_slot=None):
         t, r, b = int(t_cap), int(r_cap), int(sample_slots)
         i32, f32 = np.int32, np.float32
         # (name, shape, dtype, what an unused slot reads)
@@ -73,6 +118,10 @@ class DispatchLayout:
             ("slot_vals", (r, b), f32, 0.0),
             ("cmodes", (r,), i32, 0),
         )
+        if trash_slot is not None:
+            # a model whose layers keep a state or a ring a sequence
+            # slot: each row's slot, a 19th field
+            spec += (("slots", (r,), i32, int(trash_slot)),)
         self.shape = (t, r, int(qb), int(width), b)
         fields, at = [], 0
         for name, shape, dtype, _ in spec:
@@ -119,11 +168,18 @@ class ServingStep:
     _mixed_forward` for the shapes): ``tokens`` packed tokens in
     ``rows`` rows of at most ``qblock`` query tokens, and what the
     engine decided of its pools (``kv_quant``, ``trash_page``). Tables
-    a layer kind needs (rotary sin/cos) are made once a step and shared
-    by its layers."""
+    a layer kind needs (rotary sin/cos, a window's ring tables) are made
+    once a step and shared by its layers. ``slots [R]`` (None for a
+    model that keeps no state or ring) is each row's sequence slot;
+    ``carry`` holds what earlier layers of this step handed on."""
 
     def __init__(self, engine, qblock, pos, flat_idx, tables, kv_lens,
-                 q_starts, q_lens, w_starts, w_flats, w_ends):
+                 q_starts, q_lens, w_starts, w_flats, w_ends, slots=None):
+        self.slots, self.carry = slots, {}
+        self._ring_pages = engine.ring_pages
+        #: of an engine that prefills one chunk a sequence a dispatch:
+        #: the most rows of a dispatch that are longer than one token
+        self.chunk_rows = engine.chunk_rows
         self.pos, self.flat_idx, self.tables = pos, flat_idx, tables
         self.kv_lens, self.q_starts, self.q_lens = kv_lens, q_starts, q_lens
         self.w_starts, self.w_flats, self.w_ends = w_starts, w_flats, w_ends
@@ -151,6 +207,39 @@ class ServingStep:
         return self._shared(
             ("pairs", dim, base), "serving_rope_tables_interleaved",
             lambda p: rope_tables_interleaved(p, dim, base), self.pos)
+
+    def no_rope(self, head_dim):
+        """sin 0 / cos 1 ``[T, D]`` f32: the rotation that leaves every
+        value as it is (``x * 1 + rot(x) * 0``, exact), for layers
+        without positional encoding."""
+        t = self.tokens
+        return self._shared(
+            ("norope", head_dim), "serving_no_rope",
+            lambda p: (jnp.zeros((t, head_dim), jnp.float32),
+                       jnp.ones((t, head_dim), jnp.float32)), self.pos)
+
+    def ring_tables(self, window):
+        """``[R, W]`` tables of the layers that keep ``window`` keys:
+        logical page ``p`` of the sequence in slot ``s`` lies in page
+        ``s * ring + p % ring`` of their pools, so a page behind the
+        window is the page a later token overwrites. No allocator and
+        nothing from the host but the slots."""
+        ring, width = self._ring_pages(window), self.tables.shape[1]
+        return self._shared(
+            ("ring", window), "serving_ring_tables",
+            lambda s: s.astype(jnp.int32)[:, None] * ring
+            + (jnp.arange(width, dtype=jnp.int32) % ring)[None, :],
+            self.slots)
+
+    def row_index(self):
+        """``[R, QB]`` packed index of every row's slots (row ``r``'s
+        tokens sit back to back from ``w_flats + q_starts - w_starts``)."""
+        from ..ops.selective_scan import row_index
+        t, qb = self.tokens, self.qblock
+        return self._shared(
+            "rows", "serving_row_index",
+            lambda wf, qs, ws: row_index(wf + qs - ws, qb, t),
+            self.w_flats, self.q_starts, self.w_starts)
 
     def token_valid(self):
         """``[T]`` bool: the packed tokens that belong to a row (they

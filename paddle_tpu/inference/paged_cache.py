@@ -71,10 +71,20 @@ class PageAllocator:
     its last reference drops. Writing into a shared page goes through
     :meth:`ensure_writable` — copy-on-write: the writer gets a private
     copy and the shared original stays immutable for its other owners.
+
+    With ``slots`` every admitted sequence also holds one of that many
+    sequence SLOTS until it is released: the index of what a model
+    keeps a sequence whatever its length (a recurrent state, a ring of
+    window pages). :meth:`admit` raises ``MemoryError`` when none is
+    free; ``import_table`` (a sequence resumed from a host tier) takes
+    none, since a slot's contents are not pages.
     """
 
-    def __init__(self, num_pages, page_size, max_pages_per_seq=None):
+    def __init__(self, num_pages, page_size, max_pages_per_seq=None,
+                 slots=0):
         self.num_pages = num_pages
+        self._free_slots = list(range(int(slots) - 1, -1, -1))
+        self._slots: dict[int, int] = {}    # seq_id -> slot
         self.page_size = page_size
         self.max_pages_per_seq = max_pages_per_seq or num_pages
         self._free = list(range(num_pages - 1, -1, -1))
@@ -112,6 +122,14 @@ class PageAllocator:
     def live_sequences(self):
         return sorted(self._tables)
 
+    def slot_of(self, seq_id):
+        """The sequence slot ``seq_id`` holds (allocators with slots)."""
+        return self._slots[seq_id]
+
+    @property
+    def slots_held(self):
+        return len(self._slots)
+
     def admit(self, seq_id, n_tokens, shared_pages=None):
         """Reserve pages for a new sequence of ``n_tokens`` (prefill).
 
@@ -143,6 +161,10 @@ class PageAllocator:
                 raise MemoryError(
                     f"paged cache exhausted: need {need - len(shared)} "
                     f"pages, {len(self._free)} free")
+            if self._free_slots:
+                self._slots[seq_id] = self._free_slots.pop()
+            elif self._slots:
+                raise MemoryError("every sequence slot is held")
             for p in shared:
                 self._refs[p] += 1
             self._tables[seq_id] = shared + [
@@ -239,6 +261,9 @@ class PageAllocator:
                     f"{seq_id} ignored", RuntimeWarning, stacklevel=2)
                 return
             self._lens.pop(seq_id, None)
+            slot = self._slots.pop(seq_id, None)
+            if slot is not None:
+                self._free_slots.append(slot)
             for p in table:
                 if p in self._free_set or p not in self._refs:
                     self.double_free_count += 1

@@ -299,6 +299,9 @@ def _build_model(model_spec):
         "tiny_mla_moe": (models.tiny_mla_moe_config,
                          models.MlaMoeForCausalLM),
         "mla_moe": (models.MlaMoeConfig, models.MlaMoeForCausalLM),
+        "tiny_sambay": (models.tiny_sambay_config,
+                        models.SambaYForCausalLM),
+        "sambay": (models.SambaYConfig, models.SambaYForCausalLM),
     }
     kind = model_spec.get("kind", "tiny_llama")
     if kind not in kinds:

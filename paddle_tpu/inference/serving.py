@@ -139,7 +139,8 @@ from ..observability.trace import record as _record_span
 from ..observability.trace import span as _span
 from ..testing import faults as _faults
 from .kv_tier import KvPageTier, TierError
-from .layer_step import DispatchLayout, ServingStep, _token_gather
+from .layer_step import (DispatchLayout, PagedKV, ServingStep,
+                         SharedPages, SlotState, _token_gather)
 from .paged_cache import PageAllocator
 from .sampling import SamplingParams, sampled_next_tokens
 from .speculative import NGramDrafter
@@ -493,7 +494,7 @@ class LlamaServingEngine:
                  max_pages_per_seq=None, chunk_budget=None,
                  chunk_block=None, decode_ticks=None, burst=None,
                  admit_retries=0, admit_backoff=0.005, stuck_factor=8.0,
-                 stuck_min_timeout=30.0, prefix_cache=True,
+                 stuck_min_timeout=30.0, prefix_cache=None,
                  prefix_cache_pages=None, prewarm=None, kv_dtype=None,
                  spec_k=None, spec_ngram=3, drafter_factory=None,
                  sampling=None, sample_slots=8, weight_dtype=None,
@@ -551,14 +552,48 @@ class LlamaServingEngine:
         # recorder post-mortem. stuck_factor=0/None disables it.
         self.stuck_factor = stuck_factor
         self.stuck_min_timeout = float(stuck_min_timeout)
+        # every layer states what it keeps (see `.layer_step`): a list
+        # of (heads, width), one entry a pool, where all layers of a
+        # model keep the same; or, a layer of a model whose layers
+        # differ, a `PagedKV` (with or without a window), a `SlotState`,
+        # `SharedPages` of another layer, or None
+        specs = [layer.serving_cache() for layer in model.model.layers]
+        mixed_caches = any(not isinstance(sp, list) for sp in specs)
+        # a row's scan starts from its slot's state and writes it back:
+        # two rows of one sequence in one dispatch would both start
+        # from the same stored state, so such a model prefills one
+        # chunk a sequence a dispatch
+        self._single_chunk = any(isinstance(sp, SlotState) for sp in specs)
+        #: and at most this many chunks a dispatch (None: no such bound),
+        #: which lets a scan advance the decode rows one token and only
+        #: the chunk rows further
+        self.chunk_rows = self.rows_cap - max_batch \
+            if self._single_chunk else None
+        #: ``{window: layers that keep it}`` (their rings' counters)
+        self._windows = collections.Counter(
+            sp.window for sp in specs
+            if isinstance(sp, PagedKV) and sp.window)
+        #: layers keep a state or a ring a sequence SLOT: every live
+        #: sequence holds one of ``max_batch`` slots (slot ``max_batch``
+        #: is where rows that are none read and write)
+        self._slotted = self._single_chunk or bool(self._windows)
         # page num_pages-1 is the trash page for inactive batch slots
         self.alloc = PageAllocator(num_pages - 1, page_size,
-                                   max_pages_per_seq)
+                                   max_pages_per_seq,
+                                   slots=max_batch if self._slotted else 0)
         self.width = self.alloc.max_pages_per_seq
         self.trash_page = num_pages - 1
         # shared-prefix KV cache: page-aligned prompt prefixes are
         # prefilled once and later admissions reference the cached
-        # pages (refcounted in the allocator; see prefix_cache.py)
+        # pages (refcounted in the allocator; see prefix_cache.py).
+        # On by default where every layer's cache is pages of the whole
+        # context; asked for where a layer's is not, it is refused below
+        unsupported = {what for layer in model.model.layers
+                       for what in getattr(layer, "serving_unsupported",
+                                           ())}
+        asked_prefix = bool(prefix_cache)
+        if prefix_cache is None:
+            prefix_cache = "prefix_cache" not in unsupported
         from .prefix_cache import PrefixCache
         self.prefix = PrefixCache(self.alloc, page_size,
                                   max_pages=prefix_cache_pages) \
@@ -582,6 +617,11 @@ class LlamaServingEngine:
                 f"got {weight_dtype!r}")
         from ..quant.format import (is_quantized, model_weight_block,
                                     quantize_model, serving_weight_bytes)
+        if weight_dtype == "int8" and "weight_dtype=int8" in unsupported:
+            # refused before the model is quantized in place
+            raise UnsupportedServingFeature(
+                f"{type(model.model.layers[0]).__name__} cannot serve "
+                f"weight_dtype=int8 yet")
         if weight_dtype == "int8" and not is_quantized(model):
             quantize_model(model, block=weight_block)
         self.weight_quant = bool(weight_dtype == "int8"
@@ -604,33 +644,74 @@ class LlamaServingEngine:
                 f"got {kv_dtype!r}")
         self.kv_quant = kv_dtype == "int8"
         pool_dt = jnp.int8 if self.kv_quant else jnp.dtype(str(dt))
-        # every layer states what it keeps per token: a list of (heads,
-        # width), one entry a pool (all layers of a model alike). A
-        # pool with heads is head-major [P, Hk, page, D] (the K/V
+        # pools. Where all layers keep the same list of (heads, width):
+        # a pool with heads is head-major [P, Hk, page, D] (the K/V
         # kernels' tiling layout), one without [P, page, W] (a latent
-        # row all heads share). The first pool of each layer is held in
+        # row all heads share); the first pool of each layer is held in
         # ``k_pools``, the second, where there is one, in ``v_pools``.
-        specs = [layer.serving_cache() for layer in model.model.layers]
-        if any(sp != specs[0] for sp in specs) or len(specs[0]) > 2:
+        # Where layers differ, ``k_pools`` holds every pool of every
+        # layer, in layer order. ``_layer_pages[i]`` says which entries
+        # of ``k_pools + v_pools + k_scales + v_scales`` layer ``i``'s
+        # step is handed (and hands back)
+        if not mixed_caches and (any(sp != specs[0] for sp in specs)
+                                 or len(specs[0]) > 2):
             raise UnsupportedServingFeature(
-                "layers that keep different caches (or more than two "
-                "pools a layer) in one model")
+                "layers that state different lists of pools (or more "
+                "than two pools a layer): state a PagedKV, SlotState or "
+                "SharedPages a layer instead")
 
-        def pool_shape(heads, width, last=None):
+        def pool_shape(heads, width, last=None, pages=num_pages):
             last = width if last is None else last
-            return (num_pages, page_size, last) if heads is None \
-                else (num_pages, heads, page_size, last)
+            return (pages, page_size, last) if heads is None \
+                else (pages, heads, page_size, last)
 
-        pools = [[Tensor(jnp.zeros(pool_shape(*sp), pool_dt))
-                  for _ in specs] for sp in specs[0]]
-        self.k_pools = pools[0]
-        self.v_pools = pools[1] if len(pools) > 1 else []
-        # per-head per-slot dequant scales ride sidecar arrays indexed
-        # by the SAME page ids, so prefix-shared pages carry their
-        # scales for free and a COW page copy copies both
-        scales = [[Tensor(jnp.zeros(pool_shape(*sp, last=1), jnp.float32))
-                   for _ in specs] if self.kv_quant else []
-                  for sp in specs[0]]
+        n_layers = len(specs)
+        if mixed_caches:
+            self.k_pools, self._layer_pages = [], []
+            for sp in specs:
+                own = []
+                if isinstance(sp, PagedKV):
+                    pages = num_pages if not sp.window else \
+                        (max_batch + 1) * self.ring_pages(sp.window)
+                    own = [jnp.zeros(pool_shape(sp.heads, sp.width,
+                                                pages=pages), pool_dt)
+                           for _ in range(2)]
+                elif isinstance(sp, SlotState):
+                    own = [jnp.zeros((max_batch + 1,) + shape, d)
+                           for shape, d in sp.shapes]
+                elif isinstance(sp, SharedPages):
+                    if not isinstance(specs[sp.layer], PagedKV) \
+                            or specs[sp.layer].window:
+                        raise UnsupportedServingFeature(
+                            "a layer shares the pages of a layer that "
+                            "keeps the whole context")
+                elif sp is not None:
+                    raise UnsupportedServingFeature(
+                        "a model whose layers keep different caches "
+                        "states each as a PagedKV, a SlotState, "
+                        "SharedPages or None")
+                at = len(self.k_pools)
+                self._layer_pages.append(list(range(at, at + len(own))))
+                self.k_pools += [Tensor(a) for a in own]
+            for li, sp in enumerate(specs):
+                if isinstance(sp, SharedPages):
+                    self._layer_pages[li] = self._layer_pages[sp.layer]
+            self.v_pools, scales = [], [[], []]
+        else:
+            pools = [[Tensor(jnp.zeros(pool_shape(*sp), pool_dt))
+                      for _ in specs] for sp in specs[0]]
+            self.k_pools = pools[0]
+            self.v_pools = pools[1] if len(pools) > 1 else []
+            # per-head per-slot dequant scales ride sidecar arrays
+            # indexed by the SAME page ids, so prefix-shared pages carry
+            # their scales for free and a COW page copy copies both
+            scales = [[Tensor(jnp.zeros(pool_shape(*sp, last=1),
+                                        jnp.float32))
+                       for _ in specs] if self.kv_quant else []
+                      for sp in specs[0]]
+            groups = len(pools) * (2 if self.kv_quant else 1)
+            self._layer_pages = [[g * n_layers + li for g in range(groups)]
+                                 for li in range(n_layers)]
         self.k_scales = scales[0]
         self.v_scales = scales[1] if len(scales) > 1 else []
         # self-speculative decoding (ROADMAP item 3a): an n-gram /
@@ -676,10 +757,18 @@ class LlamaServingEngine:
         self._m = _serving_metrics()
         # bytes a cached token costs over all layers, as allocated (a
         # latent row's pad lanes included)
+        # (of a model whose layers differ: the pools that hold the
+        # whole context; a ring and a state do not grow with it)
+        def per_token(sp):
+            if isinstance(sp, list):
+                return sp
+            whole = isinstance(sp, PagedKV) and not sp.window
+            return [(sp.heads, sp.width)] * 2 if whole else []
+
         tok_bytes = sum(
             (heads or 1) * (width * jnp.dtype(pool_dt).itemsize
                             + (4 if self.kv_quant else 0))
-            for sp in specs for heads, width in sp)
+            for sp in specs for heads, width in per_token(sp))
         self.kv_bytes_per_token = tok_bytes
         self._m["kv_bytes"].set(tok_bytes)
         self._m["weight_bytes"].set(self.weight_bytes_per_param)
@@ -705,7 +794,8 @@ class LlamaServingEngine:
         # a layer kind names the engine features its pages do not reach
         # yet: asked for, each is refused here by name, never served by
         # a silent fallback
-        asked = {"kv_dtype=int8": self.kv_quant,
+        asked = {"prefix_cache": asked_prefix,
+                 "kv_dtype=int8": self.kv_quant,
                  "kv_tier": self.tier is not None,
                  "spec_k": bool(self.spec_k),
                  "weight_dtype=int8": self.weight_quant,
@@ -774,6 +864,15 @@ class LlamaServingEngine:
                 in ("1", "true", "on", "auto")
         if prewarm:
             self.prewarm()
+
+    def ring_pages(self, window):
+        """Pages of the ring a sequence slot holds in the pools of a
+        layer that reads the last ``window`` keys: the window, the most
+        tokens one dispatch writes of a sequence, and a page (a
+        dispatch's first key and last query both lie mid-page)."""
+        chunk = self.chunk_block if self._single_chunk \
+            else self.chunk_budget
+        return -(-(int(window) + chunk) // self.page_size) + 1
 
     def __state_tensors__(self):
         """State-discovery override for ``to_static``: the KV pools are
@@ -988,7 +1087,7 @@ class LlamaServingEngine:
                        kv_lens, q_starts, q_lens, w_starts, w_flats,
                        w_ends, temps, top_ps, top_ks, seeds, slot_ids,
                        slot_vals, cmodes, k_pools, v_pools, k_scales,
-                       v_scales):
+                       v_scales, slots=None):
         """ONE token-packed model step: embed [1, T] real tokens (a mix
         of prefill-chunk tokens, speculative verify tokens and decode
         tokens, back to back with no inter-row padding), ask every
@@ -1045,23 +1144,28 @@ class LlamaServingEngine:
         # the step's metadata, and the tables its layers share (rotary
         # sin/cos are made once a dispatch, not once a layer)
         step = ServingStep(self, qb, pos, flat_idx, tables, kv_lens,
-                           q_starts, q_lens, w_starts, w_flats, w_ends)
-        # every layer runs its own step over its own pages; the pools
-        # it stated come first, then their scale sidecars
-        held = [p for p in (k_pools, v_pools, k_scales, v_scales) if p]
-        new_pools = [[] for _ in held]
+                           q_starts, q_lens, w_starts, w_flats, w_ends,
+                           slots=slots)
+        # every layer runs its own step over the pools it is handed
+        # (``_layer_pages``: of layers that all keep the same, the pools
+        # it stated, then their scale sidecars; a layer that reads
+        # another's pools is handed those, as that layer left them) and
+        # may hand something on to later layers (``step.carry``)
+        groups = (k_pools, v_pools, k_scales, v_scales)
+        flat = [p for g in groups for p in g]
         stats = []
-        for li, layer in enumerate(m.layers):
-            x, pages, st = layer.serving_step(x, step,
-                                              [p[li] for p in held])
-            for out, page in zip(new_pools, pages):
-                out.append(page)
+        for layer, idx in zip(m.layers, self._layer_pages):
+            x, pages, st, *more = layer.serving_step(
+                x, step, [flat[i] for i in idx])
+            for i, page in zip(idx, pages):
+                flat[i] = page
             if st is not None:
                 stats.append(st)
-        it = iter(new_pools)
-        new_k, new_v, new_ks, new_vs = (
-            next(it) if p else [] for p in (k_pools, v_pools, k_scales,
-                                            v_scales))
+            for handed in more:
+                step.carry.update(handed)
+        it = iter(flat)
+        new_k, new_v, new_ks, new_vs = ([next(it) for _ in g]
+                                        for g in groups)
         if len(stats) > 1:
             # one array, so the host reads the layers' counters in one
             # copy beside the tokens
@@ -1141,7 +1245,8 @@ class LlamaServingEngine:
                 return None
             lay = self._layouts[t_cap] = DispatchLayout(
                 t_cap, r_cap, qb, self.width, self.sample_slots,
-                self.trash_page)
+                self.trash_page,
+                trash_slot=self.max_batch if self._slotted else None)
         return lay
 
     def _mixed_packed(self, packed, k_pools, v_pools, k_scales, v_scales):
@@ -1155,9 +1260,11 @@ class LlamaServingEngine:
         layout = self._dispatch_layout(self.chunk_budget)
         if packed.shape[0] != layout.size:
             layout = self._dispatch_layout(self.max_batch)
-        return self._mixed_forward(
-            *[Tensor(a) for a in layout.unpack(packed._data)],
-            k_pools, v_pools, k_scales, v_scales)
+        fields = [Tensor(a) for a in layout.unpack(packed._data)]
+        # a model that keeps a state or a ring a slot has a 19th field
+        slots = fields.pop() if self._slotted else None
+        return self._mixed_forward(*fields, k_pools, v_pools, k_scales,
+                                   v_scales, slots=slots)
 
     def _run_mixed(self, buf):
         """Hand the mixed program one host buffer (its only transfer)
@@ -1313,7 +1420,9 @@ class LlamaServingEngine:
         ``chunk_budget`` fills with prefill chunks of at most
         ``chunk_block`` tokens each, FIFO by admission — a long prompt
         may take several chunk rows of ONE dispatch when the budget
-        allows, and what doesn't fit waits for the next step, so a
+        allows (one, of a model whose layers keep a state a sequence:
+        a row's scan starts from the stored state), and what doesn't
+        fit waits for the next step, so a
         10k-token prompt never stalls a live decode for more than one
         budget. Returns (rows, cow) where each row is
         ``(req, sid, start, n, toks, is_decode)``."""
@@ -1378,7 +1487,8 @@ class LlamaServingEngine:
             rows.append((r, sid, prev, n, (tok,) + drafts, True))
             budget -= n
         for r in prefill:
-            if budget <= 0 or len(rows) >= self.rows_cap:
+            if budget <= 0 or len(rows) >= self.rows_cap \
+                    or len(rows) - n_dec == self.chunk_rows:
                 break
             off = int(r._prefilled)
             n_total = len(r.prompt_ids)
@@ -1396,6 +1506,8 @@ class LlamaServingEngine:
                 rows.append((r, r.seq_id, off, n, toks, False))
                 off += n
                 budget -= n
+                if self._single_chunk:
+                    break
         return rows, cow
 
     def _sample_arrays(self, reqs, r_cap, into=None):
@@ -1524,6 +1636,7 @@ class LlamaServingEngine:
         # are consecutive, so one forward pass collects all three)
         w_starts, w_flats, w_ends = (f["w_starts"], f["w_flats"],
                                      f["w_ends"])
+        slots = f.get("slots")
         seq_first: dict[int, tuple] = {}     # sid -> (w_start, w_flat)
         seq_last: dict[int, int] = {}        # sid -> w_end
         t = 0
@@ -1534,6 +1647,8 @@ class LlamaServingEngine:
             kv_lens[i] = start + n
             q_starts[i] = start
             q_lens[i] = n
+            if slots is not None:
+                slots[i] = self.alloc.slot_of(sid)
             tokens[0, t:t + n] = toks
             pos[0, t:t + n] = start + np.arange(n)
             flat_idx[t:t + n] = i * qb + np.arange(n)
@@ -2586,6 +2701,8 @@ class LlamaServingEngine:
                      kv_pages=self._kv_pages(row[2] + row[3]
                                              for row in rows),
                      table_slots=r_cap * self.width)
+            if self._slotted:
+                disp.set(**self._slot_counters(rows))
             if layer_stats is not None:
                 # per expert layer [experts that got a row, rows of the
                 # largest group]: the medians over the layers; and the
@@ -2635,6 +2752,29 @@ class LlamaServingEngine:
             # concurrent admission can't consume the pages between
             # _relieve_pressure's proof and the extend
             return self._schedule_rows()
+
+    def _slot_counters(self, rows):
+        """What a model that keeps states and windows holds after a
+        dispatch of ``rows``: sequences with a slot, pages of the whole-
+        context pools, ring pages all window layers hold live keys in
+        (a context of ``n`` tokens and a window ``w``: the pages of
+        positions ``max(n - w, 0) .. n - 1``), and those that fell
+        behind a window in this dispatch (free to be overwritten)."""
+        page = self.page_size
+        with self._lock:
+            lens = [n for n in self.alloc._lens.values() if n > 0]
+        held = freed = 0
+        for w, layers in self._windows.items():
+            held += layers * sum((n - 1) // page - max(n - w, 0) // page
+                                 + 1 for n in lens)
+            freed += layers * sum(max(start + n - w, 0) // page
+                                  - max(start - w, 0) // page
+                                  for _, _, start, n, _, _ in rows)
+        # (no prefix cache holds pages of such a model)
+        shared = self.alloc.num_pages - self.alloc.free_pages
+        return dict(state_slots=self.alloc.slots_held,
+                    shared_kv_pages=shared, window_pages=held,
+                    window_pages_freed=freed)
 
     def _kv_pages(self, kv_lens):
         """Pages that hold contexts of these lengths, summed."""
@@ -2711,6 +2851,11 @@ class LlamaServingEngine:
         return fn
 
     def _ensure_scan_compiled(self, n):
+        if self._slotted:
+            raise UnsupportedServingFeature(
+                "the decode scan does not carry sequence slots: a model "
+                "whose layers keep a state or a window decodes by "
+                "single steps")
         sf = self._scan_static.get(n)
         if sf is None:
             from ..jit import StaticFunction
@@ -2926,7 +3071,9 @@ class LlamaServingEngine:
                         else self._spec_idle + 1
                 if not live:
                     chunk = 1       # pump parked requests via a step
-                elif prefilling or constrained:
+                elif prefilling or constrained or self._slotted:
+                    # (the scan does not carry the rows' slots: a model
+                    # that keeps a state or a ring decodes step by step)
                     chunk = 1
                 elif spec_now:
                     # speculation rides the mixed step: one dispatch

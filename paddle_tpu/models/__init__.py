@@ -14,6 +14,10 @@ from .mla_moe import (  # noqa: F401
     MlaMoeConfig, MlaAttention, MlaMoeMLP, MlaMoeDecoderLayer, MlaMoeModel,
     MlaMoeForCausalLM, tiny_mla_moe_config,
 )
+from .sambay import (  # noqa: F401
+    SambaYConfig, SambaYDecoderLayer, SambaYModel, SambaYForCausalLM,
+    tiny_sambay_config,
+)
 from .llama_pipe import LlamaForCausalLMPipe  # noqa: F401
 from .bert import (  # noqa: F401
     BertConfig, BertModel, BertForSequenceClassification,
@@ -27,6 +31,8 @@ __all__ = [
     "tiny_llama_config", "LlamaForCausalLMPipe",
     "MlaMoeConfig", "MlaAttention", "MlaMoeMLP", "MlaMoeDecoderLayer",
     "MlaMoeModel", "MlaMoeForCausalLM", "tiny_mla_moe_config",
+    "SambaYConfig", "SambaYDecoderLayer", "SambaYModel",
+    "SambaYForCausalLM", "tiny_sambay_config",
     "BertConfig", "BertModel", "BertForSequenceClassification",
     "BertForTokenClassification", "ErnieModel",
     "ErnieForSequenceClassification", "ernie_base_config",

@@ -137,14 +137,16 @@ def _interpret():
 
 
 def _softmax_accumulate(q, k, v, page_start, q_start, q_len, ctx,
-                        group, acc_ref, m_ref, l_ref):
+                        group, acc_ref, m_ref, l_ref, window=None):
     """ONE step of the shared online-softmax update over the K/V
     positions ``[page_start, page_start + len(k))``: causal/ragged
     masking, running max/sum rescale, accumulator update. Both kernels
     call exactly this body: the accumulation math is maintained in ONE
     place, never per-kernel copies. ``q`` ``[rows, D]`` is pre-scaled
     f32; ``k``/``v`` f32, ``[page, D]`` from the int8 program's
-    per-page grid and ``[B*page, D]`` from the float program's walk."""
+    per-page grid and ``[B*page, D]`` from the float program's walk.
+    With ``window`` a query at position ``p`` sees the keys
+    ``(p - window, p]`` only."""
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
     kpos = page_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -153,6 +155,8 @@ def _softmax_accumulate(q, k, v, page_start, q_start, q_len, ctx,
     qrow = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // group
     qpos = q_start + qrow
     valid = (kpos <= qpos) & (kpos < ctx) & (qrow < q_len)
+    if window is not None:
+        valid &= kpos > qpos - window
     s = jnp.where(valid, s, NEG_INF)
     m_prev, l_prev = m_ref[...], l_ref[...]
     m_cur = jnp.max(s, axis=-1, keepdims=True)
@@ -343,7 +347,7 @@ def _fused_rope_kernel(tables_ref, kv_lens_ref, q_starts_ref,
                        sin_ref, cos_ref, o_ref, ko_hbm, vo_hbm,
                        kbuf, vbuf, fsem, wsem, acc_ref, m_ref, l_ref,
                        q_s, *, page_size, bpages, group, scale, qblock,
-                       dtype):
+                       dtype, window=None, read_only=False):
     """The float rope-fused program: grid ``(R,)``, one step a row,
     the kv heads looped inside. The pools stay in HBM; the row walks
     its OWN context in blocks of ``bpages`` pages: ``ceil(kv_len /
@@ -367,7 +371,15 @@ def _fused_rope_kernel(tables_ref, kv_lens_ref, q_starts_ref,
     VMEM buffer (every reader replays; HBM is never trusted for them),
     and the sequence's LAST row then writes each such page back once,
     by a DMA from the buffer to the page; no other step writes
-    anything. One `_softmax_accumulate` update a block and head."""
+    anything. One `_softmax_accumulate` update a block and head.
+
+    With ``window`` (a layer whose queries see the last ``window`` keys
+    only) the walk starts at the block that holds the first key the
+    row's first query sees, fetches no page behind it and masks what
+    the block holds of earlier keys: a row reads about ``window +
+    q_len`` keys whatever its context. ``read_only`` (a layer that
+    attends through ANOTHER layer's pool, which that layer's call has
+    already written): nothing is overlaid and nothing written."""
     r = pl.program_id(0)
     hk = kbuf.shape[1]
     bt = bpages * page_size
@@ -386,6 +398,13 @@ def _fused_rope_kernel(tables_ref, kv_lens_ref, q_starts_ref,
     l_ref[...] = jnp.zeros_like(l_ref)
 
     npages = pl.cdiv(ctx, page_size)
+    # the first key the row walks, its page and its block: the first
+    # one a query of the row sees, or the first one its sequence writes
+    # in this dispatch (the sequence's last row writes those pages back)
+    first = 0 if window is None else jnp.maximum(
+        jnp.minimum(q_start - window + 1, ws), 0)
+    first_page = first // page_size
+    blk0 = first // bt
 
     def page_dmas(act, i, slot, pools, sem, into_vmem, lo=0):
         """``start`` or ``wait`` (``act``) the per-page copies of block
@@ -408,14 +427,14 @@ def _fused_rope_kernel(tables_ref, kv_lens_ref, q_starts_ref,
                           jnp.minimum((i + 1) * bpages, npages), one, 0)
 
     fetch = functools.partial(page_dmas, pools=(k_hbm, v_hbm), sem=fsem,
-                              into_vmem=True)
+                              into_vmem=True, lo=first_page)
     # the pages that overlap the write span [w_start, kv_len)
     write = functools.partial(page_dmas, pools=(ko_hbm, vo_hbm), sem=wsem,
                               into_vmem=False, lo=ws // page_size)
 
     @pl.when(nblk > 0)
     def _row():
-        fetch("start", 0, 0)
+        fetch("start", blk0, blk0 % 2)
         # the row's query tokens sit contiguously on the packed axis
         # at w_flat + (q_start - w_start), as do their sin/cos rows:
         # rope + scale them once for all kv heads
@@ -439,52 +458,57 @@ def _fused_rope_kernel(tables_ref, kv_lens_ref, q_starts_ref,
         def _prefetch():
             fetch("start", i + 1, 1 - slot)
 
-        replay = block_start + bt > ws
         kpos = block_start + jax.lax.broadcasted_iota(
             jnp.int32, (bt, 1), 0)
+        if not read_only:
+            replay = block_start + bt > ws
 
-        @pl.when(replay)
-        def _overlay():
-            # positions [w_start, kv_len) were produced by rows <= r
-            # of THIS dispatch: position pos lives at packed index
-            # w_flat + pos - w_start (+ the left pad of one block),
-            # roped already and rounded to the pool dtype: what the
-            # unfused scatter stores, bit for bit
-            tpad = nk_ref.shape[1]
-            f0 = jnp.clip(w_flats_ref[r] + block_start - ws + bt, 0,
-                          tpad - bt)
-            fresh = (kpos >= ws) & (kpos < kv_len)
-            kbuf[slot] = jnp.where(
-                fresh[None],
-                nk_ref[:, pl.ds(f0, bt), :].astype(kbuf.dtype),
-                kbuf[slot])
-            vbuf[slot] = jnp.where(
-                fresh[None],
-                nv_ref[:, pl.ds(f0, bt), :].astype(vbuf.dtype),
-                vbuf[slot])
+            @pl.when(replay)
+            def _overlay():
+                # positions [w_start, kv_len) were produced by rows <= r
+                # of THIS dispatch: position pos lives at packed index
+                # w_flat + pos - w_start (+ the left pad of one block),
+                # roped already and rounded to the pool dtype: what the
+                # unfused scatter stores, bit for bit
+                tpad = nk_ref.shape[1]
+                f0 = jnp.clip(w_flats_ref[r] + block_start - ws + bt, 0,
+                              tpad - bt)
+                fresh = (kpos >= ws) & (kpos < kv_len)
+                kbuf[slot] = jnp.where(
+                    fresh[None],
+                    nk_ref[:, pl.ds(f0, bt), :].astype(kbuf.dtype),
+                    kbuf[slot])
+                vbuf[slot] = jnp.where(
+                    fresh[None],
+                    nv_ref[:, pl.ds(f0, bt), :].astype(vbuf.dtype),
+                    vbuf[slot])
 
-            @pl.when(last_row)
-            def _write():
-                write("start", i, slot)
+                @pl.when(last_row)
+                def _write():
+                    write("start", i, slot)
 
+        # nothing at or past the context (or on a page behind the
+        # window, which was not fetched) is used: a slot there may
+        # hold anything (a NaN would survive the zero weight of the
+        # P.V dot)
+        held = kpos < ctx
+        if window is not None:
+            held &= kpos >= first_page * page_size
         for h in range(hk):
-            # nothing at or past the context is used: a slot there may
-            # hold anything (a NaN would survive the zero weight of the
-            # P.V dot)
             _softmax_accumulate(
                 q_s[h], kbuf[slot, h].astype(jnp.float32),
-                jnp.where(kpos < ctx, vbuf[slot, h].astype(jnp.float32),
-                          0.0),
+                jnp.where(held, vbuf[slot, h].astype(jnp.float32), 0.0),
                 block_start, q_start, q_len, ctx, group, acc_ref.at[h],
-                m_ref.at[h], l_ref.at[h])
+                m_ref.at[h], l_ref.at[h], window)
 
-        @pl.when(replay & last_row)
-        def _written():
-            write("wait", i, slot)
+        if not read_only:
+            @pl.when(replay & last_row)
+            def _written():
+                write("wait", i, slot)
 
         return carry
 
-    jax.lax.fori_loop(0, nblk, block, 0)
+    jax.lax.fori_loop(blk0, nblk, block, 0)
     for h in range(hk):
         _softmax_finish(o_ref.at[:, pl.ds(h, 1)], acc_ref.at[h],
                         l_ref.at[h])
@@ -576,7 +600,7 @@ def _fused_write_map(page_size, dump_page):
 
 @functools.lru_cache(maxsize=32)
 def _make_fused_rope(scale, page_size, bpages, qblock, group, dtype,
-                     interpret):
+                     interpret, window=None, read_only=False):
     bt = bpages * page_size
 
     def call(qp, k_pages, v_pages, nk, nv, sin, cos, tables, kv_lens,
@@ -631,7 +655,8 @@ def _make_fused_rope(scale, page_size, bpages, qblock, group, dtype,
         return pl.pallas_call(
             functools.partial(_fused_rope_kernel, page_size=page_size,
                               bpages=bpages, group=group, scale=scale,
-                              qblock=qblock, dtype=dtype),
+                              qblock=qblock, dtype=dtype, window=window,
+                              read_only=read_only),
             grid_spec=grid_spec,
             out_shape=[
                 jax.ShapeDtypeStruct((r, hk, qbg, d), dtype),
@@ -778,7 +803,7 @@ def _rope_tpad(t, page_size, qblock):
 def _fused_rope_impl(q, new_k, new_v, k_pages, v_pages, block_tables,
                      kv_lens, q_starts, q_lens, w_starts, w_flats,
                      w_ends, rope_sin, rope_cos, dump_page, scale,
-                     qblock):
+                     qblock, window=None, read_only=False):
     """``dump_page`` is part of the fused programs' common signature
     and unused here: a step of this program that has nothing to write
     writes nothing."""
@@ -807,7 +832,8 @@ def _fused_rope_impl(q, new_k, new_v, k_pages, v_pages, block_tables,
     sin = _pack_rope_table(rope_sin, t, 0, tq)
     cos = _pack_rope_table(rope_cos, t, 0, tq)
     call = _make_fused_rope(scale, page_size, bpages, qblock, group,
-                            jnp.dtype(q.dtype), _interpret())
+                            jnp.dtype(q.dtype), _interpret(), window,
+                            read_only)
     tables = jnp.clip(block_tables.astype(jnp.int32), 0,
                       k_pages.shape[0] - 1)
     out, kp, vp = call(qp, k_pages, v_pages, nk, nv, sin, cos, tables,
@@ -861,7 +887,8 @@ def fused_ragged_paged_attention(q, new_k, new_v, k_pages, v_pages,
                                  q_lens, w_starts, w_flats, w_ends,
                                  dump_page, *, rope_sin, rope_cos,
                                  qblock, scale=None, k_scale=None,
-                                 v_scale=None):
+                                 v_scale=None, window=None,
+                                 read_only=False):
     """Rope, KV page write and ragged paged attention in ONE kernel
     (see module docstring): ropes the packed PRE-rope ``q [T, H, D]``
     and ``new_k [T, Hk, D]`` by the per-dispatch ``rope_sin``/
@@ -874,7 +901,12 @@ def fused_ragged_paged_attention(q, new_k, new_v, k_pages, v_pages,
     ``w_flats[r]`` that position's index on the packed token axis,
     ``w_ends[r]`` the sequence's final kv_len in this dispatch (so the
     last row owns the write-back). ``qblock`` is the row-block width
-    the metadata was built for. ``dump_page`` is a page id no live
+    the metadata was built for. ``window`` (float pools only): a query
+    at position ``p`` sees the keys ``(p - window, p]``, and a row
+    walks the pages that hold them and no others. ``read_only``
+    (float pools only): the pools already hold the dispatch's K/V rows
+    (the layer that owns them ran before); ``new_k``/``new_v`` are not
+    read and the pools come back byte for byte. ``dump_page`` is a page id no live
     table references; the int8 program's steps with nothing to write
     dump there and its contents are undefined after the call.
     Tape-integrated but non-differentiable (serving path)."""
@@ -898,12 +930,16 @@ def fused_ragged_paged_attention(q, new_k, new_v, k_pages, v_pages,
     rows = (block_tables, kv_lens, q_starts, q_lens, w_starts, w_flats,
             w_ends, rope_sin, rope_cos)
     if k_scale is not None:
+        if window is not None or read_only:
+            raise ValueError("the int8-page program has no window mask "
+                             "and no read-only call")
         return run_op("fused_rope_ragged_paged_attention_q8",
                       functools.partial(_fused_rope_impl_q8, **static),
                       (q, new_k, new_v, k_pages, v_pages, k_scale,
                        v_scale) + rows, differentiable=False)
     return run_op("fused_rope_ragged_paged_attention",
-                  functools.partial(_fused_rope_impl, **static),
+                  functools.partial(_fused_rope_impl, window=window,
+                                    read_only=read_only, **static),
                   (q, new_k, new_v, k_pages, v_pages) + rows,
                   differentiable=False)
 
@@ -914,7 +950,7 @@ def fused_ragged_paged_attention_xla(q, new_k, new_v, k_pages, v_pages,
                                      dump_page, scale=None,
                                      k_scale=None, v_scale=None,
                                      rope_sin=None, rope_cos=None,
-                                     qblock=None):
+                                     qblock=None, window=None):
     """Write-THEN-read reference for the fused kernel: scatter every
     row's packed new K/V rows into the pools (host-built indices, rows
     applied in order — unambiguous last-writer-wins), then run the
@@ -1001,13 +1037,13 @@ def fused_ragged_paged_attention_xla(q, new_k, new_v, k_pages, v_pages,
         return out, k_pages, v_pages, ks, vs
     out = ragged_paged_attention_xla(q, k_pages, v_pages, tables,
                                      kv_lens, q_starts, q_lens,
-                                     scale=scale)
+                                     scale=scale, window=window)
     return out, k_pages, v_pages
 
 
 def ragged_paged_attention_xla(q, k_pages, v_pages, block_tables,
                                kv_lens, q_starts, q_lens, scale=None,
-                               k_scale=None, v_scale=None):
+                               k_scale=None, v_scale=None, window=None):
     """XLA reference path: gather every row's pages to a contiguous
     [R, S, Hk, D] window, apply the causal/ragged mask, softmax.
     Semantically identical to the kernel (zeros on padded query rows
@@ -1041,6 +1077,8 @@ def ragged_paged_attention_xla(q, k_pages, v_pages, block_tables,
     qvalid = (jnp.arange(qb)[None, :]
               < q_lens[:, None])[:, None, :, None]
     mask = (kpos <= qpos) & (kpos < kv_lens[:, None, None, None]) & qvalid
+    if window is not None:
+        mask &= kpos > qpos - window
     logits = jnp.where(mask, logits, NEG_INF)
     w = jax.nn.softmax(logits, axis=-1)
     # fully-masked rows (padding / inactive) -> zeros, matching the

@@ -169,6 +169,24 @@ def test_walk_compiles_at_mistral_widths(chip_compile, rows, tokens,
                      compiled.as_text())
 
 
+# the float program as the hybrid family's three attention kinds call
+# it: 40 padded query heads over 10 pairs of key heads (128 lanes), page
+# 16, behind the window layers' ring tables (window 512), the shared
+# pool's tables, and read-only for the layers that own no pool
+@pytest.mark.parametrize("kind", ["window", "full", "cross"])
+@pytest.mark.parametrize("rows,tokens,qblock", [(102, 192, 32), (96, 96, 1)])
+def test_walk_compiles_for_window_and_cross_layers(chip_compile, rows,
+                                                   tokens, qblock, kind):
+    pool = ((96 * 36 + 1) if kind == "window" else 30721, 10, 16, 128)
+    fn = functools.partial(
+        rpa._fused_rope_impl, dump_page=pool[0] - 1, scale=0.125,
+        qblock=qblock, window=512 if kind == "window" else None,
+        read_only=kind == "cross")
+    compiled = chip_compile(fn, *_ragged_specs(False, pool, rows, tokens,
+                                               320))
+    _assert_kernel(compiled, "ragged_attn_fused_rope")
+
+
 # ---------------------------------------------------------------------------
 # training: flash attention fwd+bwd, fused CE, and the quantized /
 # grouped matmuls

@@ -52,12 +52,26 @@ def test_benchmark_names_the_new_cells_and_their_readers():
         assert os.path.isfile(bench.reader_path(name))
     assert doc not in per["attn_roofline.tok"]["workloads"]
     assert sat in per["attn_roofline.tok"]["workloads"]
+    # the hybrid family's cell (PR 34) and its four readers
+    reason = "phi-4-mini-flash-reasoning.reason-closed"
+    assert cells[reason]["chips"] == 1
+    hybrid = ("ssm_ms.tok", "ssm_scan_roofline.tok",
+              "diff_attn_roofline.tok", "window_held_share.tok")
+    for name in hybrid:
+        assert per[name]["workloads"] == [reason]
+        assert per[name]["moves"] == "serve_tok_s"
+        assert os.path.isfile(bench.reader_path(name))
+    assert per["window_held_share.tok"]["better"] == "lower"
     for m in SPEC["per_layer"]:
-        if m["name"].endswith(".tok") and m["name"] not in (
+        if m["name"].endswith(".tok") and m["name"] not in hybrid + (
                 "mla_roofline.tok", "moe_gemm_roofline.tok",
                 "moe_route_ms.tok", "experts_touched.tok",
                 "attn_roofline.tok"):
             assert doc in m["workloads"] and sat in m["workloads"], m
+            # every kernel-agnostic .tok metric is read in the new cell
+            assert reason in m["workloads"], m
+    assert reason in next(m for m in SPEC["end_to_end"]
+                          if m["name"] == "serve_tok_s")["workloads"]
 
 
 def test_configuration_keeps_every_published_width():

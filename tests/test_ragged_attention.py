@@ -585,3 +585,68 @@ def test_table_tail_garbage_is_clamped(quant):
     assert np.array_equal(a[0], b[0])
     for x, y in zip(a[1:], b[1:]):
         assert np.array_equal(x[live], y[live])
+
+
+# -- a window: queries see the last `window` keys, rows walk those pages --
+WINDOW_CASES = {
+    # a decode row far past the window, a chunk that straddles it, a
+    # context shorter than it, an inactive row
+    "mixed": [(5 * BLOCK + 3, [1]), (2 * BLOCK - 5, [8]), (3, [4]),
+              (0, [])],
+    # two chunks of one sequence in one dispatch: the second reads the
+    # first's rows from the packed operands and writes both back
+    "two_chunks": [(BLOCK + 6, [8, 5]), (40, [1])],
+    "all_decode": [(9 * BLOCK, [1]), (17, [1]), (BLOCK, [1])],
+}
+
+
+@pytest.mark.parametrize("window", [PAGE, 20, BLOCK + 5])
+@pytest.mark.parametrize("case", list(WINDOW_CASES))
+def test_window_matches_reference(case, window):
+    """The float program with a window against the windowed XLA
+    reference: same outputs, same pool bytes."""
+    rng = np.random.RandomState(90)
+    args, kw = _walk_case(rng, WINDOW_CASES[case], 150, 8)
+    _assert_walk_parity(args, dict(kw, window=window))
+
+
+@pytest.mark.parametrize("window", [PAGE, 20])
+def test_window_reads_no_page_behind_it(window):
+    """Pages wholly behind every query's window may hold anything (a
+    ring hands them to later tokens): NaN there changes nothing."""
+    seqs = [(5 * BLOCK + 3, [1]), (2 * BLOCK - 5, [8])]
+    args, kw = _walk_case(np.random.RandomState(91), seqs, 90, 8)
+    kw = dict(kw, window=window)
+    clean = _assert_walk_parity(args, kw)
+    kp, vp = np.array(args[3]), np.array(args[4])
+    tables = np.asarray(args[5])
+    for row, (prior, chunks) in enumerate(seqs):
+        behind = (prior - window + 1) // PAGE      # pages before this one
+        for pg in tables[row, :max(behind, 0)]:
+            kp[pg], vp[pg] = np.nan, np.nan
+    dirty = list(map(_unwrap, RPA.fused_ragged_paged_attention(
+        *args[:3], jnp.asarray(kp), jnp.asarray(vp), *args[5:], **kw)))
+    assert np.array_equal(clean[0], dirty[0])
+
+
+def test_read_only_call_writes_nothing():
+    """``read_only``: a layer that reads another layer's pool attends
+    through what it holds (this dispatch's positions too) and leaves
+    every byte; the packed K/V operands are not read."""
+    seqs = [(BLOCK + 3, [1]), (7, [5])]
+    args, kw = _walk_case(np.random.RandomState(92), seqs, 40, 8)
+    args = list(args)
+    # no rotation (sin 0, cos 1), so the reference's rows are q's own
+    kw = dict(kw, rope_sin=jnp.zeros_like(kw["rope_sin"]),
+              rope_cos=jnp.ones_like(kw["rope_cos"]), read_only=True)
+    out, kp, vp = map(_unwrap, RPA.fused_ragged_paged_attention(
+        *args, **kw))
+    assert np.array_equal(kp, np.asarray(args[3]))
+    assert np.array_equal(vp, np.asarray(args[4]))
+    q, qb = np.asarray(args[0]), kw["qblock"]
+    rows = np.zeros((len(seqs), qb) + q.shape[1:], q.dtype)
+    rows[0, :1], rows[1, :5] = q[0:1], q[1:6]
+    want = RPA.ragged_paged_attention_xla(
+        jnp.asarray(rows), args[3], args[4], args[5], args[6], args[7],
+        args[8])
+    _assert_parity(jnp.asarray(out), jnp.asarray(want))

@@ -469,3 +469,65 @@ def test_a_decoder_without_expert_layers_sets_no_expert_counters(served):
     for d in disp:
         assert not {"experts_touched", "expert_rows_max",
                     "latent_rows"} & set(d["args"])
+
+
+def test_dispatch_counts_slots_windows_and_the_shared_pool():
+    """A model whose layers keep states and windows (ISSUE 34): after
+    every dispatch ``state_slots`` is the allocator's slots in use,
+    ``shared_kv_pages`` its pages in use, ``window_pages`` what its
+    lengths and the window give for every window layer, and
+    ``window_pages_freed`` sums to the pages that fell behind the
+    windows; the build hands over one buffer with a 19th field."""
+    from paddle_tpu.models import SambaYForCausalLM, tiny_sambay_config
+
+    paddle.seed(0)
+    m = SambaYForCausalLM(tiny_sambay_config())     # window 16, 2 layers
+    m.eval()
+    engine = _engine(m, chunk_block=8)
+    page, window, layers = 8, 16, 2
+    seen = []
+    real = engine._slot_counters
+
+    def spy(rows):
+        got = real(rows)
+        with engine._lock:
+            seen.append((got, engine.alloc.slots_held,
+                         engine.alloc.num_pages - engine.alloc.free_pages,
+                         sorted(engine.alloc._lens.values())))
+        return got
+
+    engine._slot_counters = spy
+    otrace.clear()
+    reqs = [Request(list(range(1, n + 1)), max_new_tokens=30)
+            for n in (5, 37, 20)]
+    for r in reqs:
+        engine.add_request(r)
+    while not all(r.done for r in reqs):
+        engine.step()
+    events = otrace.get_events()
+    disp = _by(events, "serving.dispatch")
+    assert len(disp) == len(seen) > 30
+    for d, (got, slots, pages, lens) in zip(disp, seen):
+        a = d["args"]
+        assert {k: a[k] for k in got} == got
+        assert a["state_slots"] == slots <= 3
+        assert a["shared_kv_pages"] == pages
+        assert a["window_pages"] == layers * sum(
+            (n - 1) // page - max(n - window, 0) // page + 1 for n in lens)
+    total = sum(d["args"]["window_pages_freed"] for d in disp)
+    assert total == layers * sum(
+        (len(r.prompt_ids) + 29 - window) // page for r in reqs)
+    layout = engine._dispatch_layout(engine.chunk_budget)
+    assert [f[0] for f in layout.fields][-1] == "slots"
+    for b in _by(events, "serving.build"):
+        assert b["args"]["h2d_arrays"] == 1
+    assert engine.alloc.slots_held == 0
+    engine.close()
+
+
+def test_a_model_without_states_sets_no_slot_counters(served, model):
+    for d in _by(served[0], "serving.dispatch"):
+        assert not {"state_slots", "window_pages", "shared_kv_pages",
+                    "window_pages_freed"} & set(d["args"])
+    layout = _engine(model)._dispatch_layout(16)
+    assert [f[0] for f in layout.fields][-1] == "cmodes"
