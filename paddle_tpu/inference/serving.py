@@ -714,6 +714,17 @@ class LlamaServingEngine:
                                  for li in range(n_layers)]
         self.k_scales = scales[0]
         self.v_scales = scales[1] if len(scales) > 1 else []
+        # the most query tokens of a row that the float ragged program
+        # computes on its small tile (`small_tile`), at the query heads
+        # a kv head of the K/V pools `[P, Hk, page, D]`; None where no
+        # layer runs that program (latent rows, int8 pages)
+        hk = next((p._data.shape[1] for p in self.k_pools
+                   if p._data.ndim == 4), None)
+        self._tile_tokens = None
+        if hk and not self.kv_quant:
+            from ..ops.ragged_paged_attention import small_tile
+            g = cfg.num_attention_heads // hk
+            self._tile_tokens = small_tile(g) // g
         # self-speculative decoding (ROADMAP item 3a): an n-gram /
         # prompt-lookup drafter proposes up to spec_k tokens per live
         # decoder; the scheduler packs each speculating row into the
@@ -1581,7 +1592,8 @@ class LlamaServingEngine:
         what :meth:`_apply_rows` needs and what the spans say: ``(next
         tokens still on the device, each row's first index in the T
         axis, enqueue seconds, cold, needs_mixed, t_cap, bytes handed
-        to the device, rows that sample)``."""
+        to the device, rows that sample, rows on the attention kernel's
+        small tile)``."""
         # speculative verify rows are multi-token decode rows: they
         # need the chunk-shaped program exactly like prefill chunks do
         needs_mixed = any(n > 1 or not is_dec
@@ -1665,6 +1677,9 @@ class LlamaServingEngine:
         # 0 is the false side of the sample step's branch: the program
         # takes the argmax and nothing else
         sampled = int(np.count_nonzero(f["temps"] > 0))
+        # the rows the attention kernel computes on its small tile
+        tile_rows = None if self._tile_tokens is None else int(
+            np.count_nonzero((q_lens > 0) & (q_lens <= self._tile_tokens)))
         self._record_shape("mixed", t_cap)
         self._arm_watchdog(cold)
         with self._lock:
@@ -1684,7 +1699,7 @@ class LlamaServingEngine:
         self._flush_deferred()
         self._layer_stats = stats[0] if stats else None
         return (nxt, flat_start, dur, cold, needs_mixed, t_cap,
-                buf.nbytes, sampled)
+                buf.nbytes, sampled, tile_rows)
 
     def _apply_rows(self, rows, out, flat_start, dur, cold, needs_mixed):
         """Apply one mixed dispatch's next tokens ``out`` (``[t_cap]``,
@@ -2674,7 +2689,7 @@ class LlamaServingEngine:
                     return 0, 0
             with _span("serving.build", step=step) as build:
                 (nxt, flat_start, dur, cold, needs_mixed, t_cap, nbytes,
-                 sampled) = self._dispatch_rows(rows, cow)
+                 sampled, tile_rows) = self._dispatch_rows(rows, cow)
                 # what the host handed the program: one staged buffer
                 build.set(h2d_arrays=1, h2d_bytes=nbytes)
             with _span("serving.wait", step=step):
@@ -2701,6 +2716,8 @@ class LlamaServingEngine:
                      kv_pages=self._kv_pages(row[2] + row[3]
                                              for row in rows),
                      table_slots=r_cap * self.width)
+            if tile_rows is not None:
+                disp.set(tile_rows=tile_rows)
             if self._slotted:
                 disp.set(**self._slot_counters(rows))
             if layer_stats is not None:

@@ -89,7 +89,13 @@ one is computed) — no trip for an inactive row, the same trips
 whatever W is. It makes ONE softmax update a block and head, so its
 attention output equals the XLA reference's to float rounding (1e-5
 relative in f32); a page is written by a DMA of its own and a step
-with nothing to write writes nothing.
+with nothing to write writes nothing. The softmax rows a row computes
+follow its ``q_lens``: a row whose query tokens fit `small_tile`
+(``group`` rows rounded up to f32 sublane tiles: every decode row of a
+mixed dispatch) ropes, scores, accumulates and finishes that tile, all
+kv heads in one batched dot; every other row the whole ``QB * group``
+block a head at a time. The kernel picks from ``q_lens_ref[r]``: one
+program, the same walk, nothing for a caller to say.
 
 The int8 program (`_fused_rope_kernel_q8`) is still a per-page grid
 (R, Hk, W) with one online-softmax accumulator in VMEM scratch per
@@ -127,7 +133,7 @@ from ..framework.tensor import run_op
 
 __all__ = ["ragged_paged_attention_xla", "fused_ragged_paged_attention",
            "fused_ragged_paged_attention_xla", "fused_supported",
-           "fused_rope_geometry_ok", "rope_tables"]
+           "fused_rope_geometry_ok", "rope_tables", "small_tile"]
 
 NEG_INF = -1e30
 
@@ -145,14 +151,19 @@ def _softmax_accumulate(q, k, v, page_start, q_start, q_len, ctx,
     place, never per-kernel copies. ``q`` ``[rows, D]`` is pre-scaled
     f32; ``k``/``v`` f32, ``[page, D]`` from the int8 program's
     per-page grid and ``[B*page, D]`` from the float program's walk.
+    All of them (and the three refs) may carry one leading axis of kv
+    heads: the float program's small tile updates every head by one
+    batched dot (``q [Hk, rows, D]``, ``k``/``v`` ``[Hk, B*page, D]``).
     With ``window`` a query at position ``p`` sees the keys
     ``(p - window, p]`` only."""
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+    heads = tuple(range(q.ndim - 2))
+    row, col = q.ndim - 2, q.ndim - 1
+    s = jax.lax.dot_general(q, k, (((col,), (col,)), (heads, heads)),
                             preferred_element_type=jnp.float32)
-    kpos = page_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    kpos = page_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, col)
     # query rows are laid out [QB, G] flattened (qi major): the
     # token index of softmax row i is i // G
-    qrow = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // group
+    qrow = jax.lax.broadcasted_iota(jnp.int32, s.shape, row) // group
     qpos = q_start + qrow
     valid = (kpos <= qpos) & (kpos < ctx) & (qrow < q_len)
     if window is not None:
@@ -171,7 +182,7 @@ def _softmax_accumulate(q, k, v, page_start, q_start, q_len, ctx,
     l_ref[...] = l_prev * alpha + jnp.sum(pexp, axis=-1, keepdims=True)
     m_ref[...] = m_new
     acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        pexp, v, (((1,), (0,)), ((), ())),
+        pexp, v, (((col,), (row,)), (heads, heads)),
         preferred_element_type=jnp.float32)
 
 
@@ -181,7 +192,16 @@ def _softmax_finish(o_ref, acc_ref, l_ref):
     NaN."""
     l = l_ref[...]
     out = acc_ref[...] / jnp.where(l > 0.0, l, 1.0)
-    o_ref[0, 0] = jnp.where(l > 0.0, out, 0.0).astype(o_ref.dtype)
+    o_ref[...] = jnp.where(l > 0.0, out, 0.0).astype(o_ref.dtype)
+
+
+def small_tile(group):
+    """Softmax rows of the float program's small tile: one query
+    token's ``group`` rows rounded up to whole f32 sublane tiles. A row
+    of a dispatch whose query tokens fit it (``q_len * group <=
+    small_tile(group)``: every decode row) computes that tile a kv
+    head and not its whole query block."""
+    return 8 * -(-group // 8)
 
 
 def fused_rope_geometry_ok(head_dim):
@@ -371,7 +391,11 @@ def _fused_rope_kernel(tables_ref, kv_lens_ref, q_starts_ref,
     VMEM buffer (every reader replays; HBM is never trusted for them),
     and the sequence's LAST row then writes each such page back once,
     by a DMA from the buffer to the page; no other step writes
-    anything. One `_softmax_accumulate` update a block and head.
+    anything. One `_softmax_accumulate` update a block and head, at the
+    row's size (`sized`): the small tile for a row whose query tokens
+    fit it, where the update of all kv heads is one batched dot, else
+    the whole query block a head at a time. Walk, DMAs, overlay and
+    write-back are the same for both.
 
     With ``window`` (a layer whose queries see the last ``window`` keys
     only) the walk starts at the block that holds the first key the
@@ -393,9 +417,35 @@ def _fused_rope_kernel(tables_ref, kv_lens_ref, q_starts_ref,
     ctx = jnp.minimum(kv_len, width * page_size)
     nblk = jnp.where(q_len > 0, pl.cdiv(ctx, bt), 0)
 
-    acc_ref[...] = jnp.zeros_like(acc_ref)
-    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-    l_ref[...] = jnp.zeros_like(l_ref)
+    # the softmax rows this row computes a kv head: the small tile where
+    # its query tokens fit one (a decode row of a mixed dispatch, or a
+    # row with nothing to do), else the whole query block: a `[8, D] x
+    # [D, bt]` dot sixteen times would starve the MXU on a chunk row. A
+    # block no larger than the tile (the decode-only shape) has one size.
+    qbg = qblock * group
+    tile = small_tile(group)
+    small = q_len * group <= tile
+
+    def sized(fn):
+        """Run ``fn(rows)`` at this row's size."""
+        if tile >= qbg:
+            return fn(qbg)
+        pl.when(small)(functools.partial(fn, tile))
+        pl.when(jnp.logical_not(small))(functools.partial(fn, qbg))
+
+    def heads_of(n):
+        """The kv heads of one update at ``n`` rows: the whole block a
+        head at a time (`[QB*G, D] x [D, bt]` fills the MXU by itself),
+        the small tile all heads in one batched dot."""
+        return range(hk) if n == qbg else (slice(None),)
+
+    @sized
+    def _init(n):
+        rows = pl.ds(0, n)
+        acc_ref[:, rows] = jnp.zeros((hk, n) + acc_ref.shape[2:],
+                                     jnp.float32)
+        m_ref[:, rows] = jnp.full((hk, n, 1), NEG_INF, jnp.float32)
+        l_ref[:, rows] = jnp.zeros((hk, n, 1), jnp.float32)
 
     npages = pl.cdiv(ctx, page_size)
     # the first key the row walks, its page and its block: the first
@@ -440,12 +490,16 @@ def _fused_rope_kernel(tables_ref, kv_lens_ref, q_starts_ref,
         # rope + scale them once for all kv heads
         tq = q_ref.shape[1]
         f0q = jnp.clip(w_flats_ref[r] + q_start - ws, 0, tq - qblock)
-        qv = q_ref[:, pl.ds(f0q, qblock), :, :]       # [Hk, QB, G, D]
-        sin_q = sin_ref[pl.ds(f0q, qblock), :][None, :, None, :]
-        cos_q = cos_ref[pl.ds(f0q, qblock), :][None, :, None, :]
-        q_rot = (qv * cos_q + _rot_half(qv) * sin_q).astype(dtype)
-        q_s[...] = q_rot.reshape(hk, qblock * group, qv.shape[-1]) \
-            .astype(jnp.float32) * scale
+
+        @sized
+        def _rope(n):
+            toks = -(-n // group)           # the tokens of n softmax rows
+            qv = q_ref[:, pl.ds(f0q, toks), :, :]     # [Hk, toks, G, D]
+            sin_q = sin_ref[pl.ds(f0q, toks), :][None, :, None, :]
+            cos_q = cos_ref[pl.ds(f0q, toks), :][None, :, None, :]
+            q_rot = (qv * cos_q + _rot_half(qv) * sin_q).astype(dtype)
+            q_s[:, pl.ds(0, toks * group)] = q_rot.reshape(
+                hk, toks * group, qv.shape[-1]).astype(jnp.float32) * scale
 
     last_row = (kv_len == w_ends_ref[r])
 
@@ -494,12 +548,18 @@ def _fused_rope_kernel(tables_ref, kv_lens_ref, q_starts_ref,
         held = kpos < ctx
         if window is not None:
             held &= kpos >= first_page * page_size
-        for h in range(hk):
-            _softmax_accumulate(
-                q_s[h], kbuf[slot, h].astype(jnp.float32),
-                jnp.where(held, vbuf[slot, h].astype(jnp.float32), 0.0),
-                block_start, q_start, q_len, ctx, group, acc_ref.at[h],
-                m_ref.at[h], l_ref.at[h], window)
+
+        @sized
+        def _attend(n):
+            rows = pl.ds(0, n)
+            for h in heads_of(n):
+                _softmax_accumulate(
+                    q_s[h, rows], kbuf[slot, h].astype(jnp.float32),
+                    jnp.where(held, vbuf[slot, h].astype(jnp.float32),
+                              0.0),
+                    block_start, q_start, q_len, ctx, group,
+                    acc_ref.at[h, rows], m_ref.at[h, rows],
+                    l_ref.at[h, rows], window)
 
         if not read_only:
             @pl.when(replay & last_row)
@@ -509,9 +569,17 @@ def _fused_rope_kernel(tables_ref, kv_lens_ref, q_starts_ref,
         return carry
 
     jax.lax.fori_loop(blk0, nblk, block, 0)
-    for h in range(hk):
-        _softmax_finish(o_ref.at[:, pl.ds(h, 1)], acc_ref.at[h],
-                        l_ref.at[h])
+
+    @sized
+    def _finish(n):
+        rows = pl.ds(0, n)
+        for h in heads_of(n):
+            _softmax_finish(o_ref.at[0, h, rows], acc_ref.at[h, rows],
+                            l_ref.at[h, rows])
+        if n < qbg:
+            # the query tokens past the tile are none of the row's
+            o_ref[0, :, pl.ds(n, qbg - n)] = jnp.zeros(
+                (hk, qbg - n) + o_ref.shape[3:], o_ref.dtype)
 
 
 def _fused_rope_kernel_q8(tables_ref, kv_lens_ref, q_starts_ref,
@@ -579,7 +647,7 @@ def _fused_rope_kernel_q8(tables_ref, kv_lens_ref, q_starts_ref,
 
     @pl.when(p == num_pages - 1)
     def _finish():
-        _softmax_finish(o_ref, acc_ref, l_ref)
+        _softmax_finish(o_ref.at[0, 0], acc_ref, l_ref)
 
 
 def _fused_write_map(page_size, dump_page):

@@ -109,11 +109,12 @@ R, T, QB = 18, 64, 32
 MAX_CTX = 1152
 
 
-def _ragged_specs(q8, pool, rows, tokens, width):
-    """``(shape, dtype)`` of a ragged program's operands: packed q,
-    new K/V, the pools (int8 with f32 sidecars where ``q8``), tables,
-    the six per-row arrays, the sin/cos tables."""
-    h, d = 4 * pool[1], pool[3]
+def _ragged_specs(q8, pool, rows, tokens, width, group=4):
+    """``(shape, dtype)`` of a ragged program's operands: packed q
+    (``group`` query heads a kv head), new K/V, the pools (int8 with
+    f32 sidecars where ``q8``), tables, the six per-row arrays, the
+    sin/cos tables."""
+    h, d = group * pool[1], pool[3]
     pools = [(pool, I8 if q8 else BF16)] * 2 \
         + ([(pool[:3] + (1,), F32)] * 2 if q8 else [])
     return [((tokens, h, d), BF16)] + [((tokens, pool[1], d), BF16)] * 2 \
@@ -143,7 +144,14 @@ def test_ragged_programs_compile_bf16(chip_compile, program, page_size):
 # and, for the float program, of a long-context cell's. The float walk
 # is bounded by kv_lens, so the width only sizes its table in SMEM; the
 # int8 program's grid has a step a table slot (ROADMAP S8c starts here).
+# The float program's mixed shape holds both of a row's sizes (ISSUE 36:
+# the small tile of `small_tile(group)` softmax rows for a row whose
+# query tokens fit it, the whole query block otherwise): 8 of 128 rows
+# at the cells' 4 query heads a kv head, where a tile is two tokens,
+# and 8 of 256 at 8, where it is one token's rows exactly.
 MISTRAL_POOL = (4097, 8, 16, 128)
+SHAPES = [(36, 128, 32, 4), (32, 32, 1, 4), (36, 128, 32, 8)]
+SHAPE_IDS = ["mixed", "decode", "mixed-group8"]
 
 
 @pytest.mark.parametrize("program,width", [
@@ -151,14 +159,14 @@ MISTRAL_POOL = (4097, 8, 16, 128)
     ("_fused_rope_impl", 521), ("_fused_rope_impl_q8", 66),
     ("_fused_rope_impl_q8", 161)],
     ids=["float-66", "float-161", "float-521", "int8-66", "int8-161"])
-@pytest.mark.parametrize("rows,tokens,qblock", [(36, 128, 32), (32, 32, 1)])
+@pytest.mark.parametrize("rows,tokens,qblock,group", SHAPES, ids=SHAPE_IDS)
 def test_walk_compiles_at_mistral_widths(chip_compile, rows, tokens,
-                                         qblock, program, width):
+                                         qblock, group, program, width):
     q8 = program.endswith("q8")
     fn = functools.partial(getattr(rpa, program), dump_page=4096,
                            scale=D ** -0.5, qblock=qblock)
     compiled = chip_compile(
-        fn, *_ragged_specs(q8, MISTRAL_POOL, rows, tokens, width))
+        fn, *_ragged_specs(q8, MISTRAL_POOL, rows, tokens, width, group))
     _assert_kernel(compiled, RAGGED_KERNELS[program])
     # the one custom call a layer whose result holds both pools: what
     # the benchmark's attention roofline finds the kernel by
@@ -174,16 +182,18 @@ def test_walk_compiles_at_mistral_widths(chip_compile, rows, tokens,
 # 16, behind the window layers' ring tables (window 512), the shared
 # pool's tables, and read-only for the layers that own no pool
 @pytest.mark.parametrize("kind", ["window", "full", "cross"])
-@pytest.mark.parametrize("rows,tokens,qblock", [(102, 192, 32), (96, 96, 1)])
+@pytest.mark.parametrize("rows,tokens,qblock,group", [
+    (102, 192, 32, 4), (96, 96, 1, 4), (102, 192, 32, 8)], ids=SHAPE_IDS)
 def test_walk_compiles_for_window_and_cross_layers(chip_compile, rows,
-                                                   tokens, qblock, kind):
+                                                   tokens, qblock, group,
+                                                   kind):
     pool = ((96 * 36 + 1) if kind == "window" else 30721, 10, 16, 128)
     fn = functools.partial(
         rpa._fused_rope_impl, dump_page=pool[0] - 1, scale=0.125,
         qblock=qblock, window=512 if kind == "window" else None,
         read_only=kind == "cross")
     compiled = chip_compile(fn, *_ragged_specs(False, pool, rows, tokens,
-                                               320))
+                                               320, group))
     _assert_kernel(compiled, "ragged_attn_fused_rope")
 
 

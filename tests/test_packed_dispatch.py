@@ -205,10 +205,11 @@ def legacy_dispatch_rows(self, rows, cow):
     if self.kv_quant:
         self.k_scales, self.v_scales = list(new_ks), list(new_vs)
     self._layer_stats = stats[0] if stats else None
-    # the one edit: the caller now also asks for the bytes handed over
-    # and (PR 33) for the rows that sample
+    # the one edit: the caller now also asks for the bytes handed over,
+    # (PR 33) for the rows that sample and (PR 36) for the rows on the
+    # attention kernel's small tile
     return (nxt, flat_start, dur, cold, needs_mixed, t_cap, 0,
-            int(np.count_nonzero(temps > 0)))
+            int(np.count_nonzero(temps > 0)), None)
 
 
 def legacy_warm_mixed(self, t_cap):

@@ -11,10 +11,15 @@ the pool bytes they write, and the int8 program's scale sidecars, are
 held bitwise.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.inference.paged_cache import quantize_kv_int8
 from paddle_tpu.ops import ragged_paged_attention as RPA
@@ -256,7 +261,7 @@ POISON_SCALE = np.float32(1e30)
 
 
 def _walk_case(rng, seqs, width, qb, tail=10_000, poison=False,
-               quant=False):
+               quant=False, g=2):
     """A dispatch over a fresh pool. ``seqs`` is a list of ``(prior,
     chunks)``: a sequence holding ``prior`` tokens in the pool whose
     next ``chunks`` (a list of lengths) are this dispatch's rows, in
@@ -265,9 +270,10 @@ def _walk_case(rng, seqs, width, qb, tail=10_000, poison=False,
     holds, every slot at or past a sequence's ``prior`` (what this
     dispatch writes too) and the trash page, where the tails then
     point, are poisoned. With ``quant`` the pools are int8 with
-    ``[P, Hk, page, 1]`` f32 scale sidecars. Returns the kernel's
-    arguments and its keywords (sin/cos, qblock, the sidecars)."""
-    hk, g, d = 2, 2, 16
+    ``[P, Hk, page, 1]`` f32 scale sidecars; ``g`` query heads a kv
+    head. Returns the kernel's arguments and its keywords (sin/cos,
+    qblock, the sidecars)."""
+    hk, d = 2, 16
     kv, qs, ql, ws, wf, we, owner = [], [], [], [], [], [], []
     t = 0
     for si, (prior, chunks) in enumerate(seqs):
@@ -650,3 +656,284 @@ def test_read_only_call_writes_nothing():
         jnp.asarray(rows), args[3], args[4], args[5], args[6], args[7],
         args[8])
     _assert_parity(jnp.asarray(out), jnp.asarray(want))
+
+
+# ----------------------------------------------------------------------
+# the small tile (ISSUE 36): a row of a mixed dispatch whose query
+# tokens fit ``small_tile(group)`` softmax rows computes that tile a kv
+# head and not its whole query block. Below, FROZEN, is the float
+# program's body as it stood before (every row the whole block, a head
+# at a time): the straight line the sized program is held to, bitwise.
+# ----------------------------------------------------------------------
+def _frozen_softmax_accumulate(q, k, v, page_start, q_start, q_len, ctx,
+                               group, acc_ref, m_ref, l_ref, window=None):
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    kpos = page_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    # query rows are laid out [QB, G] flattened (qi major): the
+    # token index of softmax row i is i // G
+    qrow = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // group
+    qpos = q_start + qrow
+    valid = (kpos <= qpos) & (kpos < ctx) & (qrow < q_len)
+    if window is not None:
+        valid &= kpos > qpos - window
+    s = jnp.where(valid, s, RPA.NEG_INF)
+    m_prev, l_prev = m_ref[...], l_ref[...]
+    m_cur = jnp.max(s, axis=-1, keepdims=True)
+    m_new = jnp.maximum(m_prev, m_cur)
+    alpha = jnp.exp(m_prev - m_new)
+    pexp = jnp.exp(s - m_new)
+    # fully-masked softmax rows (a padded query, or a page entirely
+    # behind this query's causal horizon) must contribute nothing:
+    # with finite RPA.NEG_INF, exp(s - m_new) would be exp(0) = 1 when
+    # m_new is still RPA.NEG_INF, silently polluting l and acc
+    pexp = jnp.where(valid, pexp, 0.0)
+    l_ref[...] = l_prev * alpha + jnp.sum(pexp, axis=-1, keepdims=True)
+    m_ref[...] = m_new
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+        pexp, v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
+def _frozen_softmax_finish(o_ref, acc_ref, l_ref):
+    l = l_ref[...]
+    out = acc_ref[...] / jnp.where(l > 0.0, l, 1.0)
+    o_ref[0, 0] = jnp.where(l > 0.0, out, 0.0).astype(o_ref.dtype)
+
+
+def _frozen_fused_rope_kernel(tables_ref, kv_lens_ref, q_starts_ref,
+                              q_lens_ref, w_starts_ref, w_flats_ref,
+                              w_ends_ref, q_ref, k_hbm, v_hbm, nk_ref,
+                              nv_ref, sin_ref, cos_ref, o_ref, ko_hbm,
+                              vo_hbm, kbuf, vbuf, fsem, wsem, acc_ref,
+                              m_ref, l_ref, q_s, *, page_size, bpages,
+                              group, scale, qblock, dtype, window=None,
+                              read_only=False):
+    r = pl.program_id(0)
+    hk = kbuf.shape[1]
+    bt = bpages * page_size
+    width = tables_ref.shape[1]
+    kv_len = kv_lens_ref[r]
+    q_len = q_lens_ref[r]
+    q_start = q_starts_ref[r]
+    ws = w_starts_ref[r]
+    # a context longer than its table (never from the engine) is
+    # attended as far as the table reaches, as the XLA reference does
+    ctx = jnp.minimum(kv_len, width * page_size)
+    nblk = jnp.where(q_len > 0, pl.cdiv(ctx, bt), 0)
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, RPA.NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+    npages = pl.cdiv(ctx, page_size)
+    # the first key the row walks, its page and its block: the first
+    # one a query of the row sees, or the first one its sequence writes
+    # in this dispatch (the sequence's last row writes those pages back)
+    first = 0 if window is None else jnp.maximum(
+        jnp.minimum(q_start - window + 1, ws), 0)
+    first_page = first // page_size
+    blk0 = first // bt
+
+    def page_dmas(act, i, slot, pools, sem, into_vmem, lo=0):
+        def one(pg, carry):
+            pid = tables_ref[r, pg]
+            for s, (pool, buf) in enumerate(zip(pools, (kbuf, vbuf))):
+                piece = buf.at[slot, :, pl.ds(pl.multiple_of(
+                    (pg - i * bpages) * page_size, page_size),
+                    page_size), :]
+                src, dst = (pool.at[pid], piece) if into_vmem \
+                    else (piece, pool.at[pid])
+                getattr(pltpu.make_async_copy(src, dst, sem.at[s, slot]),
+                        act)()
+            return carry
+
+        jax.lax.fori_loop(jnp.maximum(lo, i * bpages),
+                          jnp.minimum((i + 1) * bpages, npages), one, 0)
+
+    fetch = functools.partial(page_dmas, pools=(k_hbm, v_hbm), sem=fsem,
+                              into_vmem=True, lo=first_page)
+    # the pages that overlap the write span [w_start, kv_len)
+    write = functools.partial(page_dmas, pools=(ko_hbm, vo_hbm), sem=wsem,
+                              into_vmem=False, lo=ws // page_size)
+
+    @pl.when(nblk > 0)
+    def _row():
+        fetch("start", blk0, blk0 % 2)
+        # the row's query tokens sit contiguously on the packed axis
+        # at w_flat + (q_start - w_start), as do their sin/cos rows:
+        # rope + scale them once for all kv heads
+        tq = q_ref.shape[1]
+        f0q = jnp.clip(w_flats_ref[r] + q_start - ws, 0, tq - qblock)
+        qv = q_ref[:, pl.ds(f0q, qblock), :, :]       # [Hk, QB, G, D]
+        sin_q = sin_ref[pl.ds(f0q, qblock), :][None, :, None, :]
+        cos_q = cos_ref[pl.ds(f0q, qblock), :][None, :, None, :]
+        q_rot = (qv * cos_q + RPA._rot_half(qv) * sin_q).astype(dtype)
+        q_s[...] = q_rot.reshape(hk, qblock * group, qv.shape[-1]) \
+            .astype(jnp.float32) * scale
+
+    last_row = (kv_len == w_ends_ref[r])
+
+    def block(i, carry):
+        slot = i % 2
+        block_start = i * bt
+        fetch("wait", i, slot)
+
+        @pl.when(i + 1 < nblk)
+        def _prefetch():
+            fetch("start", i + 1, 1 - slot)
+
+        kpos = block_start + jax.lax.broadcasted_iota(
+            jnp.int32, (bt, 1), 0)
+        if not read_only:
+            replay = block_start + bt > ws
+
+            @pl.when(replay)
+            def _overlay():
+                # positions [w_start, kv_len) were produced by rows <= r
+                # of THIS dispatch: position pos lives at packed index
+                # w_flat + pos - w_start (+ the left pad of one block),
+                # roped already and rounded to the pool dtype: what the
+                # unfused scatter stores, bit for bit
+                tpad = nk_ref.shape[1]
+                f0 = jnp.clip(w_flats_ref[r] + block_start - ws + bt, 0,
+                              tpad - bt)
+                fresh = (kpos >= ws) & (kpos < kv_len)
+                kbuf[slot] = jnp.where(
+                    fresh[None],
+                    nk_ref[:, pl.ds(f0, bt), :].astype(kbuf.dtype),
+                    kbuf[slot])
+                vbuf[slot] = jnp.where(
+                    fresh[None],
+                    nv_ref[:, pl.ds(f0, bt), :].astype(vbuf.dtype),
+                    vbuf[slot])
+
+                @pl.when(last_row)
+                def _write():
+                    write("start", i, slot)
+
+        # nothing at or past the context (or on a page behind the
+        # window, which was not fetched) is used: a slot there may
+        # hold anything (a NaN would survive the zero weight of the
+        # P.V dot)
+        held = kpos < ctx
+        if window is not None:
+            held &= kpos >= first_page * page_size
+        for h in range(hk):
+            _frozen_softmax_accumulate(
+                q_s[h], kbuf[slot, h].astype(jnp.float32),
+                jnp.where(held, vbuf[slot, h].astype(jnp.float32), 0.0),
+                block_start, q_start, q_len, ctx, group, acc_ref.at[h],
+                m_ref.at[h], l_ref.at[h], window)
+
+        if not read_only:
+            @pl.when(replay & last_row)
+            def _written():
+                write("wait", i, slot)
+
+        return carry
+
+    jax.lax.fori_loop(blk0, nblk, block, 0)
+    for h in range(hk):
+        _frozen_softmax_finish(o_ref.at[:, pl.ds(h, 1)], acc_ref.at[h],
+                               l_ref.at[h])
+
+
+def _whole_block(monkeypatch, args, kw):
+    """What the frozen whole-block body gives for a dispatch."""
+    monkeypatch.setattr(RPA, "_fused_rope_kernel",
+                        _frozen_fused_rope_kernel)
+    RPA._make_fused_rope.cache_clear()
+    try:
+        return list(map(_unwrap, RPA.fused_ragged_paged_attention(
+            *args, **kw)))
+    finally:
+        monkeypatch.undo()
+        RPA._make_fused_rope.cache_clear()
+
+
+def _assert_sized_equals_whole_block(monkeypatch, args, kw, group):
+    """Outputs of every row and both pools, bit for bit, but for ONE
+    thing the interpreter does: XLA:CPU contracts the q rotation ``x *
+    cos + rot * sin`` into an FMA around either product depending on
+    the shape it fuses the chain at (2 tokens on the tile, the whole
+    block otherwise: the same one-ulp trap the new K rows avoid by
+    `_rope_rows`; the chip's vector unit has no FMA to contract into).
+    So with tables that rotate, the small-tile rows are held to 1e-6
+    relative and every other row and the pools bitwise; with tables
+    that do not (sin 0, cos 1: the hybrid family's), everything is
+    bitwise: the dots, the softmax update and the finish sum a row
+    the same at the tile's height as at the block's."""
+    want = _whole_block(monkeypatch, args, kw)
+    got = list(map(_unwrap, RPA.fused_ragged_paged_attention(*args, **kw)))
+    for g, w in zip(got[1:], want[1:]):
+        assert np.array_equal(g, w, equal_nan=True)     # a poisoned pool
+    ql = np.asarray(args[8])
+    on_tile = (ql > 0) & (ql * group <= RPA.small_tile(group))
+    assert on_tile.any() and (~on_tile & (ql > 0)).any()
+    assert np.array_equal(got[0][~on_tile], want[0][~on_tile])
+    if not bool(jnp.any(kw["rope_sin"] != 0)):
+        assert np.array_equal(got[0], want[0])
+    err = np.max(np.abs(got[0][on_tile] - want[0][on_tile]))
+    assert err <= 1e-6 * np.max(np.abs(want[0])), err
+    return got
+
+
+def _no_rotation(kw):
+    return dict(kw, rope_sin=jnp.zeros_like(kw["rope_sin"]),
+                rope_cos=jnp.ones_like(kw["rope_cos"]))
+
+
+# decode rows (one far past a window, one short), a 2-token row, chunk
+# rows of 3, 31 and 32 tokens, a sequence whose second row of the
+# dispatch is one token (it owns the write-back of both), an inactive row
+SIZED_ROWS = [(5 * BLOCK + 3, [1]), (17, [1]), (40, [2]), (9, [3]),
+              (2 * BLOCK - 5, [31]), (3, [32]), (BLOCK + 6, [32, 1]),
+              (0, [])]
+
+
+@pytest.mark.parametrize("rotate", [True, False],
+                         ids=["rope", "no_rope"])
+@pytest.mark.parametrize("read_only", [False, True],
+                         ids=["writes", "read_only"])
+@pytest.mark.parametrize("window", [None, 4 * BLOCK],
+                         ids=["whole", "window"])
+@pytest.mark.parametrize("group", [4, 8])
+def test_small_tile_is_bitwise_the_whole_block(monkeypatch, group, window,
+                                               read_only, rotate):
+    args, kw = _walk_case(np.random.RandomState(36), SIZED_ROWS, 90, 32,
+                          g=group)
+    kw = dict(kw if rotate else _no_rotation(kw), window=window,
+              read_only=read_only)
+    got = _assert_sized_equals_whole_block(monkeypatch, args, kw, group)
+    if read_only:
+        assert all(np.array_equal(g, b)
+                   for g, b in zip(got[1:], _pools_before(args, kw)))
+    else:
+        kw.pop("read_only")
+        ref = RPA.fused_ragged_paged_attention_xla(*args, **kw)
+        _assert_parity(jnp.asarray(got[0]), ref[0])
+
+
+@pytest.mark.parametrize("rotate", [True, False],
+                         ids=["rope", "no_rope"])
+@pytest.mark.parametrize("group", [4, 8])
+def test_small_tile_across_a_block_edge_into_its_write_span(monkeypatch,
+                                                            group, rotate):
+    """A decode row whose context crosses a block edge INTO its own
+    write span (its new token is the next block's first slot), one
+    whose new token is a block's last slot, and a 2-token row whose
+    span straddles the edge: the tile's rows read the overlaid slots
+    and the pages are written back as the whole block's were."""
+    seqs = [(BLOCK, [1]), (BLOCK - 1, [1]), (BLOCK - 1, [2]),
+            (2 * BLOCK - 3, [7])]
+    for poison in (False, True):
+        args, kw = _walk_case(np.random.RandomState(37), seqs, 40, 8,
+                              poison=poison, g=group)
+        kw = kw if rotate else _no_rotation(kw)
+        got = _assert_sized_equals_whole_block(monkeypatch, args, kw,
+                                               group)
+        if poison:      # nothing past a context or off a table is used
+            assert np.all(np.isfinite(got[0]))
+        else:
+            _assert_walk_parity(args, kw)

@@ -14,9 +14,11 @@ from paddle_tpu.inference.cluster import ServingCluster
 from paddle_tpu.inference.sampling import SamplingParams
 from paddle_tpu.inference.serving import LlamaServingEngine, Request
 from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+from paddle_tpu.observability import compile_watch as cw
 from paddle_tpu.observability import metrics as om
 from paddle_tpu.observability import trace as otrace
 from paddle_tpu.observability import tracing as otracing
+from paddle_tpu.ops import ragged_paged_attention as RPA
 
 PHASES = ("serving.schedule", "serving.build", "serving.wait",
           "serving.apply")
@@ -459,6 +461,8 @@ def test_dispatch_carries_the_expert_layers_counters():
         assert a["expert_rows_max"] == med[1]
         assert a["latent_rows"] == sum(start + n for _, start, n, _ in rows)
         assert all(pages * 8 >= start + n for _, start, n, pages in rows)
+        # no layer runs the float ragged program: its counter stays away
+        assert "tile_rows" not in a
     assert max(d["args"]["experts_touched"] for d in disp) <= experts
     engine.close()
 
@@ -531,3 +535,125 @@ def test_a_model_without_states_sets_no_slot_counters(served, model):
                     "window_pages_freed"} & set(d["args"])
     layout = _engine(model)._dispatch_layout(16)
     assert [f[0] for f in layout.fields][-1] == "cmodes"
+
+
+# ---------------------------------------------------------------------------
+# the attention kernel's small tile (ISSUE 36): how often it engages, and
+# that the engine compiles, registers and prewarms what it did before
+GEOMETRIES = ["mistral", "hybrid"]
+
+
+def _geometry(name):
+    """A tiny model with a cell's attention geometry: 4 query heads a
+    kv head (Mistral-7B's 32 over 8), or the hybrid family's layers
+    (pairs of key heads are one head of the cache: 8 padded query heads
+    over 2, states, windows, a shared pool). Returns (model, kernel
+    variants its step programs hold: whole context, window, read-only)."""
+    paddle.seed(0)
+    if name == "mistral":
+        m = LlamaForCausalLM(LlamaConfig(
+            vocab_size=256, hidden_size=64, intermediate_size=128,
+            num_hidden_layers=2, num_attention_heads=8,
+            num_key_value_heads=2, max_position_embeddings=256))
+        variants = 1
+    else:
+        from paddle_tpu.models import SambaYForCausalLM, tiny_sambay_config
+        m, variants = SambaYForCausalLM(tiny_sambay_config()), 3
+    m.eval()
+    return m, variants
+
+
+def _serve_three(engine):
+    reqs = [Request(list(range(1, n + 1)), max_new_tokens=6)
+            for n in (40, 5, 23)]
+    for r in reqs:
+        engine.add_request(r)
+    while any(not r.done for r in reqs):
+        engine.step()
+
+
+@pytest.mark.parametrize("geometry", GEOMETRIES)
+def test_dispatch_says_how_many_rows_take_the_small_tile(geometry):
+    """``serving.dispatch`` carries ``tile_rows``, the dispatch's rows
+    whose query tokens fit the float ragged program's small tile (two
+    tokens at 4 query heads a kv head), counted here from the scheduled
+    rows' own lengths: every row of a decode-only dispatch, the decode
+    rows and the short chunk tails of a mixed one."""
+    om.default_registry().clear()
+    m, _ = _geometry(geometry)
+    engine = _engine(m, chunk_block=8)
+    group, want = 4, {}
+    assert RPA.small_tile(group) == 8
+    rows_of = engine._dispatch_rows
+
+    def spy_rows(rows, cow):
+        want[engine._dispatch_count - 1] = sum(
+            0 < n * group <= 8 for _, _, _, n, _, _ in rows)
+        return rows_of(rows, cow)
+
+    engine._dispatch_rows = spy_rows
+    otrace.clear()
+    _serve_three(engine)
+    disp = _by(otrace.get_events(), "serving.dispatch")
+    assert disp and len(disp) == len(want)
+    assert {d["args"]["kind"] for d in disp} == {"mixed", "decode"}
+    for d in disp:
+        a = d["args"]
+        assert a["tile_rows"] == want[a["step"]] <= a["rows"]
+        if a["kind"] == "decode":
+            assert a["tile_rows"] == a["rows"] == a["decode_rows"]
+        else:
+            assert a["tile_rows"] >= a["decode_rows"]
+    # a mixed dispatch holds rows of both sizes
+    assert any(0 < d["args"]["tile_rows"] < d["args"]["rows"]
+               for d in disp if d["args"]["kind"] == "mixed")
+    engine.close()
+
+
+#: the fields of a dispatch's one buffer, as they were before ISSUE 36
+LAYOUT_FIELDS = ["tokens", "pos", "flat_idx", "last_idx", "tables",
+                 "kv_lens", "q_starts", "q_lens", "w_starts", "w_flats",
+                 "w_ends", "temps", "top_ps", "top_ks", "seeds",
+                 "slot_ids", "slot_vals", "cmodes"]
+
+
+@pytest.mark.parametrize("geometry", GEOMETRIES)
+def test_the_small_tile_adds_no_compiled_program(geometry, tmp_path,
+                                                 monkeypatch):
+    """The kernel picks a row's size from ``q_lens`` inside ONE
+    program: the engine still has its two program shapes, its layout
+    the same fields, the step programs one kernel a variant and shape
+    (the layers share it), the shape registry the same two entries,
+    and an engine of equal geometry prewarms exactly those."""
+    monkeypatch.setenv("PADDLE_TPU_SHAPE_REGISTRY",
+                       str(tmp_path / "serving_shapes.json"))
+    monkeypatch.setattr(cw, "_shape_registry", None)
+    m, variants = _geometry(geometry)
+    made = {}
+    make = RPA._make_fused_rope
+    make.cache_clear()
+    monkeypatch.setattr(
+        RPA, "_make_fused_rope",
+        lambda *key: made.setdefault(key, make(*key)))
+    engine = _engine(m, chunk_block=8)
+    assert engine._cache_dir is not None
+    _serve_three(engine)
+    assert sorted(engine._layouts) == [4, 16]
+    assert len(engine._mixed_static._cache) == 2
+    assert engine._warm_dispatches == 2 and engine.prewarmed is None
+    for t_cap in (4, 16):
+        fields = [f[0] for f in engine._dispatch_layout(t_cap).fields]
+        assert fields == LAYOUT_FIELDS + ["slots"] * (geometry == "hybrid")
+    # a kernel a variant and program shape, none for a size of tile
+    assert len(made) == 2 * variants
+    assert cw.shape_registry().lookup(engine._shape_key) \
+        == {"mixed": [4, 16]}
+    engine.close()
+    other = _engine(m, chunk_block=8)
+    assert other._shape_key == engine._shape_key
+    assert other.prewarm() == other.prewarmed \
+        == {"mixed": [4, 16], "scan": []}
+    assert other._warm_dispatches == 2
+    assert len(other._mixed_static._cache) == 2
+    assert len(made) == 2 * variants
+    other.close()
