@@ -37,6 +37,7 @@ that line and never exits 0.
 """
 
 import argparse
+import functools
 import gc
 import json
 import os
@@ -530,6 +531,97 @@ def phase_latent(seed, rehearse, on_chip):
          ms_a_layer=timings)
 
 
+def phase_kda(seed, rehearse, on_chip):
+    """The gated delta rule alone at Kimi-Linear's head shape (32 heads
+    of 128 x 128 float32): the Pallas step over 64 rows of one token
+    against a pool of 65 slots (in place; held to the XLA chunkwise form
+    on the same rows), and the chunk rows' loop with 1 and with 8 long
+    rows of 64 tokens (held to the rows computed all at once)."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import kda
+
+    if rehearse:
+        h, d, rows, slots, long_rows, q, layers, reps = 4, 16, 6, 7, 2, 8, 2, 1
+    else:
+        h, d, rows, slots, long_rows, q, layers, reps = \
+            32, 128, 64, 65, 8, 64, 5, 5
+    keys = jax.random.split(jax.random.PRNGKey(seed), 6)
+    f32 = jnp.float32
+
+    def unit(a):
+        return a / jnp.linalg.norm(a, axis=-1, keepdims=True)
+
+    def operands(lead):
+        qq = unit(jax.random.normal(keys[0], lead + (h, d), f32)) * d ** -0.5
+        kk = unit(jax.random.normal(keys[1], lead + (h, d), f32))
+        vv = jax.random.normal(keys[2], lead + (h, d), f32)
+        gg = -jax.random.uniform(keys[3], lead + (h, d), f32)
+        bb = jax.random.uniform(keys[4], lead + (h,), f32)
+        return qq, kk, vv, gg, bb
+
+    pool = jax.random.normal(keys[5], (slots, h, d, d), f32)
+    slot = jnp.asarray(np.random.RandomState(seed).permutation(slots - 1)
+                       [:rows], jnp.int32)
+    fresh = jnp.zeros((rows,), bool).at[1].set(True)
+    one = operands((rows,))
+    timings = {}
+
+    @jax.jit
+    def steps(pool, *ops):
+        for _ in range(layers):
+            o, pool = kda.kda_step(*ops, pool, slot, fresh)
+        return o, pool
+
+    @jax.jit
+    def once(pool, *ops):
+        return kda.kda_step(*ops, pool, slot, fresh)
+
+    _, ms = best_ms(steps, (pool,) + one, reps)
+    timings["step_ms_a_layer"] = ms / layers
+    # least time: every row's state once in and once out
+    timings["step_floor_ms"] = 1e3 * 2 * rows * h * d * d * 4 / 819e9
+    o, new = once(pool, *one)
+    s0 = jnp.where(fresh[:, None, None, None], 0.0, pool[slot])
+    want_o, want_s = jax.jit(kda.kda_rows)(
+        *(a[:, None] for a in one), s0, jnp.ones((rows,), jnp.int32))
+    err_o = float(jnp.max(jnp.abs(o - want_o[:, 0])))
+    err_s = float(jnp.max(jnp.abs(new[slot] - want_s)))
+    rest = jnp.asarray([i for i in range(slots)
+                        if i not in set(np.asarray(slot))], jnp.int32)
+    touched = float(jnp.max(jnp.abs(new[rest] - pool[rest])))
+    timings.update(step_out_err=err_o, step_state_err=err_s,
+                   step_other_slots=touched)
+    tol = 1e-4
+    if touched or not max(err_o, err_s) <= tol:
+        raise RuntimeError(f"kda_step and the XLA form differ: out "
+                           f"{err_o}, state {err_s}, other slots {touched}")
+    # the chunk rows
+    chunk = operands((long_rows, q))
+    s0 = pool[:long_rows]
+    whole = jax.jit(kda.kda_rows)
+    loop = jax.jit(functools.partial(kda.kda_rows, long_rows=long_rows))
+    for there in (1, long_rows):
+        lens = jnp.where(jnp.arange(long_rows) < there, q, 0) \
+            .astype(jnp.int32)
+        (lo, ls), ms = best_ms(loop, chunk + (s0, lens), reps)
+        timings[f"chunk_ms.{there}_rows"] = ms
+        wo, ws = whole(*chunk, s0, lens)
+        live = (jnp.arange(long_rows) < there)[:, None, None, None]
+        err = max(float(jnp.max(jnp.abs(jnp.where(live, lo - wo, 0)))),
+                  float(jnp.max(jnp.abs(ls - ws))))
+        timings[f"chunk_err.{there}_rows"] = err
+        if not err <= tol:
+            raise RuntimeError(f"the chunk rows' loop and the rows at "
+                               f"once differ by {err}")
+    _, ms = best_ms(whole, chunk + (s0, jnp.full((long_rows,), q,
+                                                 jnp.int32)), reps)
+    timings["chunk_ms.all_at_once"] = ms
+    emit(phase="kda", device=device_info(),
+         shapes=dict(heads=h, head_dim=d, rows=rows, slots=slots,
+                     chunk_rows=long_rows, chunk=q), timings=timings)
+
+
 # ---------------------------------------------------------------------------
 # train
 # ---------------------------------------------------------------------------
@@ -746,7 +838,8 @@ def main():
                          "never prints the success line, exits "
                          f"{REHEARSAL_EXIT}")
     ap.add_argument("--phase", default="all",
-                    choices=("all", "walk", "latent", "serve", "train"),
+                    choices=("all", "walk", "latent", "kda", "serve",
+                             "train"),
                     help="one chip: run only this phase")
     args = ap.parse_args()
 
@@ -765,7 +858,8 @@ def main():
         phase_mesh(args.seed, args.rehearse, on_chip)
     else:
         for name, phase in (("walk", phase_walk), ("latent", phase_latent),
-                            ("serve", phase_serve), ("train", phase_train)):
+                            ("kda", phase_kda), ("serve", phase_serve),
+                            ("train", phase_train)):
             if args.phase in ("all", name):
                 phase(args.seed, args.rehearse, on_chip)
                 gc.collect()    # a phase's weights leave before the next

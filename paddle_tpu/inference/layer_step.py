@@ -21,6 +21,10 @@ may state one of:
   behind the engine's block tables; with one, a RING of pages a sequence
   slot (``ServingStep.ring_tables``) that holds the window, one
   dispatch's chunk and a page, whatever the context;
+- `PagedLatent` ``(width)``: ONE pool ``[P, page, width]`` with no head
+  axis (a latent row all heads share), the whole context behind the
+  engine's block tables: what a model whose layers are all latent states
+  as ``[(None, width)]``, for a layer beside layers of other kinds;
 - `SlotState` ``(shapes)``: arrays of fixed shape a sequence slot
   (``[slots + 1, *shape]``, the last slot for rows that are none), which
   live and die with the sequence (``ServingStep.slots``);
@@ -41,8 +45,8 @@ import numpy as np
 from ..framework.tensor import run_op
 from ..ops.ragged_paged_attention import rope_tables
 
-__all__ = ["DispatchLayout", "ServingStep", "PagedKV", "SlotState",
-           "SharedPages"]
+__all__ = ["DispatchLayout", "ServingStep", "PagedKV", "PagedLatent",
+           "SlotState", "SharedPages"]
 
 
 class PagedKV:
@@ -51,6 +55,14 @@ class PagedKV:
 
     def __init__(self, heads, width, window=None):
         self.heads, self.width, self.window = int(heads), int(width), window
+
+
+class PagedLatent:
+    """One pool of rows ``width`` wide that all heads share, the whole
+    context."""
+
+    def __init__(self, width):
+        self.width = int(width)
 
 
 class SlotState:

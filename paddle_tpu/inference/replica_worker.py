@@ -302,6 +302,10 @@ def _build_model(model_spec):
         "tiny_sambay": (models.tiny_sambay_config,
                         models.SambaYForCausalLM),
         "sambay": (models.SambaYConfig, models.SambaYForCausalLM),
+        "tiny_kimi_linear": (models.tiny_kimi_linear_config,
+                             models.KimiLinearForCausalLM),
+        "kimi_linear": (models.KimiLinearConfig,
+                        models.KimiLinearForCausalLM),
     }
     kind = model_spec.get("kind", "tiny_llama")
     if kind not in kinds:

@@ -139,7 +139,7 @@ from ..observability.trace import record as _record_span
 from ..observability.trace import span as _span
 from ..testing import faults as _faults
 from .kv_tier import KvPageTier, TierError
-from .layer_step import (DispatchLayout, PagedKV, ServingStep,
+from .layer_step import (DispatchLayout, PagedKV, PagedLatent, ServingStep,
                          SharedPages, SlotState, _token_gather)
 from .paged_cache import PageAllocator
 from .sampling import SamplingParams, sampled_next_tokens
@@ -555,8 +555,9 @@ class LlamaServingEngine:
         # every layer states what it keeps (see `.layer_step`): a list
         # of (heads, width), one entry a pool, where all layers of a
         # model keep the same; or, a layer of a model whose layers
-        # differ, a `PagedKV` (with or without a window), a `SlotState`,
-        # `SharedPages` of another layer, or None
+        # differ, a `PagedKV` (with or without a window), a
+        # `PagedLatent`, a `SlotState`, `SharedPages` of another layer,
+        # or None
         specs = [layer.serving_cache() for layer in model.model.layers]
         mixed_caches = any(not isinstance(sp, list) for sp in specs)
         # a row's scan starts from its slot's state and writes it back:
@@ -676,6 +677,8 @@ class LlamaServingEngine:
                     own = [jnp.zeros(pool_shape(sp.heads, sp.width,
                                                 pages=pages), pool_dt)
                            for _ in range(2)]
+                elif isinstance(sp, PagedLatent):
+                    own = [jnp.zeros(pool_shape(None, sp.width), pool_dt)]
                 elif isinstance(sp, SlotState):
                     own = [jnp.zeros((max_batch + 1,) + shape, d)
                            for shape, d in sp.shapes]
@@ -688,8 +691,8 @@ class LlamaServingEngine:
                 elif sp is not None:
                     raise UnsupportedServingFeature(
                         "a model whose layers keep different caches "
-                        "states each as a PagedKV, a SlotState, "
-                        "SharedPages or None")
+                        "states each as a PagedKV, a PagedLatent, a "
+                        "SlotState, SharedPages or None")
                 at = len(self.k_pools)
                 self._layer_pages.append(list(range(at, at + len(own))))
                 self.k_pools += [Tensor(a) for a in own]
@@ -718,8 +721,8 @@ class LlamaServingEngine:
         # computes on its small tile (`small_tile`), at the query heads
         # a kv head of the K/V pools `[P, Hk, page, D]`; None where no
         # layer runs that program (latent rows, int8 pages)
-        hk = next((p._data.shape[1] for p in self.k_pools
-                   if p._data.ndim == 4), None)
+        hk = next((sp.heads for sp in specs if isinstance(sp, PagedKV)),
+                  None) if mixed_caches else specs[0][0][0]
         self._tile_tokens = None
         if hk and not self.kv_quant:
             from ..ops.ragged_paged_attention import small_tile
@@ -773,8 +776,15 @@ class LlamaServingEngine:
         def per_token(sp):
             if isinstance(sp, list):
                 return sp
+            if isinstance(sp, PagedLatent):
+                return [(None, sp.width)]
             whole = isinstance(sp, PagedKV) and not sp.window
             return [(sp.heads, sp.width)] * 2 if whole else []
+
+        #: bytes of the states one sequence slot holds, all layers
+        self._slot_bytes = sum(
+            int(np.prod(shape)) * d.itemsize for sp in specs
+            if isinstance(sp, SlotState) for shape, d in sp.shapes)
 
         tok_bytes = sum(
             (heads or 1) * (width * jnp.dtype(pool_dt).itemsize
@@ -2775,8 +2785,9 @@ class LlamaServingEngine:
         dispatch of ``rows``: sequences with a slot, pages of the whole-
         context pools, ring pages all window layers hold live keys in
         (a context of ``n`` tokens and a window ``w``: the pages of
-        positions ``max(n - w, 0) .. n - 1``), and those that fell
-        behind a window in this dispatch (free to be overwritten)."""
+        positions ``max(n - w, 0) .. n - 1``), those that fell behind a
+        window in this dispatch (free to be overwritten), and the bytes
+        of slot states the dispatch's rows moved (in and out)."""
         page = self.page_size
         with self._lock:
             lens = [n for n in self.alloc._lens.values() if n > 0]
@@ -2789,9 +2800,11 @@ class LlamaServingEngine:
                                   for _, _, start, n, _, _ in rows)
         # (no prefix cache holds pages of such a model)
         shared = self.alloc.num_pages - self.alloc.free_pages
+        # every row reads its slot's states and writes them back
         return dict(state_slots=self.alloc.slots_held,
                     shared_kv_pages=shared, window_pages=held,
-                    window_pages_freed=freed)
+                    window_pages_freed=freed,
+                    state_bytes=2 * len(rows) * self._slot_bytes)
 
     def _kv_pages(self, kv_lens):
         """Pages that hold contexts of these lengths, summed."""

@@ -49,8 +49,8 @@ from ..framework.tensor import Parameter, Tensor, run_op
 from ..nn.initializer import Normal
 from .llama import LlamaMLP
 
-__all__ = ["MlaMoeConfig", "MlaAttention", "MlaMoeMLP",
-           "MlaMoeDecoderLayer", "MlaMoeModel", "MlaMoeForCausalLM",
+__all__ = ["MlaMoeConfig", "MlaAttention", "MlaMoeMLP", "expert_stats",
+           "serving_ffn", "MlaMoeDecoderLayer", "MlaMoeModel", "MlaMoeForCausalLM",
            "tiny_mla_moe_config"]
 
 
@@ -62,7 +62,7 @@ class MlaMoeConfig:
     moe_intermediate_size: int = 768
     num_hidden_layers: int = 40
     num_attention_heads: int = 32
-    q_lora_rank: int = 1536
+    q_lora_rank: int | None = 1536   # None: one query projection, no norm
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
@@ -78,6 +78,8 @@ class MlaMoeConfig:
     rope_theta: float = 32000000.0
     tie_word_embeddings: bool = False
     initializer_range: float = 0.02
+    #: no rotation: the "rope" lanes are ordinary key lanes all heads share
+    mla_use_nope: bool = False
 
     # what the serving engine reads of any decoder's config
     @property
@@ -133,9 +135,12 @@ def rope_interleaved(x, sin, cos):
 
 
 class MlaAttention(nn.Layer):
-    """Multi-head latent attention (module docstring)."""
+    """Multi-head latent attention (module docstring). A config with
+    ``q_lora_rank`` None has ONE query projection (no low-rank pair, no
+    query norm); one with ``mla_use_nope`` rotates nothing. ``config``
+    is any object with the fields read here."""
 
-    def __init__(self, config: MlaMoeConfig):
+    def __init__(self, config):
         super().__init__()
         self.config = config
         c = config
@@ -149,9 +154,15 @@ class MlaAttention(nn.Layer):
         def lin(i, o):
             return nn.Linear(i, o, weight_attr=wa, bias_attr=False)
 
-        self.q_a = lin(c.hidden_size, c.q_lora_rank)
-        self.q_a_norm = nn.RMSNorm(c.q_lora_rank, epsilon=c.rms_norm_eps)
-        self.q_b = lin(c.q_lora_rank, h * self.head_dim)
+        self.rotates = not getattr(c, "mla_use_nope", False)
+        self.q_rank = c.q_lora_rank
+        if self.q_rank is None:
+            self.q_proj = lin(c.hidden_size, h * self.head_dim)
+        else:
+            self.q_a = lin(c.hidden_size, c.q_lora_rank)
+            self.q_a_norm = nn.RMSNorm(c.q_lora_rank,
+                                       epsilon=c.rms_norm_eps)
+            self.q_b = lin(c.q_lora_rank, h * self.head_dim)
         self.kv_a = lin(c.hidden_size, self.kv_rank + self.rope)
         self.kv_a_norm = nn.RMSNorm(self.kv_rank, epsilon=c.rms_norm_eps)
         self.kv_b = lin(self.kv_rank, h * (self.nope + self.v_dim))
@@ -160,7 +171,8 @@ class MlaAttention(nn.Layer):
     def _project(self, x):
         """``q [.., heads*(nope+rope)]``, the normed latent ``c_kv`` and
         the un-rotated shared ``k_rope`` of ``x``."""
-        q = self.q_b(self.q_a_norm(self.q_a(x)))
+        q = self.q_proj(x) if self.q_rank is None \
+            else self.q_b(self.q_a_norm(self.q_a(x)))
         r = self.kv_rank
 
         def split(a):
@@ -177,13 +189,17 @@ class MlaAttention(nn.Layer):
         q, c, kr = self._project(x)
         kv = self.kv_b(c)
         base, scale = float(self.config.rope_theta), self.scale
+        rotates = self.rotates
 
         def fn(q, kr, kv, pos):
-            pos = jnp.arange(s) if pos is None else pos.reshape(-1)[:s]
-            sin, cos = rope_tables_interleaved(pos, rope, base)
             q = q.reshape(b, s, h, nope + rope)
-            qr = rope_interleaved(q[..., nope:], sin, cos)
-            kr_ = rope_interleaved(kr.reshape(b, s, 1, rope), sin, cos)
+            if rotates:
+                pos = jnp.arange(s) if pos is None else pos.reshape(-1)[:s]
+                sin, cos = rope_tables_interleaved(pos, rope, base)
+                qr = rope_interleaved(q[..., nope:], sin, cos)
+                kr_ = rope_interleaved(kr.reshape(b, s, 1, rope), sin, cos)
+            else:
+                qr, kr_ = q[..., nope:], kr.reshape(b, s, 1, rope)
             kv = kv.reshape(b, s, h, nope + vd)
             f32 = jnp.float32
             sc = jnp.einsum("bqhd,bkhd->bhqk", q[..., :nope].astype(f32),
@@ -203,7 +219,8 @@ class MlaAttention(nn.Layer):
     def absorbed(self, x, sin, cos, width):
         """The serving operands of packed tokens ``x [1, T, H]``: the
         absorbed, rotated queries ``[T, heads, width]`` and the latent
-        rows to cache ``[T, width]`` (``[c_kv | k_rope | 0]``)."""
+        rows to cache ``[T, width]`` (``[c_kv | k_rope | 0]``). A layer
+        that rotates nothing is handed ``sin = cos = None``."""
         t = x.shape[1]
         h, nope, rope, r = self.num_heads, self.nope, self.rope, \
             self.kv_rank
@@ -211,8 +228,12 @@ class MlaAttention(nn.Layer):
 
         def fn(q, c, kr, wkvb, sin, cos):
             q = q.reshape(t, h, nope + rope)
-            qr = rope_interleaved(q[..., nope:], sin, cos)
-            kr_ = rope_interleaved(kr.reshape(t, 1, rope), sin, cos)[:, 0]
+            if sin is not None:
+                qr = rope_interleaved(q[..., nope:], sin, cos)
+                kr_ = rope_interleaved(kr.reshape(t, 1, rope), sin,
+                                       cos)[:, 0]
+            else:
+                qr, kr_ = q[..., nope:], kr.reshape(t, rope)
             wk = wkvb.reshape(r, h, nope + self.v_dim)[..., :nope]
             qa = jnp.einsum("thn,chn->thc", q[..., :nope], wk,
                             preferred_element_type=jnp.float32) \
@@ -245,13 +266,61 @@ class MlaAttention(nn.Layer):
                              differentiable=False))
 
 
+    def serving(self, x, step, pool):
+        """One packed step ``x [1, T, H]`` (normed) over this layer's
+        latent pool: ``(the attention's output [1, T, H], the pool)``."""
+        from ..ops.ragged_mla_attention import ragged_mla_attention
+
+        sin, cos = step.rope_interleaved(
+            self.rope, float(self.config.rope_theta)) if self.rotates \
+            else (None, None)
+        qf, rows = self.absorbed(x, sin, cos, pool.shape[-1])
+        out4, pool = ragged_mla_attention(
+            qf, rows, pool, step.tables, step.kv_lens, step.q_starts,
+            step.q_lens, step.w_starts, step.w_flats, step.w_ends,
+            v_width=self.kv_rank, scale=self.scale, qblock=step.qblock)
+        u = step.unpack(out4.reshape([step.rows * step.qblock,
+                                      self.num_heads, self.kv_rank]))
+        return self.unabsorb(u), pool
+
+
+def expert_stats(mlp):
+    """What a layer's ``serving_step`` returns of its expert FFN's last
+    call, the one shape every model's expert layers use: int32 ``[1, 2,
+    1]``, ``[[experts (of those held) that got a row], [rows of the
+    largest group]]``. The engine stacks the layers' along axis 0 and
+    the host reads them with the tokens."""
+    return mlp.last_stats.reshape([1, 2, 1])
+
+
+def serving_ffn(layer, x, step):
+    """The FFN half of a layer's packed step, for any layer with a
+    ``post_attention_layernorm``, an ``mlp`` and ``is_moe``: ``(x + FFN
+    (norm(x)), stats)``, ``stats`` `expert_stats` of an expert layer
+    (padding tokens get no expert row), None of a dense one."""
+    h = layer.post_attention_layernorm(x)
+    if layer.is_moe:
+        return x + layer.mlp(h, valid=step.token_valid()), \
+            expert_stats(layer.mlp)
+    return x + layer.mlp(h), None
+
+
 class MlaMoeMLP(nn.Layer):
     """The expert FFN: sigmoid router with a score-correction bias,
     top-k routed SwiGLU experts over the packed grouped GEMM, and one
     shared SwiGLU expert (module docstring). ``last_stats`` holds, after
-    a call, ``[experts that got a row, rows of the largest group]``."""
+    a call, ``[experts that got a row, rows of the largest group]``.
 
-    def __init__(self, config: MlaMoeConfig):
+    ``experts_held`` (default: all) and ``first_expert`` make this the
+    share of an expert-parallel deployment that one chip computes: it
+    holds the weights of experts ``[first_expert, first_expert +
+    experts_held)``; the router keeps all its outputs and its ``k`` a
+    token; the sum runs over the chosen experts that are held, the shared
+    expert is computed whole, and what the absent experts would add is
+    left out. ``last_stats`` then counts the held experts. ``config`` is
+    any dataclass with the fields read here."""
+
+    def __init__(self, config, experts_held=None, first_expert=0):
         super().__init__()
         from ..framework import random as frandom
         from ..framework.dtype import get_default_dtype
@@ -261,7 +330,15 @@ class MlaMoeMLP(nn.Layer):
         self.top_k = int(c.num_experts_per_tok)
         self.scaling = float(c.routed_scaling_factor)
         self.normalize = bool(c.norm_topk_prob)
+        self.held = self.num_experts if experts_held is None \
+            else int(experts_held)
+        self.first = int(first_expert)
+        if not 0 <= self.first <= self.num_experts - self.held:
+            raise ValueError(
+                f"experts [{self.first}, {self.first + self.held}) are not "
+                f"among the router's {self.num_experts}")
         e, d, f = self.num_experts, c.hidden_size, c.moe_intermediate_size
+        held = self.held
         dt = jnp.dtype(get_default_dtype())
         std = c.initializer_range
 
@@ -273,9 +350,9 @@ class MlaMoeMLP(nn.Layer):
 
         self.router = init((d, e))
         self.router_bias = Parameter(jnp.zeros((e,), dt))
-        self.experts_gate = init((e, d, f))
-        self.experts_up = init((e, d, f))
-        self.experts_down = init((e, f, d))
+        self.experts_gate = init((held, d, f))
+        self.experts_up = init((held, d, f))
+        self.experts_down = init((held, f, d))
         shared = dataclasses.replace(
             c, intermediate_size=f * max(1, int(c.n_shared_experts)))
         self.shared = LlamaMLP(shared)
@@ -300,13 +377,21 @@ class MlaMoeMLP(nn.Layer):
         shape = x.shape
         d = shape[-1]
         n = int(np.prod(shape[:-1]))
-        e, k = self.num_experts, self.top_k
+        e, k = self.held, self.top_k
+        first, share = self.first, self.held != self.num_experts
         sub = 32 // jnp.dtype(x._data.dtype).itemsize
         bm = gg.packed_block_m(n * k, e, sublane=sub)
 
         def fn(x2d, wr, br, wg, wu, wd, valid):
             with jax.named_scope("paddle_tpu.moe"):
                 idx, w = self.route(x2d, wr, br)
+                if share:
+                    # a chosen expert another chip holds gets no row
+                    # here, as an invalid token's do
+                    idx = idx - first
+                    mine = (idx >= 0) & (idx < e)
+                    idx = jnp.where(mine, idx, e)
+                    w = jnp.where(mine, w, 0.0)
                 if valid is not None:
                     idx = jnp.where(valid[:, None], idx, e)
                     w = jnp.where(valid[:, None], w, 0.0)
@@ -366,28 +451,12 @@ class MlaMoeDecoderLayer(nn.Layer):
 
     def serving_step(self, x, step, pages):
         """One packed step of this layer over its latent pages:
-        ``(x, pages, stats)``; ``stats`` is the expert layer's
-        ``[experts touched, largest group]`` or None."""
-        from ..ops.ragged_mla_attention import ragged_mla_attention
-
-        a = self.self_attn
-        pool = pages[0]
-        width = pool.shape[-1]
-        sin, cos = step.rope_interleaved(a.rope,
-                                         float(a.config.rope_theta))
-        qf, rows = a.absorbed(self.input_layernorm(x), sin, cos, width)
-        out4, pool = ragged_mla_attention(
-            qf, rows, pool, step.tables, step.kv_lens, step.q_starts,
-            step.q_lens, step.w_starts, step.w_flats, step.w_ends,
-            v_width=a.kv_rank, scale=a.scale, qblock=step.qblock)
-        u = step.unpack(out4.reshape([step.rows * step.qblock,
-                                      a.num_heads, a.kv_rank]))
-        x = x + a.unabsorb(u)
-        h = self.post_attention_layernorm(x)
-        if self.is_moe:
-            x = x + self.mlp(h, valid=step.token_valid())
-            return x, [pool], self.mlp.last_stats.reshape([1, 2, 1])
-        return x + self.mlp(h), [pool], None
+        ``(x, pages, stats)``; ``stats`` is `expert_stats` of an expert
+        layer, None of a dense one."""
+        y, pool = self.self_attn.serving(self.input_layernorm(x), step,
+                                         pages[0])
+        x, stats = serving_ffn(self, x + y, step)
+        return x, [pool], stats
 
 
 class MlaMoeModel(nn.Layer):

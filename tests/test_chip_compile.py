@@ -26,6 +26,7 @@ from jax.sharding import SingleDeviceSharding
 from paddle_tpu.ops import flash_attention as fa
 from paddle_tpu.ops import fused_linear_cross_entropy as flce
 from paddle_tpu.ops import grouped_gemm as gg
+from paddle_tpu.ops import kda
 from paddle_tpu.ops import ragged_mla_attention as mla
 from paddle_tpu.ops import ragged_paged_attention as rpa
 from paddle_tpu.quant import kernels as qk
@@ -70,7 +71,7 @@ def chip_compile(one_chip, no_persistent_cache, monkeypatch):
     """``compile(fn, *specs) -> compiled``: ``fn`` jitted and compiled
     for one described v5e with every kernel module off interpret mode.
     ``specs`` are ``(shape, dtype)`` pairs."""
-    for mod in (rpa, fa, flce, gg, qk, mla):
+    for mod in (rpa, fa, flce, gg, qk, mla, kda):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
     def compile_(fn, *specs):
@@ -319,16 +320,64 @@ def test_packed_grouped_gemm_compiles_at_published_widths(chip_compile, m,
         ((1,), I32)), "grouped_gemm_packed")
 
 
-@pytest.mark.parametrize("tokens,rows,qblock", [(1024, 80, 32), (48, 48, 1)])
+@pytest.mark.parametrize("tokens,rows,qblock,pages,table", [
+    (1024, 80, 32, 24577, 1057), (48, 48, 1, 24577, 1057),
+    (512, 72, 64, 8193, 385), (64, 64, 1, 8193, 385)],
+    ids=["docqa-mixed", "docqa-decode", "assist-mixed", "assist-decode"])
 def test_latent_attention_compiles_at_published_widths(chip_compile,
                                                        tokens, rows,
-                                                       qblock):
+                                                       qblock, pages,
+                                                       table):
     """32 heads over a 640-lane latent row (512 + 64 in use), pages of
-    16, a table 1,057 pages wide: the docqa cell's two step shapes."""
+    16: the docqa cell's two step shapes (a table 1,057 pages wide) and
+    the assist cell's (chunks of 64, a table of 385)."""
     width = mla.latent_row_width(512, 64)
     _assert_kernel(chip_compile(
         functools.partial(mla._kernel_impl, v_width=512,
                           scale=192 ** -0.5, qblock=qblock),
         ((tokens, 32, width), BF16), ((tokens, width), BF16),
-        ((24577, 16, width), BF16), ((rows, 1057), I32),
+        ((pages, 16, width), BF16), ((rows, table), I32),
         *[((rows,), I32)] * 6), "ragged_mla_attn")
+
+
+@pytest.mark.parametrize("m,k,n,block_m", [
+    (6144, 2304, 1024, 128), (6144, 1024, 2304, 128),
+    (1024, 2304, 1024, 32)],
+    ids=["mixed-gate-up", "mixed-down", "decode-gate-up"])
+def test_packed_grouped_gemm_compiles_for_an_expert_share(chip_compile, m,
+                                                          k, n, block_m):
+    """16 held experts of width 1024 over hidden 2304: the worst-case
+    packed rows (every assignment on a held expert) of the assist cell's
+    512-token and 64-token steps, in the row tiles the layer picks."""
+    assert gg.packed_block_m(512 * 8, 16, sublane=16) == 128
+    assert gg.packed_rows(512 * 8, 16, 128) == 6144
+    assert gg.packed_block_m(64 * 8, 16, sublane=16) == 32
+    assert gg.packed_rows(64 * 8, 16, 32) == 1024
+    _assert_kernel(chip_compile(
+        functools.partial(gg._packed_kernel_impl, block_m=block_m),
+        ((m, k), BF16), ((16, k, n), BF16), ((m // block_m,), I32),
+        ((1,), I32)), "grouped_gemm_packed")
+
+
+@pytest.mark.parametrize("rows", [72, 64], ids=["mixed", "decode"])
+def test_kda_step_compiles_at_published_widths(chip_compile, rows):
+    """32 heads of 128 x 128 float32 a row against a pool of 65 slots,
+    updated in place: no second pool in the compiled program."""
+    h, d, slots = 32, 128, 65
+    c = chip_compile(
+        kda.kda_step, ((rows, h, d), F32), ((rows, h, d), F32),
+        ((rows, h, d), F32), ((rows, h, d), F32), ((rows, h), F32),
+        ((slots, h, d, d), F32), ((rows,), I32), ((rows,), I32))
+    _assert_kernel(c, "kda_step")
+
+
+def test_kda_chunk_rows_compile_at_published_widths(chip_compile):
+    """Eight chunk rows of 64 tokens, one row at a time (XLA: no
+    kernel): the triangular solve and the loop lower for the chip."""
+    rows, q, h, d = 8, 64, 32, 128
+    c = chip_compile(
+        functools.partial(kda.kda_rows, long_rows=rows),
+        ((rows, q, h, d), F32), ((rows, q, h, d), F32),
+        ((rows, q, h, d), F32), ((rows, q, h, d), F32),
+        ((rows, q, h), F32), ((rows, h, d, d), F32), ((rows,), I32))
+    assert "while" in c.as_text()

@@ -13,6 +13,7 @@ import pytest
 from paddle_tpu.ops import flash_attention as fa
 from paddle_tpu.ops import fused_linear_cross_entropy as flce
 from paddle_tpu.ops import grouped_gemm as gg
+from paddle_tpu.ops import kda
 from paddle_tpu.ops import paged_attention as pa
 from paddle_tpu.ops import ragged_mla_attention as mla
 from paddle_tpu.ops import ragged_paged_attention as rpa
@@ -85,6 +86,12 @@ SITES = {
             [((R, H, D), BF16), ((PAGES, HK, PAGE, D), BF16),
              ((PAGES, HK, PAGE, D), BF16), ((R, WIDTH), I32),
              ((R,), I32)])),
+    "kda.py step": (
+        "paddle_tpu.kda_step", lambda: (
+            kda.kda_step,
+            [((R, H, D), F32)] * 4 + [((R, H), F32),
+                                      ((R + 1, H, D, D), F32),
+                                      ((R,), I32), ((R,), I32)])),
     "quant/kernels.py": (
         "paddle_tpu.dequant_matmul", lambda: (
             functools.partial(qk._kernel_impl, block=128),
@@ -120,7 +127,7 @@ def test_pallas_call_site_carries_its_name(site):
 def test_names_are_distinct_and_cover_every_site():
     import pathlib
     names = [n for n, _ in SITES.values()]
-    assert len(set(names)) == len(names) == 12
+    assert len(set(names)) == len(names) == 13
     root = pathlib.Path(fa.__file__).parent.parent
     calls = named = 0
     for path in root.rglob("*.py"):
@@ -128,4 +135,4 @@ def test_names_are_distinct_and_cover_every_site():
         calls += len(re.findall(r"\bpl\.pallas_call\(", text))
         named += len(re.findall(r"\bname=(?:\"paddle_tpu\.|KERNEL_NAME)",
                                 text))
-    assert calls == named == 12
+    assert calls == named == 13

@@ -45,9 +45,13 @@ def test_benchmark_names_the_new_cells_and_their_readers():
         "mistral-7b-v0.3-l16.chat-sat"
     assert cells[doc]["chips"] == cells[sat]["chips"] == 1
     per = {m["name"]: m for m in SPEC["per_layer"]}
+    # the linear-attention expert family's cell (PR 37) shares the latent
+    # kernel and the packed GEMM: it joined three of the four lists
+    assist = "kimi-linear-48b-a3b-ep16.assist-closed"
     for name in ("mla_roofline.tok", "moe_gemm_roofline.tok",
                  "moe_route_ms.tok", "experts_touched.tok"):
-        assert per[name]["workloads"] == [doc]
+        assert per[name]["workloads"] == [doc] + [assist] * (
+            name != "experts_touched.tok")
         assert per[name]["moves"] == "serve_tok_s"
         assert os.path.isfile(bench.reader_path(name))
     assert doc not in per["attn_roofline.tok"]["workloads"]
@@ -62,16 +66,24 @@ def test_benchmark_names_the_new_cells_and_their_readers():
         assert per[name]["moves"] == "serve_tok_s"
         assert os.path.isfile(bench.reader_path(name))
     assert per["window_held_share.tok"]["better"] == "lower"
+    assert cells[assist]["chips"] == 1
+    kda = ("kda_ms.tok", "kda_roofline.tok")
+    for name in kda:
+        assert per[name]["workloads"] == [assist]
+        assert per[name]["moves"] == "serve_tok_s"
+        assert os.path.isfile(bench.reader_path(name))
     for m in SPEC["per_layer"]:
-        if m["name"].endswith(".tok") and m["name"] not in hybrid + (
+        if m["name"].endswith(".tok") and m["name"] not in hybrid + kda + (
                 "mla_roofline.tok", "moe_gemm_roofline.tok",
                 "moe_route_ms.tok", "experts_touched.tok",
                 "attn_roofline.tok"):
             assert doc in m["workloads"] and sat in m["workloads"], m
-            # every kernel-agnostic .tok metric is read in the new cell
+            # every kernel-agnostic .tok metric is read in the new cells
             assert reason in m["workloads"], m
-    assert reason in next(m for m in SPEC["end_to_end"]
-                          if m["name"] == "serve_tok_s")["workloads"]
+            assert m["workloads"][-1] == assist, m
+    serve = next(m for m in SPEC["end_to_end"]
+                 if m["name"] == "serve_tok_s")["workloads"]
+    assert reason in serve and serve[-1] == assist
 
 
 def test_configuration_keeps_every_published_width():
@@ -92,6 +104,20 @@ def test_configuration_keeps_every_published_width():
     assert cfg["reduced"]["num_hidden_layers"]["published"] == 40
     assert cfg["num_hidden_layers"] == 5
     assert cfg["num_nextn_predict_layers"] == 0
+
+
+def test_the_share_configuration_keeps_every_published_width():
+    cfg = bench.load_json("perfbench", "configs",
+                          "kimi-linear-48b-a3b-ep16.json")
+    fam = family.load(cfg, "kimi-linear-48b-a3b-ep16")
+    fam.selfcheck()
+    assert cfg["num_hidden_layers"] == 27 and cfg["vocab_size"] == 163840
+    assert sorted(cfg["reduced"]) == ["num_experts"]
+    assert fam.dims(cfg)["e"] == 256 and fam.dims(cfg)["held"] == 16
+    assert fam.total_params(cfg) == 4956660608
+    # one decode row: 20 states of 2,170,880 bytes in and out
+    assert fam.kda_work(cfg, [], [1000])[1] \
+        == 20 * (2 * 2170880 + 3 * 4096 * 2 + 5 * 4096 * 4 + 128)
 
 
 def test_family_costs_against_hand_figures():
