@@ -308,7 +308,9 @@ class ClusterRequest:
         """Engine-side per-token hook of the CURRENT in-process
         attempt; forwards to the caller's ``on_token``."""
         cb = self.on_token
-        if cb is not None:
+        # (an engine finishing a dispatch it had in flight when its
+        # replica was replaced still emits into the abandoned attempt)
+        if cb is not None and req is self.request:
             try:
                 cb(int(token))
             except Exception:
@@ -662,8 +664,9 @@ class EngineReplica:
                 e = self.engine
                 with self._lock:
                     queued = bool(self._backlog)
-                live = e is not None \
-                    and any(not r.done for r in e._live.values())
+                live = e is not None and (
+                    e._inflight is not None
+                    or any(not r.done for r in e._live.values()))
                 # one span a turn that has work (an idle sleep is not a
                 # span); its self time, the turn less its dispatches,
                 # is the loop's own cost
@@ -672,8 +675,11 @@ class EngineReplica:
                     admitted = len(self._admit_from_backlog())
                     served = 0
                     if live or admitted:
+                        # one dispatch ahead: the turn launches the
+                        # next dispatch, then applies the last one's
+                        # tokens (a burst decodes by synchronous scans)
                         served = e.decode_many(self.burst) if self.burst \
-                            else e.step()
+                            else e.step_ahead()
                     reaped = self._reap_completed()
                     if tick is not None:
                         tick.set(admitted=admitted, reaped=reaped)
@@ -739,7 +745,7 @@ class EngineReplica:
             admitted.append(req)
         # no explicit prefill here: admitted prompts chunk-prefill
         # inside the worker tick's very next mixed dispatch
-        # (engine.step()/decode_many), interleaved with live decodes
+        # (engine.step_ahead()/decode_many), interleaved with live decodes
         return admitted
 
     def _unpend(self, creq):

@@ -95,7 +95,13 @@ class DispatchLayout:
     """Where each host-built field of one dispatch lies in the ONE flat
     int32 buffer the host hands the step program: the 18 arrays
     :meth:`LlamaServingEngine._mixed_forward` takes before its pools,
-    in its argument order, back to back with no padding. The host fills
+    in its argument order, then ``prev_idx`` (and, of a model that keeps
+    a state or a ring a sequence slot, ``slots``), back to back with no
+    padding. ``prev_idx [1, T]`` says where a packed token comes from:
+    -1, the host wrote it into ``tokens``; ``i >= 0``, it is entry ``i``
+    of the tokens the dispatch before this one returned, which never
+    left the device (a decode row launched before the host had read
+    them). The host fills
     :meth:`views` of a buffer from :meth:`new` and transfers it once;
     the program takes it apart again with :meth:`unpack` at static
     offsets. The float32 fields ride as their bit patterns (a numpy
@@ -129,10 +135,11 @@ class DispatchLayout:
             ("slot_ids", (r, b), i32, -1),
             ("slot_vals", (r, b), f32, 0.0),
             ("cmodes", (r,), i32, 0),
+            ("prev_idx", (1, t), i32, -1),
         )
         if trash_slot is not None:
             # a model whose layers keep a state or a ring a sequence
-            # slot: each row's slot, a 19th field
+            # slot: each row's slot, a 20th field
             spec += (("slots", (r,), i32, int(trash_slot)),)
         self.shape = (t, r, int(qb), int(width), b)
         fields, at = [], 0

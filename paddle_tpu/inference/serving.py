@@ -32,6 +32,17 @@ Design (TPU-first, chunked prefill over ONE mixed program):
   and inactive rows carry ``kv_len 0``, so shapes never change and two
   executables (the ``chunk_budget``-token mixed shape and the
   [max_batch]-token decode-only shape) cover the engine's lifetime.
+- The continuous loops (:meth:`LlamaServingEngine.step_ahead`: the
+  replica's worker loop, ``generate``, ``drain``) run ONE DISPATCH
+  AHEAD: a turn plans, builds and enqueues dispatch n+1 and only then
+  waits for dispatch n's tokens and applies them, so the host's turn
+  runs beside the device's step, not between two of them. What a plan
+  needs of the dispatch in flight does not depend on its tokens' values
+  (prompt positions prefilled, tokens emitted against
+  ``max_new_tokens``); a decode row's input token is taken where the
+  device left it (`DispatchLayout`'s ``prev_idx``). A row launched for
+  a sequence that ended meanwhile is stale and dropped at apply.
+  ``step()`` launches a dispatch and finishes it, as it always did.
 - Sustained decode amortizes the host round trip with ``lax.scan``
   over the SAME mixed step (``decode_ticks`` tokens per sequence per
   dispatch, pages reserved up front, lengths advancing on device as
@@ -304,6 +315,11 @@ def _serving_metrics():
             "them: prefill, decode (speculative drafts among them) or "
             "pad (slots the program's shape had and no row used)",
             labelnames=("kind",)),
+        "stale_rows": _om.counter(
+            "serving_dispatch_stale_rows_total",
+            "rows of dispatched programs dropped at apply because their "
+            "sequence had ended (EOS, a stop token, a cancel, a "
+            "deadline, an eviction) after the row was launched"),
         "tpot": _om.histogram(
             "serving_token_latency_seconds",
             "per-token decode latency (scan dispatches amortized)",
@@ -483,6 +499,14 @@ class Request:
         self._prefilled = 0           # prompt tokens written to pages
         self._tier_key = None         # host-tier handle while paused
         self._tier_tokens = 0         # context length of the parked KV
+
+
+#: A mixed dispatch the device has been handed and the host has not
+#: applied: what :meth:`LlamaServingEngine._finish` needs of it, and what
+#: its `serving.dispatch` span will say (``said``).
+_Launched = collections.namedtuple(
+    "_Launched", "step rows nxt layer_stats flat_start dur cold "
+                 "needs_mixed said")
 
 
 class LlamaServingEngine:
@@ -861,6 +885,16 @@ class LlamaServingEngine:
         self._drain_active = False
         self._pending_drain = None    # (grace, exit_code, on_drained)
         self._dispatch_count = 0
+        # one dispatch ahead: the mixed dispatch the device runs (or has
+        # queued) whose tokens the host has not applied yet, and the
+        # token array the last mixed program returned, which the next
+        # one reads on the device (`_mixed_packed`). ONE shape whichever
+        # of the two program shapes wrote it: a row each (a packed token
+        # each, of a speculative engine), padded to the longer shape's
+        self._inflight = None
+        self._carry_len = self.chunk_budget if self.spec_k \
+            else self.rows_cap
+        self._carry = Tensor(jnp.zeros((self._carry_len,), jnp.int32))
         self._dispatch_times: collections.deque[float] = \
             collections.deque(maxlen=256)
         self._token_times: collections.deque[float] = \
@@ -1108,7 +1142,7 @@ class LlamaServingEngine:
                        kv_lens, q_starts, q_lens, w_starts, w_flats,
                        w_ends, temps, top_ps, top_ks, seeds, slot_ids,
                        slot_vals, cmodes, k_pools, v_pools, k_scales,
-                       v_scales, slots=None):
+                       v_scales, slots=None, prev_idx=None, prev=None):
         """ONE token-packed model step: embed [1, T] real tokens (a mix
         of prefill-chunk tokens, speculative verify tokens and decode
         tokens, back to back with no inter-row padding), ask every
@@ -1146,6 +1180,12 @@ class LlamaServingEngine:
         final kv_len). The query block QB is the one of the dispatch
         layout that has T tokens.
 
+        ``prev_idx [1, T]`` (None: every token is in ``tokens``) names,
+        for a token the host could not write because the dispatch that
+        produces it was still running when this one was built, its
+        index in ``prev``, that dispatch's returned tokens; -1 for a
+        token that is in ``tokens``.
+
         tokens/pos [1, T]; flat_idx [T];
         last_idx/kv_lens/q_starts/q_lens/w_starts/w_flats/w_ends/
         temps/top_ps/top_ks/seeds/cmodes [R]; slot_ids/slot_vals
@@ -1161,6 +1201,15 @@ class LlamaServingEngine:
         m = self.model.model
         t = tokens.shape[1]
         r_rows, qb = tables.shape[0], self._dispatch_layout(t).shape[2]
+        if prev_idx is not None:
+            # a decode row launched one dispatch ahead: its input token
+            # is where the dispatch before left it, on the device
+            tokens = run_op(
+                "serving_token_feed",
+                lambda tok, at, pv: jnp.where(
+                    at >= 0, pv[jnp.maximum(at, 0)].astype(tok.dtype),
+                    tok),
+                (tokens, prev_idx, prev), differentiable=False)
         x = m.embed_tokens(tokens)                       # [1, T, H]
         # the step's metadata, and the tables its layers share (rotary
         # sin/cos are made once a dispatch, not once a layer)
@@ -1200,7 +1249,9 @@ class LlamaServingEngine:
         # alias is a use-after-free. The mixed program's one host-built
         # input is the packed [size] buffer, longer than T; the scan's
         # token input is 2-D [B, 1]. These shapes always get a fresh
-        # buffer.
+        # buffer. (The token array one mixed program hands the next,
+        # `_mixed_packed`'s ``prev``, HAS the returned tokens' aval: it
+        # is kept out of the donation, `keep_args`.)
         if self.spec_k:
             logits = self.model._logits(x)               # [1, T, V]
             if self.sample_enabled:
@@ -1270,31 +1321,45 @@ class LlamaServingEngine:
                 trash_slot=self.max_batch if self._slotted else None)
         return lay
 
-    def _mixed_packed(self, packed, k_pools, v_pools, k_scales, v_scales):
+    def _mixed_packed(self, packed, prev, k_pools, v_pools, k_scales,
+                      v_scales):
         """The compiled entry of the mixed program: ``packed`` is one
         dispatch's metadata as :class:`DispatchLayout` lays it out
         (int32 ``[size]``); it is taken apart at static offsets into the
-        18 tensors :meth:`_mixed_forward` takes. The buffer's length
-        names the program shape: the chunk-budget layout is always the
-        longer (``chunk_budget >= 2 * max_batch``, ``rows_cap >
-        max_batch``)."""
+        18 tensors :meth:`_mixed_forward` takes and ``prev_idx``. The
+        buffer's length names the program shape: the chunk-budget layout
+        is always the longer (``chunk_budget >= 2 * max_batch``,
+        ``rows_cap > max_batch``). ``prev`` int32 ``[_carry_len]`` is
+        the token array the mixed program before this one returned (it
+        is not donated: the host reads it after this call is enqueued);
+        the tokens returned are padded to that one length, so that both
+        program shapes read and write the same array and an engine
+        compiles two programs, not one a pair of shapes."""
         layout = self._dispatch_layout(self.chunk_budget)
         if packed.shape[0] != layout.size:
             layout = self._dispatch_layout(self.max_batch)
         fields = [Tensor(a) for a in layout.unpack(packed._data)]
-        # a model that keeps a state or a ring a slot has a 19th field
+        # a model that keeps a state or a ring a slot has a 20th field
         slots = fields.pop() if self._slotted else None
-        return self._mixed_forward(*fields, k_pools, v_pools, k_scales,
-                                   v_scales, slots=slots)
+        prev_idx = fields.pop()
+        nxt, *rest = self._mixed_forward(
+            *fields, k_pools, v_pools, k_scales, v_scales, slots=slots,
+            prev_idx=prev_idx, prev=prev)
+        pad = self._carry_len - nxt.shape[0]
+        nxt = run_op("serving_token_carry",
+                     lambda a: jnp.pad(a.astype(jnp.int32), (0, pad)),
+                     (nxt,), differentiable=False)
+        return (nxt, *rest)
 
     def _run_mixed(self, buf):
         """Hand the mixed program one host buffer (its only transfer)
-        and adopt the donated pools it returns. Returns ``(next tokens,
-        the layers' counters)``, both still on the device."""
+        and the tokens the program before it returned, and adopt the
+        donated pools it returns. Returns ``(next tokens, the layers'
+        counters)``, both still on the device."""
         sf = self._ensure_mixed_compiled()
         nxt, new_k, new_v, new_ks, new_vs, stats = sf(
-            Tensor(jnp.asarray(buf)), self.k_pools, self.v_pools,
-            self.k_scales, self.v_scales)
+            Tensor(jnp.asarray(buf)), self._carry, self.k_pools,
+            self.v_pools, self.k_scales, self.v_scales)
         self.k_pools, self.v_pools = list(new_k), list(new_v)
         if self.kv_quant:
             self.k_scales, self.v_scales = list(new_ks), list(new_vs)
@@ -1314,7 +1379,7 @@ class LlamaServingEngine:
             # the donated buffers, corrupting the model in place.
             self._mixed_static = StaticFunction(
                 self._mixed_packed, state=[self.model], warmup="once",
-                donate=False, donate_inputs=True,
+                donate=False, donate_inputs=True, keep_args=(1,),
                 name="serving.mixed_step")
             self._mixed_static._warmed_any = True
         return self._mixed_static
@@ -1431,7 +1496,27 @@ class LlamaServingEngine:
         return {"k": self.spec_k, "proposed": p, "accepted": a,
                 "accept_rate": a / p if p else 0.0}
 
-    def _schedule_rows(self):
+    @staticmethod
+    def _landing(prev):
+        """What the dispatch in flight ``prev`` (or None) will have done
+        to its sequences whatever its tokens' values: ``{seq_id:
+        (prompt positions prefilled once it lands, index of the row
+        whose token the sequence will emit, or None)}``."""
+        landing: dict[int, tuple] = {}
+        for i, (r, sid, start, n, _, is_dec) in enumerate(
+                prev.rows if prev is not None else ()):
+            if r.done or r.seq_id != sid:
+                continue
+            if is_dec:
+                landing[sid] = (r._prefilled, i)
+            else:
+                # rows of one sequence are consecutive: the last wins
+                end = start + n
+                landing[sid] = (end,
+                                i if end >= len(r.prompt_ids) else None)
+        return landing
+
+    def _schedule_rows(self, prev=None):
         """Build one mixed step's row list (caller holds the engine
         lock): every fully-prefilled live sequence gets a decode row
         (one guaranteed token plus up to ``spec_k`` speculative draft
@@ -1446,10 +1531,30 @@ class LlamaServingEngine:
         fit waits for the next step, so a
         10k-token prompt never stalls a live decode for more than one
         budget. Returns (rows, cow) where each row is
-        ``(req, sid, start, n, toks, is_decode)``."""
-        live = [r for r in self._live.values() if not r.done]
-        decode = [r for r in live if r._prefilled >= len(r.prompt_ids)]
-        prefill = [r for r in live if r._prefilled < len(r.prompt_ids)]
+        ``(req, sid, start, n, toks, is_decode)``.
+
+        ``prev`` is the dispatch in flight, whose tokens the host has
+        not seen (None: there is none). What it does to a sequence that
+        does not depend on their VALUES is counted as done: its chunk
+        rows' positions as prefilled, the token each of its decode rows
+        and final chunks will emit as emitted, so a request whose last
+        token is in flight gets no row. A decode row whose input token
+        is that token names it by where the device holds it: ``toks``
+        is ``(~i,)``, ``i`` the index of the producing row in
+        ``prev``'s returned tokens (`DispatchLayout`'s ``prev_idx``).
+        If the sequence turns out to have ended with that token (EOS, a
+        stop token; or a cancel, a deadline, an eviction lands first),
+        the row is stale: :meth:`_apply_rows` drops it."""
+        landing = self._landing(prev)
+        decode, prefill = [], []
+        for r in self._live.values():
+            if r.done:
+                continue
+            done_to, src = landing.get(r.seq_id, (r._prefilled, None))
+            if done_to < len(r.prompt_ids):
+                prefill.append(r)
+            elif src is None or len(r.output_ids) + 1 < r.max_new_tokens:
+                decode.append(r)
         decode = self._relieve_pressure(decode, 1)
         rows, cow = [], []
         budget = self.chunk_budget
@@ -1495,23 +1600,27 @@ class LlamaServingEngine:
                             break
                         drafts = drafts[:-1]
             n = 1 + len(drafts)
-            prev = self.alloc.extend(sid, n)
+            at = self.alloc.extend(sid, n)
             # copy-on-write backstop: the write position must never
             # land in a page shared with the prefix cache (positions
-            # past ``prev`` sit in the same now-private page or in
+            # past ``at`` sit in the same now-private page or in
             # pages the extend just allocated)
-            cp = self.alloc.ensure_writable(sid, prev)
+            cp = self.alloc.ensure_writable(sid, at)
             if cp is not None:
                 cow.append(cp)
-            tok = r.output_ids[-1] if r.output_ids \
-                else int(r.prompt_ids[-1])
-            rows.append((r, sid, prev, n, (tok,) + drafts, True))
+            src = landing.get(sid, (0, None))[1]
+            if src is not None:
+                tok = ~src
+            else:
+                tok = r.output_ids[-1] if r.output_ids \
+                    else int(r.prompt_ids[-1])
+            rows.append((r, sid, at, n, (tok,) + drafts, True))
             budget -= n
         for r in prefill:
             if budget <= 0 or len(rows) >= self.rows_cap \
                     or len(rows) - n_dec == self.chunk_rows:
                 break
-            off = int(r._prefilled)
+            off = int(landing.get(r.seq_id, (r._prefilled,))[0])
             n_total = len(r.prompt_ids)
             # defensive copy-on-write for the chunk's first position:
             # page-aligned prefix matches always continue into pages
@@ -1598,7 +1707,9 @@ class LlamaServingEngine:
     def _dispatch_rows(self, rows, cow):
         """Build and enqueue ONE mixed program over an already-scheduled
         row list (caller holds the dispatch locks): copy-on-write, the
-        host-built metadata, its one transfer, the enqueue. Returns
+        host-built metadata, its one transfer, the enqueue. A token the
+        plan named by its place in the last program's output (``~i``,
+        see :meth:`_schedule_rows`) goes into ``prev_idx``. Returns
         what :meth:`_apply_rows` needs and what the spans say: ``(next
         tokens still on the device, each row's first index in the T
         axis, enqueue seconds, cold, needs_mixed, t_cap, bytes handed
@@ -1683,6 +1794,9 @@ class LlamaServingEngine:
         for i, (r, sid, start, n, toks, is_dec) in enumerate(rows):
             w_starts[i], w_flats[i] = seq_first[sid]
             w_ends[i] = seq_last[sid]
+        fed = tokens < 0
+        f["prev_idx"][fed] = ~tokens[fed]
+        tokens[fed] = 0
         self._sample_arrays([row[0] for row in rows], r_cap, into=f)
         # 0 is the false side of the sample step's branch: the program
         # takes the argmax and nothing else
@@ -1707,6 +1821,7 @@ class LlamaServingEngine:
             self._warmed_keys.add(key)
         self._note_mixed_bytes(t_cap)
         self._flush_deferred()
+        self._carry = nxt       # what the next program's ``prev`` is
         self._layer_stats = stats[0] if stats else None
         return (nxt, flat_start, dur, cold, needs_mixed, t_cap,
                 buf.nbytes, sampled, tile_rows)
@@ -1715,8 +1830,23 @@ class LlamaServingEngine:
         """Apply one mixed dispatch's next tokens ``out`` (``[t_cap]``,
         on the host): prefill progress, prefix-cache pins, speculative
         verification (accept the longest exactly-matching draft prefix,
-        roll back rejected draft pages), emitted tokens. Returns tokens
-        emitted."""
+        roll back rejected draft pages), emitted tokens. Returns
+        ``(tokens emitted, stale rows)``.
+
+        A row is STALE when its sequence ended after the row was
+        launched: the request is done (EOS or a stop token in the
+        dispatch before, a cancel, a deadline) or lives on under another
+        ``seq_id`` (evicted, paused). Nothing of it is applied. What the
+        device did for it is harmless: it wrote one token's K/V (or a
+        slot's state, a ring's page) into pages and a slot the sequence
+        still held when the row was planned, and they went back to the
+        allocator with the sequence's release, the row's one planned
+        ``extend`` among them. The device runs programs in the order
+        they were enqueued and a freed page or slot is handed out only
+        by a plan that comes after the release, so a new owner's writes
+        all land after the stale one; and a new owner reads only the
+        positions it wrote itself (``kv_len``), its slot's states and
+        rings restarting where its ``starts == 0``."""
         if not cold and not needs_mixed:
             # a pure-decode dispatch is one token per live row: honest
             # per-token latency. Mixed dispatches carry prefill work
@@ -1784,13 +1914,14 @@ class LlamaServingEngine:
                 if self._spec_proposed:
                     self._m["spec_rate"].set(
                         self._spec_accepted / self._spec_proposed)
-        emitted = 0
+        emitted = stale = 0
         dec_rows = dec_tokens = 0
         # spec engines index `out` by flat token position ([T] argmax);
         # plain engines by row ([R] last-position argmax)
         by_pos = bool(self.spec_k)
         for i, (r, sid, start, n, toks, is_dec) in enumerate(rows):
             if r.done or r.seq_id != sid:
+                stale += 1
                 continue
             f = flat_start[i]
             if is_dec:
@@ -1821,7 +1952,9 @@ class LlamaServingEngine:
             self._token_times.append(per)
             for _ in range(dec_tokens):
                 self._m["tpot"].observe(per)
-        return emitted
+        if stale:
+            self._m["stale_rows"].inc(stale)
+        return emitted, stale
 
     # ------------------------------------------------------------------
     # stuck-dispatch watchdog
@@ -1855,6 +1988,15 @@ class LlamaServingEngine:
         watchdog thread). Idempotent; the engine stays usable but
         unwatched — later dispatches will NOT respawn the watchdog."""
         self._closed = True
+        # nothing stays in flight: its tokens reach their requests. The
+        # wait is bounded, so that a turn hanging in another thread (what
+        # the watchdog is there to report) cannot hang the closing one
+        if self._dispatch_lock.acquire(timeout=2.0):
+            try:
+                with contextlib.suppress(Exception):
+                    self._settle()
+            finally:
+                self._dispatch_lock.release()
         if self._wd is not None:
             self._wd.stop()
             self._wd = None
@@ -2675,89 +2817,227 @@ class LlamaServingEngine:
         # observable anyway
 
     def step(self):
-        """Advance the engine by ONE mixed dispatch: every live
-        fully-prefilled sequence decodes one token and pending prompt
-        chunks pack into the remaining ``chunk_budget``. Returns the
-        number of rows dispatched (0 = nothing live)."""
+        """Advance the engine by ONE mixed dispatch, launched and
+        finished: every live fully-prefilled sequence decodes one token
+        and pending prompt chunks pack into the remaining
+        ``chunk_budget``; when it returns, the dispatch's tokens are on
+        the requests and nothing is in flight (a dispatch
+        :meth:`step_ahead` left in flight is finished first). Returns
+        the number of rows dispatched (0 = nothing live)."""
         return self._mixed_step()[0]
 
+    def step_ahead(self):
+        """One turn of a continuous loop, ONE DISPATCH AHEAD: plan,
+        build and enqueue the next dispatch, THEN wait for the tokens
+        of the one that was in flight and apply them. The device finds
+        its next program queued when it ends the last, and the host's
+        turn runs beside the device's step and not between two of them.
+        At most one dispatch is ever in flight: its successor is planned
+        from what it will do whatever its tokens turn out to be
+        (:meth:`_schedule_rows`), and takes them on the device
+        (`DispatchLayout`'s ``prev_idx``).
+
+        Where the next plan needs the host's view of the last token the
+        turn is :meth:`step`'s (:meth:`_runs_ahead`). Returns the rows
+        it launched plus the rows it finished: 0 says that nothing is
+        live and nothing in flight. Callers that stop turning while the
+        return is not 0 leave a dispatch in flight; :meth:`step`,
+        :meth:`decode_many`, :meth:`drain` and :meth:`close` finish
+        it."""
+        return self._mixed_step(ahead=True)[0]
+
+    def _runs_ahead(self):
+        """May the next dispatch be launched before the last one's
+        tokens are on the host (caller holds the dispatch locks)? Not
+        where planning or building it reads them: a speculative engine
+        drafts from ``output_ids``, a constraint hook is handed them; a
+        host-tier engine pauses and resumes sequences by their applied
+        lengths; and a program shape's first dispatch compiles, which
+        the tokens in flight should not wait for."""
+        if self.spec_k or self.tier is not None:
+            return False
+        with self._lock:
+            live = [r for r in self._live.values() if not r.done]
+        if any(r.sampling is not None and r.sampling.constraint is not None
+               for r in live):
+            return False
+        # the shape the plan will take: chunk rows where a prompt has
+        # tokens left once the dispatch in flight lands
+        landing = self._landing(self._inflight)
+        mixed = any(landing.get(r.seq_id, (r._prefilled,))[0]
+                    < len(r.prompt_ids) for r in live)
+        return ("mixed", self.chunk_budget if mixed else self.max_batch) \
+            in self._warmed_keys
+
     @_fatal_guard("serving.step")
-    def _mixed_step(self):
-        """One mixed dispatch. Returns (rows dispatched, tokens
-        emitted) — a dispatch that only advanced mid-prompt chunks
-        reports rows > 0 with emitted == 0."""
-        # one `serving.dispatch` span a dispatch, its four phases under
-        # it sharing `step`; a turn that dispatches nothing records none
+    def _mixed_step(self, ahead=False):
+        """One mixed dispatch launched (see :meth:`_turn`); with
+        ``ahead`` false also finished, the one in flight before it.
+        Returns (rows launched and rows finished, tokens emitted) — a
+        dispatch that only advanced mid-prompt chunks reports rows > 0
+        with emitted == 0."""
+        rows = emitted = 0
+        try:
+            again = True
+            while again:
+                n, e, again = self._turn(ahead)
+                rows, emitted = rows + n, emitted + e
+        except BaseException:
+            # nothing stays in flight behind a fault: the tokens it
+            # already computed reach their requests (the plan that
+            # raised fired before it touched the allocator)
+            with contextlib.suppress(Exception):
+                self._settle()
+            raise
+        return rows, emitted
+
+    def _settle(self):
+        """Finish the dispatch in flight, if there is one."""
+        while self._inflight is not None:
+            self._turn(ahead=False, launch=False)
+
+    def _turn(self, ahead, launch=True):
+        """One turn of the engine under the dispatch locks: plan, build
+        and enqueue a dispatch (``launch``), and finish one (wait for
+        its tokens, apply them): the dispatch that was in flight, or,
+        with none in flight and ``ahead`` false, the one just launched.
+        With one in flight and no leave to run ahead of it
+        (:meth:`_runs_ahead`) the turn finishes it and launches nothing.
+        Returns ``(rows launched plus rows finished, tokens emitted,
+        whether the caller's launch is still to come)``.
+
+        One `serving.dispatch` span a turn that finishes a dispatch,
+        which says everything of THAT dispatch (``step``, its rows and
+        tokens as launched, ``ahead``: 1 where it was enqueued while its
+        predecessor was unapplied, ``dev_tokens``: input tokens it took
+        from the predecessor's output on the device, ``stale_rows``:
+        rows dropped at apply); the four phases carry the ``step`` of
+        the dispatch they work for, so a turn ahead holds the
+        ``serving.schedule`` and ``serving.build`` of dispatch n+1 and
+        then the ``serving.wait`` and ``serving.apply`` of dispatch n. A
+        turn that finishes nothing (an idle one, the first of a run
+        ahead) records no `serving.dispatch`."""
         with _span("serving.dispatch") as disp, \
                 contextlib.ExitStack() as locks:
+            rows, launched = [], None
             with _span("serving.schedule") as sched:
-                step = self._enter_dispatch(locks, disp, sched)
-                rows, cow = self._plan_rows()
+                step = self._enter_dispatch(locks, sched)
+                prev = self._inflight
+                ahead = ahead and self._runs_ahead()
+                # synchronous with one in flight: that one first
+                finish_first = prev is not None and not ahead
+                if launch and not finish_first:
+                    rows, cow = self._plan_rows(prev)
                 if not rows:
                     sched.cancel()
-                    disp.cancel()
-                    return 0, 0
-            with _span("serving.build", step=step) as build:
+            if rows:
+                launched = self._inflight = self._launch(
+                    step, rows, cow, ahead=prev is not None)
+            if prev is not None:
+                fin, self._inflight = prev, launched
+            elif launched is not None and not ahead:
+                fin, self._inflight = launched, None
+            else:
+                # an idle turn, or the first of a run ahead
+                disp.cancel()
+                return len(rows), 0, False
+            emitted = self._finish(fin, disp)
+            return (len(rows) + (len(prev.rows) if prev is not None else 0),
+                    emitted, launch and finish_first)
+
+    def _launch(self, step, rows, cow, ahead):
+        """Build and enqueue the planned ``rows`` as dispatch ``step``
+        under its `serving.build` span, start the copy of its outputs
+        to the host, and say what was launched (:class:`_Launched`)."""
+        with _span("serving.build", step=step) as build:
+            try:
                 (nxt, flat_start, dur, cold, needs_mixed, t_cap, nbytes,
                  sampled, tile_rows) = self._dispatch_rows(rows, cow)
-                # what the host handed the program: one staged buffer
-                build.set(h2d_arrays=1, h2d_bytes=nbytes)
-            with _span("serving.wait", step=step):
-                out = np.asarray(nxt._data).reshape(-1)      # [t_cap]
-                # the expert layers' counters came with the tokens
-                layer_stats = None if self._layer_stats is None \
-                    else np.asarray(self._layer_stats._data)
-            with _span("serving.apply", step=step) as applied:
-                emitted = self._apply_rows(rows, out, flat_start, dur,
-                                           cold, needs_mixed)
-                self._expire_deadlines()
-                self._set_pool_gauges()
-                applied.set(emitted=emitted)
-            kind = "mixed" if needs_mixed else "decode"
-            tokens = sum(row[3] for row in rows)
-            prefill = sum(row[3] for row in rows if not row[5])
-            # what the attention kernel walks (the pages the rows'
-            # contexts hold) against the slots of the tables it is given
-            r_cap = self.rows_cap if needs_mixed else self.max_batch
-            disp.set(rows=len(rows),
-                     decode_rows=sum(1 for row in rows if row[5]),
-                     prefill_tokens=prefill, tokens=tokens, t_cap=t_cap,
-                     kind=kind, sampled_rows=sampled,
-                     kv_pages=self._kv_pages(row[2] + row[3]
-                                             for row in rows),
-                     table_slots=r_cap * self.width)
-            if tile_rows is not None:
-                disp.set(tile_rows=tile_rows)
-            if self._slotted:
-                disp.set(**self._slot_counters(rows))
-            if layer_stats is not None:
-                # per expert layer [experts that got a row, rows of the
-                # largest group]: the medians over the layers; and the
-                # latent rows the dispatch's contexts hold
-                med = np.median(layer_stats.reshape(-1, 2), axis=0)
-                disp.set(experts_touched=float(med[0]),
-                         expert_rows_max=float(med[1]),
-                         latent_rows=sum(row[2] + row[3] for row in rows))
-            self._count_dispatch(kind, prefill, tokens - prefill,
-                                t_cap - tokens)
-            return len(rows), emitted
+            except BaseException:
+                # as if the rows had never been planned: a decode row's
+                # extend is the plan's one change to the allocator
+                with self._lock:
+                    for r, sid, _, n, _, is_dec in rows:
+                        if is_dec and sid in self.alloc._lens:
+                            self.alloc.rollback(sid, n)
+                raise
+            # what the host handed the program: one staged buffer
+            build.set(h2d_arrays=1, h2d_bytes=nbytes)
+        layer_stats = self._layer_stats
+        # the tokens (and the expert layers' counters beside them) set
+        # out for the host as soon as the program has them
+        for a in (nxt, layer_stats):
+            if a is not None:
+                a._data.copy_to_host_async()
+        tokens = sum(row[3] for row in rows)
+        prefill = sum(row[3] for row in rows if not row[5])
+        # what the attention kernel walks (the pages the rows'
+        # contexts hold) against the slots of the tables it is given
+        r_cap = self.rows_cap if needs_mixed else self.max_batch
+        said = dict(rows=len(rows),
+                    decode_rows=sum(1 for row in rows if row[5]),
+                    prefill_tokens=prefill, tokens=tokens, t_cap=t_cap,
+                    kind="mixed" if needs_mixed else "decode",
+                    sampled_rows=sampled, ahead=int(ahead),
+                    dev_tokens=sum(1 for row in rows if row[4][0] < 0),
+                    kv_pages=self._kv_pages(row[2] + row[3]
+                                            for row in rows),
+                    table_slots=r_cap * self.width)
+        if tile_rows is not None:
+            said["tile_rows"] = tile_rows
+        if self._slotted:
+            said.update(self._slot_counters(rows))
+        return _Launched(step, rows, nxt, layer_stats, flat_start, dur,
+                         cold, needs_mixed, said)
 
-    def _enter_dispatch(self, locks, disp, sched):
+    def _finish(self, fin, disp):
+        """Wait for the tokens of the launched dispatch ``fin`` and
+        apply them, under its `serving.wait` and `serving.apply` spans;
+        ``disp``, the turn's `serving.dispatch`, says what ``fin`` was.
+        Returns tokens emitted."""
+        with _span("serving.wait", step=fin.step):
+            out = np.asarray(fin.nxt._data).reshape(-1)  # [_carry_len]
+            # the expert layers' counters came with the tokens
+            layer_stats = None if fin.layer_stats is None \
+                else np.asarray(fin.layer_stats._data)
+        with _span("serving.apply", step=fin.step) as applied:
+            emitted, stale = self._apply_rows(
+                fin.rows, out, fin.flat_start, fin.dur, fin.cold,
+                fin.needs_mixed)
+            self._expire_deadlines()
+            self._set_pool_gauges()
+            applied.set(emitted=emitted)
+        said = fin.said
+        disp.set(step=fin.step, stale_rows=stale, **said)
+        if layer_stats is not None:
+            # per expert layer [experts that got a row, rows of the
+            # largest group]: the medians over the layers; and the
+            # latent rows the dispatch's contexts hold
+            med = np.median(layer_stats.reshape(-1, 2), axis=0)
+            disp.set(experts_touched=float(med[0]),
+                     expert_rows_max=float(med[1]),
+                     latent_rows=sum(row[2] + row[3] for row in fin.rows))
+        self._count_dispatch(said["kind"], said["prefill_tokens"],
+                             said["tokens"] - said["prefill_tokens"],
+                             said["t_cap"] - said["tokens"])
+        return emitted
+
+    def _enter_dispatch(self, locks, sched):
         """Take the dispatch locks (held until ``locks`` closes) under
-        the open ``serving.schedule`` span and give it and its
-        ``serving.dispatch`` the dispatch's ``step``, which it returns."""
+        the open ``serving.schedule`` span and give it the ``step`` of
+        the dispatch a plan made now would be, which it returns."""
         t_lock = time.perf_counter()
         locks.enter_context(self._entry())
         locks.enter_context(self._dispatch_lock)
         locks.enter_context(_CROSS_ENGINE_LOCK)
         step = self._dispatch_count
-        disp.set(step=step)
         sched.set(step=step, lock_wait_s=time.perf_counter() - t_lock)
         return step
 
-    def _plan_rows(self):
+    def _plan_rows(self, prev=None):
         """The scheduling half of a mixed step (dispatch locks held):
-        expire, pump the requeue, count the dispatch, schedule its rows.
+        expire, pump the requeue, count the dispatch, schedule its rows
+        (behind ``prev``, the dispatch in flight, where there is one).
         Returns ``(rows, cow)``; no rows means nothing to dispatch."""
         self._expire_deadlines()
         self._pump_requeue()
@@ -2778,7 +3058,7 @@ class LlamaServingEngine:
             # extends happen while still holding the lock, so a
             # concurrent admission can't consume the pages between
             # _relieve_pressure's proof and the extend
-            return self._schedule_rows()
+            return self._schedule_rows(prev)
 
     def _slot_counters(self, rows):
         """What a model that keeps states and windows holds after a
@@ -2912,11 +3192,20 @@ class LlamaServingEngine:
         requests that retire mid-scan (EOS / max_new_tokens / expired
         deadline) have their tail tokens discarded at emit time —
         bounded waste, no correctness impact."""
+        with self._dispatch_lock:
+            # a scan starts from the tokens the host holds: the mixed
+            # dispatch in flight is finished first, and none is
+            # launched in between
+            self._settle()
+            return self._scan(n)
+
+    def _scan(self, n):
         # the same spans as a mixed step, kind "scan"
         with _span("serving.dispatch", kind="scan") as disp, \
                 contextlib.ExitStack() as locks:
             with _span("serving.schedule") as sched:
-                step = self._enter_dispatch(locks, disp, sched)
+                step = self._enter_dispatch(locks, sched)
+                disp.set(step=step)
                 live, sids, last_tok, start_lens, cow = self._plan_scan(n)
                 if not live:
                     sched.cancel()
@@ -3181,8 +3470,9 @@ class LlamaServingEngine:
             if live:
                 if prefilling:
                     # mixed steps until every admitted prompt is in:
-                    # prefill chunks and live decodes share dispatches
-                    self.step()
+                    # prefill chunks and live decodes share dispatches,
+                    # each launched while the one before it runs
+                    self.step_ahead()
                     continue
                 # scan until the earliest possible retirement; with EOS
                 # or pending admissions cap at decode_ticks so a
@@ -3246,7 +3536,10 @@ class LlamaServingEngine:
                     for r in live:
                         self._expire(r, reason="drain grace window")
                     break
-                self.step()
+                self.step_ahead()
+            # what the grace window cut off may have left a dispatch
+            # (of stale rows) in flight
+            self._settle()
             # admission is closed, so requests parked on the requeue
             # (evicted under decode-boundary pressure) can never run
             # again — expire them typed rather than stranding them

@@ -135,7 +135,8 @@ class StaticFunction:
     """The compiled wrapper returned by ``to_static``."""
 
     def __init__(self, function, input_spec=None, state=None, donate=True,
-                 warmup="per-signature", donate_inputs=False, name=None):
+                 warmup="per-signature", donate_inputs=False, name=None,
+                 keep_args=()):
         functools.update_wrapper(self, function)
         self._fn = function
         self._input_spec = input_spec
@@ -169,6 +170,10 @@ class StaticFunction:
         # a decode loop). Only safe when the caller never reuses an input
         # after the call.
         self._donate_inputs = donate_inputs
+        # positional arguments whose arrays are NOT donated with the
+        # rest: the caller reads them after the call (an array a later
+        # call's output would otherwise be aliased into)
+        self._keep_args = frozenset(keep_args)
         self._warmup = warmup   # "per-signature" | "once"
         self._warmed_any = False
         self._cache = {}        # signature -> (jitted fn, grad slots, out box)
@@ -237,8 +242,19 @@ class StaticFunction:
                     if t.grad is not None]
         out_box = {}
         donate_state = self._donate
+        # flat indices of the inputs that are kept out of the donation
+        kept_idx, at = [], 0
+        for i, arg in enumerate(in_treedef.children()[0].children()):
+            if i in self._keep_args:
+                kept_idx += range(at, at + arg.num_leaves)
+            at += arg.num_leaves
+        out_box["kept"] = kept_idx
 
-        def pure_step(state, grads, in_arrays, lrs, key):
+        def pure_step(state, grads, in_arrays, lrs, key, kept=()):
+            if kept:
+                in_arrays = list(in_arrays)
+                for i, a in zip(kept_idx, kept):
+                    in_arrays[i] = a
             saved = [(t._data, t.grad, t._node) for t in state_tensors]
             overrides = [o._lr_override for o in optimizers]
             try:
@@ -325,6 +341,13 @@ class StaticFunction:
                for o in self._optimizers]
         key = frandom.next_key()
         step_args = (state, grads, in_arrays, lrs, key)
+        if out_box["kept"]:
+            # the kept arrays ride as an argument of their own, which
+            # is not donated; None holds their place among the rest
+            kept = [in_arrays[i] for i in out_box["kept"]]
+            donated = [None if i in out_box["kept"] else a
+                       for i, a in enumerate(in_arrays)]
+            step_args = (state, grads, donated, lrs, key, kept)
         if self._donate_inputs:
             # some inputs (e.g. prefill tokens) have no same-shaped output
             # to alias — the resulting JAX warning is expected, not a bug

@@ -1,6 +1,8 @@
 """One dispatch's host-built metadata rides to the device as ONE flat
 int32 buffer (ISSUE 31): `DispatchLayout` says where each of the 18
-fields of ``_mixed_forward``'s argument list lies in it. What the host
+fields of ``_mixed_forward``'s argument list lies in it, and since
+ISSUE 38 ``prev_idx`` (per packed token: -1, or where the token lies in
+the last dispatch's output on the device). What the host
 writes into the views comes back from the device-side unpack with its
 shape, its dtype and, for the float fields, its bits."""
 
@@ -12,7 +14,7 @@ from paddle_tpu.inference.layer_step import DispatchLayout
 
 FIELDS = ("tokens pos flat_idx last_idx tables "
           "kv_lens q_starts q_lens w_starts w_flats w_ends temps top_ps "
-          "top_ks seeds slot_ids slot_vals cmodes").split()
+          "top_ks seeds slot_ids slot_vals cmodes prev_idx").split()
 FLOATS = ("temps", "top_ps", "slot_vals")
 SLOTS, TRASH = 8, 4096
 # the serving cells' two program shapes (t_cap, r_cap, qb): a chunk
@@ -33,6 +35,7 @@ def _want(lay):
         "last_idx kv_lens q_starts q_lens w_starts w_flats w_ends top_ks "
         "seeds cmodes").split()}
     return dict(row, tokens=((1, t), i32, 0), pos=((1, t), i32, 0),
+                prev_idx=((1, t), i32, -1),
                 flat_idx=((t,), i32, r * qb - 1),
                 tables=((r, w), i32, TRASH), temps=((r,), f32, 0.0),
                 top_ps=((r,), f32, 1.0), slot_ids=((r, b), i32, -1),
@@ -73,7 +76,7 @@ def test_offsets_do_not_overlap_and_cover_the_buffer(shape, width):
         at = stop
     assert at == lay.size and lay.nbytes == 4 * at
     t, r, qb, w, b = lay.shape
-    assert lay.size == 3 * t + r * (w + 12 + 2 * b)
+    assert lay.size == 4 * t + r * (w + 12 + 2 * b)
     # every word of the buffer belongs to exactly one view
     buf = lay.new()
     owner = np.zeros(lay.size, np.int32)
@@ -94,7 +97,7 @@ def test_host_pack_device_unpack_gives_every_field_back(shape, width):
     wrote = _random_fill(lay, rng, buf)
     assert buf.dtype == np.int32 and buf.shape == (lay.size,)
     got = jax.jit(lay.unpack)(jax.numpy.asarray(buf))
-    assert len(got) == len(FIELDS) == 18
+    assert len(got) == len(FIELDS) == 19
     for name, a in zip(FIELDS, got):
         want_shape, want_dtype, _ = _want(lay)[name]
         a = np.asarray(a)

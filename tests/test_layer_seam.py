@@ -14,6 +14,7 @@ from paddle_tpu.inference.layer_step import _token_gather
 from paddle_tpu.inference.serving import LlamaServingEngine
 from paddle_tpu.models.llama import (LlamaConfig, LlamaDecoderLayer,
                                      LlamaForCausalLM)
+from paddle_tpu.observability import trace as otrace
 from paddle_tpu.ops.ragged_paged_attention import \
     fused_ragged_paged_attention
 
@@ -78,10 +79,16 @@ def _run(model, **kw):
     e = LlamaServingEngine(model, max_batch=4, page_size=8, num_pages=41,
                            max_pages_per_seq=8, chunk_budget=32,
                            chunk_block=8, **kw)
+    e.prewarm(mixed=[e.chunk_budget, e.max_batch])
+    otrace.clear()
     out = e.generate(prompts, max_new_tokens=6)
     state = [np.asarray(p._data) for pools in (
         e.k_pools, e.v_pools, e.k_scales, e.v_scales) for p in pools]
     e.close()
+    # `generate` prefills one dispatch ahead (ISSUE 38): both sides of
+    # the comparison took tokens on the device through ``prev_idx``
+    assert sum(ev["args"].get("dev_tokens", 0) for ev in otrace.get_events()
+               if ev["name"] == "serving.dispatch") > 0
     return out, state
 
 

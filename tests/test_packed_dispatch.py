@@ -454,6 +454,8 @@ def test_unused_slots_of_every_dispatch_read_their_fill(llama):
             assert (f[name][rows:] == 0).all(), name
         assert (f["top_ps"][rows:] == 1.0).all()
         assert (f["slot_ids"][rows:] == -1).all()
+        # a loop of step() writes every token on the host
+        assert (f["prev_idx"] == -1).all()
     # both program shapes, and dispatches of few rows after many
     assert {t for t, _ in seen} == {16, 4}
     assert len({r for _, r in seen}) >= 3
@@ -481,3 +483,68 @@ def test_warm_dispatch_goes_through_the_layout(llama):
     # the prewarmed programs are the ones traffic runs: no third compile
     assert len(e._mixed_static._cache) == 2 and len(seen) == 2 + 3
     e.close()
+
+
+def test_a_token_the_device_holds_rides_as_its_index(llama):
+    """ISSUE 38's field, ``prev_idx``: the same requests through a loop
+    one dispatch ahead and through a loop of ``step()`` plan the same
+    rows dispatch by dispatch, and their buffers are equal bitwise field
+    by field, but for a decode row's input token: the ahead loop built
+    the buffer before that token reached the host, so ``tokens`` holds 0
+    there and ``prev_idx`` the row of the dispatch before that computes
+    it (the same sequence's row: same pages), where the step loop wrote
+    the token itself and -1. The tokens served are the same."""
+    kw = dict(max_batch=4, page_size=8, num_pages=65, max_pages_per_seq=16,
+              chunk_budget=16, chunk_block=8, prefix_cache=False)
+    rng = np.random.RandomState(5)
+    prompts = [rng.randint(0, 256, (n,)).tolist() for n in (30, 5, 19, 12)]
+
+    def run(ahead):
+        e = LlamaServingEngine(llama, **kw)
+        e.prewarm(mixed=[16, 4])
+        bufs, run_of = [], e._run_mixed
+        e._run_mixed = lambda buf: (bufs.append(buf.copy()),
+                                    run_of(buf))[1]
+        reqs = [Request(p, max_new_tokens=3 + i)
+                for i, p in enumerate(prompts)]
+        for r in reqs:
+            e._admit(r)
+        while any(not r.done for r in reqs):
+            (e.step_ahead if ahead else e.step)()
+        layouts = {lay.size: lay for lay in e._layouts.values()}
+        assert e._inflight is None
+        e.close()
+        return [layouts[b.size].views(b) for b in bufs], \
+            [list(r.output_ids) for r in reqs], layouts
+
+    got, outs, layouts = run(True)
+    want, outs_step, _ = run(False)
+    assert outs == outs_step and len(got) == len(want) > 6
+    fed_total = 0
+    for k, (a, b) in enumerate(zip(got, want)):
+        assert a.keys() == b.keys()
+        for name in a:
+            if name not in ("tokens", "prev_idx"):
+                assert np.array_equal(a[name], b[name]), (k, name)
+        assert (b["prev_idx"] == -1).all()
+        fed = a["prev_idx"] >= 0
+        assert np.array_equal(a["tokens"][~fed], b["tokens"][~fed])
+        assert (a["tokens"][fed] == 0).all()
+        # exactly the decode rows (a row of one token past its prompt's
+        # end... every one-token row that writes past position 0 here)
+        qb = next(lay for lay in layouts.values()
+                  if lay.shape[0] == a["tokens"].shape[1]).shape[2]
+        for t in np.flatnonzero(fed[0]):
+            row, src = a["flat_idx"][t] // qb, a["prev_idx"][0, t]
+            assert a["q_lens"][row] == 1 and k > 0
+            before = got[k - 1]
+            assert before["q_lens"][src] > 0
+            # the same sequence: the producing row holds the same pages
+            held = -(-int(before["kv_lens"][src]) // 8)
+            assert np.array_equal(before["tables"][src, :held],
+                                  a["tables"][row, :held])
+            assert before["kv_lens"][src] == a["q_starts"][row]
+        fed_total += int(fed.sum())
+    # every decode row but none: each request's decode rows all follow
+    # the dispatch that computed their token
+    assert fed_total == sum(len(o) - 1 for o in outs)

@@ -1,6 +1,10 @@
 """The serving loop's spans and counters (ISSUE 26): every dispatch is one
-``serving.dispatch`` with ``schedule``, ``build``, ``wait`` and ``apply``
-under it sharing a ``step``; every retired request writes one
+``serving.dispatch`` and a ``schedule``, ``build``, ``wait`` and
+``apply`` sharing its ``step`` (under it where the dispatch is launched
+and finished in one turn; since ISSUE 38, in a loop that runs one
+dispatch ahead, the turn's span holds the ``schedule`` and ``build`` of
+the NEXT dispatch before this one's ``wait`` and ``apply``); every
+retired request writes one
 ``serving.request`` with its five stamps in order; what the spans count
 is what the counters count and what the requests received."""
 
@@ -98,16 +102,20 @@ def test_every_dispatch_has_its_four_phases_under_one_step(served):
     assert len(disp) >= 8
     steps = [d["args"]["step"] for d in disp]
     assert len(set(steps)) == len(steps) and steps == sorted(steps)
+    kids_of = {}
     for d in disp:
-        kids = sorted((e for e in events if e["name"] in PHASES
-                       and e["args"]["step"] == d["args"]["step"]),
-                      key=lambda e: e["ts"])
+        kids = kids_of[d["args"]["step"]] = sorted(
+            (e for e in events if e["name"] in PHASES
+             and e["args"]["step"] == d["args"]["step"]),
+            key=lambda e: e["ts"])
         assert [k["name"] for k in kids] == list(PHASES)
-        # inside the parent, in order, never overlapping
-        at = d["ts"]
+        # in order, never overlapping; the span is the turn that
+        # FINISHED the dispatch: its wait and apply lie inside it
+        at = kids[0]["ts"]
         for k in kids:
             assert k["ts"] >= at - 1e-3
             at = k["ts"] + k["dur"]
+        assert kids[2]["ts"] >= d["ts"] - 1e-3
         assert at <= d["ts"] + d["dur"] + 1e-3
         assert kids[0]["args"]["lock_wait_s"] >= 0
         assert d["args"]["kind"] in ("mixed", "decode")
@@ -115,6 +123,24 @@ def test_every_dispatch_has_its_four_phases_under_one_step(served):
                                       else 4)
         assert 0 < d["args"]["tokens"] <= d["args"]["t_cap"]
         assert d["args"]["decode_rows"] <= d["args"]["rows"]
+    for d, nxt in zip(disp, disp[1:]):
+        # a dispatch enqueued while its predecessor was unapplied was
+        # planned and built in the turn that finished the predecessor,
+        # BEFORE that turn waited: the device had it queued
+        if nxt["args"]["ahead"]:
+            assert nxt["args"]["step"] == d["args"]["step"] + 1
+            sched, build = kids_of[nxt["args"]["step"]][:2]
+            wait = kids_of[d["args"]["step"]][2]
+            assert d["ts"] - 1e-3 <= sched["ts"]
+            assert build["ts"] + build["dur"] <= wait["ts"] + 1e-3
+    for d in disp:
+        if not d["args"]["ahead"]:
+            # launched with nothing in flight: in its own turn (all
+            # four phases under its span) or as the first of a run
+            # ahead (planned and built the turn before)
+            sched = kids_of[d["args"]["step"]][0]
+            first_of_run = sched["ts"] < d["ts"] - 1e-3
+            assert first_of_run or d["args"]["dev_tokens"] == 0
     # the pre-existing span stays, inside the build
     inner = _by(events, "serving.mixed_step")
     builds = _by(events, "serving.build")
@@ -143,6 +169,25 @@ def test_every_build_says_what_it_handed_the_device(served, model):
         assert b["args"]["h2d_bytes"] == nbytes[kind_of[b["args"]["step"]]]
     assert {kind_of[b["args"]["step"]] for b in builds} \
         == {"mixed", "decode"}
+
+
+def test_ahead_counters_follow_the_requests_own_parameters(served):
+    """``ahead``, ``dev_tokens`` and ``stale_rows`` of the cluster's
+    loop: every request asks for NEW tokens and names no EOS, so no row
+    is ever stale; a request's NEW - 1 decode rows each took their input
+    token on the device exactly where their dispatch was enqueued behind
+    the one that computed it; and the loop did run ahead once both
+    program shapes were warm."""
+    disp = [d["args"] for d in _by(served[0], "serving.dispatch")]
+    assert all(d["stale_rows"] == 0 for d in disp)
+    assert _value("serving_dispatch_stale_rows_total") == 0
+    assert sum(d["decode_rows"] for d in disp) \
+        == (NEW - 1) * (len(PROMPTS) + 1)
+    for d in disp:
+        assert d["ahead"] in (0, 1)
+        assert d["dev_tokens"] == d["ahead"] * d["decode_rows"]
+    assert sum(d["ahead"] for d in disp) >= 4
+    assert sum(d["dev_tokens"] for d in disp) > 0
 
 
 def test_replica_tick_wraps_each_dispatch_and_idle_turns_are_dark(served):
@@ -481,7 +526,7 @@ def test_dispatch_counts_slots_windows_and_the_shared_pool():
     ``shared_kv_pages`` its pages in use, ``window_pages`` what its
     lengths and the window give for every window layer, and
     ``window_pages_freed`` sums to the pages that fell behind the
-    windows; the build hands over one buffer with a 19th field."""
+    windows; the build hands over one buffer with a 20th field."""
     from paddle_tpu.models import SambaYForCausalLM, tiny_sambay_config
 
     paddle.seed(0)
@@ -534,7 +579,7 @@ def test_a_model_without_states_sets_no_slot_counters(served, model):
         assert not {"state_slots", "window_pages", "shared_kv_pages",
                     "window_pages_freed"} & set(d["args"])
     layout = _engine(model)._dispatch_layout(16)
-    assert [f[0] for f in layout.fields][-1] == "cmodes"
+    assert [f[0] for f in layout.fields][-1] == "prev_idx"
 
 
 # ---------------------------------------------------------------------------
@@ -610,11 +655,12 @@ def test_dispatch_says_how_many_rows_take_the_small_tile(geometry):
     engine.close()
 
 
-#: the fields of a dispatch's one buffer, as they were before ISSUE 36
+#: the fields of a dispatch's one buffer: as they were before ISSUE 36,
+#: and ``prev_idx`` (ISSUE 38: where a token the device holds lies)
 LAYOUT_FIELDS = ["tokens", "pos", "flat_idx", "last_idx", "tables",
                  "kv_lens", "q_starts", "q_lens", "w_starts", "w_flats",
                  "w_ends", "temps", "top_ps", "top_ks", "seeds",
-                 "slot_ids", "slot_vals", "cmodes"]
+                 "slot_ids", "slot_vals", "cmodes", "prev_idx"]
 
 
 @pytest.mark.parametrize("geometry", GEOMETRIES)
@@ -657,3 +703,49 @@ def test_the_small_tile_adds_no_compiled_program(geometry, tmp_path,
     assert len(other._mixed_static._cache) == 2
     assert len(made) == 2 * variants
     other.close()
+
+
+@pytest.mark.parametrize("geometry", GEOMETRIES)
+def test_running_ahead_adds_no_compiled_program(geometry):
+    """ISSUE 38: the token array one mixed program hands the next has
+    ONE shape whichever of the two program shapes wrote it, so an engine
+    that served both shapes one dispatch ahead (each shape behind the
+    other, tokens taken on the device both ways) holds two compiled
+    mixed programs, two layouts and its two warm-up dispatches; what
+    `serving.build` says it handed over is that layout's bytes, the new
+    field among them."""
+    om.default_registry().clear()
+    m, _ = _geometry(geometry)
+    engine = _engine(m, chunk_block=8)
+    engine.prewarm(mixed=[16, 4])
+    reqs = [Request(list(range(1, n + 1)), max_new_tokens=12)
+            for n in (40, 5, 23)]
+    otrace.clear()
+    for r in reqs[:2]:
+        engine._admit(r)
+    turns = 0
+    while any(not r.done for r in reqs):
+        engine.step_ahead()
+        turns += 1
+        if turns == 9:      # decode-only dispatches, then chunks again
+            engine._admit(reqs[2])
+        assert turns < 200
+    events = otrace.get_events()
+    disp = [d["args"] for d in _by(events, "serving.dispatch")]
+    ahead = [d["kind"] for d in disp if d["ahead"] and d["dev_tokens"]]
+    assert {"mixed", "decode"} <= set(ahead)
+    kinds = [d["kind"] for d in disp]
+    assert any(a != b for a, b in zip(kinds, kinds[1:]))
+    assert sorted(engine._layouts) == [4, 16]
+    assert len(engine._mixed_static._cache) == 2
+    assert engine._warm_dispatches == 2
+    assert engine._carry._data.shape == (engine.rows_cap,)
+    kind_of = {d["step"]: d["kind"] for d in disp}
+    for b in _by(events, "serving.build"):
+        lay = engine._dispatch_layout(
+            16 if kind_of[b["args"]["step"]] == "mixed" else 4)
+        assert b["args"]["h2d_bytes"] == lay.nbytes == 4 * sum(
+            end - at for _, at, end, _, _ in lay.fields)
+        assert [f[0] for f in lay.fields] \
+            == LAYOUT_FIELDS + ["slots"] * (geometry == "hybrid")
+    engine.close()
