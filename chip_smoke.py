@@ -15,6 +15,9 @@ calls, at published widths, on one TPU:
   docqa-closed's two programs behind tables 161, 521 and 1,057 pages
   wide, held to ``ragged_mla_attention``'s XLA formulation and to the
   same two rules;
+- ``flash``: the three training flash-attention kernels alone, by name,
+  at pretrain-4k's attention shape: ms a call each, and at S = 1,024 the
+  output and gradients against the XLA path;
 - ``serve``: Llama-3-8B widths (hidden 4096, FFN 14336, 32q/8kv, vocab
   128256), depth cut to 8 layers, seeded bf16 weights;
   ``LlamaServingEngine`` with its default (rope-fused) program answers
@@ -622,6 +625,83 @@ def phase_kda(seed, rehearse, on_chip):
                      chunk_rows=long_rows, chunk=q), timings=timings)
 
 
+# the flash kernels' bf16 results against the f32 XLA path, relative to
+# its largest magnitude (the bf16 XLA path itself reads ~4e-3)
+FLASH_RTOL = 2e-2
+# bf16 MXU peak of one v5e (perfbench/harness/peaks.py)
+V5E_BF16_FLOPS = 197e12
+
+
+def phase_flash(seed, rehearse, on_chip):
+    """The three training flash kernels alone, called by name at
+    pretrain-4k's attention shape (2 x 4,096 tokens, 16 query heads over
+    8 kv heads of 128, bf16, causal): ms a call each, beside the least
+    time of the dots each executes at the bf16 peak. Then, at S = 1,024,
+    the forward's output and the three gradients against the XLA path
+    computed in f32 from the same bf16 inputs (and the bf16 XLA path's
+    own error beside them)."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.nn.functional.attention import _naive_attention
+    from paddle_tpu.ops import flash_attention as fa
+
+    if rehearse:
+        b, s, h, hk, d, check_s, reps = 1, 256, 4, 2, 128, 256, 1
+    else:
+        b, s, h, hk, d, check_s, reps = 2, 4096, 16, 8, 128, 1024, 10
+    group, scale, bf16 = h // hk, d ** -0.5, jnp.bfloat16
+    keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+
+    def normal(key, *shape):
+        return jax.random.normal(key, shape, jnp.float32).astype(bf16)
+
+    # head-major, as the kernels take them
+    q, do = normal(keys[0], b, h, s, d), normal(keys[3], b, h, s, d)
+    k, v = normal(keys[1], b, hk, s, d), normal(keys[2], b, hk, s, d)
+    fwd = jax.jit(lambda q, k, v: fa._fwd(q, k, v, scale, True, group))
+    (out, lse), fwd_ms = best_ms(fwd, (q, k, v), reps)
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1, keepdims=True)
+    args = (q, k, v, do, lse, delta, scale, True, group)
+    _, dq_ms = best_ms(jax.jit(fa._bwd_dq, static_argnums=(6, 7, 8)),
+                       args, reps)
+    _, dkv_ms = best_ms(jax.jit(fa._bwd_dkv, static_argnums=(6, 7, 8)),
+                        args, reps)
+    # a dot over every causal (query, key) pair: 2 FLOPs x head_dim
+    dot_ms = 1e3 * 2 * d * b * h * s * (s + 1) / 2 / V5E_BF16_FLOPS
+    timings = {"flash_fwd_ms": fwd_ms, "flash_dq_ms": dq_ms,
+               "flash_dkdv_ms": dkv_ms, "fwd_dots_floor_ms": 2 * dot_ms,
+               "dq_dots_floor_ms": 3 * dot_ms,
+               "dkdv_dots_floor_ms": 4 * dot_ms}
+
+    # parity at S = check_s, [B, S, H, D] as a model calls it
+    q, do = (normal(keys[i], 1, check_s, h, d) for i in (0, 3))
+    k, v = (normal(keys[i], 1, check_s, hk, d) for i in (1, 2))
+
+    def xla(q, k, v):
+        return _naive_attention(q, k, v, None, 0.0, True, None)
+
+    f32 = lambda a: a.astype(jnp.float32)        # noqa: E731
+    with jax.default_matmul_precision("highest"):
+        ref, ref_vjp = jax.vjp(xla, f32(q), f32(k), f32(v))
+        ref = (ref,) + ref_vjp(f32(do))
+    flash = fa._make_flash(scale, True, group)
+    for name, fn in (("kernel", flash), ("xla_bf16", xla)):
+        got, vjp = jax.vjp(fn, q, k, v)
+        for part, a, r in zip(("out", "dq", "dk", "dv"),
+                              (got,) + vjp(do), ref):
+            timings[f"{name}_err.{part}"] = float(
+                jnp.max(jnp.abs(f32(a) - r)) / jnp.max(jnp.abs(r)))
+    worst = max(timings[f"kernel_err.{p}"] for p in ("out", "dq", "dk", "dv"))
+    if not worst <= FLASH_RTOL:
+        raise RuntimeError(f"flash kernels and the f32 XLA path differ by "
+                           f"{worst} of the reference's largest magnitude")
+    emit(phase="flash", device=device_info(),
+         shapes=dict(batch=b, seq=s, heads=h, kv_heads=hk, head_dim=d,
+                     dtype="bfloat16", causal=True, check_seq=check_s),
+         timings=timings)
+
+
 # ---------------------------------------------------------------------------
 # train
 # ---------------------------------------------------------------------------
@@ -838,8 +918,8 @@ def main():
                          "never prints the success line, exits "
                          f"{REHEARSAL_EXIT}")
     ap.add_argument("--phase", default="all",
-                    choices=("all", "walk", "latent", "kda", "serve",
-                             "train"),
+                    choices=("all", "walk", "latent", "kda", "flash",
+                             "serve", "train"),
                     help="one chip: run only this phase")
     args = ap.parse_args()
 
@@ -858,8 +938,8 @@ def main():
         phase_mesh(args.seed, args.rehearse, on_chip)
     else:
         for name, phase in (("walk", phase_walk), ("latent", phase_latent),
-                            ("kda", phase_kda), ("serve", phase_serve),
-                            ("train", phase_train)):
+                            ("kda", phase_kda), ("flash", phase_flash),
+                            ("serve", phase_serve), ("train", phase_train)):
             if args.phase in ("all", name):
                 phase(args.seed, args.rehearse, on_chip)
                 gc.collect()    # a phase's weights leave before the next

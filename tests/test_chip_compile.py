@@ -225,12 +225,34 @@ def test_flash_fwd_bwd_compiles_bf16(chip_compile, b, s, h, hk):
                    *FLASH_KERNELS)
 
 
+def test_flash_compiles_at_the_training_cell(chip_compile):
+    """pretrain-4k's attention (2 x 4096, 16 query heads over 8 kv heads
+    of 128, bf16): the kernels compile, and each returns the bf16
+    ``[batch, heads, seq, head_dim]`` result the benchmark's
+    ``flash_roofline.train`` finds it by."""
+    compiled = chip_compile(_flash_grad(2), *_flash_specs(2, 4096, 16, 8,
+                                                          BF16))
+    _assert_kernel(compiled, *FLASH_KERNELS)
+    text = compiled.as_text()
+    for name, heads in (("flash_fwd", 16), ("flash_dq", 16),
+                        ("flash_dkdv", 8)):
+        line = next(ln for ln in text.splitlines()
+                    if "paddle_tpu." + name in ln.split("=")[0]
+                    and "tpu_custom_call" in ln)
+        assert re.search(r"= \(?bf16\[2,%d,4096,128\]" % heads, line), line
+
+
 def test_flash_supported_agrees_with_compiler(chip_compile):
     """Whatever ``supported()`` accepts the compiler accepts; the walk
-    must really reach Llama-3-8B's own context in bf16."""
+    must really reach Llama-3-8B's own context in bf16. The largest
+    lengths it accepts (K and V of a kv head at the budget: 12,288 in
+    bf16, 6,144 in f32) also walk the dK/dV kernel's query rows in
+    windows, a whole head's not fitting its budget."""
     walk = [(1, 8192, 16, 8, BF16), (1, 8192, 32, 8, BF16),
             (2, 2048, 32, 8, F32), (1, 4096, 32, 8, F32),
-            (1, 8192, 32, 8, F32), (1, 16384, 32, 8, BF16)]
+            (1, 8192, 32, 8, F32), (1, 16384, 32, 8, BF16),
+            (1, 12288, 32, 8, BF16), (1, 12416, 32, 8, BF16),
+            (1, 6144, 32, 8, F32)]
     accepted = []
     for b, s, h, hk, dtype in walk:
         specs = _flash_specs(b, s, h, hk, dtype)
@@ -241,6 +263,11 @@ def test_flash_supported_agrees_with_compiler(chip_compile):
                            *FLASH_KERNELS)
     assert (1, 8192, 32, 8, BF16) in accepted
     assert (1, 8192, 16, 8, BF16) in accepted
+    assert (1, 12288, 32, 8, BF16) in accepted
+    assert (1, 6144, 32, 8, F32) in accepted
+    assert (1, 12416, 32, 8, BF16) not in accepted
+    assert fa._dkv_window(12288, D, 2, 128) < 12288
+    assert fa._dkv_window(6144, D, 4, 128) < 6144
 
 
 def test_flash_under_a_mesh_compiles_for_2x2(topo, no_persistent_cache,

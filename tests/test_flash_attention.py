@@ -5,6 +5,8 @@ numerics must match the naive composition (interpret mode on CPU; the
 real-chip speed check lives in bench.py).
 """
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -113,6 +115,79 @@ def test_sdpa_dispatches_to_pallas_and_matches():
     paddle.set_flags({"use_pallas_kernels": True})
     np.testing.assert_allclose(out_pallas.numpy(), out_naive.numpy(),
                                rtol=2e-4, atol=2e-4)
+
+
+# ---------------------------------------------------------------------------
+# bf16 inputs: the kernels feed the MXU 16-bit operands (P and dS cast to
+# q's dtype) and accumulate in f32; held to the f32 composition of the same
+# bf16 values, relative to its largest magnitude (measured 2e-3 - 4.5e-3)
+# ---------------------------------------------------------------------------
+BF16_RTOL = 1.5e-2
+
+
+def _reference(q, k, v, causal, scale):
+    """f32 attention, GQA by repetition, causal bottom-right aligned."""
+    sq, h, sk, hk = q.shape[1], q.shape[2], k.shape[1], k.shape[2]
+    k, v = (jnp.repeat(a, h // hk, axis=2) for a in (k, v))
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision="highest") * scale
+    if causal:
+        s = jnp.where(np.arange(sk)[None, :] <= np.arange(sq)[:, None]
+                      + sk - sq, s, -jnp.inf)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v, precision="highest")
+
+
+def _bf16_case(sq, sk, h, hk, causal, d=64, seed=0, rows_with_grad=None):
+    """(kernel's out, dq, dk, dv; the f32 reference's) on bf16 inputs."""
+    r = np.random.RandomState(seed)
+    bf = lambda *s: jnp.asarray(r.randn(*s), jnp.bfloat16)  # noqa: E731
+    q, k, v, g = bf(1, sq, h, d), bf(1, sk, hk, d), bf(1, sk, hk, d), \
+        bf(1, sq, h, d)
+    if rows_with_grad is not None:
+        g = g.at[:, rows_with_grad:].set(0)
+    scale = d ** -0.5
+    out, vjp = jax.vjp(fa._make_flash(scale, causal, h // hk), q, k, v)
+    f32 = lambda a: a.astype(jnp.float32)                    # noqa: E731
+    ref, ref_vjp = jax.vjp(lambda *a: _reference(*a, causal, scale),
+                           f32(q), f32(k), f32(v))
+    return (out,) + vjp(g), (ref,) + ref_vjp(f32(g))
+
+
+@pytest.mark.parametrize("sq,sk,h,hk,causal", [
+    (s, s, h, hk, c) for s in (128, 384, 1024)
+    for h, hk in ((2, 2), (4, 2), (4, 1)) for c in (True, False)] + [
+    (128, 384, 4, 2, True), (256, 1024, 4, 1, True),
+    (512, 640, 4, 2, True)],
+    ids=lambda a: str(a))
+def test_bf16_fwd_bwd_match_f32_reference(sq, sk, h, hk, causal):
+    """S = 128 takes 128-wide blocks everywhere, 384 too (256 and 512 do
+    not divide it), 1024 the widest; ``sq < sk`` meets the diagonal at a
+    non-zero offset, with query blocks narrower than the key blocks (256
+    over 512: unmasked steps of 256 after the wide blocks) and wider
+    (512 over 128: rows that see nothing of a masked step)."""
+    got, want = _bf16_case(sq, sk, h, hk, causal)
+    for name, a, w in zip(("out", "dq", "dk", "dv"), got, want):
+        assert a.dtype == jnp.bfloat16, name
+        err = float(jnp.max(jnp.abs(a.astype(jnp.float32) - w)))
+        assert err <= BF16_RTOL * float(jnp.max(jnp.abs(w))), (name, err)
+
+
+@pytest.mark.parametrize("window", [None, 128], ids=["whole", "windows"])
+def test_dkdv_zero_for_a_key_block_no_query_sees(window, monkeypatch):
+    """Causal, S = 384: query rows 256-383 carry no gradient, and no row
+    before them sees keys 256-383, so that key block's dK and dV are
+    exactly zero; the blocks before it are not. ``windows`` walks each
+    query head in windows of 128 rows (what a head too long for VMEM
+    takes), so that dead windows are skipped too."""
+    if window:
+        monkeypatch.setattr(fa, "_dkv_window", lambda *a: window)
+    got, want = _bf16_case(384, 384, 4, 2, True, rows_with_grad=256)
+    dk, dv = got[2], got[3]
+    assert not jnp.any(dk[:, 256:]) and not jnp.any(dv[:, 256:])
+    assert jnp.all(jnp.any(dk[:, :256] != 0, axis=-1))
+    for a, w in zip(got, want):
+        err = float(jnp.max(jnp.abs(a.astype(jnp.float32) - w)))
+        assert err <= BF16_RTOL * float(jnp.max(jnp.abs(w)))
 
 
 def test_mesh_attention_matches_one_device_fwd_and_bwd():
