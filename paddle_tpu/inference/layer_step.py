@@ -213,12 +213,15 @@ class ServingStep:
             self._tables[key] = run_op(name, fn, args, differentiable=False)
         return self._tables[key]
 
-    def rope(self, head_dim, base):
+    def rope(self, head_dim, base, yarn=None):
         """Rotary sin/cos ``[T, D]`` f32, one row a packed token, in the
         duplicated-half layout: bitwise the values
-        `fused_rotary_position_embedding` derives from ``position_ids``."""
-        return self._shared(("neox", head_dim, base), "serving_rope_tables",
-                            lambda p: rope_tables(p, head_dim, base),
+        `fused_rotary_position_embedding` derives from ``position_ids``;
+        with ``yarn`` (a tuple, `rope_tables`) YaRN's. One pair a rotary
+        kind a step, whichever layers ask."""
+        return self._shared(("neox", head_dim, base, yarn),
+                            "serving_rope_tables",
+                            lambda p: rope_tables(p, head_dim, base, yarn),
                             self.pos)
 
     def rope_interleaved(self, dim, base):

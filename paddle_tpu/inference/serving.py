@@ -3091,10 +3091,28 @@ class LlamaServingEngine:
         # (no prefix cache holds pages of such a model)
         shared = self.alloc.num_pages - self.alloc.free_pages
         # every row reads its slot's states and writes them back
-        return dict(state_slots=self.alloc.slots_held,
+        said = dict(state_slots=self.alloc.slots_held,
                     shared_kv_pages=shared, window_pages=held,
                     window_pages_freed=freed,
                     state_bytes=2 * len(rows) * self._slot_bytes)
+        if self._windows:
+            said.update(self._keys_read(rows))
+        return said
+
+    def _keys_read(self, rows):
+        """What ONE layer of each kind reads for the dispatch's rows: a
+        row of ``n`` query tokens ending at context ``c`` reads ``c``
+        keys in a layer that keeps the whole context and ``min(c, w - 1
+        + n)`` in one that keeps a window ``w`` (what its first query
+        sees on). ``*_reads`` sums the keys, ``*_keys`` the (query, key)
+        pairs of a row's whole query block against them."""
+        ends = [(start + n, n) for _, _, start, n, _, _ in rows]
+        w = min(self._windows)
+        win = [(min(c, w - 1 + n), n) for c, n in ends]
+        return dict(full_reads=sum(c for c, _ in ends),
+                    full_keys=sum(c * n for c, n in ends),
+                    window_reads=sum(c for c, _ in win),
+                    window_keys=sum(c * n for c, n in win))
 
     def _kv_pages(self, kv_lens):
         """Pages that hold contexts of these lengths, summed."""

@@ -22,6 +22,10 @@ from .kimi_linear import (  # noqa: F401
     KimiLinearConfig, KdaAttention, KimiLinearDecoderLayer, KimiLinearModel,
     KimiLinearForCausalLM, tiny_kimi_linear_config,
 )
+from .mellum import (  # noqa: F401
+    MellumConfig, MellumAttention, MellumDecoderLayer, MellumModel,
+    MellumForCausalLM, tiny_mellum_config,
+)
 from .llama_pipe import LlamaForCausalLMPipe  # noqa: F401
 from .bert import (  # noqa: F401
     BertConfig, BertModel, BertForSequenceClassification,
@@ -40,6 +44,8 @@ __all__ = [
     "SambaYForCausalLM", "tiny_sambay_config",
     "KimiLinearConfig", "KdaAttention", "KimiLinearDecoderLayer",
     "KimiLinearModel", "KimiLinearForCausalLM", "tiny_kimi_linear_config",
+    "MellumConfig", "MellumAttention", "MellumDecoderLayer", "MellumModel",
+    "MellumForCausalLM", "tiny_mellum_config",
     "BertConfig", "BertModel", "BertForSequenceClassification",
     "BertForTokenClassification", "ErnieModel",
     "ErnieForSequenceClassification", "ernie_base_config",

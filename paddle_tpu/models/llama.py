@@ -394,7 +394,13 @@ def _mesh_attention(q, k, v, mesh, batch_axes, tp_axis):
 
 class LlamaAttention(nn.Layer):
     """GQA attention with rotary embeddings; [B, S, H, D] layout throughout
-    so the Pallas flash kernel path needs no relayout."""
+    so the Pallas flash kernel path needs no relayout. In serving, a
+    layer whose queries see the last ``window`` keys only and a layer
+    whose rope is YaRN's (``yarn``, a tuple: `ops.ragged_paged_attention.
+    rope_tables`) say so here; a Llama layer does neither."""
+
+    window = None
+    yarn = None
 
     def __init__(self, config: LlamaConfig):
         super().__init__()
@@ -507,21 +513,32 @@ class LlamaDecoderLayer(nn.Layer):
     def serving_step(self, x, step, pages):
         """One packed step of this layer over its pages (``[K, V]``, then
         their int8 scale sidecars where the engine quantizes pages):
-        ``(x, pages, None)``. Rope, page write and attention are ONE
+        ``(x, pages, None)``: `serving_attention`, then the MLP."""
+        x, pages = self.serving_attention(x, step, pages)
+        with scope("mlp"):
+            x = x + self.mlp(self.post_attention_layernorm(x))
+        return x, pages, None
+
+    def serving_attention(self, x, step, pages):
+        """The attention half of the packed step: ``(x + attn(norm(x)),
+        pages)``. Rope, page write and attention are ONE
         `fused_ragged_paged_attention` call: q and k stay PRE-rope in
         the packed token layout, the kernel slices each row's
         contiguous tokens through the scalar-prefetched write metadata,
         rotates them in VMEM by the step's shared sin/cos tables (no
         transcendentals in-kernel: Mosaic and XLA then agree bit for
-        bit) and writes each row's K/V into its pages in-grid."""
+        bit) and writes each row's K/V into its pages in-grid. A window
+        layer's pools are rings a sequence slot, behind the step's ring
+        tables."""
         from ..ops.ragged_paged_attention import \
             fused_ragged_paged_attention
 
         att = self.self_attn
         t, qb = step.tokens, step.qblock
+        window = att.window
         with scope("attn"):
             rsin, rcos = step.rope(att.head_dim,
-                                   float(att.config.rope_theta))
+                                   float(att.config.rope_theta), att.yarn)
             with scope("attn_proj"):
                 h = self.input_layernorm(x)
                 q = att.q_proj(h).reshape([t, att.num_heads, att.head_dim])
@@ -531,18 +548,20 @@ class LlamaDecoderLayer(nn.Layer):
                                            att.head_dim])
             sidecars = dict(k_scale=pages[2], v_scale=pages[3]) \
                 if step.kv_quant else {}
-            attn4, *pages = fused_ragged_paged_attention(
-                q, k, v, pages[0], pages[1], step.tables, step.kv_lens,
-                step.q_starts, step.q_lens, step.w_starts, step.w_flats,
-                step.w_ends, step.trash_page, rope_sin=rsin, rope_cos=rcos,
-                qblock=qb, **sidecars)
-            attn = step.unpack(attn4.reshape([step.rows * qb,
-                                              att.num_heads, att.head_dim]))
+            tables = step.tables if window is None \
+                else step.ring_tables(window)
+            with scope("attn_full" if window is None else "attn_window"):
+                attn4, *pages = fused_ragged_paged_attention(
+                    q, k, v, pages[0], pages[1], tables, step.kv_lens,
+                    step.q_starts, step.q_lens, step.w_starts,
+                    step.w_flats, step.w_ends, pages[0].shape[0] - 1,
+                    rope_sin=rsin, rope_cos=rcos, qblock=qb, window=window,
+                    **sidecars)
+                attn = step.unpack(attn4.reshape(
+                    [step.rows * qb, att.num_heads, att.head_dim]))
             with scope("attn_proj"):
                 x = x + att.o_proj(attn.reshape([1, t, -1]))
-        with scope("mlp"):
-            x = x + self.mlp(self.post_attention_layernorm(x))
-        return x, pages, None
+        return x, pages
 
 
 class LlamaModel(nn.Layer):

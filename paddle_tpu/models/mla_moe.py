@@ -323,6 +323,8 @@ def serving_ffn(layer, x, step):
         valid = step.token_valid()
     routed = mlp.routed(h, valid)
     with scope("mlp"):
+        if mlp.shared is None:
+            return x + routed, expert_stats(mlp)
         return x + (routed + mlp.shared(h)), expert_stats(mlp)
 
 
@@ -331,6 +333,12 @@ class MlaMoeMLP(nn.Layer):
     top-k routed SwiGLU experts over the packed grouped GEMM, and one
     shared SwiGLU expert (module docstring). ``last_stats`` holds, after
     a call, ``[experts that got a row, rows of the largest group]``.
+
+    ``scoring="softmax"`` routes as Mixtral and Qwen do instead: ``p =
+    softmax(float32(x W_r))`` over all experts, the top k by ``p``,
+    weights ``p[chosen]`` (divided by their sum where the config's
+    ``norm_topk_prob``), times the scaling factor; no correction bias.
+    ``n_shared_experts`` 0 builds no shared expert (``shared`` None).
 
     ``experts_held`` (default: all) and ``first_expert`` make this the
     share of an expert-parallel deployment that one chip computes: it
@@ -341,12 +349,17 @@ class MlaMoeMLP(nn.Layer):
     left out. ``last_stats`` then counts the held experts. ``config`` is
     any dataclass with the fields read here."""
 
-    def __init__(self, config, experts_held=None, first_expert=0):
+    def __init__(self, config, experts_held=None, first_expert=0,
+                 scoring="sigmoid"):
         super().__init__()
         from ..framework import random as frandom
         from ..framework.dtype import get_default_dtype
 
         c = config
+        if scoring not in ("sigmoid", "softmax"):
+            raise ValueError(f"scoring is 'sigmoid' or 'softmax', got "
+                             f"{scoring!r}")
+        self.scoring = scoring
         self.num_experts = int(c.n_routed_experts)
         self.top_k = int(c.num_experts_per_tok)
         self.scaling = float(c.routed_scaling_factor)
@@ -370,21 +383,27 @@ class MlaMoeMLP(nn.Layer):
                 frandom.next_key(), shape, dt) * jnp.asarray(std, dt))
 
         self.router = init((d, e))
-        self.router_bias = Parameter(jnp.zeros((e,), dt))
+        self.router_bias = Parameter(jnp.zeros((e,), dt)) \
+            if scoring == "sigmoid" else None
         self.experts_gate = init((held, d, f))
         self.experts_up = init((held, d, f))
         self.experts_down = init((held, f, d))
-        shared = dataclasses.replace(
-            c, intermediate_size=f * max(1, int(c.n_shared_experts)))
-        self.shared = LlamaMLP(shared)
+        ns = int(c.n_shared_experts)
+        self.shared = LlamaMLP(dataclasses.replace(
+            c, intermediate_size=f * ns)) if ns else None
         self.last_stats = None
 
     def route(self, x2d, wr, br):
         """``(expert ids [n, k], weights [n, k] f32)`` of tokens
-        ``x2d [n, H]`` (raw arrays, traceable)."""
-        s = jax.nn.sigmoid(jnp.matmul(x2d.astype(jnp.float32),
-                                      wr.astype(jnp.float32)))
-        _, idx = jax.lax.top_k(s + br.astype(jnp.float32), self.top_k)
+        ``x2d [n, H]`` (raw arrays, traceable; ``br`` None where the
+        scoring is softmax)."""
+        logits = jnp.matmul(x2d.astype(jnp.float32), wr.astype(jnp.float32))
+        if self.scoring == "softmax":
+            s = jax.nn.softmax(logits, axis=-1)
+            _, idx = jax.lax.top_k(s, self.top_k)
+        else:
+            s = jax.nn.sigmoid(logits)
+            _, idx = jax.lax.top_k(s + br.astype(jnp.float32), self.top_k)
         w = jnp.take_along_axis(s, idx, axis=-1)
         if self.normalize:
             w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
@@ -393,7 +412,8 @@ class MlaMoeMLP(nn.Layer):
     def forward(self, x, valid=None):
         """``valid [n]`` (bool) marks the real tokens of a packed step:
         the others get no expert row and are not counted."""
-        return self.routed(x, valid) + self.shared(x)
+        routed = self.routed(x, valid)
+        return routed if self.shared is None else routed + self.shared(x)
 
     def routed(self, x, valid=None):
         """The routed experts' half of `forward` (``x``'s shape; sets

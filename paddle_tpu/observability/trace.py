@@ -48,8 +48,9 @@ __all__ = ["span", "record", "TraceBuffer", "default_buffer", "get_events",
 
 #: every device scope of the program, by the part of the model its ops
 #: compute; a name is declared here once. Nested as the serving step
-#: nests them: ``attn`` holds ``attn_proj`` and ``attn_pack`` (the kernel
-#: between them keeps its own ``pallas_call`` name), ``ssm`` holds
+#: nests them: ``attn`` holds ``attn_proj`` and, of a Llama layer,
+#: ``attn_window`` or ``attn_full``, which hold ``attn_pack`` and the
+#: kernel (it keeps its own ``pallas_call`` name), ``ssm`` holds
 #: ``ssm_scan``, ``kda`` holds ``kda_scan``, and ``diff_attn`` holds
 #: ``attn_pack``. PERF.md section 3 names the metric that reads each.
 SCOPES = {part: "paddle_tpu." + part for part in (
@@ -58,6 +59,8 @@ SCOPES = {part: "paddle_tpu." + part for part in (
     "attn",         # one attention block, input norm to residual add
     "attn_proj",    # its norm and projections into and out of attention
     "attn_pack",    # packing and unpacking around a ragged kernel call
+    "attn_window",  # a window layer's ragged kernel call and its packing
+    "attn_full",    # a whole-context layer's ragged kernel call, packing
     "mlp",          # a dense MLP or the shared expert, norm included
     "norm",         # input norm and residual add around a scoped mixer
     "moe",          # routed experts: router, sort, gathers, combine

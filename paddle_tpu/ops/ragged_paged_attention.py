@@ -213,19 +213,52 @@ def fused_rope_geometry_ok(head_dim):
     return _HAS_PLTPU and head_dim % 2 == 0 and head_dim >= 2
 
 
-def rope_tables(pos, head_dim, base):
+def yarn_inv_freq(head_dim, base, yarn):
+    """YaRN's rotary frequencies ``[D/2]`` f32 and its attention factor.
+    ``yarn`` is ``(factor, original_max_position_embeddings, beta_fast,
+    beta_slow, attention_factor)``. Frequency ``i`` of the default rope,
+    ``f_i = base^(-2i/D)``, is blended with ``f_i / factor`` by the ramp
+    ``r_i = clamp((i - lo) / (hi - lo), 0, 1)``: ``r f / factor + (1 - r)
+    f``, where ``lo = floor(c(beta_fast))``, ``hi = ceil(c(beta_slow))``
+    and ``c(b) = D ln(original / (2 pi b)) / (2 ln base)`` is the index
+    whose wavelength turns ``b`` times over the original context."""
+    factor, original, fast, slow, attn = yarn
+
+    def corr(b):
+        return head_dim * math.log(original / (b * 2 * math.pi)) \
+            / (2 * math.log(base))
+
+    lo = max(math.floor(corr(fast)), 0)
+    hi = min(math.ceil(corr(slow)), head_dim - 1)
+    hi = hi + 0.001 if hi == lo else hi
+    f = 1.0 / (base ** (jnp.arange(0, head_dim, 2,
+                                   dtype=jnp.float32) / head_dim))
+    r = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - lo)
+                 / (hi - lo), 0.0, 1.0)
+    return r * (f / factor) + (1.0 - r) * f, float(attn)
+
+
+def rope_tables(pos, head_dim, base, yarn=None):
     """Per-dispatch rotary sin/cos tables, one row per PACKED token:
     ``[T, D]`` f32 with the neox duplicated-half layout (``emb =
     concat([ang, ang])``). Bitwise the same values
     `fused_rotary_position_embedding` derives from ``position_ids`` —
     the single source of the angle formula, computed ONCE per dispatch
     and shared by every layer. ``pos`` is any integer array; it is
-    flattened to ``[T]``. Pure jnp — safe under jit/trace."""
-    inv = 1.0 / (base ** (jnp.arange(0, head_dim, 2,
-                                     dtype=jnp.float32) / head_dim))
+    flattened to ``[T]``. ``yarn`` (see `yarn_inv_freq`) takes YaRN's
+    frequencies and multiplies both tables by its attention factor, so
+    that q and k are each scaled by it. Pure jnp — safe under
+    jit/trace."""
+    if yarn is None:
+        inv = 1.0 / (base ** (jnp.arange(0, head_dim, 2,
+                                         dtype=jnp.float32) / head_dim))
+    else:
+        inv, attn = yarn_inv_freq(head_dim, base, yarn)
     ang = pos.reshape(-1).astype(jnp.float32)[:, None] * inv  # [T, D/2]
     emb = jnp.concatenate([ang, ang], axis=-1)                # [T, D]
-    return jnp.sin(emb), jnp.cos(emb)
+    if yarn is None:
+        return jnp.sin(emb), jnp.cos(emb)
+    return jnp.sin(emb) * attn, jnp.cos(emb) * attn
 
 
 def fused_supported(q, new_k, new_v, k_pages, v_pages, block_tables,

@@ -198,6 +198,26 @@ def test_walk_compiles_for_window_and_cross_layers(chip_compile, rows,
     _assert_kernel(compiled, "ragged_attn_fused_rope")
 
 
+# the float program as the window / full expert family calls it: 32 query
+# heads over 4 kv heads of 128 (a group of 8: a decode row's small tile is
+# one token's rows), page 16, behind the window layers' ring tables
+# (window 1,024, 65 slots of 97 pages) and the full layers' pool of the
+# code cell (49,153 pages, tables 1,089 wide), both of its step shapes
+@pytest.mark.parametrize("kind", ["window", "full"])
+@pytest.mark.parametrize("rows,tokens,qblock", [(80, 512, 32), (64, 64, 1)],
+                         ids=["mixed", "decode"])
+def test_walk_compiles_at_the_code_cell(chip_compile, rows, tokens, qblock,
+                                        kind):
+    window = kind == "window"
+    pool = (65 * 97 if window else 49153, 4, 16, 128)
+    fn = functools.partial(
+        rpa._fused_rope_impl, dump_page=pool[0] - 1, scale=D ** -0.5,
+        qblock=qblock, window=1024 if window else None)
+    compiled = chip_compile(fn, *_ragged_specs(False, pool, rows, tokens,
+                                               1089, 8))
+    _assert_kernel(compiled, "ragged_attn_fused_rope")
+
+
 # ---------------------------------------------------------------------------
 # training: flash attention fwd+bwd, fused CE, and the quantized /
 # grouped matmuls
@@ -344,6 +364,22 @@ def test_packed_grouped_gemm_compiles_at_published_widths(chip_compile, m,
     _assert_kernel(chip_compile(
         functools.partial(gg._packed_kernel_impl, block_m=block_m),
         ((m, k), BF16), ((256, k, n), BF16), ((m // block_m,), I32),
+        ((1,), I32)), "grouped_gemm_packed")
+
+
+@pytest.mark.parametrize("tokens,k,n", [
+    (512, 2304, 896), (512, 896, 2304), (64, 2304, 896), (64, 896, 2304)],
+    ids=["mixed-in", "mixed-down", "decode-in", "decode-down"])
+def test_packed_grouped_gemm_compiles_at_the_code_cell(chip_compile, tokens,
+                                                       k, n):
+    """64 experts of width 896 over hidden 2304, 8 a token: the packed
+    rows of the window / full expert family's two step shapes."""
+    block_m = gg.packed_block_m(tokens * 8, 64)
+    m = gg.packed_rows(tokens * 8, 64, block_m)
+    assert (m, block_m) in ((8128, 64), (1472, 16))
+    _assert_kernel(chip_compile(
+        functools.partial(gg._packed_kernel_impl, block_m=block_m),
+        ((m, k), BF16), ((64, k, n), BF16), ((m // block_m,), I32),
         ((1,), I32)), "grouped_gemm_packed")
 
 

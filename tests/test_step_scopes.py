@@ -44,7 +44,7 @@ _PART = re.compile(r"paddle_tpu\.([a-z0-9]+(?:_[a-z0-9]+)*)")
 #: the scopes each served family's step program holds
 FAMILIES = {
     "mistral-7b-v0.3-l16": {"embed", "head", "attn", "attn_proj",
-                            "attn_pack", "mlp"},
+                            "attn_full", "attn_pack", "mlp"},
     "joyai-llm-flash-l5": {"embed", "head", "attn", "attn_proj",
                            "attn_pack", "mlp", "moe"},
     "phi-4-mini-flash-reasoning": {"embed", "head", "norm", "ssm",
@@ -53,6 +53,9 @@ FAMILIES = {
     "kimi-linear-48b-a3b-ep16": {"embed", "head", "norm", "kda",
                                  "kda_scan", "attn", "attn_proj",
                                  "attn_pack", "mlp", "moe"},
+    "mellum2-12b-a2.5b-l8": {"embed", "head", "attn", "attn_proj",
+                             "attn_window", "attn_full", "attn_pack", "mlp",
+                             "moe"},
 }
 
 
