@@ -18,6 +18,10 @@ calls, at published widths, on one TPU:
 - ``flash``: the three training flash-attention kernels alone, by name,
   at pretrain-4k's attention shape: ms a call each, and at S = 1,024 the
   output and gradients against the XLA path;
+- ``ce``: the fused cross-entropy's forward kernel alone at
+  pretrain-4k's head shape (f32): ms a call at the blocks it picks and
+  at a sweep of others, beside its MXU and HBM floors; lse and pick
+  against the chunked-XLA formulation in f32;
 - ``serve``: Llama-3-8B widths (hidden 4096, FFN 14336, 32q/8kv, vocab
   128256), depth cut to 8 layers, seeded bf16 weights;
   ``LlamaServingEngine`` with its default (rope-fused) program answers
@@ -702,6 +706,77 @@ def phase_flash(seed, rehearse, on_chip):
          timings=timings)
 
 
+# |kernel - XLA path in f32 at `highest`| on lse and pick, in logit units
+# (one bf16 rounding of the operands moves a logit of std ~0.9 by ~3e-3)
+CE_ATOL = 2e-2
+# HBM bandwidth of one v5e (perfbench/harness/peaks.py)
+V5E_HBM_BYTES = 819e9
+# (block_n, block_v) pairs the ce phase times beside the chosen blocks:
+# a 128-row tile (64 weight passes) and the rows x lanes around the
+# chosen one
+CE_SWEEP = ((128, 512), (512, 256), (512, 512), (512, 1024), (1024, 256),
+            (1024, 512), (1024, 1024), (2048, 256), (2048, 512),
+            (2048, 1024))
+
+
+def phase_ce(seed, rehearse, on_chip):
+    """The fused cross-entropy's forward kernel alone at pretrain-4k's
+    head shape (8,192 tokens over hidden 2,048 and a vocabulary of
+    92,544, f32, as the train step hands it over): ms a call at the
+    blocks ``_blocks`` picks and at each pair of ``CE_SWEEP``, beside the
+    least time of its dots at the bf16 peak and of its weight passes at
+    the HBM bandwidth. Then lse and pick at the chosen blocks against
+    the chunked-XLA formulation in f32 at `highest` (and the XLA path at
+    the default precision beside them)."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import fused_linear_cross_entropy as flce
+
+    if rehearse:
+        n, d, v, reps = 200, 128, 1000, 1
+        sweep = ((64, 256), (128, 128))
+    else:
+        n, d, v, reps = 8192, 2048, 92544, 5
+        sweep = CE_SWEEP
+    f32 = jnp.float32
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    h = jax.random.normal(keys[0], (n, d), f32)
+    w = 0.02 * jax.random.normal(keys[1], (d, v), f32)
+    lab = jax.random.randint(keys[2], (n,), 0, v, jnp.int32)
+    lab = jnp.where(jnp.arange(n) % 97 == 0, -100, lab)   # ignored rows
+    bn, bv = flce._blocks(n, d, v, 4)
+
+    def floors(bn):
+        passes = -(-n // bn)
+        return {"passes": passes,
+                "dots_floor_ms": 1e3 * 2 * n * d * v / V5E_BF16_FLOPS,
+                "weight_floor_ms": 1e3 * (passes * d * v + n * d) * 4
+                / V5E_HBM_BYTES}
+
+    timings = {}
+    for nb, vb in ((bn, bv),) + tuple(sweep):
+        kern = jax.jit(functools.partial(flce._kernel_parts, block_n=nb,
+                                         block_v=vb))
+        (lse, pick), ms = best_ms(kern, (h, w, lab), reps)
+        timings[f"ms.{nb}x{vb}"] = dict(ms=ms, **floors(nb))
+        if (nb, vb) == (bn, bv):
+            got = (lse, pick)
+    with jax.default_matmul_precision("highest"):
+        ref = jax.jit(lambda h, w, l: flce._xla_parts(h, w, l, 8192))(
+            h, w, lab)
+    xla = jax.jit(lambda h, w, l: flce._xla_parts(h, w, l, 8192))(h, w, lab)
+    for name, parts in (("kernel", got), ("xla_default", xla)):
+        for part, a, r in zip(("lse", "pick"), parts, ref):
+            timings[f"{name}_err.{part}"] = float(jnp.max(jnp.abs(a - r)))
+    worst = max(timings["kernel_err.lse"], timings["kernel_err.pick"])
+    if not worst <= CE_ATOL:
+        raise RuntimeError(f"the fused CE kernel and the f32 XLA path "
+                           f"differ by {worst} in lse or pick")
+    emit(phase="ce", device=device_info(),
+         shapes=dict(tokens=n, hidden=d, vocab=v, dtype="float32",
+                     block_n=bn, block_v=bv), timings=timings)
+
+
 # ---------------------------------------------------------------------------
 # train
 # ---------------------------------------------------------------------------
@@ -919,7 +994,7 @@ def main():
                          f"{REHEARSAL_EXIT}")
     ap.add_argument("--phase", default="all",
                     choices=("all", "walk", "latent", "kda", "flash",
-                             "serve", "train"),
+                             "ce", "serve", "train"),
                     help="one chip: run only this phase")
     args = ap.parse_args()
 
@@ -939,7 +1014,8 @@ def main():
     else:
         for name, phase in (("walk", phase_walk), ("latent", phase_latent),
                             ("kda", phase_kda), ("flash", phase_flash),
-                            ("serve", phase_serve), ("train", phase_train)):
+                            ("ce", phase_ce), ("serve", phase_serve),
+                            ("train", phase_train)):
             if args.phase in ("all", name):
                 phase(args.seed, args.rehearse, on_chip)
                 gc.collect()    # a phase's weights leave before the next

@@ -26,7 +26,10 @@ Three formulations, one contract:
   friendly D): grid ``(row-tiles, vocab-tiles)`` with the vocab index
   minor, so VMEM scratch carries each row tile's running
   ``(max, sumexp, label-logit)`` across that row's vocab sweep — one
-  read of ``hidden``, one stream over ``w``, outputs ``[N]``.
+  read of ``hidden``, one stream over ``w`` a row tile, outputs ``[N]``.
+  The row tile is sized from the shapes (``_blocks``) so that those
+  streams cost less than the tile's dots, and the call asks for the
+  scoped VMEM its windows need.
 - **chunked-XLA formulation** (the parity bar and the fallback
   everywhere else): the SAME online update unrolled over static vocab
   chunks. Math is identical op for op, so the kernel is testable
@@ -69,10 +72,27 @@ __all__ = ["fused_linear_cross_entropy", "fused_linear_cross_entropy_xla",
 #: and the scope of the backward's ops)
 KERNEL_NAME = "paddle_tpu.fused_ce"
 
-#: VMEM budget for one grid step's input windows (hidden tile + w tile,
-#: each double-buffered by the pipeline), kept under the 16 MB of scoped
-#: VMEM the compiler grants a kernel
-_VMEM_BUDGET = 12 * 1024 * 1024
+#: VMEM budget for one grid step's buffers (``_window_bytes``): the
+#: pipeline's double-buffered windows, the row tile's scratch and the
+#: ``[block_n, block_v]`` f32 temporaries of the online update
+_VMEM_BUDGET = 64 << 20
+#: scoped VMEM the call asks for above its budgeted buffers (Mosaic's own
+#: scratch), and the cap the repo's other kernels keep; budget plus slack
+#: stays under the cap, so every shape :func:`supported` takes compiles
+_VMEM_SLACK = 16 << 20
+_VMEM_CAP = 100 << 20
+#: vocab tile (lanes) of the grid: on a v5e at 8,192 x 2,048 x 92,544 f32
+#: a call took 22.4 / 19.7 / 18.4 ms at 256 / 512 / 1,024 lanes and
+#: 1,024 rows (``chip_smoke.py --phase ce``)
+_BLOCK_V = 1024
+#: row-tile target a byte of operand itemsize. The head weight is streamed
+#: from HBM once per row tile, so a grid step moves ``D * block_v *
+#: itemsize`` weight bytes for ``2 * block_n * D * block_v`` FLOPs; at a
+#: v5e's 197 TFLOP/s over 819 GB/s the stream matches the dots at
+#: ``block_n = 120 * itemsize`` rows. Twice that leaves the stream at most
+#: half the dots' time: 1,024 rows of f32, eight weight passes at 8,192
+#: tokens
+_ROWS_PER_ITEMSIZE = 256
 
 
 def _interpret():
@@ -93,19 +113,48 @@ def default_chunk():
         return 8192
 
 
+def _ceil(x, m):
+    return -(-x // m) * m
+
+
 def _window_bytes(bn, d, bv, itemsize):
-    return 2 * (bn * d + d * bv) * itemsize
+    """VMEM bytes of one grid step at blocks ``(bn, bv)``: the hidden and
+    weight windows double-buffered, the ``[bn, 1]`` label and output
+    windows (double-buffered) and scratch columns (lane-padded to 128),
+    and four ``[bn, bv]`` f32 temporaries."""
+    return (2 * (bn * d + d * bv) * itemsize
+            + 9 * bn * 128 * 4
+            + 4 * bn * bv * 4)
+
+
+def _vmem_limit(bn, d, bv, itemsize):
+    return min(_VMEM_CAP, max(32 << 20, _window_bytes(bn, d, bv, itemsize)
+                              + _VMEM_SLACK))
 
 
 def _blocks(n, d, v, itemsize):
-    """(block_n, block_v) for the kernel grid: row tiles sublane-aligned
-    and capped at 128 (the sequence tile), vocab tiles shrunk while one
-    grid step's input windows exceed the VMEM budget."""
-    bn = min(128, -(-n // 8) * 8)
-    bv = min(512, -(-v // 128) * 128)
-    while bv > 128 and _window_bytes(bn, d, bv, itemsize) > _VMEM_BUDGET:
-        bv //= 2
+    """(block_n, block_v) for the kernel grid, from the shapes alone. The
+    row tile is ``_ROWS_PER_ITEMSIZE * itemsize`` rows (at most ``n``
+    rounded up to the sublanes), so the weight stream costs at most half
+    the dots. While one grid step's buffers exceed the VMEM budget it
+    halves down to where the stream matches the dots, then the vocab tile
+    halves down to 128 lanes, then the row tile goes on halving."""
+    target = _ROWS_PER_ITEMSIZE * itemsize
+    bv = min(_BLOCK_V, _ceil(v, 128))
+    bn = min(target, _ceil(n, 8))
+    while _window_bytes(bn, d, bv, itemsize) > _VMEM_BUDGET:
+        if bn > target // 2 or (bv == 128 and bn > 8):
+            bn = _ceil(bn // 2, 8)
+        elif bv > 128:
+            bv //= 2
+        else:
+            break
     return bn, bv
+
+
+def _itemsize(h2d, w):
+    return max(jnp.dtype(getattr(a, "_data", a).dtype).itemsize
+               for a in (h2d, w))
 
 
 def supported(hidden2d, w):
@@ -125,7 +174,7 @@ def supported(hidden2d, w):
         return False
     if d % 128 or v < 128:
         return False
-    itemsize = jnp.dtype(getattr(hidden2d, "_data", hidden2d).dtype).itemsize
+    itemsize = _itemsize(hidden2d, w)
     bn, bv = _blocks(n, d, v, itemsize)
     return _window_bytes(bn, d, bv, itemsize) <= _VMEM_BUDGET
 
@@ -204,7 +253,7 @@ def _ce_kernel(h_ref, w_ref, lab_ref, lse_ref, pick_ref, m_s, s_s, p_s,
 
 
 @functools.lru_cache(maxsize=32)
-def _make_ce_call(n, d, v, block_n, block_v, interpret):
+def _make_ce_call(n, d, v, block_n, block_v, itemsize, interpret):
     nt = -(-n // block_n)
     vt = -(-v // block_v)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -230,6 +279,9 @@ def _make_ce_call(n, d, v, block_n, block_v, interpret):
             grid_spec=grid_spec,
             out_shape=[jax.ShapeDtypeStruct((n, 1), jnp.float32),
                        jax.ShapeDtypeStruct((n, 1), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=_vmem_limit(block_n, d, block_v,
+                                             itemsize)),
             interpret=interpret,
             name=KERNEL_NAME,
         )(h2d, w, lab2d)
@@ -237,16 +289,19 @@ def _make_ce_call(n, d, v, block_n, block_v, interpret):
     return call
 
 
-def _kernel_parts(h2d, w, labels, block_v=None):
+def _kernel_parts(h2d, w, labels, block_v=None, block_n=None):
     """Pallas dispatch (raw jax arrays) -> (lse [N], pick [N]). Caller
-    guarantees :func:`supported` (tests pass ``block_v`` explicitly and
-    run the interpreter off-TPU)."""
+    guarantees :func:`supported` (tests pass ``block_n`` / ``block_v``
+    explicitly and run the interpreter off-TPU)."""
     n, d = h2d.shape
     v = w.shape[1]
-    bn, bv = _blocks(n, d, v, jnp.dtype(h2d.dtype).itemsize)
+    itemsize = _itemsize(h2d, w)
+    bn, bv = _blocks(n, d, v, itemsize)
+    if block_n is not None:
+        bn = int(block_n)
     if block_v is not None:
         bv = int(block_v)
-    call = _make_ce_call(n, d, v, bn, bv, _interpret())
+    call = _make_ce_call(n, d, v, bn, bv, itemsize, _interpret())
     lse2, pick2 = call(h2d, w, labels.reshape(n, 1))
     return lse2[:, 0], pick2[:, 0]
 

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import paddle_tpu as paddle
 from paddle_tpu.models import (LlamaForCausalLM, shard_llama,
                                tiny_llama_config)
+from paddle_tpu.ops import fused_linear_cross_entropy as flce
 from paddle_tpu.ops.fused_linear_cross_entropy import (
     _kernel_parts, _loss_raw, _xla_parts, fused_linear_cross_entropy,
     fused_linear_cross_entropy_xla, supported)
@@ -95,38 +96,55 @@ class TestChunkedXlaFormulation:
         np.testing.assert_allclose(float(lx), ref, rtol=1e-6, atol=1e-6)
 
 
+def _kernel_loss(lse, pick, lab, ignore_index=-100):
+    valid = lab != ignore_index
+    nll = jnp.where(valid, lse - pick, 0.0)
+    return jnp.sum(nll) / jnp.maximum(
+        jnp.sum(valid.astype(jnp.float32)), 1.0)
+
+
 class TestPallasKernel:
-    def test_kernel_bitwise_vs_xla_same_chunking(self):
-        # interpret mode off-TPU: same online update, same chunk order
-        h, w, lab = _case(ignore=(3,))
-        lse_x, pick_x = _xla_parts(h, w, lab, 16)
-        lse_k, pick_k = _kernel_parts(h, w, lab, block_v=16)
-        np.testing.assert_array_equal(np.asarray(lse_x),
-                                      np.asarray(lse_k))
-        np.testing.assert_array_equal(np.asarray(pick_x),
-                                      np.asarray(pick_k))
-
-    def test_kernel_vocab_not_divisible_by_block(self):
-        h, w, lab = _case(n=16, d=32, v=50)      # 50 % 16 != 0
-        lse_x, pick_x = _xla_parts(h, w, lab, 16)
-        lse_k, pick_k = _kernel_parts(h, w, lab, block_v=16)
-        # one ulp on two rows, not bitwise: the last vocab tile is a
-        # 2-wide slice there and a masked 16-wide tile here, and
-        # XLA:CPU contracts the update ``s * exp(m - m_new) + sum``
-        # into an FMA or not by what it fuses the chain with (dot and
-        # sum alone agree bit for bit at both widths)
-        np.testing.assert_allclose(np.asarray(lse_x), np.asarray(lse_k),
-                                   rtol=2.5e-7, atol=0.0)
-        np.testing.assert_array_equal(np.asarray(pick_x),
-                                      np.asarray(pick_k))
-
-    def test_kernel_rows_not_divisible_by_block(self):
+    # (n, v, ignored rows, block_n, block_v, lse bitwise): interpret mode
+    # off-TPU runs the same online update in the same chunk order as
+    # ``_xla_parts`` at ``chunk = block_v``; pick is bitwise everywhere.
+    # The first and third cases hold lse bitwise; elsewhere it may miss by
+    # one ulp on a row: XLA:CPU contracts the update ``s * exp(m - m_new)
+    # + sum`` into an FMA or not by what it fuses the chain with, and
+    # that changes with the tile's shape (a ragged last vocab tile is a
+    # (v % block_v)-wide slice there and a masked block_v-wide tile here;
+    # a row tile of block_n rows against the whole N there). Dot and sum
+    # alone agree bit for bit.
+    @pytest.mark.parametrize("n,v,ignore,block_n,block_v,exact", [
+        pytest.param(24, 50, (3,), None, 16, True, id="same-chunking"),
+        pytest.param(16, 50, (), None, 16, False, id="vocab-tail"),
         # N=20 rides a ragged final row tile; real rows must be exact
-        h, w, lab = _case(n=20, d=32, v=32)
-        lse_x, _ = _xla_parts(h, w, lab, 16)
-        lse_k, _ = _kernel_parts(h, w, lab, block_v=16)
-        np.testing.assert_array_equal(np.asarray(lse_x),
-                                      np.asarray(lse_k))
+        pytest.param(20, 32, (), None, 16, True, id="rows-ragged"),
+        pytest.param(40, 64, (), 16, 32, False,
+                     id="rows-not-multiple-of-block-n"),
+        pytest.param(24, 300, (), 8, 128, False, id="vocab-tail-wide"),
+        pytest.param(32, 64, tuple(range(0, 32, 3)), 8, 32, False,
+                     id="ignored-rows"),
+        pytest.param(48, 200, (5, 47), 16, 128, False,
+                     id="all-three"),
+    ])
+    def test_kernel_matches_xla_and_materialized(self, n, v, ignore,
+                                                 block_n, block_v, exact):
+        h, w, lab = _case(n=n, d=32, v=v, ignore=ignore)
+        lse_x, pick_x = _xla_parts(h, w, lab, block_v)
+        lse_k, pick_k = _kernel_parts(h, w, lab, block_v=block_v,
+                                      block_n=block_n)
+        if exact:
+            np.testing.assert_array_equal(np.asarray(lse_x),
+                                          np.asarray(lse_k))
+        else:
+            np.testing.assert_allclose(np.asarray(lse_x),
+                                       np.asarray(lse_k),
+                                       rtol=2.5e-7, atol=0.0)
+        np.testing.assert_array_equal(np.asarray(pick_x),
+                                      np.asarray(pick_k))
+        np.testing.assert_allclose(
+            float(_kernel_loss(lse_k, pick_k, lab)),
+            float(_materialized(h, w, lab)), rtol=1e-6, atol=1e-6)
 
     def test_kernel_grads_flow_through_custom_vjp(self):
         h, w, lab = _case()
@@ -145,6 +163,41 @@ class TestPallasKernel:
         h, w, _ = _case(n=16, d=128, v=256)
         # CPU backend: public dispatch always takes the XLA formulation
         assert supported(h, w) is False
+
+    def test_blocks_at_pretrain_4k_shape(self):
+        # 8,192 tokens over the f32 head [2,048, 92,544]: the weight is
+        # streamed once per row tile, so at most 8 passes, and one grid
+        # step's buffers fit the scoped VMEM the call asks for
+        n, d, v = 8192, 2048, 92544
+        bn, bv = flce._blocks(n, d, v, 4)
+        assert bn % 8 == 0 and bv % 128 == 0
+        assert -(-n // bn) <= 8
+        need = flce._window_bytes(bn, d, bv, 4)
+        assert need <= flce._VMEM_BUDGET
+        assert need < flce._vmem_limit(bn, d, bv, 4) <= 100 << 20
+
+    @pytest.mark.parametrize("n,d,v,dtype", [
+        (8192, 2048, 92544, jnp.float32),    # pretrain-4k's head
+        (4096, 4096, 128256, jnp.bfloat16),
+        (4096, 4096, 128256, jnp.float32),
+        (20, 128, 1000, jnp.float32),        # small N: one row tile
+        (8192, 16384, 128256, jnp.float32),  # wide D: blocks shrink
+        (64, 65536, 256, jnp.float32),       # nothing fits: refused
+    ])
+    def test_supported_and_blocks_agree(self, monkeypatch, n, d, v, dtype):
+        monkeypatch.setattr(flce, "_interpret", lambda: False)
+        h = jax.ShapeDtypeStruct((n, d), dtype)
+        w = jax.ShapeDtypeStruct((d, v), dtype)
+        item = jnp.dtype(dtype).itemsize
+        bn, bv = flce._blocks(n, d, v, item)
+        need = flce._window_bytes(bn, d, bv, item)
+        assert supported(h, w) is (need <= flce._VMEM_BUDGET)
+        assert bn % 8 == 0 and bv % 128 == 0
+        assert bn <= -(-n // 8) * 8 and bv <= -(-v // 128) * 128
+        if supported(h, w):
+            assert need < flce._vmem_limit(bn, d, bv, item) <= 100 << 20
+            if n <= 1024:       # small N: one row tile
+                assert bn == -(-n // 8) * 8
 
 
 class TestModelWiring:
