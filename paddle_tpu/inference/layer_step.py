@@ -110,10 +110,14 @@ class DispatchLayout:
 
     ``t_cap`` packed tokens, ``r_cap`` rows of at most ``qb`` query
     tokens, tables ``width`` wide, ``sample_slots`` bias slots a row;
-    ``trash_page`` fills what no row claims."""
+    ``trash_page`` fills what no row claims. An engine whose drafting
+    module runs in the program (``next_tokens``) has a last field,
+    ``next_tok [1, T]``: the token that follows a packed position where
+    the host knows it (a prompt's), -1 where the program's own sampled
+    token follows it."""
 
     def __init__(self, t_cap, r_cap, qb, width, sample_slots, trash_page,
-                 trash_slot=None):
+                 trash_slot=None, next_tokens=False):
         t, r, b = int(t_cap), int(r_cap), int(sample_slots)
         i32, f32 = np.int32, np.float32
         # (name, shape, dtype, what an unused slot reads)
@@ -142,6 +146,8 @@ class DispatchLayout:
             # a model whose layers keep a state or a ring a sequence
             # slot: each row's slot, a 20th field
             spec += (("slots", (r,), i32, int(trash_slot)),)
+        if next_tokens:
+            spec += (("next_tok", (1, t), i32, -1),)
         self.shape = (t, r, int(qb), int(width), b)
         fields, at = [], 0
         for name, shape, dtype, _ in spec:
@@ -230,16 +236,6 @@ class ServingStep:
         return self._shared(
             ("pairs", dim, base), "serving_rope_tables_interleaved",
             lambda p: rope_tables_interleaved(p, dim, base), self.pos)
-
-    def no_rope(self, head_dim):
-        """sin 0 / cos 1 ``[T, D]`` f32: the rotation that leaves every
-        value as it is (``x * 1 + rot(x) * 0``, exact), for layers
-        without positional encoding."""
-        t = self.tokens
-        return self._shared(
-            ("norope", head_dim), "serving_no_rope",
-            lambda p: (jnp.zeros((t, head_dim), jnp.float32),
-                       jnp.ones((t, head_dim), jnp.float32)), self.pos)
 
     def ring_tables(self, window):
         """``[R, W]`` tables of the layers that keep ``window`` keys:

@@ -498,6 +498,7 @@ class Request:
         self._cancel_requested = False  # honored at (re-)admission
         self._cached_tokens = 0       # prefix tokens served from cache
         self._prefilled = 0           # prompt tokens written to pages
+        self._draft_tok = None        # the model's own draft, if any
         self._tier_key = None         # host-tier handle while paused
         self._tier_tokens = 0         # context length of the parked KV
 
@@ -584,6 +585,30 @@ class LlamaServingEngine:
         # `PagedLatent`, a `SlotState`, `SharedPages` of another layer,
         # or None
         specs = [layer.serving_cache() for layer in model.model.layers]
+        # self-speculative decoding (ROADMAP item 3a): a drafter proposes
+        # up to spec_k tokens per live decoder; the scheduler packs each
+        # speculating row into the mixed step as a (q_len = k+1) chunk
+        # and batched verification accepts the longest exactly-matching
+        # prefix — greedy outputs stay token-exact, rejected draft pages
+        # roll back via the allocator. spec_k=0 (default) disables. The
+        # drafter is ``drafter_factory``'s (one a sequence; an n-gram /
+        # prompt-lookup drafter by default), or, of a model that states
+        # a drafting module (``model.serving_drafter()``) and given no
+        # factory, that module: it runs inside the step program on every
+        # packed position, over pages of its own, and its argmax after
+        # a row's last committed token is the row's one draft
+        if spec_k is None:
+            spec_k = int(os.environ.get("PADDLE_TPU_SPEC_K", "0") or 0)
+        self.spec_k = max(0, min(int(spec_k), self.chunk_block - 1))
+        stated = getattr(model, "serving_drafter", None)
+        self._drafter = stated() if stated is not None and self.spec_k \
+            and drafter_factory is None else None
+        if self._drafter is not None:
+            specs.append(self._drafter.serving_cache())
+            if not isinstance(specs[-1], PagedKV) or specs[-1].window:
+                raise UnsupportedServingFeature(
+                    "a drafting module keeps pages of the whole context "
+                    "(a PagedKV without a window)")
         mixed_caches = any(not isinstance(sp, list) for sp in specs)
         # a row's scan starts from its slot's state and writes it back:
         # two rows of one sequence in one dispatch would both start
@@ -753,16 +778,6 @@ class LlamaServingEngine:
             from ..ops.ragged_paged_attention import small_tile
             g = cfg.num_attention_heads // hk
             self._tile_tokens = small_tile(g) // g
-        # self-speculative decoding (ROADMAP item 3a): an n-gram /
-        # prompt-lookup drafter proposes up to spec_k tokens per live
-        # decoder; the scheduler packs each speculating row into the
-        # mixed step as a (q_len = k+1) chunk and batched verification
-        # accepts the longest exactly-matching prefix — greedy outputs
-        # stay token-exact, rejected draft pages roll back via the
-        # allocator. spec_k=0 (default) disables.
-        if spec_k is None:
-            spec_k = int(os.environ.get("PADDLE_TPU_SPEC_K", "0") or 0)
-        self.spec_k = max(0, min(int(spec_k), self.chunk_block - 1))
         # per-request sampling (ROADMAP item 4): the mixed program
         # grows a vectorized per-row sample step next to the argmax —
         # every sampler knob is runtime data ([R]-shaped arrays), so
@@ -791,6 +806,7 @@ class LlamaServingEngine:
         self._spec_state: dict[int, object] = {}   # seq_id -> drafter
         self._spec_proposed = 0
         self._spec_accepted = 0
+        self._spec_last = (0, 0)    # (proposed, accepted) last applied
         self._spec_idle = 0     # consecutive no-proposal probes
         self._live: dict[int, Request] = {}
         self._m = _serving_metrics()
@@ -893,7 +909,10 @@ class LlamaServingEngine:
         # of the two program shapes wrote it: a row each (a packed token
         # each, of a speculative engine), padded to the longer shape's
         self._inflight = None
-        self._carry_len = self.chunk_budget if self.spec_k \
+        # (an engine whose drafting module runs in the program: a packed
+        # token each, then its draft at the same place in a second half)
+        self._carry_len = self.chunk_budget * (
+            2 if self._drafter is not None else 1) if self.spec_k \
             else self.rows_cap
         self._carry = Tensor(jnp.zeros((self._carry_len,), jnp.int32))
         self._dispatch_times: collections.deque[float] = \
@@ -1143,7 +1162,8 @@ class LlamaServingEngine:
                        kv_lens, q_starts, q_lens, w_starts, w_flats,
                        w_ends, temps, top_ps, top_ks, seeds, slot_ids,
                        slot_vals, cmodes, k_pools, v_pools, k_scales,
-                       v_scales, slots=None, prev_idx=None, prev=None):
+                       v_scales, slots=None, prev_idx=None, prev=None,
+                       next_tok=None):
         """ONE token-packed model step: embed [1, T] real tokens (a mix
         of prefill-chunk tokens, speculative verify tokens and decode
         tokens, back to back with no inter-row padding), ask every
@@ -1186,6 +1206,14 @@ class LlamaServingEngine:
         produces it was still running when this one was built, its
         index in ``prev``, that dispatch's returned tokens; -1 for a
         token that is in ``tokens``.
+
+        An engine whose model drafts its own tokens (``_drafter``) runs
+        the drafting module after the head, on every packed position:
+        from the last hidden state before the final norm and the token
+        that follows the position (``next_tok [1, T]`` where the host
+        knows it, else the token sampled here), over the module's own
+        pool (the last entry of ``_layer_pages``). The tokens returned
+        are then ``[2T]``: the sampled ones, then the drafts.
 
         tokens/pos [1, T]; flat_idx [T];
         last_idx/kv_lens/q_starts/q_lens/w_starts/w_flats/w_ends/
@@ -1242,9 +1270,7 @@ class LlamaServingEngine:
                 stats.append(st)
             for handed in more:
                 step.carry.update(handed)
-        it = iter(flat)
-        new_k, new_v, new_ks, new_vs = ([next(it) for _ in g]
-                                        for g in groups)
+        hidden = x
         if len(stats) > 1:
             # one array, so the host reads the layers' counters in one
             # copy beside the tokens
@@ -1309,6 +1335,19 @@ class LlamaServingEngine:
                 else:
                     nxt = search.argmax(logits, axis=-1).astype("int64") \
                         .reshape([r_rows])
+        if self._drafter is not None:
+            idx = self._layer_pages[-1]
+            drafts, pages = self._drafter.serving_draft(
+                self.model, hidden, next_tok, nxt, step,
+                [flat[i] for i in idx])
+            for i, page in zip(idx, pages):
+                flat[i] = page
+            nxt = run_op("serving_drafts", lambda a, b: jnp.concatenate(
+                [a, b.astype(a.dtype)]), (nxt, drafts),
+                differentiable=False)
+        it = iter(flat)
+        new_k, new_v, new_ks, new_vs = ([next(it) for _ in g]
+                                        for g in groups)
         return nxt, new_k, new_v, new_ks, new_vs, stats
 
     def _dispatch_layout(self, t_cap):
@@ -1328,7 +1367,8 @@ class LlamaServingEngine:
             lay = self._layouts[t_cap] = DispatchLayout(
                 t_cap, r_cap, qb, self.width, self.sample_slots,
                 self.trash_page,
-                trash_slot=self.max_batch if self._slotted else None)
+                trash_slot=self.max_batch if self._slotted else None,
+                next_tokens=self._drafter is not None)
         return lay
 
     def _mixed_packed(self, packed, prev, k_pools, v_pools, k_scales,
@@ -1349,12 +1389,23 @@ class LlamaServingEngine:
         if packed.shape[0] != layout.size:
             layout = self._dispatch_layout(self.max_batch)
         fields = [Tensor(a) for a in layout.unpack(packed._data)]
-        # a model that keeps a state or a ring a slot has a 20th field
+        # a model that drafts in the program has a last field, and one
+        # that keeps a state or a ring a slot a 20th
+        next_tok = fields.pop() if self._drafter is not None else None
         slots = fields.pop() if self._slotted else None
         prev_idx = fields.pop()
         nxt, *rest = self._mixed_forward(
             *fields, k_pools, v_pools, k_scales, v_scales, slots=slots,
-            prev_idx=prev_idx, prev=prev)
+            prev_idx=prev_idx, prev=prev, next_tok=next_tok)
+        if self._drafter is not None:
+            # the sampled tokens and the drafts, each padded to one half
+            t, half = nxt.shape[0] // 2, self._carry_len // 2
+            nxt = run_op("serving_token_carry",
+                         lambda a: jnp.pad(
+                             a.astype(jnp.int32).reshape(2, t),
+                             ((0, 0), (0, half - t))).reshape(-1),
+                         (nxt,), differentiable=False)
+            return (nxt, *rest)
         pad = self._carry_len - nxt.shape[0]
         nxt = run_op("serving_token_carry",
                      lambda a: jnp.pad(a.astype(jnp.int32), (0, pad)),
@@ -1468,6 +1519,11 @@ class LlamaServingEngine:
         position, so mid-dispatch draft positions can't consult it."""
         if r.sampling is not None and r.sampling.constraint is not None:
             return ()
+        if self._drafter is not None:
+            # what the model's own module drafted after the row's last
+            # committed token, in the dispatch that committed it
+            d = r._draft_tok
+            return (d,) if d is not None and kcap >= 1 else ()
         st = self._spec_state.get(r.seq_id)
         if st is None:
             st = self._spec_state[r.seq_id] = self._drafter_factory()
@@ -1780,6 +1836,9 @@ class LlamaServingEngine:
         w_starts, w_flats, w_ends = (f["w_starts"], f["w_flats"],
                                      f["w_ends"])
         slots = f.get("slots")
+        # (of a drafting engine) the prompt token after a packed prompt
+        # position, -1 after a prompt's last and after a decode token
+        next_tok = f.get("next_tok")
         seq_first: dict[int, tuple] = {}     # sid -> (w_start, w_flat)
         seq_last: dict[int, int] = {}        # sid -> w_end
         t = 0
@@ -1793,6 +1852,9 @@ class LlamaServingEngine:
             if slots is not None:
                 slots[i] = self.alloc.slot_of(sid)
             tokens[0, t:t + n] = toks
+            if next_tok is not None and not is_dec:
+                nt = r.prompt_ids[start + 1:start + n + 1]
+                next_tok[0, t:t + len(nt)] = nt
             pos[0, t:t + n] = start + np.arange(n)
             flat_idx[t:t + n] = i * qb + np.arange(n)
             flat_start.append(t)
@@ -1896,6 +1958,7 @@ class LlamaServingEngine:
         # still live (an emission below may retire it and release
         # everything — rollback after that would touch a freed table)
         accepted: dict[int, int] = {}
+        proposed0, accepted0 = self._spec_proposed, self._spec_accepted
         if any(is_dec and n > 1 for *_, n, _, is_dec in rows):
             with self._lock:
                 for i, (r, sid, start, n, toks, is_dec) \
@@ -1924,11 +1987,16 @@ class LlamaServingEngine:
                 if self._spec_proposed:
                     self._m["spec_rate"].set(
                         self._spec_accepted / self._spec_proposed)
+        self._spec_last = (self._spec_proposed - proposed0,
+                           self._spec_accepted - accepted0)
         emitted = stale = 0
         dec_rows = dec_tokens = 0
         # spec engines index `out` by flat token position ([T] argmax);
-        # plain engines by row ([R] last-position argmax)
+        # plain engines by row ([R] last-position argmax); a drafting
+        # engine's drafts sit in the second half, at the same places
         by_pos = bool(self.spec_k)
+        drafts = out[len(out) // 2:] if self._drafter is not None \
+            else None
         for i, (r, sid, start, n, toks, is_dec) in enumerate(rows):
             if r.done or r.seq_id != sid:
                 stale += 1
@@ -1939,7 +2007,10 @@ class LlamaServingEngine:
                 # (greedy-exact by construction); _emit retires at EOS
                 # or max_new_tokens, discarding the accepted tail
                 dec_rows += 1
-                for j in range(accepted.get(i, 0) + 1):
+                acc = accepted.get(i, 0)
+                if drafts is not None:
+                    r._draft_tok = int(drafts[f + acc])
+                for j in range(acc + 1):
                     if r.done:
                         break
                     self._emit(r, int(out[f + j] if by_pos else out[i]))
@@ -1948,6 +2019,8 @@ class LlamaServingEngine:
             elif (start + n) >= len(r.prompt_ids):
                 # FINAL prompt chunks emit their last position; a mid-
                 # prompt chunk's argmax is meaningless and discarded
+                if drafts is not None:
+                    r._draft_tok = int(drafts[f + n - 1])
                 self._emit(r, int(out[f + n - 1] if by_pos else out[i]))
                 emitted += 1
         if accepted and dec_rows:
@@ -3019,6 +3092,10 @@ class LlamaServingEngine:
             applied.set(emitted=emitted)
         said = fin.said
         disp.set(step=fin.step, stale_rows=stale, **said)
+        if self.spec_k:
+            # the drafts this dispatch verified, and those it accepted
+            disp.set(spec_proposed=self._spec_last[0],
+                     spec_accepted=self._spec_last[1])
         if layer_stats is not None:
             # per expert layer [experts that got a row, rows of the
             # largest group]: the medians over the layers; and the
@@ -3194,6 +3271,11 @@ class LlamaServingEngine:
                 "the decode scan does not carry sequence slots: a model "
                 "whose layers keep a state or a window decodes by "
                 "single steps")
+        if self._drafter is not None:
+            raise UnsupportedServingFeature(
+                "the decode scan runs no drafting module: an engine "
+                "whose model drafts its own tokens decodes by single "
+                "steps")
         sf = self._scan_static.get(n)
         if sf is None:
             from ..jit import StaticFunction
@@ -3418,9 +3500,12 @@ class LlamaServingEngine:
                         else self._spec_idle + 1
                 if not live:
                     chunk = 1       # pump parked requests via a step
-                elif prefilling or constrained or self._slotted:
-                    # (the scan does not carry the rows' slots: a model
-                    # that keeps a state or a ring decodes step by step)
+                elif prefilling or constrained or self._slotted \
+                        or self._drafter is not None:
+                    # (the scan does not carry the rows' slots, nor run
+                    # a drafting module: a model that keeps a state or a
+                    # ring, or drafts its own tokens, decodes step by
+                    # step)
                     chunk = 1
                 elif spec_now:
                     # speculation rides the mixed step: one dispatch

@@ -26,6 +26,10 @@ from .mellum import (  # noqa: F401
     MellumConfig, MellumAttention, MellumDecoderLayer, MellumModel,
     MellumForCausalLM, tiny_mellum_config,
 )
+from .exaone_moe import (  # noqa: F401
+    ExaoneMoeConfig, ExaoneMoeAttention, ExaoneMoeDecoderLayer, ExaoneMoeMtp,
+    ExaoneMoeModel, ExaoneMoeForCausalLM, tiny_exaone_moe_config,
+)
 from .llama_pipe import LlamaForCausalLMPipe  # noqa: F401
 from .bert import (  # noqa: F401
     BertConfig, BertModel, BertForSequenceClassification,
@@ -46,6 +50,9 @@ __all__ = [
     "KimiLinearModel", "KimiLinearForCausalLM", "tiny_kimi_linear_config",
     "MellumConfig", "MellumAttention", "MellumDecoderLayer", "MellumModel",
     "MellumForCausalLM", "tiny_mellum_config",
+    "ExaoneMoeConfig", "ExaoneMoeAttention", "ExaoneMoeDecoderLayer",
+    "ExaoneMoeMtp", "ExaoneMoeModel", "ExaoneMoeForCausalLM",
+    "tiny_exaone_moe_config",
     "BertConfig", "BertModel", "BertForSequenceClassification",
     "BertForTokenClassification", "ErnieModel",
     "ErnieForSequenceClassification", "ernie_base_config",

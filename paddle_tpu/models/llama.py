@@ -395,12 +395,15 @@ def _mesh_attention(q, k, v, mesh, batch_axes, tp_axis):
 class LlamaAttention(nn.Layer):
     """GQA attention with rotary embeddings; [B, S, H, D] layout throughout
     so the Pallas flash kernel path needs no relayout. In serving, a
-    layer whose queries see the last ``window`` keys only and a layer
+    layer whose queries see the last ``window`` keys only, a layer
     whose rope is YaRN's (``yarn``, a tuple: `ops.ragged_paged_attention.
-    rope_tables`) say so here; a Llama layer does neither."""
+    rope_tables`) and a layer that rotates nothing (``rotary`` False)
+    say so here, and a layer that normalises q and k overrides
+    `serving_qk`; a Llama layer does none of these."""
 
     window = None
     yarn = None
+    rotary = True
 
     def __init__(self, config: LlamaConfig):
         super().__init__()
@@ -465,8 +468,19 @@ class LlamaAttention(nn.Layer):
             out = F.scaled_dot_product_attention(q, k, v, is_causal=True)
         return self.o_proj(out.reshape([b, s, h * d]))
 
+    def serving_qk(self, q, k):
+        """What the packed step does to q ``[T, H, D]`` and k ``[T, Hk,
+        D]`` between the projections and the ragged kernel (which
+        rotates them): nothing, for a Llama layer."""
+        return q, k
+
 
 class LlamaDecoderLayer(nn.Layer):
+    #: a layer whose attention adds ``post_attention_layernorm(attn(x))``
+    #: to ``x`` (no input norm) says so; a Llama layer adds ``attn(
+    #: input_layernorm(x))``
+    post_norm = False
+
     def __init__(self, config: LlamaConfig):
         super().__init__()
         self.input_layernorm = nn.RMSNorm(config.hidden_size,
@@ -529,7 +543,9 @@ class LlamaDecoderLayer(nn.Layer):
         transcendentals in-kernel: Mosaic and XLA then agree bit for
         bit) and writes each row's K/V into its pages in-grid. A window
         layer's pools are rings a sequence slot, behind the step's ring
-        tables."""
+        tables; a layer that rotates nothing hands the kernel no tables,
+        and a post-norm layer (``post_norm``) normalises the attention's
+        output instead of its input."""
         from ..ops.ragged_paged_attention import \
             fused_ragged_paged_attention
 
@@ -538,14 +554,16 @@ class LlamaDecoderLayer(nn.Layer):
         window = att.window
         with scope("attn"):
             rsin, rcos = step.rope(att.head_dim,
-                                   float(att.config.rope_theta), att.yarn)
+                                   float(att.config.rope_theta), att.yarn) \
+                if att.rotary else (None, None)
             with scope("attn_proj"):
-                h = self.input_layernorm(x)
+                h = x if self.post_norm else self.input_layernorm(x)
                 q = att.q_proj(h).reshape([t, att.num_heads, att.head_dim])
                 k = att.k_proj(h).reshape([t, att.num_kv_heads,
                                            att.head_dim])
                 v = att.v_proj(h).reshape([t, att.num_kv_heads,
                                            att.head_dim])
+            q, k = att.serving_qk(q, k)
             sidecars = dict(k_scale=pages[2], v_scale=pages[3]) \
                 if step.kv_quant else {}
             tables = step.tables if window is None \
@@ -560,7 +578,9 @@ class LlamaDecoderLayer(nn.Layer):
                 attn = step.unpack(attn4.reshape(
                     [step.rows * qb, att.num_heads, att.head_dim]))
             with scope("attn_proj"):
-                x = x + att.o_proj(attn.reshape([1, t, -1]))
+                o = att.o_proj(attn.reshape([1, t, -1]))
+                x = x + (self.post_attention_layernorm(o) if self.post_norm
+                         else o)
         return x, pages
 
 

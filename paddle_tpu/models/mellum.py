@@ -185,7 +185,7 @@ class MellumDecoderLayer(LlamaDecoderLayer):
     def serving_unsupported(self):
         """Engine features that reach no ring of pages (or no packed
         expert layer) yet, beside the Llama layer's own."""
-        return ("prefix_cache", "kv_dtype=int8", "kv_tier", "spec_k",
+        return ("prefix_cache", "kv_dtype=int8", "kv_tier",
                 "weight_dtype=int8") + super().serving_unsupported
 
     def serving_cache(self):

@@ -313,19 +313,28 @@ def serving_ffn(layer, x, step):
     (padding tokens get no expert row), None of a dense one. The norm,
     a dense MLP or the shared expert and the residual add lie under the
     scope ``mlp``; the routed experts, which run between the norm and
-    the shared expert, under ``moe`` alone."""
+    the shared expert, under ``moe`` alone.
+
+    A post-norm layer (``layer.post_norm``) runs ``x + RMSNorm(FFN(x))``
+    instead, the norm its ``post_feedforward_layernorm``."""
     mlp = layer.mlp
+    post = getattr(layer, "post_norm", False)
+    norm_in = (lambda a: a) if post else layer.post_attention_layernorm
+
+    def add(y):
+        return x + (layer.post_feedforward_layernorm(y) if post else y)
+
     if not layer.is_moe:
         with scope("mlp"):
-            return x + mlp(layer.post_attention_layernorm(x)), None
+            return add(mlp(norm_in(x))), None
     with scope("mlp"):
-        h = layer.post_attention_layernorm(x)
+        h = norm_in(x)
         valid = step.token_valid()
     routed = mlp.routed(h, valid)
     with scope("mlp"):
         if mlp.shared is None:
-            return x + routed, expert_stats(mlp)
-        return x + (routed + mlp.shared(h)), expert_stats(mlp)
+            return add(routed), expert_stats(mlp)
+        return add(routed + mlp.shared(h)), expert_stats(mlp)
 
 
 class MlaMoeMLP(nn.Layer):

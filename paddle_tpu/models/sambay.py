@@ -432,14 +432,13 @@ class DiffAttention(nn.Layer):
 
         q, k, v = run_op("sambay_diff_operands", operands, (self.qkv(u),),
                          differentiable=False)
-        sin, cos = step.no_rope(d2)
         tables = step.tables if self.window is None \
             else step.ring_tables(self.window)
         attn4, kp, vp = fused_ragged_paged_attention(
             q, k, v, pages[0], pages[1], tables, step.kv_lens,
             step.q_starts, step.q_lens, step.w_starts, step.w_flats,
-            step.w_ends, pages[0].shape[0] - 1, rope_sin=sin,
-            rope_cos=cos, qblock=qb, scale=1.0 / math.sqrt(self.d),
+            step.w_ends, pages[0].shape[0] - 1, rope_sin=None,
+            rope_cos=None, qblock=qb, scale=1.0 / math.sqrt(self.d),
             window=self.window, read_only=self.kind == "cross")
         attn = step.unpack(attn4.reshape([step.rows * qb, self.heads, d2]))
         o = run_op("sambay_diff_combine", self._combine,

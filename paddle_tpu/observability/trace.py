@@ -50,7 +50,9 @@ __all__ = ["span", "record", "TraceBuffer", "default_buffer", "get_events",
 #: compute; a name is declared here once. Nested as the serving step
 #: nests them: ``attn`` holds ``attn_proj`` and, of a Llama layer,
 #: ``attn_window`` or ``attn_full``, which hold ``attn_pack`` and the
-#: kernel (it keeps its own ``pallas_call`` name), ``ssm`` holds
+#: kernel (it keeps its own ``pallas_call`` name), and of a layer with
+#: q/k norms ``qk_norm``; ``mtp`` holds a drafting module's whole layer
+#: (its ``attn``, ``mlp``, ``moe``) and its head; ``ssm`` holds
 #: ``ssm_scan``, ``kda`` holds ``kda_scan``, and ``diff_attn`` holds
 #: ``attn_pack``. PERF.md section 3 names the metric that reads each.
 SCOPES = {part: "paddle_tpu." + part for part in (
@@ -61,6 +63,8 @@ SCOPES = {part: "paddle_tpu." + part for part in (
     "attn_pack",    # packing and unpacking around a ragged kernel call
     "attn_window",  # a window layer's ragged kernel call and its packing
     "attn_full",    # a whole-context layer's ragged kernel call, packing
+    "qk_norm",      # per-head norms of q and k before the kernel call
+    "mtp",          # the drafting module: its input, layer, head, argmax
     "mlp",          # a dense MLP or the shared expert, norm included
     "norm",         # input norm and residual add around a scoped mixer
     "moe",          # routed experts: router, sort, gathers, combine

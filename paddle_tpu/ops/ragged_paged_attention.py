@@ -78,7 +78,10 @@ The rotation happens in VMEM — ``x * cos + rotate_half(x) * sin`` in
 f32, cast back to the model dtype — before the write/attention math:
 the transcendentals live in the XLA-computed tables, so the kernels
 add only IEEE-exact multiplies and adds, and the pool bytes they write
-are bitwise those of the rope-then-scatter reference.
+are bitwise those of the rope-then-scatter reference. A float-pool
+layer with no positional encoding passes no tables (``rope_sin`` and
+``rope_cos`` None): its program is built without them and rotates
+nothing.
 
 The float program (`_fused_rope_kernel`, every float-pool engine's)
 walks the K/V a row HOLDS: grid (R,), the pools left in HBM, and
@@ -267,7 +270,8 @@ def fused_supported(q, new_k, new_v, k_pages, v_pages, block_tables,
                     k_scale=None, v_scale=None):
     """Preconditions of `fused_ragged_paged_attention` (shapes in the
     module docstring): packed pre-rope ``q [T, H, D]`` with ``new_k/
-    new_v [T, Hk, D]`` (T >= 1) and sin/cos tables ``[T, D]``, pools
+    new_v [T, Hk, D]`` (T >= 1) and sin/cos tables ``[T, D]`` (both
+    None: a float-pool layer that rotates nothing), pools
     ``[P, Hk, page, D]`` (page % 8 == 0, D % 8 == 0, D <= 256 and even,
     H % Hk == 0), tables ``[R, W]``, the seven per-row arrays ``[R]``,
     ``qblock >= 1``, a ``dump_page`` id inside the pool (a page no live
@@ -298,7 +302,10 @@ def fused_supported(q, new_k, new_v, k_pages, v_pages, block_tables,
         return False
     if t < 1 or shape(new_k) != (t, hk, d) or shape(new_v) != (t, hk, d):
         return False
-    if shape(rope_sin) != (t, d) or shape(rope_cos) != (t, d):
+    if rope_sin is None and rope_cos is None:
+        if k_scale is not None:
+            return False        # the int8 program always rotates
+    elif shape(rope_sin) != (t, d) or shape(rope_cos) != (t, d):
         return False
     try:
         return int(qblock) >= 1 and 0 <= int(dump_page) < p
@@ -418,7 +425,9 @@ def _fused_rope_kernel(tables_ref, kv_lens_ref, q_starts_ref,
     dtype — happens here with no transcendentals (the tables carry
     them). new_k/new_v ``[Hk, tpad, D]`` arrive as the pools store
     them: `_fused_rope_impl` has roped new_k's few rows on the way in
-    (`_rope_rows`), once a call and not once a reader.
+    (`_rope_rows`), once a call and not once a reader. A layer that
+    rotates nothing is handed no tables (``sin_ref`` and ``cos_ref``
+    None): q is only cast and scaled.
 
     A block that reaches into this dispatch's write span ``[w_start,
     kv_len)`` overlays the fresh rows from the packed operands in the
@@ -529,9 +538,12 @@ def _fused_rope_kernel(tables_ref, kv_lens_ref, q_starts_ref,
         def _rope(n):
             toks = -(-n // group)           # the tokens of n softmax rows
             qv = q_ref[:, pl.ds(f0q, toks), :, :]     # [Hk, toks, G, D]
-            sin_q = sin_ref[pl.ds(f0q, toks), :][None, :, None, :]
-            cos_q = cos_ref[pl.ds(f0q, toks), :][None, :, None, :]
-            q_rot = (qv * cos_q + _rot_half(qv) * sin_q).astype(dtype)
+            if sin_ref is None:
+                q_rot = qv.astype(dtype)
+            else:
+                sin_q = sin_ref[pl.ds(f0q, toks), :][None, :, None, :]
+                cos_q = cos_ref[pl.ds(f0q, toks), :][None, :, None, :]
+                q_rot = (qv * cos_q + _rot_half(qv) * sin_q).astype(dtype)
             q_s[:, pl.ds(0, toks * group)] = q_rot.reshape(
                 hk, toks * group, qv.shape[-1]).astype(jnp.float32) * scale
 
@@ -702,11 +714,23 @@ def _fused_write_map(page_size, dump_page):
 
 @functools.lru_cache(maxsize=32)
 def _make_fused_rope(scale, page_size, bpages, qblock, group, dtype,
-                     interpret, window=None, read_only=False):
+                     interpret, window=None, read_only=False,
+                     rotate=True):
     bt = bpages * page_size
+    kernel = functools.partial(
+        _fused_rope_kernel, page_size=page_size, bpages=bpages,
+        group=group, scale=scale, qblock=qblock, dtype=dtype,
+        window=window, read_only=read_only)
+    if not rotate:
+        def kernel(*refs, rotating=kernel):
+            # the seven scalar tables, q, the two pools and the two new
+            # row operands come first; no sin/cos tables follow them
+            return rotating(*refs[:12], None, None, *refs[12:])
 
-    def call(qp, k_pages, v_pages, nk, nv, sin, cos, tables, kv_lens,
-             q_starts, q_lens, w_starts, w_flats, w_ends):
+    def call(qp, k_pages, v_pages, nk, nv, *rest):
+        sin, cos = rest[:2] if rotate else (None, None)
+        (tables, kv_lens, q_starts, q_lens, w_starts, w_flats,
+         w_ends) = rest[-7:]
         hk, tq, g, d = qp.shape
         tpad = nk.shape[1]
         r = tables.shape[0]
@@ -725,9 +749,7 @@ def _make_fused_rope(scale, page_size, bpages, qblock, group, dtype,
                 pl.BlockSpec(memory_space=pl.ANY),
                 whole(hk, tpad, d),
                 whole(hk, tpad, d),
-                whole(tq, d),
-                whole(tq, d),
-            ],
+            ] + [whole(tq, d)] * (2 if rotate else 0),
             out_specs=[
                 pl.BlockSpec((1, hk, qbg, d),
                              lambda ri, *refs: (ri, 0, 0, 0)),
@@ -750,15 +772,12 @@ def _make_fused_rope(scale, page_size, bpages, qblock, group, dtype,
         # operand; tiles pad G and the softmax columns
         f32 = 4
         operands = hk * tq * -(-g // 8) * 8 * d * f32 \
-            + 2 * hk * tpad * d * f32 + 2 * tq * d * f32 \
+            + 2 * hk * tpad * d * f32 + 2 * rotate * tq * d * f32 \
             + hk * qbg * d * jnp.dtype(dtype).itemsize
         scratch = 4 * hk * bt * d * k_pages.dtype.itemsize \
             + hk * qbg * (2 * d + 2 * 128) * f32
         return pl.pallas_call(
-            functools.partial(_fused_rope_kernel, page_size=page_size,
-                              bpages=bpages, group=group, scale=scale,
-                              qblock=qblock, dtype=dtype, window=window,
-                              read_only=read_only),
+            kernel,
             grid_spec=grid_spec,
             out_shape=[
                 jax.ShapeDtypeStruct((r, hk, qbg, d), dtype),
@@ -778,7 +797,8 @@ def _make_fused_rope(scale, page_size, bpages, qblock, group, dtype,
             interpret=interpret,
             name="paddle_tpu.ragged_attn_fused_rope",
         )(tables, kv_lens, q_starts, q_lens, w_starts, w_flats, w_ends,
-          qp, k_pages, v_pages, nk, nv, sin, cos)
+          qp, k_pages, v_pages, nk, nv,
+          *((sin, cos) if rotate else ()))
 
     # jitted, so that the layers of one step program share ONE trace
     # and ONE lowering of the kernel (they call it with equal shapes)
@@ -908,7 +928,8 @@ def _fused_rope_impl(q, new_k, new_v, k_pages, v_pages, block_tables,
                      qblock, window=None, read_only=False):
     """``dump_page`` is part of the fused programs' common signature
     and unused here: a step of this program that has nothing to write
-    writes nothing."""
+    writes nothing. ``rope_sin`` and ``rope_cos`` None: nothing is
+    rotated, and the program takes no tables."""
     t, h, d = q.shape
     hk = k_pages.shape[1]
     group = h // hk
@@ -929,20 +950,22 @@ def _fused_rope_impl(q, new_k, new_v, k_pages, v_pages, block_tables,
     # kernel's operands. The kernel's operands and its output are
     # packed under one scope, every caller's; the kernel is named
     with scope("attn_pack"):
+        rotate = rope_sin is not None
         qp = _pack_new_q(q, t, group, 0, tq)
-        nk = _pack_new_rows(_rope_rows(new_k, rope_sin, rope_cos), t, bt,
-                            tpad, k_pages.dtype)
+        nk = _pack_new_rows(_rope_rows(new_k, rope_sin, rope_cos)
+                            if rotate else new_k, t, bt, tpad,
+                            k_pages.dtype)
         nv = _pack_new_rows(new_v, t, bt, tpad, v_pages.dtype)
-        sin = _pack_rope_table(rope_sin, t, 0, tq)
-        cos = _pack_rope_table(rope_cos, t, 0, tq)
+        tabs = (_pack_rope_table(rope_sin, t, 0, tq),
+                _pack_rope_table(rope_cos, t, 0, tq)) if rotate else ()
         tables = jnp.clip(block_tables.astype(jnp.int32), 0,
                           k_pages.shape[0] - 1)
         meta = [a.astype(jnp.int32) for a in (kv_lens, q_starts, q_lens,
                                                 w_starts, w_flats, w_ends)]
     call = _make_fused_rope(scale, page_size, bpages, qblock, group,
                             jnp.dtype(q.dtype), _interpret(), window,
-                            read_only)
-    out, kp, vp = call(qp, k_pages, v_pages, nk, nv, sin, cos, tables,
+                            read_only, rotate)
+    out, kp, vp = call(qp, k_pages, v_pages, nk, nv, *tabs, tables,
                        *meta)
     with scope("attn_pack"):
         out = out.reshape(r, hk, qblock, group, d) \
@@ -1004,7 +1027,9 @@ def fused_ragged_paged_attention(q, new_k, new_v, k_pages, v_pages,
     ``w_flats[r]`` that position's index on the packed token axis,
     ``w_ends[r]`` the sequence's final kv_len in this dispatch (so the
     last row owns the write-back). ``qblock`` is the row-block width
-    the metadata was built for. ``window`` (float pools only): a query
+    the metadata was built for. ``rope_sin`` and ``rope_cos`` None
+    (float pools only): a layer with no positional encoding, whose q
+    and k are not rotated. ``window`` (float pools only): a query
     at position ``p`` sees the keys ``(p - window, p]``, and a row
     walks the pages that hold them and no others. ``read_only``
     (float pools only): the pools already hold the dispatch's K/V rows

@@ -180,8 +180,9 @@ def test_walk_compiles_at_mistral_widths(chip_compile, rows, tokens,
 
 # the float program as the hybrid family's three attention kinds call
 # it: 40 padded query heads over 10 pairs of key heads (128 lanes), page
-# 16, behind the window layers' ring tables (window 512), the shared
-# pool's tables, and read-only for the layers that own no pool
+# 16, no rope tables (the model has no positional encoding), behind the
+# window layers' ring tables (window 512), the shared pool's tables, and
+# read-only for the layers that own no pool
 @pytest.mark.parametrize("kind", ["window", "full", "cross"])
 @pytest.mark.parametrize("rows,tokens,qblock,group", [
     (102, 192, 32, 4), (96, 96, 1, 4), (102, 192, 32, 8)], ids=SHAPE_IDS)
@@ -192,9 +193,9 @@ def test_walk_compiles_for_window_and_cross_layers(chip_compile, rows,
     fn = functools.partial(
         rpa._fused_rope_impl, dump_page=pool[0] - 1, scale=0.125,
         qblock=qblock, window=512 if kind == "window" else None,
-        read_only=kind == "cross")
+        read_only=kind == "cross", rope_sin=None, rope_cos=None)
     compiled = chip_compile(fn, *_ragged_specs(False, pool, rows, tokens,
-                                               320, group))
+                                               320, group)[:-2])
     _assert_kernel(compiled, "ragged_attn_fused_rope")
 
 
@@ -215,6 +216,30 @@ def test_walk_compiles_at_the_code_cell(chip_compile, rows, tokens, qblock,
         qblock=qblock, window=1024 if window else None)
     compiled = chip_compile(fn, *_ragged_specs(False, pool, rows, tokens,
                                                1089, 8))
+    _assert_kernel(compiled, "ragged_attn_fused_rope")
+
+
+# the float program as the MTP-drafting family calls it: 64 query heads
+# over 8 kv heads of 128 (a group of 8), page 16, behind the window
+# layers' ring tables (window 128, 65 slots of 41 pages) and, with no
+# rope tables (the NoPE full layers and the MTP module's), the whole-
+# context pool of the reason-mtp cell (16,385 pages, tables 769 wide),
+# both of its step shapes
+@pytest.mark.parametrize("kind", ["window", "nope"])
+@pytest.mark.parametrize("rows,tokens,qblock", [(80, 512, 32), (64, 64, 1)],
+                         ids=["mixed", "decode"])
+def test_walk_compiles_at_the_reason_mtp_cell(chip_compile, rows, tokens,
+                                              qblock, kind):
+    window = kind == "window"
+    pool = (65 * 41 if window else 16385, 8, 16, 128)
+    fn = functools.partial(
+        rpa._fused_rope_impl, dump_page=pool[0] - 1, scale=D ** -0.5,
+        qblock=qblock, window=128 if window else None)
+    specs = _ragged_specs(False, pool, rows, tokens, 769, 8)
+    if not window:
+        fn = functools.partial(fn, rope_sin=None, rope_cos=None)
+        specs = specs[:-2]
+    compiled = chip_compile(fn, *specs)
     _assert_kernel(compiled, "ragged_attn_fused_rope")
 
 
@@ -421,6 +446,24 @@ def test_packed_grouped_gemm_compiles_for_an_expert_share(chip_compile, m,
     _assert_kernel(chip_compile(
         functools.partial(gg._packed_kernel_impl, block_m=block_m),
         ((m, k), BF16), ((16, k, n), BF16), ((m // block_m,), I32),
+        ((1,), I32)), "grouped_gemm_packed")
+
+
+@pytest.mark.parametrize("m,k,n,block_m", [
+    (5120, 6144, 2048, 128), (5120, 2048, 6144, 128),
+    (1024, 6144, 2048, 64), (1024, 2048, 6144, 64)],
+    ids=["mixed-gate-up", "mixed-down", "decode-gate-up", "decode-down"])
+def test_packed_grouped_gemm_compiles_at_the_reason_mtp_cell(chip_compile, m,
+                                                             k, n, block_m):
+    """8 held experts of width 2048 over hidden 6144: the worst-case
+    packed rows of the reason-mtp cell's 512-token and 64-token steps."""
+    assert gg.packed_block_m(512 * 8, 8, sublane=16) == 128
+    assert gg.packed_rows(512 * 8, 8, 128) == 5120
+    assert gg.packed_block_m(64 * 8, 8, sublane=16) == 64
+    assert gg.packed_rows(64 * 8, 8, 64) == 1024
+    _assert_kernel(chip_compile(
+        functools.partial(gg._packed_kernel_impl, block_m=block_m),
+        ((m, k), BF16), ((8, k, n), BF16), ((m // block_m,), I32),
         ((1,), I32)), "grouped_gemm_packed")
 
 

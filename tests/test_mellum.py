@@ -434,12 +434,29 @@ def test_the_kind_rooflines_read_their_scopes_and_counters(setup, kind):
     (dict(prefix_cache=True), "prefix_cache"),
     (dict(kv_dtype="int8"), "kv_dtype=int8"),
     (dict(kv_tier=True), "kv_tier"),
-    (dict(spec_k=2), "spec_k"),
     (dict(weight_dtype="int8"), "weight_dtype=int8"),
 ])
 def test_features_that_reach_no_ring_are_refused(setup, kw, what):
     with pytest.raises(UnsupportedServingFeature, match=what):
         _engine(setup[2], **kw)
+
+
+def test_ngram_drafts_verify_through_the_rings(setup):
+    """``spec_k`` reaches the rings: n-gram drafts of a repetitive prompt
+    verified as rows of up to 3 tokens through rings of 7 pages that
+    wrap, rejected keys rolled back; the greedy tokens are those of the
+    engine without drafting and every page comes back."""
+    cfg, _, model, _ = setup
+    rng = np.random.default_rng(8)
+    prompts = [list(rng.integers(1, cfg["vocab_size"], 10)) * 4,
+               list(rng.integers(1, cfg["vocab_size"], 29))]
+    plain = [r.output_ids for r in _serve(_engine(model), prompts, 40)]
+    e = _engine(model, spec_k=2)
+    assert [r.output_ids for r in _serve(e, prompts, 40)] == plain
+    stats = e.spec_stats()
+    assert stats["proposed"] > 0
+    assert e.alloc.free_pages == e.alloc.num_pages
+    assert e.alloc.double_free_count == 0
 
 
 def test_prefix_cache_is_off_and_the_scan_refused(setup):

@@ -48,13 +48,17 @@ def test_benchmark_names_the_new_cells_and_their_readers():
     # the linear-attention expert family's cell (PR 37) shares the latent
     # kernel and the packed GEMM: it joined three of the four lists
     assist = "kimi-linear-48b-a3b-ep16.assist-closed"
-    # and the window / full expert family's cell the packed GEMM
+    # and the window / full expert family's cell the packed GEMM; the
+    # MTP-drafting family's (PR 43) its routing, and not the GEMMs'
+    # roofline, whose bytes its family cannot count (PERF.md §6)
     code = "mellum2-12b-a2.5b-l8.code-closed"
+    mtp = "k-exaone-236b-a23b-ep16-l8.reason-mtp-closed"
     for name in ("mla_roofline.tok", "moe_gemm_roofline.tok",
                  "moe_route_ms.tok", "experts_touched.tok"):
         assert per[name]["workloads"] == [doc] + [assist] * (
             name != "experts_touched.tok") + [code] * (
-            name in ("moe_gemm_roofline.tok", "moe_route_ms.tok"))
+            name in ("moe_gemm_roofline.tok", "moe_route_ms.tok")) \
+            + [mtp] * (name == "moe_route_ms.tok")
         assert per[name]["moves"] == "serve_tok_s"
         assert os.path.isfile(bench.reader_path(name))
     assert doc not in per["attn_roofline.tok"]["workloads"]
@@ -65,7 +69,7 @@ def test_benchmark_names_the_new_cells_and_their_readers():
     hybrid = ("ssm_ms.tok", "ssm_scan_roofline.tok",
               "diff_attn_roofline.tok", "window_held_share.tok")
     for name in hybrid:
-        assert per[name]["workloads"] == [reason] + [code] * (
+        assert per[name]["workloads"] == [reason] + [code, mtp] * (
             name == "window_held_share.tok")
         assert per[name]["moves"] == "serve_tok_s"
         assert os.path.isfile(bench.reader_path(name))
@@ -79,26 +83,34 @@ def test_benchmark_names_the_new_cells_and_their_readers():
     # the dense matmuls' time is read in the Mistral cells and
     # in the code cell, whose dense matmuls are its attention projections
     assert per["dense_ms.tok"]["workloads"] == [
-        "mistral-7b-v0.3-l16.classify-closed", sat, code]
+        "mistral-7b-v0.3-l16.classify-closed", sat, code, mtp]
     assert cells[code]["chips"] == 1
     kinds = ("window_attn_roofline.tok", "full_attn_roofline.tok")
     for name in kinds:
-        assert per[name]["workloads"] == [code]
+        assert per[name]["workloads"] == [code, mtp]
         assert per[name]["moves"] == "serve_tok_s"
         assert per[name]["layer"] == per["attn_roofline.tok"]["layer"]
         assert os.path.isfile(bench.reader_path(name))
+    # the MTP-drafting family's cell and its two readers
+    assert cells[mtp]["chips"] == 1
+    drafting = ("mtp_ms.tok", "spec_accept_share.tok")
+    for name in drafting:
+        assert per[name]["workloads"] == [mtp]
+        assert per[name]["moves"] == "serve_tok_s"
+        assert os.path.isfile(bench.reader_path(name))
     for m in SPEC["per_layer"]:
         if m["name"].endswith(".tok") and m["name"] not in hybrid + kda \
-                + kinds + ("mla_roofline.tok", "moe_gemm_roofline.tok",
-                           "moe_route_ms.tok", "experts_touched.tok",
-                           "attn_roofline.tok", "dense_ms.tok"):
+                + kinds + drafting + (
+                    "mla_roofline.tok", "moe_gemm_roofline.tok",
+                    "moe_route_ms.tok", "experts_touched.tok",
+                    "attn_roofline.tok", "dense_ms.tok"):
             assert doc in m["workloads"] and sat in m["workloads"], m
             # every kernel-agnostic .tok metric is read in the new cells
             assert reason in m["workloads"], m
-            assert m["workloads"][-2:] == [assist, code], m
+            assert m["workloads"][-3:] == [assist, code, mtp], m
     serve = next(m for m in SPEC["end_to_end"]
                  if m["name"] == "serve_tok_s")["workloads"]
-    assert reason in serve and serve[-2:] == [assist, code]
+    assert reason in serve and serve[-3:] == [assist, code, mtp]
 
 
 def test_configuration_keeps_every_published_width():

@@ -658,6 +658,25 @@ def test_read_only_call_writes_nothing():
     _assert_parity(jnp.asarray(out), jnp.asarray(want))
 
 
+@pytest.mark.parametrize("window", [None, 20])
+def test_no_rotation_equals_the_identity_tables(window):
+    """A layer without positional encoding passes no tables: the
+    program built without them gives, bit for bit, what tables that do
+    not rotate (sin 0, cos 1) give, outputs and pools alike, for chunk
+    rows and decode rows, with and without a window."""
+    seqs = [(BLOCK + 3, [1]), (7, [8, 5]), (2 * BLOCK - 5, [2])]
+    args, kw = _walk_case(np.random.RandomState(93), seqs, 40, 8)
+    kw = dict(kw, window=window)
+    ident = list(map(_unwrap, RPA.fused_ragged_paged_attention(
+        *args, **dict(kw, rope_sin=jnp.zeros_like(kw["rope_sin"]),
+                      rope_cos=jnp.ones_like(kw["rope_cos"])))))
+    bare = list(map(_unwrap, RPA.fused_ragged_paged_attention(
+        *args, **dict(kw, rope_sin=None, rope_cos=None))))
+    for a, b in zip(ident, bare):
+        assert np.array_equal(a, b)
+    assert not np.array_equal(bare[1], np.asarray(args[3]))
+
+
 # ----------------------------------------------------------------------
 # the small tile (ISSUE 36): a row of a mixed dispatch whose query
 # tokens fit ``small_tile(group)`` softmax rows computes that tile a kv

@@ -56,7 +56,12 @@ FAMILIES = {
     "mellum2-12b-a2.5b-l8": {"embed", "head", "attn", "attn_proj",
                              "attn_window", "attn_full", "attn_pack", "mlp",
                              "moe"},
+    "k-exaone-236b-a23b-ep16-l8": {"embed", "head", "attn", "attn_proj",
+                                   "attn_window", "attn_full", "attn_pack",
+                                   "qk_norm", "mlp", "moe", "mtp"},
 }
+#: the engine options a family's cell serves with, where not the defaults
+ENGINE = {"k-exaone-236b-a23b-ep16-l8": dict(spec_k=1)}
 
 
 def _kernel_names():
@@ -96,7 +101,8 @@ def _programs(name):
     model.eval()
     engine = LlamaServingEngine(model, max_batch=4, page_size=8,
                                 num_pages=65, max_pages_per_seq=16,
-                                chunk_budget=32, chunk_block=8)
+                                chunk_budget=32, chunk_block=8,
+                                **ENGINE.get(name, {}))
     engine.prewarm(mixed=[32, 4])
     texts = [c.as_text() for c in engine._mixed_static._aot.values()]
     engine.close()
