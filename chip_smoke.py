@@ -10,6 +10,10 @@ calls, at published widths, on one TPU:
   and 521 pages wide. It is held against
   ``fused_ragged_paged_attention_xla``, must answer alike at every
   width and must take the same time a layer to within 10%;
+  then (``walk_verify``) at a speculative engine's verify shape, 64
+  query heads over 8 kv heads and 64 rows of a token and its draft in
+  the 512-token program, a NoPE layer and a 128-key window layer,
+  timed with the small tile of one token and of two;
 - ``latent``: the ragged paged LATENT attention kernel alone, 32 heads
   over a 640-lane row (512 + 64 in use) and 24,577 pages of 16:
   docqa-closed's two programs behind tables 161, 521 and 1,057 pages
@@ -298,19 +302,22 @@ WALK_WIDTH_RTOL = 0.10
 
 
 def walk_rows(rng, r_cap, t_cap, qblock, decode_rows, chunks, max_ctx,
-              page_size):
+              page_size, row_tokens=1, min_ctx=None):
     """One dispatch's row metadata with the values `_dispatch_rows` gives
     the fields ``kv_lens q_starts q_lens w_starts w_flats w_ends pos``:
-    ``decode_rows`` sequences one token each, then ``chunks`` rows that
-    continue ONE prompt (consecutive q_starts, one write span), then
-    inactive rows. The engine writes them into one staged buffer
-    (`DispatchLayout`) its program takes apart; the kernels here are fed
-    directly, so they stay separate arrays. Returns the [R] arrays and
-    the packed positions."""
+    ``decode_rows`` sequences of ``row_tokens`` tokens each (a decode
+    row, or a verify row of a token and its drafts) and a context in
+    ``[min_ctx, max_ctx)`` (``max_ctx // 8`` by default), then
+    ``chunks`` rows that continue ONE prompt (consecutive q_starts, one
+    write span), then inactive rows. The engine writes them into one
+    staged buffer (`DispatchLayout`) its program takes apart; the
+    kernels here are fed directly, so they stay separate arrays. Returns
+    the [R] arrays and the packed positions."""
     kv = np.zeros(r_cap, np.int32)
     ql = np.zeros(r_cap, np.int32)
-    kv[:decode_rows] = rng.randint(max_ctx // 8, max_ctx, decode_rows)
-    ql[:decode_rows] = 1
+    lo = max_ctx // 8 if min_ctx is None else min_ctx
+    kv[:decode_rows] = rng.randint(lo, max_ctx, decode_rows)
+    ql[:decode_rows] = row_tokens
     first = int(rng.randint(0, max_ctx - chunks * qblock))
     for c in range(chunks):
         kv[decode_rows + c] = first + (c + 1) * qblock
@@ -444,6 +451,101 @@ def phase_walk(seed, rehearse, on_chip):
                                             "decode_rows", "chunk_rows"),
                                            v))
                                for k, v in programs.items()}),
+         ms_a_layer=timings)
+    del kp, vp
+    gc.collect()
+    walk_verify(seed, rehearse, on_chip)
+
+
+def walk_verify(seed, rehearse, on_chip):
+    """The float program at a speculative engine's verify shape:
+    reason-mtp-closed's 512-token program with 64 verify rows of two
+    tokens (a token and its draft) at contexts of 1-2k, 64 query heads
+    over 8 kv heads (a group of 8: a row's 16 softmax rows), page 16,
+    for a NoPE whole-context layer and a 128-key window layer with the
+    rope. Each is timed with the small tile of one token (8 rows: every
+    verify row computes the whole 32-token block) and of two (the
+    engine's ``1 + spec_k``), beside the layer's bandwidth floor (the
+    keys its rows read, K and V, at 819 GB/s). The tile of two answers
+    the first 16 rows as the XLA reference does; whether it answers bit
+    for bit as the tile of one is reported (``tile_bitwise``)."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import ragged_paged_attention as rpa
+
+    if rehearse:
+        h, hk, d, page, pool, dt = 8, 1, 16, 8, 160, jnp.float32
+        r_cap, t_cap, qb, n_rows, ctx, window = 8, 24, 8, 6, (16, 64), 16
+        layers, reps, checked = 2, 1, 4
+    else:
+        h, hk, d, page, pool, dt = 64, 8, 128, 16, 16385, jnp.bfloat16
+        r_cap, t_cap, qb, n_rows, ctx, window = 80, 512, 32, 64, \
+            (1024, 2048), 128
+        layers, reps, checked = 8, 5, 16
+    rng = np.random.RandomState(seed + 1)
+    trash = pool - 1
+    kv, qs, ql, ws, wf, we, pos, pages = walk_rows(
+        rng, r_cap, t_cap, qb, n_rows, 0, ctx[1], page, row_tokens=2,
+        min_ctx=ctx[0])
+    width = -(-(ctx[1] + 1) // page)
+    owner = np.arange(r_cap)
+    starts = np.concatenate([[0], np.cumsum(pages)[:-1]])
+    tables = jnp.asarray(walk_tables(r_cap, width, trash, owner, starts,
+                                     pages, rng.permutation(trash)))
+    meta = [jnp.asarray(a) for a in (kv, qs, ql, ws, wf, we)]
+    key = jax.random.PRNGKey(seed + 1)
+    kp, vp = (jax.random.normal(k, (pool, hk, page, d), jnp.float32)
+              .astype(dt) for k in jax.random.split(key))
+    q, nk, nv = (jnp.asarray(rng.randn(t_cap, n, d), dt)
+                 for n in (h, hk, hk))
+    scale = d ** -0.5
+    timings = {}
+    for kind in ("full", "window"):
+        win = window if kind == "window" else None
+        sin, cos = rpa.rope_tables(jnp.asarray(pos), d, 1e6) \
+            if win else (None, None)
+        keys = int(np.minimum(kv, win - 1 + ql).sum()) if win \
+            else int(kv.sum())
+        timings[f"{kind}.floor"] = 1e3 * 2 * keys * hk * d \
+            * jnp.dtype(dt).itemsize / 819e9
+        outs = {}
+        for tile in (1, 2):
+            @jax.jit
+            def stack(q, nk, nv, kp, vp, tables, *meta):
+                for _ in range(layers):
+                    out, kp, vp = rpa._fused_rope_impl(
+                        q, nk, nv, kp, vp, tables, *meta, sin, cos,
+                        dump_page=trash, scale=scale, qblock=qb,
+                        window=win, tile_tokens=tile)
+                return out, kp, vp
+
+            outs[tile], ms = best_ms(stack, (q, nk, nv, kp, vp, tables,
+                                             *meta), reps)
+            timings[f"{kind}.tile{tile}"] = ms / layers
+        timings[f"{kind}.tile_bitwise"] = all(
+            bool(jnp.array_equal(a, b)) for a, b in zip(outs[1], outs[2]))
+        # the reference packs q into row blocks only behind tables: a
+        # layer that rotates nothing is what the identity tables give
+        ident = (jnp.zeros((t_cap, d)), jnp.ones((t_cap, d)))
+        ref = rpa.fused_ragged_paged_attention_xla(
+            q, nk, nv, kp, vp, tables[:checked],
+            *(a[:checked] for a in meta), trash, scale=scale,
+            rope_sin=ident[0] if sin is None else sin,
+            rope_cos=ident[1] if cos is None else cos, qblock=qb,
+            window=win)[0]
+        f32 = lambda a: a.astype(jnp.float32)        # noqa: E731
+        err = float(jnp.max(jnp.abs(f32(outs[2][0][:checked]) - f32(ref))))
+        timings[f"{kind}.out_err"] = err
+        tol = 1e-5 if rehearse else 2e-2
+        if not err <= tol * max(1.0, float(jnp.max(jnp.abs(f32(ref))))):
+            raise RuntimeError(f"verify {kind}: kernel and XLA reference "
+                               f"differ: out {err}")
+        del outs, ref
+    emit(phase="walk_verify", device=device_info(),
+         shapes=dict(heads=h, kv_heads=hk, head_dim=d, page_size=page,
+                     num_pages=pool, width=width, rows=r_cap,
+                     tokens=t_cap, qblock=qb, verify_rows=n_rows,
+                     contexts=list(ctx), window=window),
          ms_a_layer=timings)
 
 

@@ -212,6 +212,10 @@ class ServingStep:
         self.tokens = pos.shape[1]
         self.rows, self.qblock = tables.shape[0], int(qblock)
         self.kv_quant, self.trash_page = engine.kv_quant, engine.trash_page
+        #: the most query tokens of a row other than a prompt chunk: a
+        #: decode row's one, or a verify row's token and its drafts (the
+        #: float ragged program's small tile, `small_tile`)
+        self.tile_tokens = 1 + engine.spec_k
         self._tables = {}
 
     def _shared(self, key, name, fn, *args):

@@ -768,16 +768,17 @@ class LlamaServingEngine:
         self.k_scales = scales[0]
         self.v_scales = scales[1] if len(scales) > 1 else []
         # the most query tokens of a row that the float ragged program
-        # computes on its small tile (`small_tile`), at the query heads
-        # a kv head of the K/V pools `[P, Hk, page, D]`; None where no
-        # layer runs that program (latent rows, int8 pages)
+        # computes on its small tile (`small_tile`, sized for a row of
+        # ``1 + spec_k`` tokens as `ServingStep.tile_tokens`), at the
+        # query heads a kv head of the K/V pools `[P, Hk, page, D]`;
+        # None where no layer runs that program (latent rows, int8 pages)
         hk = next((sp.heads for sp in specs if isinstance(sp, PagedKV)),
                   None) if mixed_caches else specs[0][0][0]
         self._tile_tokens = None
         if hk and not self.kv_quant:
             from ..ops.ragged_paged_attention import small_tile
             g = cfg.num_attention_heads // hk
-            self._tile_tokens = small_tile(g) // g
+            self._tile_tokens = small_tile(g, 1 + self.spec_k) // g
         # per-request sampling (ROADMAP item 4): the mixed program
         # grows a vectorized per-row sample step next to the argmax —
         # every sampler knob is runtime data ([R]-shaped arrays), so

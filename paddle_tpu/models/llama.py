@@ -574,7 +574,7 @@ class LlamaDecoderLayer(nn.Layer):
                     step.q_starts, step.q_lens, step.w_starts,
                     step.w_flats, step.w_ends, pages[0].shape[0] - 1,
                     rope_sin=rsin, rope_cos=rcos, qblock=qb, window=window,
-                    **sidecars)
+                    tile_tokens=step.tile_tokens, **sidecars)
                 attn = step.unpack(attn4.reshape(
                     [step.rows * qb, att.num_heads, att.head_dim]))
             with scope("attn_proj"):

@@ -439,7 +439,8 @@ class DiffAttention(nn.Layer):
             step.q_starts, step.q_lens, step.w_starts, step.w_flats,
             step.w_ends, pages[0].shape[0] - 1, rope_sin=None,
             rope_cos=None, qblock=qb, scale=1.0 / math.sqrt(self.d),
-            window=self.window, read_only=self.kind == "cross")
+            window=self.window, read_only=self.kind == "cross",
+            tile_tokens=step.tile_tokens)
         attn = step.unpack(attn4.reshape([step.rows * qb, self.heads, d2]))
         o = run_op("sambay_diff_combine", self._combine,
                    (attn,) + self._lams(), differentiable=False)

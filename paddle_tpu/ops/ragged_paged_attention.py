@@ -94,11 +94,12 @@ attention output equals the XLA reference's to float rounding (1e-5
 relative in f32); a page is written by a DMA of its own and a step
 with nothing to write writes nothing. The softmax rows a row computes
 follow its ``q_lens``: a row whose query tokens fit `small_tile`
-(``group`` rows rounded up to f32 sublane tiles: every decode row of a
-mixed dispatch) ropes, scores, accumulates and finishes that tile, all
-kv heads in one batched dot; every other row the whole ``QB * group``
-block a head at a time. The kernel picks from ``q_lens_ref[r]``: one
-program, the same walk, nothing for a caller to say.
+(``tile_tokens`` tokens' ``group`` rows rounded up to f32 sublane
+tiles: every decode row of a mixed dispatch, and of a speculative
+engine every verify row) ropes, scores, accumulates and finishes that
+tile, all kv heads in one batched dot; every other row the whole ``QB *
+group`` block a head at a time. The kernel picks from ``q_lens_ref[r]``:
+one program, the same walk.
 
 The int8 program (`_fused_rope_kernel_q8`) is still a per-page grid
 (R, Hk, W) with one online-softmax accumulator in VMEM scratch per
@@ -199,13 +200,15 @@ def _softmax_finish(o_ref, acc_ref, l_ref):
     o_ref[...] = jnp.where(l > 0.0, out, 0.0).astype(o_ref.dtype)
 
 
-def small_tile(group):
-    """Softmax rows of the float program's small tile: one query
-    token's ``group`` rows rounded up to whole f32 sublane tiles. A row
-    of a dispatch whose query tokens fit it (``q_len * group <=
-    small_tile(group)``: every decode row) computes that tile a kv
-    head and not its whole query block."""
-    return 8 * -(-group // 8)
+def small_tile(group, tokens=1):
+    """Softmax rows of the float program's small tile: ``tokens`` query
+    tokens' ``group`` rows each, rounded up to whole f32 sublane tiles.
+    A row of a dispatch whose query tokens fit it (``q_len * group <=
+    small_tile(group, tokens)``) computes that tile a kv head and not
+    its whole query block. A serving engine sizes it for the most
+    tokens a row other than a prompt chunk carries, ``1 + spec_k``:
+    a decode row, or a verify row of the token and its drafts."""
+    return 8 * -(-(tokens * group) // 8)
 
 
 def fused_rope_geometry_ok(head_dim):
@@ -408,7 +411,8 @@ def _fused_rope_kernel(tables_ref, kv_lens_ref, q_starts_ref,
                        sin_ref, cos_ref, o_ref, ko_hbm, vo_hbm,
                        kbuf, vbuf, fsem, wsem, acc_ref, m_ref, l_ref,
                        q_s, *, page_size, bpages, group, scale, qblock,
-                       dtype, window=None, read_only=False):
+                       dtype, window=None, read_only=False,
+                       tile_tokens=1):
     """The float rope-fused program: grid ``(R,)``, one step a row,
     the kv heads looped inside. The pools stay in HBM; the row walks
     its OWN context in blocks of ``bpages`` pages: ``ceil(kv_len /
@@ -437,8 +441,9 @@ def _fused_rope_kernel(tables_ref, kv_lens_ref, q_starts_ref,
     anything. One `_softmax_accumulate` update a block and head, at the
     row's size (`sized`): the small tile for a row whose query tokens
     fit it, where the update of all kv heads is one batched dot, else
-    the whole query block a head at a time. Walk, DMAs, overlay and
-    write-back are the same for both.
+    the whole query block a head at a time (the tile holds
+    ``tile_tokens`` query tokens' rows, `small_tile`). Walk, DMAs,
+    overlay and write-back are the same for both.
 
     With ``window`` (a layer whose queries see the last ``window`` keys
     only) the walk starts at the block that holds the first key the
@@ -461,12 +466,13 @@ def _fused_rope_kernel(tables_ref, kv_lens_ref, q_starts_ref,
     nblk = jnp.where(q_len > 0, pl.cdiv(ctx, bt), 0)
 
     # the softmax rows this row computes a kv head: the small tile where
-    # its query tokens fit one (a decode row of a mixed dispatch, or a
-    # row with nothing to do), else the whole query block: a `[8, D] x
-    # [D, bt]` dot sixteen times would starve the MXU on a chunk row. A
-    # block no larger than the tile (the decode-only shape) has one size.
+    # its query tokens fit one (a decode or verify row of a mixed
+    # dispatch, or a row with nothing to do), else the whole query
+    # block: a `[8, D] x [D, bt]` dot sixteen times would starve the MXU
+    # on a chunk row. A block no larger than the tile (the decode-only
+    # shape) has one size.
     qbg = qblock * group
-    tile = small_tile(group)
+    tile = small_tile(group, tile_tokens)
     small = q_len * group <= tile
 
     def sized(fn):
@@ -715,12 +721,12 @@ def _fused_write_map(page_size, dump_page):
 @functools.lru_cache(maxsize=32)
 def _make_fused_rope(scale, page_size, bpages, qblock, group, dtype,
                      interpret, window=None, read_only=False,
-                     rotate=True):
+                     rotate=True, tile_tokens=1):
     bt = bpages * page_size
     kernel = functools.partial(
         _fused_rope_kernel, page_size=page_size, bpages=bpages,
         group=group, scale=scale, qblock=qblock, dtype=dtype,
-        window=window, read_only=read_only)
+        window=window, read_only=read_only, tile_tokens=tile_tokens)
     if not rotate:
         def kernel(*refs, rotating=kernel):
             # the seven scalar tables, q, the two pools and the two new
@@ -925,7 +931,7 @@ def _rope_tpad(t, page_size, qblock):
 def _fused_rope_impl(q, new_k, new_v, k_pages, v_pages, block_tables,
                      kv_lens, q_starts, q_lens, w_starts, w_flats,
                      w_ends, rope_sin, rope_cos, dump_page, scale,
-                     qblock, window=None, read_only=False):
+                     qblock, window=None, read_only=False, tile_tokens=1):
     """``dump_page`` is part of the fused programs' common signature
     and unused here: a step of this program that has nothing to write
     writes nothing. ``rope_sin`` and ``rope_cos`` None: nothing is
@@ -964,7 +970,7 @@ def _fused_rope_impl(q, new_k, new_v, k_pages, v_pages, block_tables,
                                                 w_starts, w_flats, w_ends)]
     call = _make_fused_rope(scale, page_size, bpages, qblock, group,
                             jnp.dtype(q.dtype), _interpret(), window,
-                            read_only, rotate)
+                            read_only, rotate, tile_tokens)
     out, kp, vp = call(qp, k_pages, v_pages, nk, nv, *tabs, tables,
                        *meta)
     with scope("attn_pack"):
@@ -1014,7 +1020,7 @@ def fused_ragged_paged_attention(q, new_k, new_v, k_pages, v_pages,
                                  dump_page, *, rope_sin, rope_cos,
                                  qblock, scale=None, k_scale=None,
                                  v_scale=None, window=None,
-                                 read_only=False):
+                                 read_only=False, tile_tokens=1):
     """Rope, KV page write and ragged paged attention in ONE kernel
     (see module docstring): ropes the packed PRE-rope ``q [T, H, D]``
     and ``new_k [T, Hk, D]`` by the per-dispatch ``rope_sin``/
@@ -1034,7 +1040,10 @@ def fused_ragged_paged_attention(q, new_k, new_v, k_pages, v_pages,
     walks the pages that hold them and no others. ``read_only``
     (float pools only): the pools already hold the dispatch's K/V rows
     (the layer that owns them ran before); ``new_k``/``new_v`` are not
-    read and the pools come back byte for byte. ``dump_page`` is a page id no live
+    read and the pools come back byte for byte. ``tile_tokens`` (float
+    pools only): the query tokens of the float program's small tile
+    (`small_tile`), the most a row other than a prompt chunk carries;
+    the answer is the same whatever it is. ``dump_page`` is a page id no live
     table references; the int8 program's steps with nothing to write
     dump there and its contents are undefined after the call.
     Tape-integrated but non-differentiable (serving path)."""
@@ -1067,7 +1076,8 @@ def fused_ragged_paged_attention(q, new_k, new_v, k_pages, v_pages,
                        v_scale) + rows, differentiable=False)
     return run_op("fused_rope_ragged_paged_attention",
                   functools.partial(_fused_rope_impl, window=window,
-                                    read_only=read_only, **static),
+                                    read_only=read_only,
+                                    tile_tokens=int(tile_tokens), **static),
                   (q, new_k, new_v, k_pages, v_pages) + rows,
                   differentiable=False)
 

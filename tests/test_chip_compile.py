@@ -224,7 +224,8 @@ def test_walk_compiles_at_the_code_cell(chip_compile, rows, tokens, qblock,
 # layers' ring tables (window 128, 65 slots of 41 pages) and, with no
 # rope tables (the NoPE full layers and the MTP module's), the whole-
 # context pool of the reason-mtp cell (16,385 pages, tables 769 wide),
-# both of its step shapes
+# both of its step shapes, with the small tile of its engine's verify
+# row (``spec_k = 1``: two tokens, 16 softmax rows)
 @pytest.mark.parametrize("kind", ["window", "nope"])
 @pytest.mark.parametrize("rows,tokens,qblock", [(80, 512, 32), (64, 64, 1)],
                          ids=["mixed", "decode"])
@@ -234,7 +235,7 @@ def test_walk_compiles_at_the_reason_mtp_cell(chip_compile, rows, tokens,
     pool = (65 * 41 if window else 16385, 8, 16, 128)
     fn = functools.partial(
         rpa._fused_rope_impl, dump_page=pool[0] - 1, scale=D ** -0.5,
-        qblock=qblock, window=128 if window else None)
+        qblock=qblock, window=128 if window else None, tile_tokens=2)
     specs = _ragged_specs(False, pool, rows, tokens, 769, 8)
     if not window:
         fn = functools.partial(fn, rope_sin=None, rope_cos=None)

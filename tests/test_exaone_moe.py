@@ -55,10 +55,14 @@ T = 64          # the tokens of a forward (one set of shapes for all)
 
 
 @pytest.fixture(scope="module")
-def setup():
-    """(cfg, family, model with the seeded weights, the weights)."""
+def setup(request):
+    """(cfg, family, model with the seeded weights, the weights): the
+    rehearsal's 8 query heads over 2 kv heads, or over the kv heads a
+    test hands in (``GROUPS``)."""
     cfg = bench.load_json("perfbench", "configs", NAME + ".json")
     bench.deep_update(cfg, cfg["rehearse"])
+    cfg["num_key_value_heads"] = getattr(request, "param",
+                                         cfg["num_key_value_heads"])
     fam = family.load(cfg, NAME)
     model = fam.build_model(cfg, "float32")
     model.eval()
@@ -328,6 +332,14 @@ def plain(setup):
     return out
 
 
+#: the drafting engine's query heads a kv head: the rehearsal's 4 (a
+#: verify row of two tokens is 8 softmax rows, one tile either way), and
+#: the published 8 (64 over 8), where the row's 16 take the float
+#: program's small tile only because it is sized for ``1 + spec_k``
+GROUPS = pytest.mark.parametrize("setup", [2, 1], indirect=True,
+                                 ids=["group4", "group8"])
+
+
 @pytest.fixture(scope="module")
 def drafting(setup):
     """ONE engine whose model's module drafts (``spec_k`` 1)."""
@@ -336,6 +348,7 @@ def drafting(setup):
     e.close()
 
 
+@GROUPS
 def test_engine_serves_the_reference_and_drafts_with_the_module(
         setup, plain, drafting):
     """Prompts in chunks of 8 beside verify rows, then 30 tokens: the
@@ -360,11 +373,12 @@ def test_engine_serves_the_reference_and_drafts_with_the_module(
         < GAP_TOL
     _assert_drafts_follow_the_reference(setup, seen, least=60)
     # three rings and the whole context a layer, then the module's pool
+    hk = cfg["num_key_value_heads"]
     assert e._drafter is model.mtp and e.ring_pages(16) == 7
     assert e._layer_pages == [[0, 1], [2, 3], [4, 5], [6, 7], [8, 9]]
-    assert [p.shape for p in e.k_pools] == [[5 * 7, 2, 8, 16]] * 6 \
-        + [[65, 2, 8, 16]] * 4
-    assert e.kv_bytes_per_token == 2 * 2 * 2 * 16 * 4
+    assert [p.shape for p in e.k_pools] == [[5 * 7, hk, 8, 16]] * 6 \
+        + [[65, hk, 8, 16]] * 4
+    assert e.kv_bytes_per_token == 2 * 2 * hk * 16 * 4
     assert e.alloc.free_pages == e.alloc.num_pages
     assert e.alloc.double_free_count == 0
     disp = [ev["args"] for ev in trace.get_events()
@@ -374,10 +388,13 @@ def test_engine_serves_the_reference_and_drafts_with_the_module(
     assert proposed > 60
     assert sum(a["spec_proposed"] for a in disp) == proposed
     assert sum(a["spec_accepted"] for a in disp) == accepted
+    # every verify row is one the attention kernel computes on its tile
+    assert all(a["tile_rows"] >= a["decode_rows"] for a in disp)
     with pytest.raises(UnsupportedServingFeature, match="single steps"):
         e._ensure_scan_compiled(4)
 
 
+@GROUPS
 def test_accepted_and_rejected_drafts_keep_every_pool_exact(
         setup, plain, drafting):
     """A drafter that proposes the reference's own next token on two

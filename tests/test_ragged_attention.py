@@ -341,10 +341,11 @@ def _assert_walk_parity(args, kw):
     """The program against the reference: output to rounding, pools
     (and an int8 pool's sidecars) bitwise but for the trash page, padded
     and inactive rows zero. Returns the program's ``(out, k_pages,
-    v_pages[, k_scale, v_scale])``."""
+    v_pages[, k_scale, v_scale])``. The reference has no tile: it
+    is not handed ``tile_tokens``."""
     got = list(map(_unwrap, RPA.fused_ragged_paged_attention(*args, **kw)))
     want = list(map(np.asarray, RPA.fused_ragged_paged_attention_xla(
-        *args, **kw)))
+        *args, **{k: v for k, v in kw.items() if k != "tile_tokens"})))
     assert len(got) == len(want) == (5 if "k_scale" in kw else 3)
     _assert_parity(jnp.asarray(got[0]), jnp.asarray(want[0]))
     live = [i for i in range(got[1].shape[0]) if i != args[-1]]
@@ -859,9 +860,12 @@ def _frozen_fused_rope_kernel(tables_ref, kv_lens_ref, q_starts_ref,
 
 
 def _whole_block(monkeypatch, args, kw):
-    """What the frozen whole-block body gives for a dispatch."""
-    monkeypatch.setattr(RPA, "_fused_rope_kernel",
-                        _frozen_fused_rope_kernel)
+    """What the frozen whole-block body gives for a dispatch (which
+    knows no tile, so it takes none)."""
+    def frozen(*refs, tile_tokens, **static):
+        return _frozen_fused_rope_kernel(*refs, **static)
+
+    monkeypatch.setattr(RPA, "_fused_rope_kernel", frozen)
     RPA._make_fused_rope.cache_clear()
     try:
         return list(map(_unwrap, RPA.fused_ragged_paged_attention(
@@ -888,7 +892,8 @@ def _assert_sized_equals_whole_block(monkeypatch, args, kw, group):
     for g, w in zip(got[1:], want[1:]):
         assert np.array_equal(g, w, equal_nan=True)     # a poisoned pool
     ql = np.asarray(args[8])
-    on_tile = (ql > 0) & (ql * group <= RPA.small_tile(group))
+    tile = RPA.small_tile(group, kw.get("tile_tokens", 1))
+    on_tile = (ql > 0) & (ql * group <= tile)
     assert on_tile.any() and (~on_tile & (ql > 0)).any()
     assert np.array_equal(got[0][~on_tile], want[0][~on_tile])
     if not bool(jnp.any(kw["rope_sin"] != 0)):
@@ -903,14 +908,29 @@ def _no_rotation(kw):
                 rope_cos=jnp.ones_like(kw["rope_cos"]))
 
 
-# decode rows (one far past a window, one short), a 2-token row, chunk
-# rows of 3, 31 and 32 tokens, a sequence whose second row of the
-# dispatch is one token (it owns the write-back of both), an inactive row
-SIZED_ROWS = [(5 * BLOCK + 3, [1]), (17, [1]), (40, [2]), (9, [3]),
-              (2 * BLOCK - 5, [31]), (3, [32]), (BLOCK + 6, [32, 1]),
-              (0, [])]
+# decode rows (one far past a window, one short), 2-token rows (a
+# verify row of a token and its draft: one short, one past a window and
+# across a block edge), chunk rows of 3, 31 and 32 tokens, a sequence
+# whose second row of the dispatch is one token (it owns the write-back
+# of both), an inactive row
+SIZED_ROWS = [(5 * BLOCK + 3, [1]), (17, [1]), (40, [2]),
+              (5 * BLOCK - 1, [2]), (9, [3]), (2 * BLOCK - 5, [31]),
+              (3, [32]), (BLOCK + 6, [32, 1]), (0, [])]
+
+# the tile's query tokens: a decode row's one, and a speculative
+# engine's ``1 + spec_k`` at ``spec_k = 1`` (at a group of 8, 16 rows)
+TILE_TOKENS = pytest.mark.parametrize("tile_tokens", [1, 2],
+                                      ids=["tile1", "tile2"])
 
 
+@pytest.mark.parametrize("group,tokens,rows", [
+    (8, 1, 8), (8, 2, 16), (4, 1, 8), (4, 2, 8), (4, 3, 16), (16, 1, 16)])
+def test_small_tile_holds_the_rows_of_its_tokens(group, tokens, rows):
+    assert RPA.small_tile(group, tokens) == rows
+    assert RPA.small_tile(group) == RPA.small_tile(group, 1)
+
+
+@TILE_TOKENS
 @pytest.mark.parametrize("rotate", [True, False],
                          ids=["rope", "no_rope"])
 @pytest.mark.parametrize("read_only", [False, True],
@@ -919,26 +939,30 @@ SIZED_ROWS = [(5 * BLOCK + 3, [1]), (17, [1]), (40, [2]), (9, [3]),
                          ids=["whole", "window"])
 @pytest.mark.parametrize("group", [4, 8])
 def test_small_tile_is_bitwise_the_whole_block(monkeypatch, group, window,
-                                               read_only, rotate):
+                                               read_only, rotate,
+                                               tile_tokens):
     args, kw = _walk_case(np.random.RandomState(36), SIZED_ROWS, 90, 32,
                           g=group)
     kw = dict(kw if rotate else _no_rotation(kw), window=window,
-              read_only=read_only)
+              read_only=read_only, tile_tokens=tile_tokens)
     got = _assert_sized_equals_whole_block(monkeypatch, args, kw, group)
     if read_only:
         assert all(np.array_equal(g, b)
                    for g, b in zip(got[1:], _pools_before(args, kw)))
     else:
         kw.pop("read_only")
+        kw.pop("tile_tokens")
         ref = RPA.fused_ragged_paged_attention_xla(*args, **kw)
         _assert_parity(jnp.asarray(got[0]), ref[0])
 
 
+@TILE_TOKENS
 @pytest.mark.parametrize("rotate", [True, False],
                          ids=["rope", "no_rope"])
 @pytest.mark.parametrize("group", [4, 8])
 def test_small_tile_across_a_block_edge_into_its_write_span(monkeypatch,
-                                                            group, rotate):
+                                                            group, rotate,
+                                                            tile_tokens):
     """A decode row whose context crosses a block edge INTO its own
     write span (its new token is the next block's first slot), one
     whose new token is a block's last slot, and a 2-token row whose
@@ -949,7 +973,8 @@ def test_small_tile_across_a_block_edge_into_its_write_span(monkeypatch,
     for poison in (False, True):
         args, kw = _walk_case(np.random.RandomState(37), seqs, 40, 8,
                               poison=poison, g=group)
-        kw = kw if rotate else _no_rotation(kw)
+        kw = dict(kw if rotate else _no_rotation(kw),
+                  tile_tokens=tile_tokens)
         got = _assert_sized_equals_whole_block(monkeypatch, args, kw,
                                                group)
         if poison:      # nothing past a context or off a table is used

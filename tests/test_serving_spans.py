@@ -584,28 +584,43 @@ def test_a_model_without_states_sets_no_slot_counters(served, model):
 
 # ---------------------------------------------------------------------------
 # the attention kernel's small tile (ISSUE 36): how often it engages, and
-# that the engine compiles, registers and prewarms what it did before
+# that the engine compiles, registers and prewarms what it did before;
+# sized for a speculative engine's verify row too
 GEOMETRIES = ["mistral", "hybrid"]
+TILE_GEOMETRIES = GEOMETRIES + ["verify"]
+#: (query heads a kv head, tokens of the tile) of each geometry's engine
+TILE = {"mistral": (4, 1), "hybrid": (4, 1), "verify": (8, 2)}
 
 
 def _geometry(name):
     """A tiny model with a cell's attention geometry: 4 query heads a
-    kv head (Mistral-7B's 32 over 8), or the hybrid family's layers
-    (pairs of key heads are one head of the cache: 8 padded query heads
-    over 2, states, windows, a shared pool). Returns (model, kernel
-    variants its step programs hold: whole context, window, read-only)."""
+    kv head (Mistral-7B's 32 over 8), the hybrid family's layers (pairs
+    of key heads are one head of the cache: 8 padded query heads over 2,
+    states, windows, a shared pool), or K-EXAONE's family at a group of
+    8 (8 query heads over one kv head: window, NoPE full and the MTP
+    module's layers) whose module drafts (``spec_k`` 1), so that every
+    decode row is a verify row of two tokens. Returns (model, kernel
+    variants its step programs hold: whole context, window, read-only,
+    no rope; the engine's keywords)."""
     paddle.seed(0)
+    kw = {}
     if name == "mistral":
         m = LlamaForCausalLM(LlamaConfig(
             vocab_size=256, hidden_size=64, intermediate_size=128,
             num_hidden_layers=2, num_attention_heads=8,
             num_key_value_heads=2, max_position_embeddings=256))
         variants = 1
-    else:
+    elif name == "hybrid":
         from paddle_tpu.models import SambaYForCausalLM, tiny_sambay_config
         m, variants = SambaYForCausalLM(tiny_sambay_config()), 3
+    else:
+        from paddle_tpu.models.exaone_moe import (ExaoneMoeForCausalLM,
+                                                  tiny_exaone_moe_config)
+        m = ExaoneMoeForCausalLM(tiny_exaone_moe_config(
+            num_key_value_heads=1))
+        variants, kw = 2, dict(spec_k=1)
     m.eval()
-    return m, variants
+    return m, variants, kw
 
 
 def _serve_three(engine):
@@ -617,23 +632,26 @@ def _serve_three(engine):
         engine.step()
 
 
-@pytest.mark.parametrize("geometry", GEOMETRIES)
+@pytest.mark.parametrize("geometry", TILE_GEOMETRIES)
 def test_dispatch_says_how_many_rows_take_the_small_tile(geometry):
     """``serving.dispatch`` carries ``tile_rows``, the dispatch's rows
     whose query tokens fit the float ragged program's small tile (two
-    tokens at 4 query heads a kv head), counted here from the scheduled
-    rows' own lengths: every row of a decode-only dispatch, the decode
-    rows and the short chunk tails of a mixed one."""
+    tokens at 4 query heads a kv head; at 8, one token's rows, or a
+    speculative engine's verify row of two), counted here from the
+    scheduled rows' own lengths: every row of a decode-only dispatch,
+    the decode rows and the short chunk tails of a mixed one."""
     om.default_registry().clear()
-    m, _ = _geometry(geometry)
-    engine = _engine(m, chunk_block=8)
-    group, want = 4, {}
-    assert RPA.small_tile(group) == 8
+    m, _, kw = _geometry(geometry)
+    engine = _engine(m, chunk_block=8, **kw)
+    (group, tokens), want, verify = TILE[geometry], {}, []
+    tile = RPA.small_tile(group, tokens)
+    assert tile == 8 * tokens
     rows_of = engine._dispatch_rows
 
     def spy_rows(rows, cow):
         want[engine._dispatch_count - 1] = sum(
-            0 < n * group <= 8 for _, _, _, n, _, _ in rows)
+            0 < n * group <= tile for _, _, _, n, _, _ in rows)
+        verify.extend(n for _, _, _, n, _, dec in rows if dec and n > 1)
         return rows_of(rows, cow)
 
     engine._dispatch_rows = spy_rows
@@ -652,6 +670,8 @@ def test_dispatch_says_how_many_rows_take_the_small_tile(geometry):
     # a mixed dispatch holds rows of both sizes
     assert any(0 < d["args"]["tile_rows"] < d["args"]["rows"]
                for d in disp if d["args"]["kind"] == "mixed")
+    # the speculative engine's verify rows, each on the tile
+    assert set(verify) == ({2} if kw else set())
     engine.close()
 
 
@@ -663,25 +683,33 @@ LAYOUT_FIELDS = ["tokens", "pos", "flat_idx", "last_idx", "tables",
                  "slot_ids", "slot_vals", "cmodes", "prev_idx"]
 
 
-@pytest.mark.parametrize("geometry", GEOMETRIES)
+#: the fields a geometry's layout adds to them: a model with rings or
+#: states names each row's slot, a drafting one the token after each
+#: packed prompt position
+MORE_FIELDS = {"mistral": [], "hybrid": ["slots"],
+               "verify": ["slots", "next_tok"]}
+
+
+@pytest.mark.parametrize("geometry", TILE_GEOMETRIES)
 def test_the_small_tile_adds_no_compiled_program(geometry, tmp_path,
                                                  monkeypatch):
     """The kernel picks a row's size from ``q_lens`` inside ONE
     program: the engine still has its two program shapes, its layout
     the same fields, the step programs one kernel a variant and shape
-    (the layers share it), the shape registry the same two entries,
+    (the layers share it; a speculative engine's tile of two tokens is
+    no variant of its own), the shape registry the same two entries,
     and an engine of equal geometry prewarms exactly those."""
     monkeypatch.setenv("PADDLE_TPU_SHAPE_REGISTRY",
                        str(tmp_path / "serving_shapes.json"))
     monkeypatch.setattr(cw, "_shape_registry", None)
-    m, variants = _geometry(geometry)
+    m, variants, kw = _geometry(geometry)
     made = {}
     make = RPA._make_fused_rope
     make.cache_clear()
     monkeypatch.setattr(
         RPA, "_make_fused_rope",
         lambda *key: made.setdefault(key, make(*key)))
-    engine = _engine(m, chunk_block=8)
+    engine = _engine(m, chunk_block=8, **kw)
     assert engine._cache_dir is not None
     _serve_three(engine)
     assert sorted(engine._layouts) == [4, 16]
@@ -689,13 +717,14 @@ def test_the_small_tile_adds_no_compiled_program(geometry, tmp_path,
     assert engine._warm_dispatches == 2 and engine.prewarmed is None
     for t_cap in (4, 16):
         fields = [f[0] for f in engine._dispatch_layout(t_cap).fields]
-        assert fields == LAYOUT_FIELDS + ["slots"] * (geometry == "hybrid")
+        assert fields == LAYOUT_FIELDS + MORE_FIELDS[geometry]
     # a kernel a variant and program shape, none for a size of tile
     assert len(made) == 2 * variants
+    assert {key[-1] for key in made} == {TILE[geometry][1]}
     assert cw.shape_registry().lookup(engine._shape_key) \
         == {"mixed": [4, 16]}
     engine.close()
-    other = _engine(m, chunk_block=8)
+    other = _engine(m, chunk_block=8, **kw)
     assert other._shape_key == engine._shape_key
     assert other.prewarm() == other.prewarmed \
         == {"mixed": [4, 16], "scan": []}
@@ -715,7 +744,7 @@ def test_running_ahead_adds_no_compiled_program(geometry):
     `serving.build` says it handed over is that layout's bytes, the new
     field among them."""
     om.default_registry().clear()
-    m, _ = _geometry(geometry)
+    m, _, _ = _geometry(geometry)
     engine = _engine(m, chunk_block=8)
     engine.prewarm(mixed=[16, 4])
     reqs = [Request(list(range(1, n + 1)), max_new_tokens=12)
